@@ -1,7 +1,9 @@
-# Common workflows.  The test harness self-configures a hermetic 8-device
-# CPU mesh regardless of the environment (see tests/conftest.py).
+# Common workflows.  The test harness forces the CPU backend and 8
+# simulated devices whatever the machine has (see tests/conftest.py);
+# every bench-*/smoke/chaos target below is a CPU gate too.  Only
+# `bench` and `chip-smoke` use the device JAX gives.
 
-.PHONY: test soak bench bench-micro bench-mesh bench-ingest bench-serve bench-delta bench-wal bench-view bench-opt bench-macro trace-smoke obs-smoke skew-smoke multiway-smoke fuse-smoke chaos check dryrun example coldcheck lint analyze plan-cert asan
+.PHONY: test soak chip-smoke bench bench-micro bench-mesh bench-ingest bench-serve bench-delta bench-wal bench-view bench-opt bench-macro trace-smoke obs-smoke skew-smoke multiway-smoke fuse-smoke chaos check dryrun example coldcheck lint analyze plan-cert asan
 
 test:
 	python -m pytest tests/ -x -q
@@ -67,8 +69,16 @@ asan:
 soak:
 	CSVPLUS_HYPOTHESIS_EXAMPLES=1000 python -m pytest tests/ -q
 
+# One process on the device JAX gives, named in the record; refuses a
+# non-TPU backend unless the caller set JAX_PLATFORMS=cpu; exits nonzero
+# when a tier fails.
 bench:
 	python bench.py
+
+# The main path once on the chip, every leg checked (run it through the
+# chip tool); exits nonzero without a TPU.
+chip-smoke:
+	python chip_smoke.py
 
 # Seconds-long CPU smoke of the batched point-lookup engine: one JSON
 # line with batched find_many lookups/s on the 1M-row big-index shape;
@@ -79,9 +89,8 @@ bench-micro:
 # Minutes-long gate of the SHARDED north-star pipeline (virtual 8-device
 # CPU mesh, 10M rows by default): one JSON line with the warm sharded
 # 3-way join rows/s; exits nonzero on a >2x regression vs
-# bench_mesh_floor.json.  The checked-in record artifact
-# (NORTHSTAR_MESH_r06.json) is only (re)written by record-tier runs:
-#   CSVPLUS_BENCH_MESH_ROWS=100000000 make bench-mesh
+# bench_mesh_floor.json.  An artifact is written only where
+# CSVPLUS_BENCH_MESH_OUT names a path.
 # A second SKEW tier then reruns the pipeline over a Zipf(s=1.1)
 # orders stream, skew-aware vs CSVPLUS_JOIN_SKEW=0 in the same child,
 # gated by warm_join_rows_per_sec_zipf with the same half-floor rule

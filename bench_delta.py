@@ -247,11 +247,10 @@ def _zero_recompile_gate(mi, probes) -> dict:
         for _ in range(3):
             mi.find_rows_many(norm)
     w.assert_zero("bench-delta warm post-compaction lookups")
-    return {"observable": bool(w.observable()), "recompiles": 0}
+    return {"recompiles": 0}
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     from csvplus_tpu.obs.memory import host_header

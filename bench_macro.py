@@ -1,6 +1,7 @@
-"""TPC-H-flavored macro-bench: named query chains through the PlanCache,
-optimizer-on vs ``CSVPLUS_FUSE=0`` in the SAME child over identical
-bytes (ISSUE 19, ROADMAP item 1's open workload).
+"""CPU correctness gate (re-execs onto 8 simulated CPU devices before
+JAX is touched): the TPC-H-flavored macro-bench — named query chains
+through the PlanCache, optimizer-on vs ``CSVPLUS_FUSE=0`` in the SAME
+child over identical bytes (ISSUE 19, ROADMAP item 1's open workload).
 
 Five named queries — multi-join star shapes, filters, projection, and
 a positional ``Top`` terminal (the plan vocabulary's order-sensitive

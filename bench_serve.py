@@ -308,7 +308,6 @@ def _overload_scenario(idx, probes) -> dict:
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     from bench import zipf_probe_values

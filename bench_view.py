@@ -189,7 +189,6 @@ def _read_scenario(view, n_reads: int) -> dict:
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     from csvplus_tpu import plan as P

@@ -87,10 +87,6 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "no transfer: len() reads host Python lists of shard segments "
         "(run/pieces), never a device array"
     ),
-    "ingest.py:link_rtt_ms": (
-        "deliberate: the RTT probe IS a measured sync (8-element array, "
-        "3 samples, cached once per process); no transfer of table data"
-    ),
     "table.py:has_absent": (
         "deliberate cached scalar presence probe, once per column "
         "lifetime; no transfer of cell data"

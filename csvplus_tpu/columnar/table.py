@@ -36,17 +36,16 @@ from ..utils.env import env_int
 ABSENT = np.int32(-1)
 
 
-def default_device(device: Optional[str] = None):
-    """Resolve a device spec ("tpu", "cpu", None=default) to a jax.Device."""
-    if device is None or isinstance(device, str) and device == "default":
+def default_device(device=None):
+    """Resolve a device spec to a jax.Device: ``None`` is the default
+    backend's first device, a platform name ("tpu", "cpu") is that
+    platform's first device, a jax.Device passes through.  A named
+    platform JAX cannot supply raises (jax's own RuntimeError) — a
+    caller that asked for a chip never runs on the CPU unannounced."""
+    if device is None:
         return jax.devices()[0]
     if isinstance(device, str):
-        try:
-            return jax.devices(device)[0]
-        except RuntimeError:
-            # requested backend not present (e.g. "tpu" in a CPU test run):
-            # fall back to the default device so pipelines still work
-            return jax.devices()[0]
+        return jax.devices(device)[0]
     return device  # already a jax.Device
 
 
@@ -828,10 +827,9 @@ class DeviceTable:
         """Force completion of every column with ONE scalar round trip.
 
         Per-column ``block_until_ready`` costs one readiness ping per
-        buffer; over a remote/tunneled backend each ping is a network
-        round trip.  Instead, dispatch a trivial reduction that depends
-        on every code array and sync its single scalar — it cannot
-        complete before all inputs have.
+        buffer.  Instead, dispatch a trivial reduction that depends on
+        every code array and sync its single scalar — it cannot complete
+        before all inputs have.
         """
         if self.already_forced:
             return self

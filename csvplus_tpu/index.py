@@ -644,7 +644,7 @@ class Index:
 
     # -- device hook -------------------------------------------------------
 
-    def on_device(self, device: str = "tpu") -> "Index":
+    def on_device(self, device=None) -> "Index":
         """Attach an HBM-resident columnar copy of this index so joins and
         finds against it run as device kernels."""
         from .columnar.ingest import index_to_device

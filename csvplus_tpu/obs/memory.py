@@ -16,9 +16,9 @@ was only caught because one bench script happened to probe
   writes the observed watermark into the span's attrs on exit, so a
   per-stage RSS column appears in the same tables/traces as the wall
   times — exactly the per-stage cost accounting fusion decisions need;
-* :func:`host_header` — the (host_cpus, device_count, platform) triple
-  every bench artifact must carry (the r07/r08 postmortems both needed
-  them and only some artifacts had them).
+* :func:`host_header` — the (host_cpus, device_count, platform,
+  device_kind) facts every bench artifact must carry (the r07/r08
+  postmortems both needed them and only some artifacts had them).
 """
 
 from __future__ import annotations
@@ -82,18 +82,16 @@ def device_memory_stats() -> Dict[str, Dict[str, int]]:
 
 
 def host_header() -> Dict[str, Any]:
-    """The artifact header facts every bench record must carry."""
-    try:
-        import jax
+    """The artifact header facts every bench record must carry: the
+    device as jax reports it, and the host's core count."""
+    import jax
 
-        devices = jax.device_count()
-        platform = jax.default_backend()
-    except Exception:
-        devices, platform = None, None
+    dev = jax.devices()[0]
     return {
         "host_cpus": os.cpu_count() or 1,
-        "jax_device_count": devices,
-        "platform": platform,
+        "jax_device_count": jax.device_count(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
     }
 
 

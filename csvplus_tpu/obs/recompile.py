@@ -22,17 +22,15 @@ snapshot/delta/assert-zero workflow the benches and tests use::
         ...warm passes...
     w.assert_zero()        # raises listing every kernel that lowered
 
-``_cache_size`` is jax-private; :func:`compile_counts` degrades to
-``None`` per kernel when the running jax build lacks it, and
-:class:`RecompileWatch` then treats that kernel as unobservable rather
-than failing the run (record-or-postmortem, not a hard dependency on a
-private API).
+``_cache_size`` is jax-private but present on the installed jax; a jax
+that drops it fails here loudly rather than letting ``assert_zero``
+pass on nothing.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict
 
 _REGISTRY_LOCK = threading.Lock()
 _KERNELS: Dict[str, Any] = {}
@@ -57,17 +55,9 @@ def registered_kernels() -> Dict[str, Any]:
         return dict(_KERNELS)
 
 
-def _cache_size(fn: Any) -> Optional[int]:
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return None
-
-
-def compile_counts() -> Dict[str, Optional[int]]:
-    """Per-kernel count of distinct lowerings jax currently caches
-    (``None`` when the kernel's count is unobservable on this jax)."""
-    return {name: _cache_size(fn) for name, fn in registered_kernels().items()}
+def compile_counts() -> Dict[str, int]:
+    """Per-kernel count of distinct lowerings jax currently caches."""
+    return {name: int(fn._cache_size()) for name, fn in registered_kernels().items()}
 
 
 class RecompileWatch:
@@ -83,7 +73,7 @@ class RecompileWatch:
 
     def __init__(self, plancache=None):
         self._plancache = plancache
-        self._before: Dict[str, Optional[int]] = {}
+        self._before: Dict[str, int] = {}
         self._plan_before = 0
 
     def __enter__(self) -> "RecompileWatch":
@@ -101,11 +91,7 @@ class RecompileWatch:
         out: Dict[str, int] = {}
         after = compile_counts()
         for name, n in after.items():
-            if n is None:
-                continue
-            base = self._before.get(name)
-            if base is None:
-                base = 0 if name not in self._before else n
+            base = self._before.get(name, 0)
             if n > base:
                 out[name] = n - base
         if self._plancache is not None:
@@ -113,11 +99,6 @@ class RecompileWatch:
             if grew > 0:
                 out["plancache"] = grew
         return out
-
-    def observable(self) -> bool:
-        """False when no registered kernel exposes a cache size (the
-        invariant cannot be checked on this jax build)."""
-        return any(v is not None for v in compile_counts().values())
 
     def assert_zero(self, context: str = "warm pass") -> None:
         d = self.delta()

@@ -87,7 +87,8 @@ def fused_equality_mask(
     every column streams through VMEM exactly once regardless of how
     many values it is compared against.  Returns a bool[nrows] device
     array, or None when the predicate shape doesn't fit this kernel
-    (caller uses the jnp path).
+    (caller uses the jnp path).  A kernel that fails to compile or run
+    raises: it is an error, not a reason to take the other path.
     """
     k = len(code_arrays)
     if k == 0 or k > MAX_COLS or nrows == 0:
@@ -104,8 +105,4 @@ def fused_equality_mask(
             # pad value -2 never equals a real code (-1 = absent, >=0 real)
             c = jnp.concatenate([c, jnp.full(pad, -2, dtype=jnp.int32)])
         cols.append(c)
-    try:
-        mask = _fused_mask_call(mode, norm, _use_interpret(), *cols)
-    except Exception:  # pallas unavailable for this backend/shape
-        return None
-    return mask[:nrows]
+    return _fused_mask_call(mode, norm, _use_interpret(), *cols)[:nrows]

@@ -57,10 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # moved out of experimental in newer jax
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import row_spec
@@ -91,14 +88,7 @@ def _dsort_shard_kernel(
     # over the axes in mesh-major order (mesh.row_spec)
     flat = jnp.int32(0)
     for ax in axes:
-        # lax.axis_size is absent from older jax; psum of 1 over the
-        # axis is the same static size
-        size = (
-            lax.axis_size(ax)
-            if hasattr(lax, "axis_size")
-            else lax.psum(jnp.int32(1), ax)
-        )
-        flat = flat * size + lax.axis_index(ax)
+        flat = flat * lax.axis_size(ax) + lax.axis_index(ax)
     my_pos = flat * m + jnp.arange(m, dtype=jnp.int32)
     valid_in = (my_pos < n_true).astype(jnp.int32)
 

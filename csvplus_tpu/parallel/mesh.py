@@ -22,10 +22,17 @@ SLICE_AXIS = "slice"
 
 
 def make_mesh(n_devices: Optional[int] = None, devices: Optional[Sequence] = None) -> Mesh:
-    """A 1-D mesh over the first *n_devices* devices (default: all)."""
+    """A 1-D mesh over the first *n_devices* devices (default: all).
+    Asking for more devices than exist raises — a short mesh would run
+    the pipeline at a sharding the caller did not ask for."""
     if devices is None:
         devices = jax.devices()
         if n_devices is not None:
+            if n_devices > len(devices):
+                raise ValueError(
+                    f"make_mesh({n_devices}): only {len(devices)} "
+                    f"{devices[0].platform} device(s) exist"
+                )
             devices = devices[:n_devices]
     return Mesh(np.array(devices), (AXIS,))
 

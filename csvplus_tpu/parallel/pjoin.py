@@ -66,10 +66,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:  # moved out of experimental in newer jax
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.recompile import register_kernel

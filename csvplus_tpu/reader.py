@@ -292,13 +292,16 @@ class Reader:
 
     # -- device ingestion hook (M2) ----------------------------------------
 
-    def on_device(self, device: str = "tpu", shards=None, mesh=None, **opts):
+    def on_device(self, device=None, shards=None, mesh=None, **opts):
         """Parse this CSV into an HBM-resident columnar DeviceTable and
         return a plan-capable DataSource over it.
 
         This is the rebuild's ``FromFile(...).OnDevice("tpu")`` entry
-        point from BASELINE.json's north star.  ``shards=N`` lays the
-        columns row-sharded over an N-device mesh (BASELINE config 5).
+        point from BASELINE.json's north star.  *device* is a platform
+        name or jax.Device; ``None`` takes the default backend's first
+        device, and a named platform JAX cannot supply raises.
+        ``shards=N`` lays the columns row-sharded over an N-device mesh
+        (BASELINE config 5).
 
         NOTE: the file is ingested as a SNAPSHOT at call time; later
         file modifications are not observed.  The host path re-opens the

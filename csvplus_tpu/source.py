@@ -373,7 +373,7 @@ class DataSource:
     # -- device migration --------------------------------------------------
 
     def on_device(
-        self, device: str = "tpu", shards: "int | None" = None, mesh=None
+        self, device=None, shards: "int | None" = None, mesh=None
     ) -> "DataSource":
         """Materialize this source into an HBM-resident columnar table and
         return a plan-capable DataSource over it.

@@ -61,9 +61,7 @@ _env("CSVPLUS_NATIVE_SO", "str", "_scanner.so",
 _env("CSVPLUS_NATIVE_CFLAGS", "str", "(empty)",
      "Extra g++ flags (space-split) appended to the native build.")
 _env("CSVPLUS_DEVICE_PARSE", "flag", "(auto)",
-     "1/0 forces the on-device parse tier on/off; unset = RTT probe.")
-_env("CSVPLUS_DEVICE_PARSE_MAX_RTT_MS", "float", "20.0",
-     "RTT probe threshold above which device parse is disabled.")
+     "1/0 forces the on-device parse tier on/off; unset = on for a non-CPU backend.")
 
 # -- ops / parallel ---------------------------------------------------------
 _env("CSVPLUS_DSORT_MIN_ROWS", "int", "1000000",

@@ -982,18 +982,40 @@ def test_device_table_sync_returns_self():
     assert empty.sync() is empty
 
 
-def test_link_rtt_probe_and_tier_gate(monkeypatch):
-    """The ingest tier gate: device parse stays off over a high-latency
-    link unless CSVPLUS_DEVICE_PARSE=1 forces it."""
+def test_device_spec_is_strict():
+    """None is the default backend's first device; a named platform JAX
+    cannot supply raises instead of quietly becoming the CPU."""
+    import jax
+
+    from csvplus_tpu.columnar.table import DeviceTable, default_device
+
+    assert default_device(None) == jax.devices()[0]
+    assert default_device("cpu").platform == "cpu"
+    assert default_device(jax.devices()[3]) == jax.devices()[3]
+    with pytest.raises(RuntimeError):
+        default_device("tpu")
+    with pytest.raises(RuntimeError):
+        DeviceTable.from_pylists({"a": ["x"]}, device="tpu")
+
+
+def test_on_device_tpu_raises_without_a_tpu(people_csv):
+    from csvplus_tpu import FromFile, Take
+
+    with pytest.raises(RuntimeError):
+        FromFile(people_csv).OnDevice("tpu")
+    with pytest.raises(RuntimeError):
+        Take(FromFile(people_csv)).OnDevice("tpu")
+    assert FromFile(people_csv).OnDevice().plan.table.nrows == 120
+
+
+def test_device_parse_tier_gate(monkeypatch):
+    """The ingest tier gate is a plain platform test: off on the CPU
+    backend, and CSVPLUS_DEVICE_PARSE forces it either way."""
     from csvplus_tpu.columnar import ingest
 
     monkeypatch.delenv("CSVPLUS_DEVICE_PARSE", raising=False)
-    rtt = ingest.link_rtt_ms()
-    assert rtt >= 0.0
-    monkeypatch.setattr(ingest, "_link_rtt_cache", [1000.0])
-    import jax
-
-    if jax.default_backend() != "cpu":
-        assert not ingest._device_parse_enabled()
+    assert not ingest._device_parse_enabled()  # conftest forces CPU
     monkeypatch.setenv("CSVPLUS_DEVICE_PARSE", "1")
     assert ingest._device_parse_enabled()
+    monkeypatch.setenv("CSVPLUS_DEVICE_PARSE", "0")
+    assert not ingest._device_parse_enabled()

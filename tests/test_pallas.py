@@ -85,3 +85,19 @@ def test_in_list_grouping_streams_column_once(people_csv):
         Like({"surname": "Jones"}),  # duplicate value, same column
     )
     assert dev.filter(mixed).to_rows() == host.filter(mixed).to_rows()
+
+
+def test_a_kernel_failure_is_an_error_not_a_fallback(monkeypatch):
+    """A Pallas compile or run failure propagates; returning None would
+    hand the predicate to the jnp path without a word."""
+    from csvplus_tpu.ops import pallas_mask
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile the kernel")
+
+    monkeypatch.setattr(pallas_mask, "_fused_mask_call", broken)
+    a = jnp.asarray([0, 1, 2, 1], dtype=jnp.int32)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        fused_equality_mask([a, a], [1, 1], 4)
+    # the shape-doesn't-fit early returns stay
+    assert fused_equality_mask([], [], 4) is None

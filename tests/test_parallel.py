@@ -25,6 +25,18 @@ def test_eight_devices_available():
     assert len(jax.devices()) == 8
 
 
+def test_make_mesh_refuses_more_shards_than_devices(people_csv):
+    """8 simulated devices: a 9-shard mesh raises instead of slicing to
+    a shorter mesh the caller did not ask for."""
+    assert make_mesh(8).devices.size == 8 and make_mesh(4).devices.size == 4
+    with pytest.raises(ValueError, match="only 8"):
+        make_mesh(9)
+    from csvplus_tpu import FromFile
+
+    with pytest.raises(ValueError, match="only 8"):
+        FromFile(people_csv).OnDevice(shards=9)
+
+
 def test_sharded_table_roundtrip(people_csv, mesh):
     """with_sharding (the one sharded-table abstraction) pads to shard
     divisibility without leaking padding into results."""
