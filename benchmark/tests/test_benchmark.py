@@ -1,0 +1,236 @@
+"""The benchmark's own tests: run by hand and in rehearsal, on the CPU,
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
+
+(not under ``tests/``: they belong to the yardstick).  Every cell runs
+end to end with ``--rehearse-cpu`` at 200K rows; no number they see is a
+device number.
+"""
+
+from __future__ import annotations
+
+import io
+import json
+import os
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+REPO = os.path.dirname(BENCH)
+sys.path[:0] = [BENCH, HERE]
+
+import run  # noqa: E402
+import tampers  # noqa: E402
+from readers import device_trace  # noqa: E402
+
+ROWS = "200000"
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+
+
+@pytest.fixture(autouse=True)
+def small_mirror_cap(monkeypatch):
+    """At rehearsal size every index is under the 16M mirror cap, and the
+    serve cell rightly refuses that; the rehearsal lowers the cap in this
+    process (no environment variable) so that the device path runs."""
+    from csvplus_tpu.ops.join import DeviceIndex
+
+    monkeypatch.setattr(DeviceIndex, "POINT_MIRROR_MAX_KEYS", 1000)
+
+
+def rehearse(cell: str, seed: int, trace: int = 0, tamper=None, seconds="1.5"):
+    out = io.StringIO()
+    rc = run.main(
+        ["--workload", cell, "--seed", str(seed), "--seconds", seconds, "--trace", str(trace),
+         "--rehearse-cpu", "--rehearse-rows", ROWS],
+        out=out, tamper=tamper,
+    )
+    lines = out.getvalue().strip().splitlines()
+    return rc, lines, json.loads(lines[-1])
+
+
+def cells_of(driver: str) -> list:
+    return [c for c in CELLS if run.load_json("workloads", f"{c}.json")["driver"] == driver]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_end_to_end_reports_its_end_to_end_metrics(cell):
+    rc, lines, result = rehearse(cell, 2_200_000_000 + len(cell))
+    assert rc == 0
+    assert set(result) == CONTRACT_KEYS
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    want = {
+        m["name"] for m in BENCHMARK["end_to_end"]
+        if cell in m.get("workloads", CELLS)
+    }
+    assert set(result["metrics"]) == want
+    units = {m["name"]: m["unit"] for m in BENCHMARK["end_to_end"]}
+    for name, m in result["metrics"].items():
+        assert m["unit"] == units[name] and m["value"] > 0
+    assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert "REHEARSAL-ON-CPU" in lines[0]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_run_reports_per_layer_metrics_that_list_the_cell(cell):
+    rc, _, result = rehearse(cell, 2_300_000_000, trace=1)
+    assert rc == 0 and result["correct"] is True
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"] if cell in m["workloads"]}
+    assert result["metrics"], "a traced run reports at least one per-layer metric"
+    assert set(result["metrics"]) <= set(listed)
+    # no device plane on the CPU: only the device_trace metrics may be missing
+    missing = set(listed) - set(result["metrics"])
+    assert all(listed[m]["source"] == "device_trace" for m in missing), missing
+    for name, m in result["metrics"].items():
+        assert m["unit"] == listed[name]["unit"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_second_seed_compiles_nothing_new(cell):
+    """No array shape depends on --seed: in one process, a second seed
+    finds every program compiled by the first."""
+    rehearse(cell, 2_400_000_001)
+    _, lines, result = rehearse(cell, 3_000_000_017)
+    assert result["correct"] is True
+    window = next(ln for ln in lines if ln.startswith("window:") and "set-up compiles=" in ln)
+    assert "set-up compiles=0 " in window, window
+
+
+@pytest.mark.parametrize("cell", cells_of("batch_query"))
+@pytest.mark.parametrize("nth", [None, 5])  # 5: the second execution of the window
+def test_a_swapped_value_makes_the_run_incorrect(cell, nth):
+    rc, _, result = rehearse(cell, 2_500_000_000, tamper=tampers.swap_one_value(nth))
+    assert rc == 0 and result["correct"] is False and result["failed"] >= 1
+
+
+@pytest.mark.parametrize("cell", cells_of("closed_loop_lookup"))
+def test_a_stale_reply_makes_the_run_incorrect(cell):
+    rc, _, result = rehearse(cell, 2_600_000_000, tamper=tampers.stale_reply(100))
+    assert rc == 0 and result["correct"] is False and result["failed"] >= 1
+
+
+@pytest.mark.parametrize("cell", cells_of("closed_loop_lookup"))
+def test_an_index_under_the_mirror_cap_makes_the_run_incorrect(cell, monkeypatch):
+    from csvplus_tpu.ops.join import DeviceIndex
+
+    monkeypatch.setattr(DeviceIndex, "POINT_MIRROR_MAX_KEYS", 16_000_000)
+    rc, _, result = rehearse(cell, 2_700_000_000)
+    assert rc == 0 and result["correct"] is False and result["failed"] == 0
+
+
+def test_without_a_tpu_and_without_the_flag_nothing_runs(capsys):
+    out = io.StringIO()
+    rc = run.main(["--workload", CELLS[0], "--seed", "1", "--seconds", "1"], out=out)
+    assert rc != 0 and out.getvalue() == ""
+    assert "not a TPU" in capsys.readouterr().err
+
+
+def test_trace_reducer_on_the_recorded_fixture():
+    with open(os.path.join(BENCH, "fixtures", "trace_small.json")) as f:
+        fx = json.load(f)
+    device = {k: [tuple(e) for e in v] for k, v in fx["device"].items()}
+    red = device_trace.reduce_events(device, [tuple(e) for e in fx["host"]])
+    assert red["window_s"] == pytest.approx(fx["expect"]["window_s"])
+    assert red["busy_s"] == pytest.approx(fx["expect"]["busy_s"])
+    # the same union by brute force, rasterised at 1 us when the fixture was made
+    assert red["busy_s"] == pytest.approx(fx["expect"]["busy_s_rasterised_at_1us"], abs=5e-4)
+    assert red["breakdown"]["device_ops"][0][0] == fx["expect"]["top_op"]
+    assert red["breakdown"]["idle_gaps"][0][0] == fx["expect"]["top_gap"]
+    gaps = sum(s for _, s in red["breakdown"]["idle_gaps"])
+    assert red["busy_s"] + gaps == pytest.approx(red["window_s"])
+
+
+def test_no_file_size_or_count_follows_the_seed(tmp_path):
+    """Row byte lengths, distinct counts (whole and per stretch of rows)
+    and the filter's hits are the configuration's for every seed."""
+    import numpy as np
+
+    gen = run.load_module("gen", "orders")
+    cfg = run.load_json("configs", "orders-star-10m.json")
+    seen = []
+    for seed in (5, 4_100_000_123):
+        root = tmp_path / str(seed)
+        root.mkdir()
+        d = gen.Data(cfg, seed, str(root), ("orders", "people", "stock"), rows=300_000)
+        ts = d.ts
+        seen.append((
+            [os.path.getsize(d.paths[k]) for k in ("orders", "people", "stock")],
+            [len(np.unique(ts[lo : lo + 50_000])) for lo in range(0, d.n, 50_000)],
+            len(np.unique(ts)), len(np.unique(d.cust)), len(np.unique(d.prod)),
+            len(d.filter_hits), int(((d.prod == 7) & (d.qty == 3)).sum()),
+        ))
+    assert seen[0] == seen[1]
+    assert seen[0][2] == 300_000 * 8_500_000 // 10_000_000 and seen[0][5:] == (100, 100)
+    assert not np.array_equal(gen.Data(cfg, 6, str(tmp_path), (), rows=1000).ts, ts[:1000])
+
+
+def test_the_benchmarks_own_digest_is_left_out_of_busy_and_window():
+    """An op inside a bench:digest annotation is neither busy nor idle
+    time of the program: the window shrinks by the annotation."""
+    device = {"/device:TPU:0": [("join", 10, 20), ("digest", 50, 10), ("join", 70, 10)]}
+    host = [("bench:window", 0, 100), ("bench:digest", 45, 20)]
+    red = device_trace.reduce_events(device, host)
+    assert red["window_s"] == pytest.approx(80e-9)
+    assert red["busy_s"] == pytest.approx(30e-9)
+    assert [n for n, _ in red["breakdown"]["device_ops"]] == ["join"]
+    gaps = sum(s for _, s in red["breakdown"]["idle_gaps"])
+    assert red["busy_s"] + gaps == pytest.approx(red["window_s"])
+
+
+def test_a_shed_or_errored_lookup_is_over_any_latency_limit():
+    driver = run.load_module("drivers", "closed_loop_lookup")
+    samples = {"t_end": 10.0, "t_sub": [0.0, 1.0, 2.0, 9.5], "t_done": [0.5, 1.001, 2.5, 11.0],
+               "errors": [None, "QueueFull()", None, None]}
+    lat = driver._latencies_in_window(samples)
+    assert lat.tolist() == [0.5, float("inf"), 0.5]
+
+
+def test_trace_reducer_by_hand():
+    """Two overlapping ops and one apart in a 100 ns window: busy is the
+    union (30 + 10), the gaps are labelled by the innermost annotation."""
+    device = {"/device:TPU:0": [("a", 10, 20), ("b", 20, 20), ("a", 70, 10), ("late", 200, 5)]}
+    host = [("bench:window", 0, 100), ("bench:outer", 0, 100), ("csvplus:inner", 45, 20)]
+    red = device_trace.reduce_events(device, host)
+    assert red["window_s"] == pytest.approx(100e-9)
+    assert red["busy_s"] == pytest.approx(40e-9)
+    assert red["breakdown"]["device_ops"] == [["a", pytest.approx(30e-9)], ["b", pytest.approx(20e-9)]]
+    # gaps: [0,10) outer, [40,70) midpoint 55 -> inner, [80,100) outer
+    assert dict(map(tuple, red["breakdown"]["idle_gaps"])) == {
+        "bench:outer": pytest.approx(30e-9), "csvplus:inner": pytest.approx(30e-9),
+    }
+    assert device_trace.reduce_events({}, host) is None
+    assert device_trace.reduce_events(device, [("bench:outer", 0, 100)]) is None
+
+
+def test_benchmark_json_agrees_with_the_files_found_by_name():
+    for cfg in BENCHMARK["configs"]:
+        assert cfg["file"] == f"benchmark/configs/{cfg['name']}.json"
+        on_disk = run.load_json("configs", f"{cfg['name']}.json")
+        assert on_disk["source"] == cfg["source"] and on_disk["reduced"] == cfg["reduced"]
+    for w in BENCHMARK["workloads"]:
+        cell = run.load_json("workloads", f"{w['name']}.json")
+        assert cell["config"] == w["config"] and cell["why"] == w["why"]
+        assert run.load_json("configs", f"{w['config']}.json")["chips"] == w["chips"]
+        assert os.path.exists(os.path.join(BENCH, "drivers", f"{cell['driver']}.py"))
+        if "query" in cell:
+            assert os.path.exists(os.path.join(BENCH, "queries", f"{cell['query']}.py"))
+        assert os.path.exists(os.path.join(BENCH, "gen", f"{run.load_json('configs', w['config'] + '.json')['gen']}.py"))
+    on_disk = {
+        name[: -len(".json")]: run.load_json("layer_metrics", name)
+        for name in os.listdir(os.path.join(BENCH, "layer_metrics"))
+    }
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"]}
+    assert set(on_disk) == set(listed)
+    for name, m in listed.items():
+        for key in ("layer", "unit", "better", "moves", "source", "workloads"):
+            assert on_disk[name][key] == m[key], (name, key)
+        assert os.path.exists(os.path.join(BENCH, "readers", f"{on_disk[name]['reader']}.py"))
+        least = on_disk[name].get("selector", {}).get("least_bytes")
+        assert least is None or os.path.exists(os.path.join(BENCH, "least_bytes", f"{least}.py"))
+    peaks = run.load_json("peaks.json")
+    assert all("source" in p and p["hbm_bytes_per_s"] > 0 for p in peaks.values())
