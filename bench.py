@@ -743,8 +743,9 @@ def _trace_smoke() -> int:
     Three gates, ONE JSON line on stdout, nonzero exit on any failure:
 
     1. a traced pass through the serving tier must produce per-request
-       span trees (serve:queue-wait / serve:dispatch with the
-       serve:bounds + serve:gather-decode batch phases as children);
+       span trees (serve:queue-wait / serve:dispatch per request, and
+       the batch's serve:cycle with the serve:bounds +
+       serve:gather-decode phases as children);
     2. the Chrome-trace export of those spans must pass the schema
        validator (``csvplus_tpu.obs.export.validate_chrome_trace``) so
        the artifact actually opens in Perfetto;
@@ -811,7 +812,7 @@ def _trace_smoke() -> int:
             return 1
     phases = [s for s in spans if s.name in ("serve:bounds", "serve:gather-decode")]
     if not phases or any(
-        by_id[s.parent_id].name != "serve:dispatch" for s in phases
+        by_id[s.parent_id].name != "serve:cycle" for s in phases
     ):
         sys.stderr.write(
             "trace-smoke FAILED: batch phases missing or mis-parented\n"

@@ -259,13 +259,20 @@ class _CtypesVisitor(_FunctionStack):
             )
 
 
+# decorators that jit: jax's own, and ``obs/recompile.register_kernel``
+# (``@register_kernel("join.pack_qk", static_argnames=...)``), which
+# jits, names and registers a module-level kernel in one step
+_JIT_DECORATOR_NAMES = frozenset({"jit", "register_kernel"})
+
+
 def _is_jit_decorator(dec: ast.expr) -> bool:
-    """``@jax.jit``, ``@jit``, or any decorator CALL mentioning ``jit``
+    """``@jax.jit``, ``@jit``, ``@register_kernel(...)``, or any
+    decorator CALL mentioning one of them
     (``functools.partial(jax.jit, ...)``)."""
     for node in ast.walk(dec):
-        if isinstance(node, ast.Attribute) and node.attr == "jit":
+        if isinstance(node, ast.Attribute) and node.attr in _JIT_DECORATOR_NAMES:
             return True
-        if isinstance(node, ast.Name) and node.id == "jit":
+        if isinstance(node, ast.Name) and node.id in _JIT_DECORATOR_NAMES:
             return True
     return False
 

@@ -1,9 +1,10 @@
 """Dataflow lints over the jit boundary: RETRACE002 and SYNC001.
 
 Both rules run an INTRAPROCEDURAL taint dataflow per function, seeded
-from the module's own jitted kernels (the same decorator shapes
-``obs/recompile.register_kernel`` stacks over: ``@jax.jit`` and
-``@partial(jax.jit, static_argnames=...)``), and prove facts about how
+from the module's own jitted kernels (``@jax.jit``,
+``@partial(jax.jit, static_argnames=...)`` and
+``@register_kernel(name, static_argnames=...)``, ``obs/recompile``'s
+decorator that jits), and prove facts about how
 device values flow — the two load-bearing contracts the benches only
 check dynamically (RecompileWatch / ``host_sync_elements``):
 
@@ -111,10 +112,6 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "serve-tier point read: O(1) scalar bound syncs per lookup ARE "
         "the operation's answer; no transfer of table rows"
     ),
-    "join.py:point_bounds_many": (
-        "serve-tier batched point read: one 2m-scalar bounds transfer "
-        "per batch — the answer itself, no transfer of table rows"
-    ),
     "join.py:probe": (
         "no transfer: len() reads the host list of key-code arrays, "
         "not a device value"
@@ -221,8 +218,9 @@ def _jit_static_params(
     dec: ast.expr, params: Sequence[str]
 ) -> Optional[Set[str]]:
     """The static parameter NAMES a jit decorator declares, or None when
-    *dec* is not a jit decorator.  Handles ``@jax.jit`` (no statics) and
-    ``@partial(jax.jit, static_argnames=..., static_argnums=...)``."""
+    *dec* is not a jit decorator.  Handles ``@jax.jit`` (no statics),
+    ``@partial(jax.jit, static_argnames=..., static_argnums=...)`` and
+    ``@register_kernel(name, static_argnames=...)``."""
     if not _is_jit_decorator(dec):
         return None
     statics: Set[str] = set()

@@ -30,8 +30,10 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.recompile import register_kernel
+from ..obs.span import tracer
 from ..row import Row
 from ..utils.env import env_int
+from ..utils.observe import telemetry
 
 ABSENT = np.int32(-1)
 
@@ -472,7 +474,7 @@ class StringColumn:
         else:
             src, flag = codes, self._dev_dict_sorted
         idx = jnp.asarray(sel, dtype=jnp.int32)
-        return self.with_codes(jnp.take(src, idx, axis=0), dev_dict_sorted=flag)
+        return self.with_codes(_gather_take(src, idx), dev_dict_sorted=flag)
 
     def decode_codes(self, codes: np.ndarray) -> List[Optional[str]]:
         """Decode a host code slice against this column's dictionary;
@@ -600,8 +602,15 @@ class StringColumn:
         return _apply_code_translation(self.codes, jnp.asarray(trans_dev))
 
 
+@register_kernel("table.gather_take")
+def _gather_take(storage: jax.Array, idx: jax.Array) -> jax.Array:
+    """``storage[idx]``: every column gather (a lookup's rows, a sort's
+    permutation, a selection) as one named program instead of an eager
+    ``jnp.take``, which a device profile shows as ``jit__take``."""
+    return jnp.take(storage, idx, axis=0)
+
+
 @register_kernel("table.apply_code_translation")
-@jax.jit
 def _apply_code_translation(codes: jax.Array, trans: jax.Array) -> jax.Array:
     """``trans[codes]`` with negative codes passed through unchanged —
     one fused kernel instead of three eager passes (the translation runs
@@ -612,7 +621,6 @@ def _apply_code_translation(codes: jax.Array, trans: jax.Array) -> jax.Array:
 
 
 @register_kernel("table.sync_probe")
-@jax.jit
 def _sync_probe(*code_arrays: jax.Array) -> jax.Array:
     """sum(first element of each array) — a one-scalar dependency on all."""
     return sum(a[0].astype(jnp.int32) for a in code_arrays)
@@ -856,20 +864,32 @@ class DeviceTable:
         heterogeneous dicts."""
         cols = self.columns
         if sel is not None:
-            cols = {n: c.gather(sel) for n, c in cols.items()}
+            # a point lookup's rows (``Index.rows_for_bounds`` past the
+            # mirror cap): one gather dispatched per column, then one
+            # blocking read per column, then the decode on the host
+            with tracer.span("serve:gather:take") as span:
+                cols = {n: c.gather(sel) for n, c in cols.items()}
+                span["dispatches"] = len(cols)
             n = int(len(sel))
+            with tracer.span("serve:gather:readback") as span:
+                for c in cols.values():
+                    c._ensure_sorted_lanes()  # as decode() does first: read the final codes
+                    np.asarray(c.storage)  # jax keeps the host copy, decode() reads that
+                telemetry.count_sync(n * len(cols))
+                span["host_syncs"], span["elements"] = len(cols), n * len(cols)
         else:
             n = self.nrows
-        decoded = {name: c.decode() for name, c in cols.items()}
-        names = list(decoded)
-        out = []
-        for i in range(n):
-            row = Row()
-            for name in names:
-                v = decoded[name][i]
-                if v is not None:
-                    row[name] = v
-            out.append(row)
+        with tracer.span("serve:gather:rows"):
+            decoded = {name: c.decode() for name, c in cols.items()}
+            names = list(decoded)
+            out = []
+            for i in range(n):
+                row = Row()
+                for name in names:
+                    v = decoded[name][i]
+                    if v is not None:
+                        row[name] = v
+                out.append(row)
         return out
 
     def rows_from_mirror(self, lower: int, upper: int) -> List[Row]:

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.recompile import register_kernel
+from .table import _gather_take
 
 
 # Sharding-pad sentinel for typed value lanes: INT32_MIN can never be a
@@ -89,7 +90,7 @@ class IntColumn:
     def gather(self, sel, codes=None) -> "IntColumn":
         src = self.values if codes is None else codes
         idx = jnp.asarray(sel, dtype=jnp.int32)
-        return IntColumn(self.prefix, jnp.take(src, idx, axis=0))
+        return IntColumn(self.prefix, _gather_take(src, idx))
 
     def __len__(self) -> int:
         return int(self.values.shape[0])
@@ -329,7 +330,6 @@ class IntColumn:
 
 
 @register_kernel("typed.translate_dense")
-@jax.jit
 def _translate_dense_kernel(values, lo, table):
     is_pad = values == jnp.int32(PAD_VALUE)
     # pads masked BEFORE the subtraction: PAD_VALUE - lo wraps int32 and
@@ -342,7 +342,6 @@ def _translate_dense_kernel(values, lo, table):
 
 
 @register_kernel("typed.translate_sorted")
-@jax.jit
 def _translate_sorted_kernel(values, sorted_vals, code_of):
     is_pad = values == jnp.int32(PAD_VALUE)
     pos = jnp.searchsorted(sorted_vals, values)
@@ -356,7 +355,6 @@ def _translate_sorted_kernel(values, sorted_vals, code_of):
 
 
 @register_kernel("typed.translate_empty")
-@jax.jit
 def _translate_empty_kernel(values):
     return jnp.where(
         values == jnp.int32(PAD_VALUE), jnp.int32(-2), jnp.int32(-1)

@@ -46,6 +46,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from .errors import CsvPlusError, DataSourceError
+from .obs.span import tracer
 from .row import Row, all_columns_unique, equal_rows
 from .source import DataSource, RowFunc, iterate, take_rows
 
@@ -263,15 +264,16 @@ class IndexImpl:
                 # — no device round trip)
                 return table.rows_from_mirror_many(bounds)
             out: List[List[Row]] = [[] for _ in bounds]
-            hit = [
-                (i, int(lo), int(hi))
-                for i, (lo, hi) in enumerate(bounds)
-                if hi > lo
-            ]
-            if hit:
+            with tracer.span("serve:gather:index"):
+                hit = [
+                    (i, int(lo), int(hi))
+                    for i, (lo, hi) in enumerate(bounds)
+                    if hi > lo
+                ]
                 idx = np.concatenate(
                     [np.arange(lo, hi, dtype=np.int64) for _, lo, hi in hit]
-                )
+                ) if hit else None
+            if hit:
                 rows = table.to_rows(idx)
                 off = 0
                 for i, lo, hi in hit:

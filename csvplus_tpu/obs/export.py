@@ -8,7 +8,11 @@ Two consumers, two formats:
   directly.  :func:`export_chrome_trace` takes the same ``log_dir``
   convention as :func:`csvplus_tpu.utils.observe.profile_to`, so the
   host-side span trace and the JAX device trace of one run land side by
-  side and open in the same Perfetto session.
+  side and open in the same Perfetto session.  Timestamps count from
+  the first trace's anchor (``Trace.t_anchor``, which the profiler's
+  trace shows as a ``csvplus:anchor`` annotation): hand
+  :func:`anchor_in_profile`'s reading to ``anchor_ts_us`` and spans
+  written after the fact lie on the device trace's own axis.
 * :func:`spans_to_json` / :func:`write_spans_jsonl` emit one flat JSON
   object per span — the shape the bench artifacts embed and the
   ``obs diff`` tooling consumes.
@@ -38,17 +42,25 @@ def _iter_spans(traces: Iterable[Trace]) -> Iterable[Span]:
         yield from t.snapshot()
 
 
-def chrome_trace_events(traces: Sequence[Trace]) -> List[Dict[str, Any]]:
+def chrome_trace_events(
+    traces: Sequence[Trace], anchor_ts_us: float = 0.0
+) -> List[Dict[str, Any]]:
     """Chrome Trace Event list for *traces*: one ``"X"`` (complete)
-    event per span plus ``"M"`` metadata naming the process and each
-    lane.  ``tid`` is a dense integer per distinct lane; timestamps are
-    microseconds relative to the earliest span so the viewer opens at
-    t=0."""
+    event per span, one ``csvplus:anchor`` instant per trace, plus
+    ``"M"`` metadata naming the process and each lane.  ``tid`` is a
+    dense integer per distinct lane.  Timestamps are microseconds from
+    the earliest trace's anchor plus *anchor_ts_us* — where the
+    profiler's own trace shows that anchor (:func:`anchor_in_profile`),
+    or 0 to open at t=0."""
     pid = os.getpid()
     spans = list(_iter_spans(traces))
     if not spans:
         return []
-    t0 = min(s.t_start for s in spans)
+    t0 = min(t.t_anchor for t in traces)
+
+    def ts(t: float) -> float:
+        return max(0.0, round(anchor_ts_us + (t - t0) * 1e6, 3))
+
     lanes: Dict[str, int] = {}
     events: List[Dict[str, Any]] = [
         {
@@ -59,6 +71,19 @@ def chrome_trace_events(traces: Sequence[Trace]) -> List[Dict[str, Any]]:
             "args": {"name": "csvplus-host"},
         }
     ]
+    for t in traces:
+        events.append(
+            {
+                "name": "csvplus:anchor",
+                "cat": "csvplus",
+                "ph": "i",
+                "s": "p",
+                "ts": ts(t.t_anchor),
+                "pid": pid,
+                "tid": 0,
+                "args": {"trace_id": t.trace_id, "perf_counter": t.t_anchor},
+            }
+        )
     for s in spans:
         tid = lanes.get(s.lane)
         if tid is None:
@@ -85,7 +110,7 @@ def chrome_trace_events(traces: Sequence[Trace]) -> List[Dict[str, Any]]:
                 "name": s.name,
                 "cat": "csvplus",
                 "ph": "X",
-                "ts": round((s.t_start - t0) * 1e6, 3),
+                "ts": ts(s.t_start),
                 "dur": round(s.seconds * 1e6, 3),
                 "pid": pid,
                 "tid": tid,
@@ -95,15 +120,34 @@ def chrome_trace_events(traces: Sequence[Trace]) -> List[Dict[str, Any]]:
     return events
 
 
+def anchor_in_profile(xplane_path: str, trace_id: int) -> Optional[float]:
+    """Microseconds, on the profiler's clock, at which the
+    ``csvplus:anchor`` annotation of trace *trace_id* starts in the
+    ``.xplane.pb`` the JAX profiler wrote; None when the profile does
+    not hold it (the trace opened before the profiler started)."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "csvplus:anchor" and dict(ev.stats).get("trace_id") == trace_id:
+                    return ev.start_ns / 1e3
+    return None
+
+
 def write_chrome_trace(
-    path: str, traces: Optional[Sequence[Trace]] = None
+    path: str,
+    traces: Optional[Sequence[Trace]] = None,
+    anchor_ts_us: float = 0.0,
 ) -> str:
     """Write *traces* (default: every finished trace in the global
     tracer) as one Chrome-trace JSON file; returns the path."""
     if traces is None:
         traces = tracer.finished()
     payload = {
-        "traceEvents": chrome_trace_events(traces),
+        "traceEvents": chrome_trace_events(traces, anchor_ts_us),
         "displayTimeUnit": "ms",
         "metadata": {"producer": "csvplus_tpu.obs"},
     }
@@ -114,14 +158,16 @@ def write_chrome_trace(
 
 
 def export_chrome_trace(
-    log_dir: str, traces: Optional[Sequence[Trace]] = None
+    log_dir: str,
+    traces: Optional[Sequence[Trace]] = None,
+    anchor_ts_us: float = 0.0,
 ) -> str:
     """Write the host span trace under *log_dir* — the same directory
     ``profile_to(log_dir)`` fills with the JAX device trace — as
     ``csvplus_host_trace.<pid>.json``; returns the file path."""
     os.makedirs(log_dir, exist_ok=True)
     path = os.path.join(log_dir, f"csvplus_host_trace.{os.getpid()}.json")
-    return write_chrome_trace(path, traces)
+    return write_chrome_trace(path, traces, anchor_ts_us)
 
 
 def validate_chrome_trace(obj: Union[dict, list]) -> List[str]:

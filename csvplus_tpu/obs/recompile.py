@@ -6,13 +6,24 @@ invariant: a warm pass over an already-seen shape must lower NOTHING.
 this module extends it to every module-level kernel — the exact
 functions whose eager predecessors caused the r05 warm-join regression.
 
-Kernels self-register at definition site::
+Kernels are made at definition site by the one decorator that jits,
+names and registers them::
 
-    @register_kernel("join.pack_qk")
-    @jax.jit
+    @register_kernel("join.pack_qk", static_argnames=("shifts",))
     def _pack_qk_kernel(...): ...
 
-and :func:`compile_counts` reads each registered function's jit-cache
+The name reaches the device twice.  The program is called
+``jit_csvplus.join.pack_qk``, which is what a TPU profile prints on its
+``XLA Modules`` line (an ``XLA Ops`` event carries no scope, PERF.md
+§3), so device time is attributed by kernel.  And the body is traced
+under ``jax.named_scope("csvplus.join.pack_qk")``, so every operation's
+``op_name`` in the lowered text starts with it.  Both are static
+strings fixed at import: nothing runs on the call path, and a second
+call with seen shapes lowers nothing.  (The program's name is part of
+the persistent compile cache's key; the scope, like all metadata, is
+not.)
+
+:func:`compile_counts` reads each registered function's jit-cache
 entry count (``PjitFunction._cache_size`` — the number of distinct
 lowerings jax holds for it).  A grown count between two snapshots IS a
 (re)compile; :class:`RecompileWatch` packages the
@@ -29,6 +40,7 @@ pass on nothing.
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Callable, Dict
 
@@ -36,15 +48,26 @@ _REGISTRY_LOCK = threading.Lock()
 _KERNELS: Dict[str, Any] = {}
 
 
-def register_kernel(name: str) -> Callable:
-    """Decorator: register a jitted callable under *name* for
-    compile-count accounting.  Returns the callable unchanged — zero
-    call-path overhead."""
+def register_kernel(name: str, **jit_kwargs) -> Callable:
+    """Decorator: ``jax.jit`` the function (*jit_kwargs* are jit's, e.g.
+    ``static_argnames``) as the program ``jit_csvplus.<name>`` with its
+    body under ``jax.named_scope("csvplus.<name>")``, and register the
+    jitted callable under *name* for compile-count accounting.  The
+    wrapper runs at trace time only — zero call-path overhead."""
+    import jax  # here, not at import: ``import csvplus_tpu`` stays jax-free
+
+    scope = f"csvplus.{name}"
 
     def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+
+        scoped.__name__ = scoped.__qualname__ = scope  # jit names the program after it
         with _REGISTRY_LOCK:
-            _KERNELS[name] = fn
-        return fn
+            jitted = _KERNELS[name] = jax.jit(scoped, **jit_kwargs)
+        return jitted
 
     return deco
 

@@ -27,9 +27,20 @@ active in the calling context (see ``utils/observe.py``), so every
 already-instrumented stage (exec nodes, ingest, joins, serve dispatch)
 shows up in span trees without touching its call site.
 
+One emitter, one clock: every span opened while a trace is active also
+opens ``jax.profiler.TraceAnnotation("csvplus:<name>")`` (:func:`_annotate`,
+the only place in the package that writes into the profiler's host
+trace), so a device profile taken over the same stretch shows what the
+host did in each gap.  Spans keep ``perf_counter`` times; a :class:`Trace`
+emits one ``csvplus:anchor`` annotation when it opens, carrying its id
+and the ``perf_counter`` value of that moment, which is what places
+spans written after the fact (:meth:`Tracer.record_span`) on the
+profiler's clock.
+
 Disabled-path cost: with no active trace, :meth:`Tracer.span` is one
-``ContextVar.get`` and one generator frame — the ``make trace-smoke``
-gate holds this under 2% on the micro lookup shape.
+``ContextVar.get`` and a shared do-nothing context manager — the ``make
+trace-smoke`` gate holds this under 2% on the micro lookup shape.  No
+annotation is constructed and no span object made.
 """
 
 from __future__ import annotations
@@ -50,6 +61,19 @@ MAX_FINISHED_TRACES = 512
 _CURRENT: "contextvars.ContextVar[Optional[Tuple[Trace, int]]]" = (
     contextvars.ContextVar("csvplus_obs_current", default=None)
 )
+
+
+def _annotate(name: str, **meta):
+    """An entered ``jax.profiler.TraceAnnotation("csvplus:<name>")``: the
+    one place in the package that writes into the profiler's host trace.
+    Only reached while a trace is active (so ``import csvplus_tpu`` stays
+    jax-free); with no profiler session running it costs one flag test
+    inside jax."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(f"csvplus:{name}", **meta)
+    ann.__enter__()
+    return ann
 
 
 @dataclass
@@ -97,7 +121,12 @@ class Trace:
         self.trace_id = trace_id
         self.name = name
         self.spans: List[Span] = []
+        # the anchor: this perf_counter value and the annotation's start
+        # on the profiler's clock are the same moment
         self.t_anchor = time.perf_counter()
+        _annotate("anchor", trace_id=trace_id, perf_counter=self.t_anchor).__exit__(
+            None, None, None
+        )
         self._lock = threading.Lock()
 
     def add(self, span: Span) -> None:
@@ -133,12 +162,42 @@ class Trace:
 class _OpenSpan:
     """Handle for a span opened via the low-level open/close API."""
 
-    __slots__ = ("trace", "span", "token")
+    __slots__ = ("trace", "span", "token", "ann")
 
-    def __init__(self, trace: Trace, span: Span, token):
+    def __init__(self, trace, span: Span, token, ann):
         self.trace = trace
         self.span = span
         self.token = token
+        self.ann = ann  # the live profiler annotation, closed with the span
+
+
+class _SharedSpans:
+    """Where the spans of one :meth:`Tracer.shared` region collect until
+    the region ends (list.append is atomic: adopted workers may add)."""
+
+    __slots__ = ("trace_id", "spans")
+
+    def __init__(self) -> None:
+        self.trace_id = 0
+        self.spans: List[Span] = []
+
+    def add(self, span: Span) -> None:
+        self.spans.append(span)
+
+
+class _NoSpan:
+    """What :meth:`Tracer.span` hands out while no trace is active."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> Dict[str, Any]:
+        return {}
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
 
 
 class Tracer:
@@ -175,27 +234,45 @@ class Tracer:
         finally:
             _CURRENT.reset(token)
 
+    def suspend(self):
+        """Leave the current context (a caller's callback run on a
+        worker's thread must not inherit the worker's trace); returns
+        what :meth:`resume` needs, None when no trace is active."""
+        if _CURRENT.get() is None:
+            return None
+        return _CURRENT.set(None)
+
+    def resume(self, token) -> None:
+        if token is not None:
+            _CURRENT.reset(token)
+
     # -- tracing -----------------------------------------------------------
+
+    def _start(self, trace_id: int, parent_id: Optional[int], name: str, attrs) -> Span:
+        """A span that starts now on this thread, still open (t_end 0)."""
+        return Span(
+            trace_id=trace_id,
+            span_id=next(self._ids),
+            parent_id=parent_id,
+            name=name,
+            t_start=time.perf_counter(),
+            t_end=0.0,
+            lane=threading.current_thread().name,
+            attrs=dict(attrs) if attrs else {},
+        )
 
     @contextlib.contextmanager
     def trace(self, name: str, **attrs) -> Iterator[Trace]:
         """Open a new root trace in this context; yields the
         :class:`Trace` and registers it in the finished list on exit."""
         t = Trace(next(self._ids), name)
-        root = Span(
-            trace_id=t.trace_id,
-            span_id=next(self._ids),
-            parent_id=None,
-            name=name,
-            t_start=time.perf_counter(),
-            t_end=0.0,
-            lane=threading.current_thread().name,
-            attrs=dict(attrs),
-        )
+        root = self._start(t.trace_id, None, name, attrs)
         token = _CURRENT.set((t, root.span_id))
+        ann = _annotate(name)
         try:
             yield t
         finally:
+            ann.__exit__(None, None, None)
             _CURRENT.reset(token)
             root.t_end = time.perf_counter()
             t.add(root)
@@ -212,37 +289,32 @@ class Tracer:
         if ctx is None:
             return None
         t, parent = ctx
-        span = Span(
-            trace_id=t.trace_id,
-            span_id=next(self._ids),
-            parent_id=parent,
-            name=name,
-            t_start=time.perf_counter(),
-            t_end=0.0,
-            lane=threading.current_thread().name,
-            attrs=dict(attrs) if attrs else {},
-        )
+        span = self._start(t.trace_id, parent, name, attrs)
         token = _CURRENT.set((t, span.span_id))
-        return _OpenSpan(t, span, token)
+        return _OpenSpan(t, span, token, _annotate(name))
 
     def close_span(self, handle: Optional[_OpenSpan], **attrs) -> None:
         if handle is None:
             return
+        handle.ann.__exit__(None, None, None)
         _CURRENT.reset(handle.token)
         handle.span.t_end = time.perf_counter()
         if attrs:
             handle.span.attrs.update(attrs)
         handle.trace.add(handle.span)
 
+    def span(self, name: str, **attrs):
+        """Child span under the current context, as a context manager
+        that yields the span's attrs dict (the body may annotate it).
+        With no trace active it is one ``ContextVar.get`` and a shared
+        do-nothing manager yielding a throwaway dict."""
+        if _CURRENT.get() is None:
+            return _NO_SPAN
+        return self._live_span(name, attrs)
+
     @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Dict[str, Any]]:
-        """Child span under the current context.  Yields the span's
-        attrs dict (the body may annotate it); a no-op yielding a
-        throwaway dict when no trace is active."""
+    def _live_span(self, name: str, attrs: Dict[str, Any]) -> Iterator[Dict[str, Any]]:
         handle = self.open_span(name, **attrs)
-        if handle is None:
-            yield {}
-            return
         try:
             yield handle.span.attrs
         except BaseException as e:
@@ -250,6 +322,48 @@ class Tracer:
             raise
         finally:
             self.close_span(handle)
+
+    @contextlib.contextmanager
+    def shared(self, name: str, ctxs, **attrs) -> Iterator[Dict[str, Any]]:
+        """One live region on the calling thread that works for several
+        traces at once (the serving dispatcher's cycle over a coalesced
+        batch).  While it runs it is the current context: spans opened
+        under it — here, in the layers it calls, on adopted workers —
+        are ordinary live spans with annotations.  When it ends, the
+        finished subtree is copied under each distinct (trace, parent)
+        of *ctxs* (captured contexts), with span ids of its own and the
+        same names, times, lanes and attrs: every trace's tree holds
+        the batch-shared work once.  Yields the region's attrs."""
+        region = _SharedSpans()
+        root = self._start(region.trace_id, None, name, attrs)
+        token = _CURRENT.set((region, root.span_id))
+        ann = _annotate(name)
+        try:
+            yield root.attrs
+        finally:
+            ann.__exit__(None, None, None)
+            _CURRENT.reset(token)
+            root.t_end = time.perf_counter()
+            spans = [root] + region.spans
+            seen = set()
+            for trace, parent in ctxs:
+                if (id(trace), parent) in seen:
+                    continue
+                seen.add((id(trace), parent))
+                ids = {s.span_id: next(self._ids) for s in spans}
+                for s in spans:
+                    trace.add(
+                        Span(
+                            trace.trace_id,
+                            ids[s.span_id],
+                            ids.get(s.parent_id, parent),
+                            s.name,
+                            s.t_start,
+                            s.t_end,
+                            s.lane,
+                            s.attrs,
+                        )
+                    )
 
     def add_span(
         self,

@@ -38,7 +38,6 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass
-from functools import partial as _partial
 from typing import ClassVar, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -48,7 +47,9 @@ import jax.numpy as jnp
 
 from ..columnar.table import DeviceTable, StringColumn, same_placement
 from ..obs.recompile import register_kernel
+from ..obs.span import tracer
 from ..utils.env import env_int
+from ..utils.observe import telemetry
 
 
 def _bits_for(n: int) -> int:
@@ -112,7 +113,6 @@ def _searchsorted2(keys_hi, keys_lo, q_hi, q_lo, side: str = "left"):
 
 
 @register_kernel("join.probe_i32pair")
-@jax.jit
 def _probe_kernel_i32pair(keys_hi, keys_lo, q_hi, q_lo, r_hi, r_lo, ok):
     """Wide-key range probe: two lane-pair binary searches (lower at the
     query, upper at query + range with a 31-bit carry)."""
@@ -154,7 +154,6 @@ def direct_probe_parts(
 
 
 @register_kernel("join.probe_direct")
-@jax.jit
 def _probe_kernel_direct(
     cum: jax.Array, qk: jax.Array, range_size: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
@@ -162,7 +161,6 @@ def _probe_kernel_direct(
 
 
 @register_kernel("join.probe_i32")
-@jax.jit
 def _probe_kernel_i32(
     keys: jax.Array, qk: jax.Array, range_size: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
@@ -179,8 +177,15 @@ def _probe_kernel_i32(
     return lower.astype(jnp.int32), counts.astype(jnp.int32)
 
 
-@register_kernel("join.build_direct_cum")
-@_partial(jax.jit, static_argnames=("total_bits",))
+@register_kernel("serve.bounds_search")
+def _bounds_search_kernel(keys: jax.Array, queries: jax.Array) -> jax.Array:
+    """A lookup batch's lower and upper bounds in one pass over the
+    packed key array (``point_bounds_many``'s device tier): a named
+    program where an eager ``jnp.searchsorted`` shows as ``jit_searchsorted``."""
+    return jnp.searchsorted(keys, queries, side="left")
+
+
+@register_kernel("join.build_direct_cum", static_argnames=("total_bits",))
 def _build_direct_cum(keys: jax.Array, total_bits: int) -> jax.Array:
     """cum[j] = number of build keys strictly below j, for every packed
     key value j in the universe [0, 2^total_bits] — one scatter-add and
@@ -391,8 +396,6 @@ class DeviceIndex:
         else:
             # EXPLICIT bounded transfer (<= 4096 elements), accounted
             # like the probe-side hot sample — transfer-guard safe
-            from ..utils.observe import telemetry
-
             sample = jax.device_get(self.packed_i32[::step])
             telemetry.count_sync(sample.size)
         vals, cnts = np.unique(sample, return_counts=True)
@@ -476,47 +479,51 @@ class DeviceIndex:
         if m == 0:
             return []
         n = int(self.table.nrows)
-        karr = np.array([len(p) for p in probes], dtype=np.int64)
-        if karr.size and int(karr.max()) > len(self.key_columns):
-            raise ValueError("too many columns in Index.find()")
-        qk = np.zeros(m, dtype=np.int64)
-        ok = np.ones(m, dtype=bool)
-        for j, (name, s) in enumerate(zip(self.key_columns, self.shifts)):
-            col = self.table.columns[name]
-            if int(karr.min()) > j:  # every probe has column j
-                codes = col.find_codes([p[j] for p in probes])
-                ok &= codes >= 0
-                qk |= np.where(codes >= 0, codes, 0) << s
-                continue
-            sel = np.flatnonzero(karr > j)
-            if sel.size == 0:
-                break
-            codes = col.find_codes([probes[i][j] for i in sel])
-            ok[sel] &= codes >= 0
-            qk[sel] |= np.where(codes >= 0, codes, 0) << s
-        shifts = np.array(self.shifts, dtype=np.int64)
-        range_size = np.where(karr > 0, 1 << shifts[np.maximum(karr, 1) - 1], 0)
-        top = qk + range_size
-        if self.packed_i32 is not None:
-            over = top > np.iinfo(np.int32).max  # one-past-top: upper = n
-            if int(self.packed_i32.shape[0]) <= self.POINT_MIRROR_MAX_KEYS:
-                host = self._packed_host_mirror()
-                lower = host.searchsorted(qk.astype(np.int32), side="left")
-                upper = host.searchsorted(
-                    np.where(over, 0, top).astype(np.int32), side="left"
-                )
-            else:
-                qt = np.concatenate([qk, np.where(over, 0, top)]).astype(np.int32)
-                res = np.asarray(
-                    jnp.searchsorted(
-                        self.packed_i32, jnp.asarray(qt), side="left"
+        with tracer.span("serve:bounds:encode"):
+            # the host half: probe strings -> dictionary codes -> packed keys
+            karr = np.array([len(p) for p in probes], dtype=np.int64)
+            if karr.size and int(karr.max()) > len(self.key_columns):
+                raise ValueError("too many columns in Index.find()")
+            qk = np.zeros(m, dtype=np.int64)
+            ok = np.ones(m, dtype=bool)
+            for j, (name, s) in enumerate(zip(self.key_columns, self.shifts)):
+                col = self.table.columns[name]
+                if int(karr.min()) > j:  # every probe has column j
+                    codes = col.find_codes([p[j] for p in probes])
+                    ok &= codes >= 0
+                    qk |= np.where(codes >= 0, codes, 0) << s
+                    continue
+                sel = np.flatnonzero(karr > j)
+                if sel.size == 0:
+                    break
+                codes = col.find_codes([probes[i][j] for i in sel])
+                ok[sel] &= codes >= 0
+                qk[sel] |= np.where(codes >= 0, codes, 0) << s
+            shifts = np.array(self.shifts, dtype=np.int64)
+            range_size = np.where(karr > 0, 1 << shifts[np.maximum(karr, 1) - 1], 0)
+            top = qk + range_size
+        with tracer.span("serve:bounds:search") as span:
+            # upload, searchsorted, read back (or the host mirror's numpy)
+            if self.packed_i32 is not None:
+                over = top > np.iinfo(np.int32).max  # one-past-top: upper = n
+                if int(self.packed_i32.shape[0]) <= self.POINT_MIRROR_MAX_KEYS:
+                    host = self._packed_host_mirror()
+                    lower = host.searchsorted(qk.astype(np.int32), side="left")
+                    upper = host.searchsorted(
+                        np.where(over, 0, top).astype(np.int32), side="left"
                     )
-                )
-                lower, upper = res[:m], res[m:]
-            upper = np.where(over, n, upper)
-        else:
-            lower = np.searchsorted(self.packed_i64, qk, side="left")
-            upper = np.searchsorted(self.packed_i64, top, side="left")
+                else:
+                    qt = np.concatenate([qk, np.where(over, 0, top)]).astype(np.int32)
+                    res = np.asarray(
+                        _bounds_search_kernel(self.packed_i32, jnp.asarray(qt))
+                    )
+                    telemetry.count_sync(2 * m)
+                    span["host_syncs"], span["elements"] = 1, 2 * m
+                    lower, upper = res[:m], res[m:]
+                upper = np.where(over, n, upper)
+            else:
+                lower = np.searchsorted(self.packed_i64, qk, side="left")
+                upper = np.searchsorted(self.packed_i64, top, side="left")
         lower = np.where(ok, lower, 0).astype(np.int64)
         upper = np.where(ok, upper, 0).astype(np.int64)
         empty = karr == 0  # empty prefix bounds the whole table
@@ -603,8 +610,6 @@ class DeviceIndex:
         skew-routing evidence accumulates into the same dict — see
         ``partitioned_probe_device``'s *info* contract.
         """
-        from ..utils.observe import telemetry
-
         assert self.supported
         self.offer_build_sample()
         k = len(probe_cols)
@@ -723,8 +728,7 @@ class DeviceIndex:
         )
 
 
-@register_kernel("join.pack_qk")
-@_partial(jax.jit, static_argnames=("shifts",))
+@register_kernel("join.pack_qk", static_argnames=("shifts",))
 def _pack_qk_kernel(  # analysis: allow[JIT001] retrace is per join-key ARITY (bounded by the 31-bit pack budget), not per data length
     codes: Tuple[jax.Array, ...], shifts: Tuple[int, ...]
 ) -> jax.Array:
@@ -754,8 +758,7 @@ def expand_matches(
     return probe_ids, build_ids
 
 
-@register_kernel("join.expand")
-@_partial(jax.jit, static_argnames=("padded_total",))
+@register_kernel("join.expand", static_argnames=("padded_total",))
 def _expand_kernel(lower, counts, padded_total: int):
     """Device fan-out expansion with a static output size: an exclusive
     prefix sum over counts locates each probe row's output segment, a
@@ -884,8 +887,6 @@ def join_tables(
         }
         return DeviceTable(out_cols, 0, stream.device)
 
-    from ..utils.observe import telemetry
-
     probe_cols = _checked_probe_cols(stream, columns)
     lower, counts = dev_index.probe(probe_cols, stream.nrows)
     probe_ids = build_ids = None
@@ -991,7 +992,6 @@ def join_tables(
 
 
 @register_kernel("join.gather_both_sides")
-@jax.jit
 def _gather_both_sides(build_codes, stream_codes, build_ids, probe_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     b_idx = jnp.asarray(build_ids, dtype=jnp.int32)
     p_idx = jnp.asarray(probe_ids, dtype=jnp.int32)
@@ -1002,14 +1002,12 @@ def _gather_both_sides(build_codes, stream_codes, build_ids, probe_ids):  # anal
 
 
 @register_kernel("join.gather_cols")
-@jax.jit
 def _gather_cols(codes, ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     idx = jnp.asarray(ids, dtype=jnp.int32)
     return tuple(jnp.take(c, idx, axis=0) for c in codes)
 
 
 @register_kernel("join.probe_stats")
-@jax.jit
 def _probe_stats(lower, counts):
     """(total matches, max run length) as one device pair — a single
     transfer decides the unique fast paths in :func:`join_tables`."""
@@ -1048,7 +1046,6 @@ def _fanout_products(counts):
 
 
 @register_kernel("join.multiway_stats")
-@jax.jit
 def _multiway_stats(counts):  # analysis: allow[JIT001] retrace is per join ARITY (number of build sides), not per data length
     """(total matches, max fanout, cascade intermediate rows avoided) as
     one stacked device triple — a single transfer decides the multiway
@@ -1064,8 +1061,7 @@ def _multiway_stats(counts):  # analysis: allow[JIT001] retrace is per join ARIT
     return jnp.stack([total, maxp, inter])
 
 
-@register_kernel("join.multiway_select")
-@_partial(jax.jit, static_argnames=("padded",))
+@register_kernel("join.multiway_select", static_argnames=("padded",))
 def _multiway_select_kernel(lowers, counts, padded: int):  # analysis: allow[JIT001] retrace is per join ARITY, not per data length
     """Unique-but-partial fast path: every dimension matched <= once, so
     the surviving fact rows compact by one pow2-padded flatnonzero and
@@ -1117,8 +1113,7 @@ def _compact_unique_partial(lowers, counts, padded: int):
     return _multiway_select_kernel(lowers, counts, padded)
 
 
-@register_kernel("join.multiway_expand")
-@_partial(jax.jit, static_argnames=("padded_total",))
+@register_kernel("join.multiway_expand", static_argnames=("padded_total",))
 def _multiway_expand_kernel(lowers, counts, padded_total: int):  # analysis: allow[JIT001] retrace is per join ARITY, not per data length
     """Device cross-product fan-out with a static output size: the
     per-row fanout (product of the dimensions' match counts) drives the
@@ -1185,7 +1180,6 @@ def _multiway_expand_host(lowers, counts):
 
 
 @register_kernel("join.gather_multiway")
-@jax.jit
 def _gather_multiway(build_codes, build_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     """All build sides' row-materializing gathers in ONE jit call (the
     unique-identity path: stream columns pass through untouched)."""
@@ -1197,7 +1191,6 @@ def _gather_multiway(build_codes, build_ids):  # analysis: allow[JIT001] — ari
 
 
 @register_kernel("join.gather_multiway_both")
-@jax.jit
 def _gather_multiway_both(build_codes, stream_codes, build_ids, probe_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     """Every side's gathers — N build sides + the stream — fused into
     one executable, the multiway form of ``_gather_both_sides``."""
@@ -1223,7 +1216,6 @@ def multiway_join(
     columns) pairs in cascade order."""
     from ..columnar.table import merge_with_fallback
     from ..obs.joinskew import joinskew
-    from ..utils.observe import telemetry
 
     if len(specs) == 1:  # degenerate run: exactly the binary join
         return join_tables(stream, specs[0][0], specs[0][1])
@@ -1402,7 +1394,6 @@ def multiway_join(
 
 
 @register_kernel("join.gather_fused_both")
-@jax.jit
 def _gather_fused_both(build_codes, stream_codes, build_ids, probe_ids, sel):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     """The fused-emit form of ``_gather_multiway_both``: stream columns
     gather from FULL-length storage by the composed ``sel[probe_ids]``
@@ -1436,7 +1427,6 @@ def multiway_join_selected(
     ``materialize()``'s identity fast path)."""
     from ..columnar.table import merge_with_fallback
     from ..obs.joinskew import joinskew
-    from ..utils.observe import telemetry
 
     n_sel = int(sel.shape[0])
 

@@ -311,8 +311,7 @@ def _probe_shard_kernel2(
     return got_lo, got_ct
 
 
-@register_kernel("pjoin.probe_spmd2")
-@partial(jax.jit, static_argnames=("mesh", "n_shards", "capacity"))
+@register_kernel("pjoin.probe_spmd2", static_argnames=("mesh", "n_shards", "capacity"))
 def _probe_spmd2(
     mesh, n_shards, capacity, qh, ql, uniq_hi, uniq_lo, lower, count, splits_hi,
     splits_lo,
@@ -328,8 +327,7 @@ def _probe_spmd2(
     return f(qh, ql, uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo)
 
 
-@register_kernel("pjoin.probe_spmd")
-@partial(jax.jit, static_argnames=("mesh", "n_shards", "capacity"))
+@register_kernel("pjoin.probe_spmd", static_argnames=("mesh", "n_shards", "capacity"))
 def _probe_spmd(mesh, n_shards, capacity, qk_sharded, uniq, lower, count, splits):
     axes = tuple(mesh.axis_names)
     rows = P(axes)
@@ -436,8 +434,7 @@ def partitioned_probe(
 # element hot-key sample and one boolean overflow scalar per retry.
 
 
-@register_kernel("pjoin.probe_spmd_dev")
-@partial(jax.jit, static_argnames=("mesh", "n_shards", "capacity", "n_hot"))
+@register_kernel("pjoin.probe_spmd_dev", static_argnames=("mesh", "n_shards", "capacity", "n_hot"))
 def _probe_spmd_dev(
     mesh, n_shards, capacity, n_hot, qk, uniq, lower, count, splits,
     hot_vals, hot_lo, hot_ct,
@@ -485,8 +482,7 @@ def _probe_spmd_dev(
     return lo, ct, jnp.any(ct < 0)
 
 
-@register_kernel("pjoin.probe_spmd_dev2")
-@partial(jax.jit, static_argnames=("mesh", "n_shards", "capacity", "n_hot"))
+@register_kernel("pjoin.probe_spmd_dev2", static_argnames=("mesh", "n_shards", "capacity", "n_hot"))
 def _probe_spmd_dev2(
     mesh, n_shards, capacity, n_hot, qh, ql,
     uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo,

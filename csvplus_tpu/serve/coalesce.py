@@ -484,8 +484,15 @@ class LookupServer:
     # -- dispatcher (single thread; THREAD001 worker entry) ----------------
 
     def _dispatch_loop(self) -> None:
+        # contexts of the last cycle's traced requests: the wait that
+        # follows a traced cycle is a span in the same trees
+        traced: list = []
         while True:
             with self._cv:
+                if traced and not self._pending and self._open:
+                    with tracer.shared("serve:idle-wait", traced):
+                        while not self._pending and self._open:
+                            self._cv.wait()
                 while not self._pending and self._open:
                     self._cv.wait()
                 if self._tick_s > 0.0 and self._pending and self._open:
@@ -505,7 +512,7 @@ class LookupServer:
             self.metrics.on_tick(depth_after + len(batch))
             if batch:
                 try:
-                    self._run_batch(batch)
+                    traced = self._run_batch(batch)
                 except BaseException as err:
                     # dispatcher hardening: an escape here used to
                     # leave every pending future hanging forever —
@@ -513,7 +520,24 @@ class LookupServer:
                     self._on_dispatcher_crash(err, batch)
                     return
 
-    def _run_batch(self, batch: List[ServeFuture]) -> None:
+    def _run_batch(self, batch: List[ServeFuture]) -> list:
+        """One dispatch cycle over a drained batch.  When a request of
+        the batch carries a trace context (one pass over the batch to
+        find out; nothing else on the untraced path), the cycle runs as
+        a live ``serve:cycle`` span with the phases below it — sweep,
+        bounds, gather-decode, scatter, account, each also a profiler
+        annotation — and the finished subtree lands once in every
+        traced request's tree (:meth:`Tracer.shared`).  Returns the
+        batch's trace contexts."""
+        traced = [r.trace_ctx for r in batch if r.trace_ctx is not None]
+        if traced:
+            with tracer.shared("serve:cycle", traced, batch=len(batch)):
+                self._run_cycle(batch)
+        else:
+            self._run_cycle(batch)
+        return traced
+
+    def _run_cycle(self, batch: List[ServeFuture]) -> None:
         """Execute one drained batch OUTSIDE the queue lock: deadline
         sweep, one coalesced lookup call, per-request plan executions,
         then scatter.  Every request in *batch* has left the queue — the
@@ -526,17 +550,18 @@ class LookupServer:
         lookups: dict = {}  # index name -> sub-batch
         writes: dict = {}  # index name -> appends+deletes, submission order
         plans: List[ServeFuture] = []
-        for req in batch:
-            req.t_dispatch = t0
-            expired = self.admission.deadline_error(req.t_submit, req.deadline_s, t0)
-            if expired is not None:
-                self._complete(req, None, expired, samples)
-            elif req.plan is not None:
-                plans.append(req)
-            elif req.rows is not None or req.del_key is not None:
-                writes.setdefault(req.index_name, []).append(req)
-            else:
-                lookups.setdefault(req.index_name, []).append(req)
+        with tracer.span("serve:sweep"):
+            for req in batch:
+                req.t_dispatch = t0
+                expired = self.admission.deadline_error(req.t_submit, req.deadline_s, t0)
+                if expired is not None:
+                    self._complete(req, None, expired, samples)
+                elif req.plan is not None:
+                    plans.append(req)
+                elif req.rows is not None or req.del_key is not None:
+                    writes.setdefault(req.index_name, []).append(req)
+                else:
+                    lookups.setdefault(req.index_name, []).append(req)
         # writes land BEFORE the cycle's view refresh and lookups: a
         # lookup (or view read) coalesced into the same dispatch cycle
         # as a write observes it
@@ -569,14 +594,15 @@ class LookupServer:
                 else:
                     tracer.close_span(handle)
                     self._complete(req, value, None, samples, own_dispatch=True)
-        self.metrics.on_batch(len(batch))
-        self.metrics.on_complete_batch(samples)
-        cycle_s = time.perf_counter() - t0
-        self.metrics.observe_dispatch(len(batch), cycle_s)
-        # telemetry plane: tail-sample the cycle's completion records
-        # and note the cycle summary in the flight ring — a constant
-        # number of lock rounds regardless of batch size
-        self.plane.on_cycle(len(batch), cycle_s, samples)
+        with tracer.span("serve:account"):
+            self.metrics.on_batch(len(batch))
+            self.metrics.on_complete_batch(samples)
+            cycle_s = time.perf_counter() - t0
+            self.metrics.observe_dispatch(len(batch), cycle_s)
+            # telemetry plane: tail-sample the cycle's completion records
+            # and note the cycle summary in the flight ring — a constant
+            # number of lock rounds regardless of batch size
+            self.plane.on_cycle(len(batch), cycle_s, samples)
 
     def _run_writes(
         self, reg: _Registered, reqs: List[ServeFuture], samples: List[tuple]
@@ -599,39 +625,39 @@ class LookupServer:
         (writes sequenced before the failure may have applied, but no
         caller was promised anything; an unsynced tail is not
         replayed)."""
-        t_a = time.perf_counter()
         wal_stats = None
         rows_appended = 0
         append_reqs = delete_reqs = 0
         try:
-            run: List[Row] = []
-            for req in reqs:
-                if req.rows is not None:
-                    append_reqs += 1
-                    run.extend(req.rows)
-                    continue
+            with tracer.span("serve:append"):
+                run: List[Row] = []
+                for req in reqs:
+                    if req.rows is not None:
+                        append_reqs += 1
+                        run.extend(req.rows)
+                        continue
+                    if run:
+                        reg.impl.append_rows(run)
+                        rows_appended += len(run)
+                        run = []
+                    delete_reqs += 1
+                    reg.impl.delete(req.del_key)
                 if run:
                     reg.impl.append_rows(run)
                     rows_appended += len(run)
-                    run = []
-                delete_reqs += 1
-                reg.impl.delete(req.del_key)
-            if run:
-                reg.impl.append_rows(run)
-                rows_appended += len(run)
-            sync = getattr(reg.impl, "wal_sync", None)
-            if sync is not None:
-                wal_stats = sync()
+                sync = getattr(reg.impl, "wal_sync", None)
+                if sync is not None:
+                    wal_stats = sync()
         except Exception as err:
             for req in reqs:
                 self._complete(req, None, err, samples, batch_n=len(reqs))
         else:
-            phases = (("serve:append", t_a, time.perf_counter()),)
-            for req in reqs:
-                self._complete(
-                    req, len(req.rows) if req.rows is not None else 1,
-                    None, samples, batch_n=len(reqs), phases=phases,
-                )
+            with tracer.span("serve:scatter"):
+                for req in reqs:
+                    self._complete(
+                        req, len(req.rows) if req.rows is not None else 1,
+                        None, samples, batch_n=len(reqs),
+                    )
         self.metrics.on_index_batch(
             reg.name,
             append_reqs=append_reqs,
@@ -694,26 +720,26 @@ class LookupServer:
             ]
             return min(budgets) if budgets else None
 
-        def primary_pass():
+        def find(source, fault_site=None):
             # find_rows_many decomposed so the coalesced batch's two
-            # phases carry their own timestamps; each request's trace
-            # gets both as batch-shared children of its dispatch span.
+            # phases are spans of their own (live when the cycle is
+            # traced, with the layers' own children below them).
             # A MutableIndex's bounds carry read-amplification counters
             # (tiers probed / pruned); a plain Index returns a list —
             # getattr reads None and the metrics cell stays untouched.
-            t_a = time.perf_counter()
-            faults.inject("serve:bounds")
-            bounds = reg.impl.bounds_many(probes)
-            t_b = time.perf_counter()
-            groups = reg.impl.rows_for_bounds(bounds)
-            return t_a, t_b, time.perf_counter(), groups, bounds
+            with tracer.span("serve:bounds"):
+                if fault_site is not None:
+                    faults.inject(fault_site)
+                bounds = source.bounds_many(probes)
+            with tracer.span("serve:gather-decode"):
+                groups = source.rows_for_bounds(bounds)
+            return groups, bounds
+
+        def primary_pass():
+            return find(reg.impl, "serve:bounds")
 
         def fallback_pass():
-            t_a = time.perf_counter()
-            bounds = reg.oracle.bounds_many(probes)
-            t_b = time.perf_counter()
-            groups = reg.oracle.rows_for_bounds(bounds)
-            return t_a, t_b, time.perf_counter(), groups, bounds
+            return find(reg.oracle)
 
         def on_retry(attempt, err):
             self.metrics.on_retry()
@@ -722,10 +748,10 @@ class LookupServer:
         degraded = self.breaker.route() == "fallback"
         try:
             if degraded:
-                t_a, t_b, t_c, groups, bounds = fallback_pass()
+                groups, bounds = fallback_pass()
             else:
                 try:
-                    t_a, t_b, t_c, groups, bounds = call_with_retry(
+                    groups, bounds = call_with_retry(
                         primary_pass,
                         policy=self.retry_policy,
                         time_left=time_left,
@@ -741,38 +767,35 @@ class LookupServer:
                     # serve the batch from the host oracle instead of
                     # failing it back to callers
                     degraded = True
-                    t_a, t_b, t_c, groups, bounds = fallback_pass()
+                    groups, bounds = fallback_pass()
         except Exception as err:
             for req in lookups:
                 self._complete(req, None, err, samples, batch_n=len(lookups))
             self.metrics.on_index_batch(reg.name, lookups=len(lookups))
             return
-        if degraded:
-            self.metrics.on_degraded(len(lookups))
-        self.metrics.on_index_batch(
-            reg.name,
-            lookups=len(lookups),
-            tiers_probed=getattr(bounds, "tiers_probed", None),
-            tiers_pruned=getattr(bounds, "tiers_pruned", None),
-        )
-        # skew evidence: the sub-batch's probe keys into this index's
-        # Space-Saving sketch, one lock round
-        self.plane.offer_probes(reg.name, probes)
-        phases = (
-            ("serve:bounds", t_a, t_b),
-            ("serve:gather-decode", t_b, t_c),
-        )
-        for req, rows in zip(lookups, groups):
-            # clone on delivery: blocks may be shared with the
-            # mirror LRU (same contract as iterate/_rows_hint)
-            self._complete(
-                req,
-                [Row(r) for r in rows],
-                None,
-                samples,
-                batch_n=len(lookups),
-                phases=phases,
+        with tracer.span("serve:account"):
+            if degraded:
+                self.metrics.on_degraded(len(lookups))
+            self.metrics.on_index_batch(
+                reg.name,
+                lookups=len(lookups),
+                tiers_probed=getattr(bounds, "tiers_probed", None),
+                tiers_pruned=getattr(bounds, "tiers_pruned", None),
             )
+            # skew evidence: the sub-batch's probe keys into this index's
+            # Space-Saving sketch, one lock round
+            self.plane.offer_probes(reg.name, probes)
+        with tracer.span("serve:scatter"):
+            for req, rows in zip(lookups, groups):
+                # clone on delivery: blocks may be shared with the
+                # mirror LRU (same contract as iterate/_rows_hint)
+                self._complete(
+                    req,
+                    [Row(r) for r in rows],
+                    None,
+                    samples,
+                    batch_n=len(lookups),
+                )
 
     def _execute_plan_with_retry(self, req: ServeFuture):
         """Execute one plan query through the cache, retrying transient
@@ -840,7 +863,6 @@ class LookupServer:
         error,
         samples: List[tuple],
         batch_n: int = 0,
-        phases: Sequence[tuple] = (),
         own_dispatch: bool = False,
     ) -> None:
         if req._done:
@@ -876,15 +898,15 @@ class LookupServer:
         )
         if req.trace_ctx is not None:
             # attribute the dispatcher's work back into the SUBMITTER's
-            # span tree: queue-wait, then the dispatch window with the
-            # coalesced batch's phases as batch-shared children
+            # span tree: queue-wait, then this request's own dispatch
+            # window; the batch-shared phases are the cycle's subtree
             trace, parent = req.trace_ctx
             t_disp = req.t_dispatch or done
             tracer.record_span(
                 trace, parent, "serve:queue-wait", req.t_submit, t_disp
             )
             if not own_dispatch:
-                dspan = tracer.record_span(
+                tracer.record_span(
                     trace,
                     parent,
                     "serve:dispatch",
@@ -893,12 +915,10 @@ class LookupServer:
                     outcome=outcome,
                     batch=batch_n,
                 )
-                for name, ts, te in phases:
-                    tracer.record_span(
-                        trace, dspan.span_id, name, ts, te,
-                        shared=batch_n > 1, batch=batch_n,
-                    )
         if req.callback is not None:
+            # the caller's code never inherits the cycle's context: what
+            # it submits from here captures the context it adopts itself
+            outside = tracer.suspend()
             try:
                 req.callback(req)
             except Exception as cb_err:
@@ -911,6 +931,8 @@ class LookupServer:
                     f"{type(cb_err).__name__}: {cb_err} (request completed; "
                     f"see metrics callback_errors)\n"
                 )
+            finally:
+                tracer.resume(outside)
         else:
             req._event.set()
 

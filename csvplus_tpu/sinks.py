@@ -20,6 +20,34 @@ from typing import IO, List
 from .csvio import write_record
 from .row import Row
 from .utils.gojson import go_json_object
+from .utils.observe import telemetry
+
+
+def _device_table(src):
+    """The executed table of a device-planned source (memoized: a prefix
+    never runs twice), None for a host source."""
+    if getattr(src, "plan", None) is None:
+        return None
+    from .columnar.exec import device_table_for
+
+    return device_table_for(src)
+
+
+def _position(out):
+    """Where *out* stands — bytes in a file, characters in a StringIO —
+    or None for a stream that cannot say (a pipe)."""
+    try:
+        return out.tell()
+    except (AttributeError, OSError, ValueError):
+        return None
+
+
+def _note_written(stage: dict, rows: int, out, start) -> None:
+    """A sink stage's extras: rows and bytes it wrote."""
+    stage["rows_out"] = rows
+    end = _position(out)
+    if start is not None and end is not None:
+        stage["bytes"] = end - start
 
 
 def to_csv(src, out: IO[str], *columns: str) -> None:
@@ -35,31 +63,39 @@ def to_csv(src, out: IO[str], *columns: str) -> None:
 
     write_record(out, list(columns))
 
-    if getattr(src, "plan", None) is not None:
-        from .columnar.csvenc import encode_csv_body
-        from .columnar.exec import device_table_for
-
-        table = device_table_for(src)  # memoized: never runs a prefix twice
+    # the plan runs here, before the stage: ``sink:csv`` times the write
+    # loop (for a host source that loop also pulls the lazy chain)
+    table = _device_table(src)
+    with telemetry.stage("sink:csv", table.nrows if table is not None else 0) as stage:
+        start = _position(out)
         if table is not None:
+            from .columnar.csvenc import encode_csv_body
+
             body = encode_csv_body(table, columns)
             if body is not None:
                 out.write(body)
-                return
-            # stream the already-computed table for exact per-row
-            # missing-column errors / partial output
-            from .source import iterate
+            else:
+                # stream the already-computed table for exact per-row
+                # missing-column errors / partial output
+                from .source import iterate
 
-            iterate(
-                table.to_rows(),
-                lambda row: write_record(out, row.select_values(*columns)),
-                clone=False,
-            )
+                iterate(
+                    table.to_rows(),
+                    lambda row: write_record(out, row.select_values(*columns)),
+                    clone=False,
+                )
+            _note_written(stage, table.nrows, out, start)
             return
 
-    def fn(row: Row) -> None:
-        write_record(out, row.select_values(*columns))
+        rows = 0
 
-    src(fn)
+        def fn(row: Row) -> None:
+            nonlocal rows
+            rows += 1
+            write_record(out, row.select_values(*columns))
+
+        src(fn)
+        _note_written(stage, rows, out, start)
 
 
 def to_csv_file(src, name: str, *columns: str) -> None:
@@ -80,15 +116,16 @@ def to_json(src, out: IO[str]) -> None:
     always-escaped U+2028/U+2029) are reproduced by
     :func:`csvplus_tpu.utils.gojson.go_json_object`.
     """
-    if getattr(src, "plan", None) is not None:
-        from .columnar.csvenc import encode_json_body
-        from .columnar.exec import device_table_for
-
-        table = device_table_for(src)
+    table = _device_table(src)  # the plan runs before the stage, as in to_csv
+    with telemetry.stage("sink:json", table.nrows if table is not None else 0) as stage:
+        start = _position(out)
         if table is not None:
+            from .columnar.csvenc import encode_json_body
+
             body = encode_json_body(table)
             if body is not None:
                 out.write("[" + body + "]")
+                _note_written(stage, table.nrows, out, start)
                 return
             # heterogeneous rows: stream the computed table instead
             from .source import iterate
@@ -97,28 +134,29 @@ def to_json(src, out: IO[str]) -> None:
             iterate(table.to_rows(), rows_out.append, clone=False)
             src = lambda fn: [fn(r) for r in rows_out]  # noqa: E731
 
-    buf: List[str] = ["["]
-    buf_len = 1
-    count = 0
+        buf: List[str] = ["["]
+        buf_len = 1
+        count = 0
 
-    def emit(row: Row) -> None:
-        nonlocal buf_len, count
-        count += 1
-        if count != 1:
-            buf.append(",")
-            buf_len += 1
-        s = go_json_object(row) + "\n"
-        buf.append(s)
-        buf_len += len(s)
-        if buf_len > 10000:
-            out.write("".join(buf))
-            buf.clear()
-            buf_len = 0
+        def emit(row: Row) -> None:
+            nonlocal buf_len, count
+            count += 1
+            if count != 1:
+                buf.append(",")
+                buf_len += 1
+            s = go_json_object(row) + "\n"
+            buf.append(s)
+            buf_len += len(s)
+            if buf_len > 10000:
+                out.write("".join(buf))
+                buf.clear()
+                buf_len = 0
 
-    src(emit)
+        src(emit)
 
-    buf.append("]")
-    out.write("".join(buf))
+        buf.append("]")
+        out.write("".join(buf))
+        _note_written(stage, count, out, start)
 
 
 def to_json_file(src, name: str) -> None:

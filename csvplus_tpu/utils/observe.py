@@ -19,20 +19,28 @@ points:
 * :func:`profile_to` — context manager around ``jax.profiler.trace`` so
   a whole pipeline run can be captured for XProf/Perfetto; the span
   exporter (:func:`csvplus_tpu.obs.export.export_chrome_trace`) writes
-  the host-side trace into the same ``log_dir`` so both open together;
-* ``TraceAnnotation`` pass-through so executor stages show up as named
-  ranges inside device traces.
+  the host-side trace into the same ``log_dir`` so both open together.
+  A stage shows up as a named range inside the device trace whenever a
+  span trace is active: the span it opens carries the annotation
+  (``obs/span.py`` is the one emitter).
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List
 
 from ..obs.span import tracer
+
+# the innermost open stage's ``out`` dict in this context, while
+# collection is on: where ``barrier()`` notes that the stage blocked
+_OPEN_STAGE: "contextvars.ContextVar[dict | None]" = contextvars.ContextVar(
+    "csvplus_open_stage", default=None
+)
 
 # count-shaped stage extras that SUM when records of one stage name
 # merge (next to the ``_s``-suffix per-worker second tallies); the skew
@@ -73,6 +81,10 @@ class Telemetry:
     # evidence that the multi-chip probe path crosses O(1)-ish data per
     # stage, not O(n) (VERDICT round-2 weak #3's done criterion)
     host_sync_elements: int = 0
+    # the blocking device->host reads those elements crossed in (one per
+    # ``count_sync`` call): a round trip costs the same for 1 element
+    # as for 64, so the serve path is judged on this count
+    host_syncs: int = 0
     # generic named counters for subsystems whose evidence is a tally,
     # not a stage timing — e.g. the plan verifier's diagnostics-per-rule
     # counts ("verify.resolution", "verify.divergence-risk", ...)
@@ -87,12 +99,14 @@ class Telemetry:
         with self._lock:
             self.records.clear()
             self.host_sync_elements = 0
+            self.host_syncs = 0
             self.counters.clear()
 
     def count_sync(self, n: int) -> None:
         if self.enabled:
             with self._lock:
                 self.host_sync_elements += int(n)
+                self.host_syncs += 1
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump a named counter (no-op unless collection is enabled)."""
@@ -120,24 +134,30 @@ class Telemetry:
         Span shim: when a trace is active in the calling context
         (:data:`csvplus_tpu.obs.span.tracer`), the stage also opens a
         child span there — the hierarchical view needs no new call
-        sites.  The span keeps even discarded/failed stages (annotated),
-        because a trace records what HAPPENED, while the table records
-        what counted."""
+        sites — and the span carries the ``csvplus:<stage>`` profiler
+        annotation.  The span keeps even discarded/failed stages
+        (annotated), because a trace records what HAPPENED, while the
+        table records what counted.  A stage inside which
+        :meth:`barrier` blocked says so: ``synced`` and ``wait_s`` in
+        its extras (and span attrs), so ``seconds - wait_s`` is the
+        host's own time in the stage."""
         handle = tracer.open_span(name, rows_in=int(rows_in))
         if not self.enabled and handle is None:
             yield {}
             return
         out: dict = {}
         t0 = time.perf_counter()
+        token = _OPEN_STAGE.set(out) if self.enabled else None
         try:
-            with _trace_annotation(f"csvplus:{name}"):
-                yield out
+            yield out
         except BaseException:
             if handle is not None:
                 tracer.close_span(handle, error=True, **out)
                 handle = None
             raise
         finally:
+            if token is not None:
+                _OPEN_STAGE.reset(token)
             if handle is not None:
                 tracer.close_span(handle, **out)
         if out.get("discard") or not self.enabled:
@@ -160,14 +180,20 @@ class Telemetry:
     def barrier(self, x):
         """``jax.block_until_ready(x)`` when collecting, so async device
         work lands inside the stage that dispatched it and per-stage
-        times are attributable.  A strict no-op (and zero dispatch-
-        overlap cost) when collection is off — headline timings are
-        measured with telemetry disabled, the per-stage table with it
-        enabled."""
+        times are attributable; the open stage records that it blocked
+        and for how long (``synced``, ``wait_s``).  A strict no-op (and
+        zero dispatch-overlap cost) when collection is off — headline
+        timings are measured with telemetry disabled, the per-stage
+        table with it enabled."""
         if self.enabled and x is not None:
             import jax
 
+            t0 = time.perf_counter()
             jax.block_until_ready(x)
+            out = _OPEN_STAGE.get()
+            if out is not None:
+                out["synced"] = True
+                out["wait_s"] = out.get("wait_s", 0.0) + time.perf_counter() - t0
         return x
 
     def add_stage(
@@ -239,6 +265,7 @@ class Telemetry:
         with self._lock:
             counters = dict(self.counters)
             host_sync = self.host_sync_elements
+            host_syncs = self.host_syncs
         return {
             "stage_table": [
                 {
@@ -252,6 +279,7 @@ class Telemetry:
             ],
             "counters": counters,
             "host_sync_elements": host_sync,
+            "host_syncs": host_syncs,
         }
 
     def report(self) -> str:
@@ -272,21 +300,6 @@ class Telemetry:
 
 
 telemetry = Telemetry()
-
-
-@contextlib.contextmanager
-def _trace_annotation(name: str):
-    # best-effort: only the annotation SETUP may be swallowed — exceptions
-    # from the body must propagate unchanged (a yield inside the except
-    # would turn them into "generator didn't stop after throw()")
-    try:
-        import jax.profiler
-
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        cm = contextlib.nullcontext()
-    with cm:
-        yield
 
 
 @contextlib.contextmanager

@@ -245,13 +245,17 @@ def test_serve_per_request_span_trees():
         assert dsp.parent_id == root.span_id
         assert qw.t_start <= dsp.t_start  # queue-wait precedes dispatch
         assert dsp.attrs["outcome"] == "ok"
-        # the coalesced batch's phases are children of the dispatch span
+        # the coalesced batch's phases are children of the cycle span,
+        # which lands once in each request's own tree (ISSUE 25; they
+        # hung under serve:dispatch before the cycle was a span)
+        cyc = next(s for s in spans if s.name == "serve:cycle")
+        assert cyc.parent_id == root.span_id
         phases = [
             s for s in spans
             if s.name in ("serve:bounds", "serve:gather-decode")
         ]
         assert len(phases) == 2
-        assert all(by_id[s.parent_id] is dsp for s in phases)
+        assert all(by_id[s.parent_id] is cyc for s in phases)
 
 
 def test_serve_plan_spans_nest_executor_stages():
@@ -635,3 +639,309 @@ def test_stage_record_str_and_collect_reset():
         assert len(records) == 1
     # collect() restores the previous enabled state
     assert not telemetry.enabled
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: one emitter on one clock, the shared region, stage waits,
+# kernel names on the device
+# ---------------------------------------------------------------------------
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what was
+    constructed, entered and left."""
+
+    made: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+        type(self).made.append(self)
+        self.entered = self.left = False
+
+    def __enter__(self):
+        self.entered = True
+        return self
+
+    def __exit__(self, *exc):
+        self.left = True
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+
+    _CountingAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    return _CountingAnnotation.made
+
+
+def test_spans_annotate_the_profiler_only_while_a_trace_is_active(annotations):
+    with tracer.span("quiet"):
+        pass
+    with telemetry.collect():  # the stage table alone opens no annotation
+        with telemetry.stage("quiet-stage", 1):
+            pass
+    assert annotations == []
+    with tracer.trace("run") as tr:
+        with tracer.span("a"):
+            with telemetry.stage("b", 3):
+                pass
+        h = tracer.open_span("c")
+        tracer.close_span(h)
+    assert [a.name for a in annotations] == [
+        "csvplus:anchor", "csvplus:run", "csvplus:a", "csvplus:b", "csvplus:c",
+    ]
+    assert all(a.entered and a.left for a in annotations)
+    # the anchor ties the trace's perf_counter origin to the profiler's clock
+    assert annotations[0].meta == {"trace_id": tr.trace_id, "perf_counter": tr.t_anchor}
+
+
+def test_export_counts_from_the_anchor_and_applies_the_profilers_offset():
+    with tracer.trace("run") as tr:
+        t0 = time.perf_counter()
+        tracer.record_span(tr, tr.root, "after-the-fact", t0, t0 + 0.002)
+    events = chrome_trace_events([tr], anchor_ts_us=5000.0)
+    anchor = next(e for e in events if e["name"] == "csvplus:anchor")
+    assert anchor["ph"] == "i" and anchor["ts"] == 5000.0
+    assert anchor["args"] == {"trace_id": tr.trace_id, "perf_counter": tr.t_anchor}
+    late = next(e for e in events if e["name"] == "after-the-fact")
+    assert late["ts"] == pytest.approx(5000.0 + (t0 - tr.t_anchor) * 1e6, abs=1e-2)
+    assert late["dur"] == pytest.approx(2000.0, abs=1e-2)
+    assert validate_chrome_trace(events) == []
+    # without the profiler's reading the anchor is the origin
+    assert next(
+        e for e in chrome_trace_events([tr]) if e["name"] == "csvplus:anchor"
+    )["ts"] == 0.0
+
+
+def test_anchor_places_recorded_spans_on_the_profilers_clock(tmp_path):
+    """A live span's annotation in the profile and the same span placed
+    through the anchor agree: one clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from csvplus_tpu.obs.export import anchor_in_profile
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tracer.trace("run") as tr:
+            with tracer.span("live"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    at = anchor_in_profile(path, tr.trace_id)
+    assert at is not None and anchor_in_profile(path, tr.trace_id + 10_000) is None
+    seen = [
+        ev.start_ns / 1e3
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "csvplus:live"
+    ]
+    assert len(seen) == 1
+    placed = next(
+        e for e in chrome_trace_events([tr], anchor_ts_us=at) if e["name"] == "live"
+    )
+    assert placed["ts"] == pytest.approx(seen[0], abs=500.0)  # microseconds
+
+
+def test_shared_region_lands_once_in_every_context(annotations):
+    with tracer.trace("one") as t1:
+        c1 = tracer.capture()
+        with tracer.span("deeper"):
+            c1b = tracer.capture()
+    with tracer.trace("two") as t2:
+        c2 = tracer.capture()
+    del annotations[:]
+    with tracer.shared("cycle", [c1, c2, c1, c1b], batch=4) as attrs:
+        attrs["note"] = "x"
+        with tracer.span("phase") as p:
+            p["host_syncs"] = 2
+            with tracer.span("leaf"):
+                pass
+        assert tracer.active()
+    assert not tracer.active()
+    assert [a.name for a in annotations] == [
+        "csvplus:cycle", "csvplus:phase", "csvplus:leaf",
+    ]
+    seen_ids = set()
+    for tr, parents in ((t1, {c1[1], c1b[1]}), (t2, {c2[1]})):
+        spans = tr.snapshot()
+        by_id = {s.span_id: s for s in spans}
+        cycles = [s for s in spans if s.name == "cycle"]
+        # once per distinct (trace, parent): c1 twice is one copy
+        assert {s.parent_id for s in cycles} == parents and len(cycles) == len(parents)
+        for cyc in cycles:
+            assert cyc.attrs == {"batch": 4, "note": "x"} and cyc.trace_id == tr.trace_id
+            (phase,) = [s for s in spans if s.name == "phase" and s.parent_id == cyc.span_id]
+            (leaf,) = [s for s in spans if s.name == "leaf" and s.parent_id == phase.span_id]
+            assert phase.attrs == {"host_syncs": 2}
+            assert cyc.t_start <= phase.t_start <= leaf.t_start <= leaf.t_end <= phase.t_end <= cyc.t_end
+            ids = {cyc.span_id, phase.span_id, leaf.span_id}
+            assert not ids & seen_ids  # every copy has ids of its own
+            seen_ids |= ids
+        assert by_id  # the trees stayed whole
+    times = {
+        (s.name, s.t_start, s.t_end)
+        for tr in (t1, t2) for s in tr.snapshot() if s.name in ("cycle", "phase", "leaf")
+    }
+    assert len(times) == 3  # the same moments in every tree
+
+
+def test_suspend_leaves_the_context_and_resume_returns():
+    assert tracer.suspend() is None  # nothing to leave
+    tracer.resume(None)
+    with tracer.trace("run"):
+        ctx = tracer.capture()
+        token = tracer.suspend()
+        assert tracer.capture() is None and not tracer.active()
+        tracer.resume(token)
+        assert tracer.capture() == ctx
+
+
+def test_stage_says_whether_it_blocked_on_the_device_and_for_how_long():
+    import jax.numpy as jnp
+
+    x = jnp.arange(1000)
+    with telemetry.collect() as recs:
+        with telemetry.stage("blocked", 1000):
+            telemetry.barrier(x + 1)
+            telemetry.barrier(x + 2)  # waits add up
+        with telemetry.stage("free", 1000):
+            _ = x + 3
+        with telemetry.stage("outer", 1):
+            with telemetry.stage("inner", 1):
+                telemetry.barrier(x)
+    by = {r.stage: r for r in recs}
+    assert by["blocked"].extra["synced"] is True
+    assert 0.0 <= by["blocked"].extra["wait_s"] <= by["blocked"].seconds
+    assert by["free"].extra == {}
+    # the innermost open stage is the one that blocked
+    assert "synced" in by["inner"].extra and by["outer"].extra == {}
+    with tracer.trace("run") as tr, telemetry.collect():
+        with telemetry.stage("blocked", 1):
+            telemetry.barrier(x)
+    span = next(s for s in tr.snapshot() if s.name == "blocked")
+    assert span.attrs["synced"] is True and "wait_s" in span.attrs
+    # collection off: a strict no-op that records nothing
+    with telemetry.stage("off", 1) as out:
+        telemetry.barrier(x)
+    assert out == {}
+    merged = telemetry.merged_stages()
+    assert [r.stage for r in merged] == ["blocked"]
+
+
+def test_count_sync_counts_reads_beside_elements():
+    telemetry.count_sync(5)  # disabled: nothing
+    assert (telemetry.host_syncs, telemetry.host_sync_elements) == (0, 0)
+    with telemetry.collect():
+        telemetry.count_sync(64)
+        telemetry.count_sync(3)
+        assert (telemetry.host_syncs, telemetry.host_sync_elements) == (2, 67)
+        assert telemetry.to_json()["host_syncs"] == 2
+    telemetry.reset()
+    assert telemetry.host_syncs == 0
+
+
+def _kernel_examples():
+    """name -> (args, kwargs) that lower each single-device kernel."""
+    import jax.numpy as jnp
+
+    i = jnp.arange(8, dtype=jnp.int32)
+    ok = i >= 0
+    return {
+        "join.probe_i32pair": ((i, i, i, i, i, i, ok), {}),
+        "join.probe_direct": ((i, i, i), {}),
+        "join.probe_i32": ((i, i, i), {}),
+        "serve.bounds_search": ((i, i), {}),
+        "join.build_direct_cum": ((i,), {"total_bits": 4}),
+        "join.pack_qk": (((i, i),), {"shifts": (4, 0)}),
+        "join.expand": ((i, i), {"padded_total": 16}),
+        "join.gather_both_sides": (((i,), (i,), i, i), {}),
+        "join.gather_cols": (((i, i), i), {}),
+        "join.probe_stats": ((i, i), {}),
+        "join.multiway_stats": (((i, i),), {}),
+        "join.multiway_select": (((i, i), (i, i)), {"padded": 8}),
+        "join.multiway_expand": (((i, i), (i, i)), {"padded_total": 16}),
+        "join.gather_multiway": ((((i,), (i, i)), (i, i)), {}),
+        "join.gather_multiway_both": ((((i,), (i,)), (i, i), (i, i), i), {}),
+        "join.gather_fused_both": ((((i,), (i,)), (i, i), (i, i), i, i), {}),
+        "typed.translate_dense": ((i, jnp.int32(0), i), {}),
+        "typed.translate_sorted": ((i, i, i), {}),
+        "typed.translate_empty": ((i,), {}),
+        "table.gather_take": ((i, i), {}),
+        "table.apply_code_translation": ((i, i), {}),
+        "table.sync_probe": ((i, i), {}),
+    }
+
+
+# the names of _kernel_examples(), spelled out so that collection touches no array
+KERNELS_LOWERED_HERE = sorted([
+    "join.probe_i32pair", "join.probe_direct", "join.probe_i32", "serve.bounds_search",
+    "join.build_direct_cum", "join.pack_qk", "join.expand", "join.gather_both_sides",
+    "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.multiway_select",
+    "join.multiway_expand", "join.gather_multiway", "join.gather_multiway_both",
+    "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
+    "typed.translate_empty", "table.gather_take", "table.apply_code_translation",
+    "table.sync_probe",
+])
+
+
+@pytest.mark.parametrize("name", KERNELS_LOWERED_HERE)
+def test_registered_kernel_lowers_under_its_scope_and_program_name(name):
+    """What names a kernel on the device: the program is called
+    jit_csvplus.<name> (the XLA Modules line of a TPU profile) and every
+    operation's op_name starts under the csvplus.<name> scope."""
+    import csvplus_tpu.columnar.typed  # noqa: F401 — registration side effect
+    import csvplus_tpu.ops.join  # noqa: F401
+
+    args, kwargs = _kernel_examples()[name]
+    text = registered_kernels()[name].lower(*args, **kwargs).as_text(debug_info=True)
+    assert f"module @jit_csvplus.{name} " in text
+    assert f"jit(csvplus.{name})/csvplus.{name}/" in text
+
+
+def test_every_registered_kernel_is_named_and_the_lowered_set_is_whole():
+    import csvplus_tpu.columnar.typed  # noqa: F401
+    import csvplus_tpu.ops.join  # noqa: F401
+    import csvplus_tpu.parallel.pjoin  # noqa: F401
+
+    kernels = {k: f for k, f in registered_kernels().items() if not k.startswith("test.")}
+    for name, fn in kernels.items():
+        assert fn.__name__ == f"csvplus.{name}"  # what jit calls the program
+    # the mesh kernels need devices to lower; everything else lowers above
+    assert {k for k in kernels if not k.startswith("pjoin.")} == set(KERNELS_LOWERED_HERE)
+    assert set(_kernel_examples()) == set(KERNELS_LOWERED_HERE)
+
+
+def test_warm_join_and_lookup_pass_recompiles_nothing(monkeypatch):
+    """RecompileWatch.assert_zero over a warm pass still holds with the
+    kernels jitted, named and registered by one decorator — the lookup
+    past the mirror cap included (its searchsorted and gathers are
+    registered kernels now)."""
+    from csvplus_tpu.ops.join import DeviceIndex
+
+    monkeypatch.setattr(DeviceIndex, "POINT_MIRROR_MAX_KEYS", 100)
+    idx, ids = _build_index(n=3_000)
+    orders = DeviceTable.from_pylists(
+        {"id": [f"c{int(v)}" for v in ids[:500]], "q": [str(i) for i in range(500)]},
+        device="cpu",
+    )
+    probes = [f"c{int(v)}" for v in ids[:16]]
+
+    def one_pass():
+        joined = cp.take(orders).join(idx, "id").to_rows()
+        found = idx._impl.find_rows_many([(p,) for p in probes])
+        return len(joined), [len(b) for b in found]
+
+    cold = one_pass()
+    with RecompileWatch() as w:
+        assert one_pass() == cold
+    w.assert_zero("warm join + lookup pass")
+    counts = compile_counts()
+    assert counts["serve.bounds_search"] >= 1 and counts["table.gather_take"] >= 1

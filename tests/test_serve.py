@@ -594,3 +594,176 @@ def test_same_cycle_delete_append_apply_in_submission_order(served):
     assert index_checksums(mi.to_index()) == index_checksums(
         rebuild_reference(mi)
     )
+
+
+# -- the dispatcher's own span tree (ISSUE 25) -----------------------------
+
+CYCLE_TREE = {
+    # span -> its parent inside one dispatch cycle
+    "serve:sweep": "serve:cycle",
+    "serve:bounds": "serve:cycle",
+    "serve:bounds:encode": "serve:bounds",
+    "serve:bounds:search": "serve:bounds",
+    "serve:gather-decode": "serve:cycle",
+    "serve:gather:index": "serve:gather-decode",
+    "serve:gather:take": "serve:gather-decode",
+    "serve:gather:readback": "serve:gather-decode",
+    "serve:gather:rows": "serve:gather-decode",
+    "serve:scatter": "serve:cycle",
+    "serve:account": "serve:cycle",
+}
+
+
+@pytest.fixture
+def past_mirror_cap(monkeypatch):
+    """Lookups take the device path (searchsorted, gather and read-back
+    on the device), as an index past the 16M mirror cap does."""
+    from csvplus_tpu.ops.join import DeviceIndex
+
+    monkeypatch.setattr(DeviceIndex, "POINT_MIRROR_MAX_KEYS", 100)
+
+
+def test_untraced_dispatch_opens_no_span_and_no_annotation(served, past_mirror_cap, monkeypatch):
+    """With no trace active nothing new runs: the dispatcher's cycle
+    makes no Span and constructs no TraceAnnotation."""
+    import jax.profiler
+
+    from csvplus_tpu.obs import span as span_mod
+
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **meta):
+            made.append(("annotation", name))
+
+    real_span = span_mod.Span
+
+    def counting_span(*a, **k):
+        made.append(("span", k.get("name")))
+        return real_span(*a, **k)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(span_mod, "Span", counting_span)
+    idx, ids = served
+    probes = _probes(ids, 60, seed=5)
+    serial = [idx.find(p).to_rows() for p in probes]
+    with LookupServer(idx) as srv:
+        got = [f.result(timeout=30) for f in [srv.submit(p) for p in probes]]
+    assert got == serial
+    assert made == []
+
+
+def test_traced_cycle_lands_whole_in_every_requests_tree(served, past_mirror_cap):
+    from csvplus_tpu.obs.span import tracer
+
+    idx, ids = served
+    n_clients = 12
+    traces = [None] * n_clients
+    with LookupServer(idx) as srv:
+        # warm the shapes, then hold the dispatcher so that the clients'
+        # requests coalesce into as few cycles as possible
+        srv.lookup(f"c{int(ids[0])}")
+        barrier = threading.Barrier(n_clients)
+
+        def client(i):
+            with tracer.trace(f"client-{i}") as tr:
+                barrier.wait()
+                assert srv.submit(f"c{int(ids[i])}").result(timeout=30)
+            traces[i] = tr
+
+        threads = [threading.Thread(target=client, args=(i,)) for i in range(n_clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    # the server has stopped: every cycle's subtree has landed
+    by_cycle: dict = {}
+    for tr in traces:
+        spans = tr.snapshot()
+        by_id = {s.span_id: s for s in spans}
+        root = tr.root()
+        (cycle,) = [s for s in spans if s.name == "serve:cycle"]
+        assert cycle.parent_id == root.span_id and cycle.attrs["batch"] >= 1
+        for name, parent in CYCLE_TREE.items():
+            found = [s for s in spans if s.name == name]
+            # the accounts are two: the index's counters after its
+            # lookups, the cycle's own at its end
+            assert len(found) == (2 if name == "serve:account" else 1), (name, len(found))
+            for s in found:
+                up = by_id[s.parent_id]
+                assert up.name == parent, name
+                assert up.t_start <= s.t_start <= s.t_end <= up.t_end, name
+                assert cycle.t_start <= s.t_start and s.t_end <= cycle.t_end
+        # the request's own two spans stay what they were
+        (qw,) = [s for s in spans if s.name == "serve:queue-wait"]
+        (dsp,) = [s for s in spans if s.name == "serve:dispatch"]
+        assert qw.parent_id == dsp.parent_id == root.span_id
+        assert qw.t_end == dsp.t_start and cycle.t_start <= dsp.t_start
+        assert dsp.t_end <= cycle.t_end  # the cycle goes on after this reply
+        # counts at the boundaries: one read for the bounds, one per column
+        attrs = {s.name: s.attrs for s in spans}
+        m = cycle.attrs["batch"]
+        assert attrs["serve:bounds:search"]["host_syncs"] == 1
+        assert attrs["serve:bounds:search"]["elements"] == 2 * m
+        assert attrs["serve:gather:take"]["dispatches"] == 2
+        assert attrs["serve:gather:readback"]["host_syncs"] == 2
+        assert attrs["serve:gather:readback"]["elements"] == 2 * m
+        by_cycle.setdefault((cycle.t_start, cycle.t_end), []).append(spans)
+    # timestamps are equal across the requests of a batch
+    assert len(by_cycle) < n_clients  # some requests did share a cycle
+    for trees in by_cycle.values():
+        stamps = [
+            sorted((s.name, s.t_start, s.t_end) for s in spans if s.name in CYCLE_TREE)
+            for spans in trees
+        ]
+        assert all(st == stamps[0] for st in stamps)
+
+
+def test_requests_sharing_one_tree_hold_the_cycle_once(served, past_mirror_cap):
+    from csvplus_tpu.obs.span import tracer
+
+    idx, ids = served
+    with LookupServer(idx) as srv:
+        with tracer.trace("one-client") as tr:
+            futs = [srv.submit(f"c{int(v)}") for v in ids[:40]]
+            for f in futs:
+                f.result(timeout=30)
+    spans = tr.snapshot()
+    names = [s.name for s in spans]
+    assert names.count("serve:queue-wait") == names.count("serve:dispatch") == 40
+    cycles = [s for s in spans if s.name == "serve:cycle"]
+    assert sum(c.attrs["batch"] for c in cycles) == 40
+    # one subtree per cycle, however many of its requests share the tree
+    assert len({(c.t_start, c.t_end) for c in cycles}) == len(cycles)
+    assert names.count("serve:bounds") == names.count("serve:scatter") == len(cycles)
+
+
+def test_callbacks_do_not_inherit_the_cycles_context(served):
+    from csvplus_tpu.obs.span import tracer
+
+    idx, ids = served
+    seen, done = [], threading.Event()
+
+    def on_reply(fut):
+        seen.append(tracer.capture())
+        done.set()
+
+    with LookupServer(idx) as srv:
+        with tracer.trace("client"):
+            srv.submit(f"c{int(ids[3])}", callback=on_reply)
+            assert done.wait(30)
+    assert seen == [None]
+
+
+def test_the_wait_after_a_traced_cycle_is_a_span(served):
+    from csvplus_tpu.obs.span import tracer
+
+    idx, ids = served
+    with LookupServer(idx) as srv:
+        with tracer.trace("client") as tr:
+            srv.lookup(f"c{int(ids[1])}")
+        # the dispatcher now waits on its condition variable; stop() ends the wait
+    waits = [s for s in tr.snapshot() if s.name == "serve:idle-wait"]
+    (cycle,) = [s for s in tr.snapshot() if s.name == "serve:cycle"]
+    assert len(waits) == 1 and waits[0].parent_id == cycle.parent_id
+    assert waits[0].t_start >= cycle.t_end

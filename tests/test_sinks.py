@@ -202,3 +202,34 @@ def test_csv_body_native_matches_numpy(monkeypatch):
     buf = io.StringIO()
     TakeRows(rows).to_csv(buf, "a", "b", "c")
     assert native == buf.getvalue().split("\n", 1)[1]
+
+
+@pytest.mark.parametrize("device", [False, True], ids=["host", "device"])
+@pytest.mark.parametrize("kind", ["csv", "json"])
+def test_sink_stage_records_rows_and_bytes_written(people_csv, tmp_path, kind, device):
+    """ISSUE 25: the sinks' write loops are a ``sink:csv`` / ``sink:json``
+    stage with the rows and bytes written as extras (after the header
+    for CSV), on the streaming path and on the vectorized device path;
+    with collection off nothing is recorded."""
+    from csvplus_tpu.utils.observe import telemetry
+
+    def source():
+        src = from_file(people_csv)
+        return Take(src.on_device("cpu") if device else src)
+
+    out_path = str(tmp_path / f"out.{kind}")
+    with telemetry.collect() as recs:
+        if kind == "csv":
+            source().to_csv_file(out_path, "id", "name", "surname")
+        else:
+            source().to_json_file(out_path)
+        (rec,) = [r for r in recs if r.stage == f"sink:{kind}"]
+    with open(out_path, "rb") as f:
+        written = f.read()
+    header = len(b"id,name,surname\n") if kind == "csv" else 0
+    rows = written.count(b"\n") - (1 if kind == "csv" else 0)
+    assert rec.rows_out == rows > 0
+    assert rec.extra["bytes"] == len(written) - header
+    telemetry.reset()
+    source().to_csv_file(out_path, "id", "name")
+    assert telemetry.records == []
