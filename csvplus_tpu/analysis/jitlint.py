@@ -139,13 +139,17 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "elements; the host_sync_elements guard excludes this "
         "stats-synced path by design"
     ),
-    "join.py:multiway_join": (
-        "deliberate multiway stats sync: (total, max fanout, rows "
-        "avoided) in ONE 3-scalar transfer; no transfer of row data"
+    "join.py:_multiway_ids": (
+        "deliberate multiway stats sync (multiway_join and the fused "
+        "multiway_join_selected): (total, max fanout, rows avoided) in "
+        "ONE 3-scalar transfer; no transfer of row data"
     ),
-    "join.py:multiway_join_selected": (
-        "deliberate multiway stats sync on the fused path: one "
-        "3-scalar transfer; no transfer of row data"
+    "join.py:_compose": (
+        "set-up only: the largest and smallest count over a composed "
+        "probe table in ONE 2-scalar transfer, once per (index, probe "
+        "prefix or dictionary), deciding rid_tab and depth 2; runs in a "
+        "plan's first execution, never in steady state, so it stays "
+        "outside the host_sync_elements guard like typed:demote"
     ),
     "lanes.py:union_device": (
         "deliberate: the one scalar union-SIZE sync needed for the "

@@ -540,25 +540,26 @@ class StringColumn:
         lanes = lanes_for_width(MAX_LANE_BYTES)
         return tuple(jax.device_put(l) for l in pack_host(sub, lanes)), pos
 
-    def renumbered_to_col(self, other: "StringColumn") -> jax.Array:
-        """Translate this column's codes into *other*'s code space —
-        the device lane translation when either side keeps its
-        dictionary on device (no host materialization), the host
-        translation-table path otherwise.  Host dictionaries with
-        entries wider than a lane can hold are handled by translating
-        the narrow subset and treating wide values as no-match."""
+    def code_translation_to(self, other: "StringColumn") -> "jax.Array | None":
+        """``trans[code]`` = *other*'s code of the same value, -1 where
+        it has none, over this column's dictionary — the device lane
+        translation when either side keeps its dictionary on device (no
+        host materialization), the host table otherwise.  Host
+        dictionaries with entries wider than a lane can hold are handled
+        by translating the narrow subset and treating wide values as
+        no-match.  None when this column's dictionary is empty (its
+        codes are all negative and answer for themselves).  Settles a
+        deferred lane dictionary: read ``self.codes`` AFTER this call."""
         if self.dev_dictionary is None and other.dev_dictionary is None:
-            return self.renumbered_to(other.dictionary)
+            return self._host_translation(other.dictionary)
         from ..ops.lanes import translate_lanes
 
         if self.dict_size == 0:
-            return self.codes
+            return None
         q_lanes, q_pos = self._lanes_narrow()
         b_lanes, b_pos = other._lanes_narrow()
         if b_lanes[0].shape[0] == 0 or q_lanes[0].shape[0] == 0:
-            # preserve negative code identity (-2 sharding pads stay -2),
-            # matching the main path below
-            return jnp.where(self.codes >= 0, ABSENT, self.codes)
+            return jnp.full(self.dict_size, ABSENT, jnp.int32)
         trans = translate_lanes(b_lanes, q_lanes)
         if b_pos is not None:
             # subset slots of other -> other's full code space
@@ -575,18 +576,13 @@ class StringColumn:
                 .at[jnp.asarray(q_pos)]
                 .set(trans)
             )
-        # negative codes pass through unchanged (-1 absent stays -1,
-        # -2 sharding pads stay -2), same as the empty-lane early return
-        return _apply_code_translation(self.codes, trans)
+        return trans
 
-    def renumbered_to(self, other_dictionary: np.ndarray) -> jax.Array:
-        """Translate this column's codes into another dictionary's code
-        space (host translation table + device gather); unmatched -> -1.
-
-        This is how a probe-side join key enters the index's key space.
-        """
+    def _host_translation(self, other_dictionary: np.ndarray) -> "jax.Array | None":
+        """Host form of :meth:`code_translation_to`: one binary search of
+        this column's dictionary in *other_dictionary*, uploaded."""
         if self.dictionary.size == 0:
-            return self.codes
+            return None
         pos = np.searchsorted(other_dictionary, self.dictionary)
         pos = np.clip(pos, 0, max(other_dictionary.size - 1, 0))
         ok = (
@@ -594,12 +590,26 @@ class StringColumn:
             if other_dictionary.size
             else np.zeros(self.dictionary.size, dtype=bool)
         )
-        trans = np.where(ok, pos, -1).astype(np.int32)
-        trans_dev = jax.device_put(trans, None)
-        # unmatched becomes -1; negative codes pass through unchanged
-        # (-1 absent stays -1, -2 sharding pads stay -2) so both
-        # translation paths keep the same negative-code identity
-        return _apply_code_translation(self.codes, jnp.asarray(trans_dev))
+        return jnp.asarray(jax.device_put(np.where(ok, pos, -1).astype(np.int32), None))
+
+    def renumbered_to_col(self, other: "StringColumn") -> jax.Array:
+        """Translate this column's codes into *other*'s code space (one
+        walk over the rows; see :meth:`code_translation_to` for the
+        table).  Unmatched becomes -1; negative codes pass through
+        unchanged (-1 absent stays -1, -2 sharding pads stay -2)."""
+        trans = self.code_translation_to(other)
+        if trans is None:
+            return self.codes
+        return _apply_code_translation(self.codes, trans)
+
+    def renumbered_to(self, other_dictionary: np.ndarray) -> jax.Array:
+        """Translate this column's codes into another dictionary's code
+        space (host translation table + device gather); unmatched -> -1,
+        negative codes unchanged."""
+        trans = self._host_translation(other_dictionary)
+        if trans is None:
+            return self.codes
+        return _apply_code_translation(self.codes, trans)
 
 
 @register_kernel("table.gather_take")

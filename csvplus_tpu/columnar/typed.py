@@ -308,15 +308,15 @@ class IntColumn:
         cand, vals = parse_affix_dictionary(other_dictionary, self.prefix)
         return self._translate_by_values(self._build_translation(vals, cand))
 
-    def renumbered_to_col(self, other) -> jax.Array:
-        """Rows translated into *other*'s code space (the probe-side join
-        translation).  ``other`` may be a StringColumn (its dictionary is
-        parsed numerically — no demotion of SELF, the 100M-row probe
-        stays value lanes) or another IntColumn (demoted first: build
-        sides are index tables whose key columns already hold code
-        semantics).  The parsed translation table is cached on *other*
-        per prefix, so repeated probes of the same build side pay the
-        host parse once."""
+    def translation_state_to(self, other):
+        """The :meth:`_build_translation` state that takes this column's
+        values into *other*'s code space.  ``other`` may be a
+        StringColumn (its dictionary is parsed numerically — no demotion
+        of SELF, the 100M-row probe stays value lanes) or another
+        IntColumn (demoted first: build sides are index tables whose key
+        columns already hold code semantics).  The parsed state is
+        cached on *other* per prefix, so repeated probes of the same
+        build side pay the host parse once."""
         if isinstance(other, IntColumn):
             other = other._demote()
         cache = getattr(other, "_affix_trans_cache", None)
@@ -326,7 +326,14 @@ class IntColumn:
         if hit is None:
             cand, vals = parse_affix_dictionary(other.dictionary, self.prefix)
             hit = cache[self.prefix] = self._build_translation(vals, cand)
-        return self._translate_by_values(hit)
+        return hit
+
+    def renumbered_to_col(self, other) -> jax.Array:
+        """Rows translated into *other*'s code space (the probe-side join
+        translation, one walk over the rows per call; the composed probe
+        of ``ops/join.py`` folds the same state into its tables
+        instead)."""
+        return self._translate_by_values(self.translation_state_to(other))
 
 
 @register_kernel("typed.translate_dense")

@@ -38,14 +38,21 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass
-from typing import ClassVar, List, Optional, Sequence, Tuple
+from typing import Any, ClassVar, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from ..columnar.table import DeviceTable, StringColumn, same_placement
+from ..columnar.table import (
+    DeviceTable,
+    StringColumn,
+    _gather_take,
+    merge_with_fallback,
+    same_placement,
+)
+from ..columnar.typed import PAD_VALUE
 from ..obs.recompile import register_kernel
 from ..obs.span import tracer
 from ..utils.env import env_int
@@ -143,6 +150,14 @@ def direct_probe_parts(
     ``cum[q]`` IS searchsorted-left(keys, q), so a probe is two gathers
     in place of the ~log2(n) sequential gather rounds XLA emits for
     ``searchsorted`` (the gain on a TPU is not measured).
+
+    Per ROW this is the staged form: a probe of one key column walks
+    the rows three times (translate, then these two gathers).  Where
+    the probe column's universe is small beside the stream
+    (``DeviceIndex._composed_for``) the same function runs once over
+    the UNIVERSE instead (``_compose_probe_kernel``), and a row reads
+    its answer from the composed tables in one or two walks (tier
+    ``direct-composed``) — same ``(lower, counts)``, bit for bit.
     """
     U = cum.shape[0] - 1
     q = jnp.clip(qk, 0, U)
@@ -194,6 +209,128 @@ def _build_direct_cum(keys: jax.Array, total_bits: int) -> jax.Array:
     hist = jnp.zeros(U + 1, dtype=jnp.int32)
     hist = hist.at[keys.astype(jnp.int32) + 1].add(1, mode="drop")
     return jnp.cumsum(hist)
+
+
+# -- composed probe (ISSUE 26) ----------------------------------------------
+#
+# An XLA gather on the TPU costs per INDEX walked, not per byte: 10M
+# indices take 0.05-0.075 s whether the table has 1,000 entries or
+# 100,000 (PERF.md section 5).  The staged probe of one key column walks
+# the rows three times — ``trans[v - lo]``, ``cum[q]``, ``cum[q + range]``
+# — and each build column is one more walk through the row id.  Every
+# one of those pointers is a function of the key's VALUE alone, and the
+# value's universe (the dense range of an IntColumn's translation, or
+# the probe column's dictionary) is small beside the stream.  So the
+# chain is composed once over the universe, at table size:
+#
+#   depth 1   lower_tab[u], cnt_tab[u] = direct_probe_parts(cum, q(trans[u]))
+#             (one table, ``rid_tab``, where the index is unique): a row
+#             reads its answer in one or two walks;
+#   depth 2   col_tab[u] = column.storage[rid_tab[u]] where the index is
+#             unique and every slot of the universe holds a build row:
+#             "matched" is a range test and the emit is one walk per
+#             emitted lane with no probe walk at all.
+#
+# Which depth runs is read off shapes and placement, never configured.
+
+
+# a composition costs one table-size walk per table, so it pays only
+# where the universe is small beside the rows it saves walks over
+_COMPOSE_ROWS_PER_SLOT = 4
+# depth 2 holds one table per build column over the universe: only where
+# the universe is no sparser than twice the build rows
+_EMIT_SLOTS_PER_BUILD_ROW = 2
+# composed entries kept per index (one per probe prefix / dictionary)
+_COMPOSED_KEPT = 8
+
+
+@dataclass
+class _Composed:
+    """One probe column's composed tables over its universe of ``size``
+    slots (see the section comment).  ``base`` says how a row finds its
+    slot: an int32 scalar ``lo`` for a dense typed range (``v - lo``),
+    the sorted values for a sparse typed one (position by search), None
+    for a string column (its dictionary code)."""
+
+    base: Any
+    lower_tab: jax.Array  # the build row (-1: none) where the index is unique
+    cnt_tab: Optional[jax.Array]  # None where the index is unique
+    emit_ok: bool  # depth 2 allowed: unique, no hole, dense enough
+    key_ref: Any  # the probe dictionary composed over (its id is the cache key)
+    walks: int  # full-length gathers one probe of this entry dispatches
+    col_tabs: Dict[str, Tuple[jax.Array, jax.Array]]  # name -> (source storage, table)
+
+    @property
+    def size(self) -> int:
+        return int(self.lower_tab.shape[0])
+
+
+def _searchsorted_rounds(n: int) -> int:
+    """Gather rounds of one ``jnp.searchsorted`` over *n* sorted entries."""
+    return max(int(n).bit_length(), 1)
+
+
+def _translation_walks(pc, ic) -> int:
+    """Full-length gathers of one staged ``pc.renumbered_to_col(ic)``."""
+    if pc.kind != "int":
+        return 1 if pc.dict_size else 0
+    kind, _, table = pc.translation_state_to(ic)  # cached on ic
+    if kind == "dense":
+        return 1
+    n = int(table.shape[0])
+    return _searchsorted_rounds(n) + 2 if n else 0
+
+
+def _universe_slot(storage, base, size):
+    """(slot clipped into the universe, row has a slot) — traceable; the
+    per-row half of the translation kernels with the table read left
+    out.  Pads and absent cells have no slot.  *base* as in
+    :class:`_Composed`."""
+    if base is None:
+        return jnp.clip(storage, 0, size - 1), storage >= 0
+    is_pad = storage == jnp.int32(PAD_VALUE)
+    if base.ndim == 0:
+        # pads masked BEFORE the subtraction (``_translate_dense_kernel``)
+        idx = jnp.where(is_pad, base, storage) - base
+        ok = (idx >= 0) & (idx < size) & ~is_pad
+        return jnp.clip(idx, 0, size - 1), ok
+    pos = jnp.minimum(jnp.searchsorted(base, storage), size - 1).astype(jnp.int32)
+    return pos, (jnp.take(base, pos, axis=0) == storage) & ~is_pad
+
+
+@register_kernel("join.compose_probe")
+def _compose_probe_kernel(trans, cum, shift, range_size):
+    """The staged chain over the UNIVERSE (table size, set-up only):
+    ``_pack_qk_kernel`` of one column, then ``direct_probe_parts``.
+    Returns (lower_tab, cnt_tab, rid_tab, [max count, min count])."""
+    qk = jnp.where(trans >= 0, jnp.maximum(trans, 0).astype(jnp.int32) << shift, -1)
+    lower, counts = direct_probe_parts(cum, qk, range_size)
+    rid = jnp.where(counts > 0, lower, -1)
+    return lower, counts, rid, jnp.stack([jnp.max(counts), jnp.min(counts)])
+
+
+@register_kernel("join.probe_composed")
+def _probe_composed_kernel(storage, base, lower_tab, cnt_tab):  # analysis: allow[JIT001] retrace is per slot rule (three: the rank of ``base``) and per table arity (two), not per data length
+    """Depth 1: a row's (lower, counts) from the composed tables — one
+    walk where the index is unique (*cnt_tab* None; ``lower`` of an
+    unmatched row then reads 0, not the insertion point: no consumer
+    reads it), two otherwise."""
+    slot, ok = _universe_slot(storage, base, lower_tab.shape[0])
+    if cnt_tab is None:
+        rid = jnp.where(ok, jnp.take(lower_tab, slot, axis=0), -1)
+        return jnp.maximum(rid, 0), (rid >= 0).astype(jnp.int32)
+    lower = jnp.where(ok, jnp.take(lower_tab, slot, axis=0), 0)
+    counts = jnp.where(ok, jnp.take(cnt_tab, slot, axis=0), 0)
+    return lower, counts
+
+
+@register_kernel("join.probe_composed_range")
+def _probe_range_kernel(storage, base, size):  # analysis: allow[JIT001] retrace is per slot rule (two: a dense range or a code), not per data length
+    """Depth 2: (slot, counts) by a range test alone — every slot of the
+    universe holds exactly one build row, so a row matched when it has
+    a slot."""
+    slot, ok = _universe_slot(storage, base, size)
+    return slot, ok.astype(jnp.int32)
 
 
 def device_index_static_info(index):
@@ -320,6 +457,8 @@ class DeviceIndex:
         # serving rates the duplicate work is a real latency spike, so
         # first-touch is serialized like IndexImpl's lazy caches.
         self._aux_lock = threading.Lock()
+        self._composed: Dict[tuple, _Composed] = {}
+        self._compositions = 0  # table-size compositions run (set-up work)
 
     @property
     def supported(self) -> bool:
@@ -582,11 +721,132 @@ class DeviceIndex:
         return repl
 
     def _translated(self, probe_cols: List[StringColumn], n_key_cols: int):
-        """Per-column probe codes translated into the build dictionaries."""
+        """(per-column probe codes translated into the build
+        dictionaries, the full-length gathers that took)."""
         out = []
+        walks = 0
         for pc, ic_name in zip(probe_cols, self.key_columns[:n_key_cols]):
-            out.append(pc.renumbered_to_col(self.table.columns[ic_name]))
-        return out
+            ic = self.table.columns[ic_name]
+            out.append(pc.renumbered_to_col(ic))
+            walks += _translation_walks(pc, ic)
+        return out, walks
+
+    def _composed_for(self, pc, nrows: int) -> "Optional[_Composed]":
+        """The composed tables for probing this index by the one column
+        *pc* (full key or prefix), or None where the staged path stands:
+        no direct tier, a stream or tables not whole on one device, or a
+        universe over a quarter of the stream's rows.  Composed once per
+        probe prefix (typed column) or probe dictionary (string column)
+        and kept beside ``_direct_cum`` — the index is immutable, so
+        nothing invalidates them."""
+        if self.packed_i32 is None or self.direct_bits is None:
+            return None
+        if not _whole_device(pc.storage):
+            return None
+        ic = self.table.columns[self.key_columns[0]]
+        if pc.kind == "int":
+            state = pc.translation_state_to(ic)
+            key_ref, key = None, ("int", pc.prefix)
+            size = int(state[2].shape[0])
+        else:
+            pc._ensure_sorted_lanes()  # the dictionary's final identity
+            state = None
+            st = pc._lane_state
+            key_ref = st.lanes if st is not None else pc._dictionary
+            key = ("str", id(key_ref))
+            size = pc.dict_size
+        if size == 0 or size * _COMPOSE_ROWS_PER_SLOT > nrows:
+            return None
+        cum = self.direct_cum  # takes _aux_lock itself on first touch
+        if not same_placement((pc.storage, cum)):
+            return None
+        with self._aux_lock:
+            cache = self._composed
+            hit = cache.get(key)
+            if hit is None or hit.key_ref is not key_ref:
+                hit = self._compose(pc, ic, state, cum, key_ref)
+                cache.pop(key, None)
+                while len(cache) >= _COMPOSED_KEPT:
+                    cache.pop(next(iter(cache)))
+                cache[key] = hit
+        return hit
+
+    def _compose(self, pc, ic, state, cum, key_ref) -> "_Composed":
+        """Build one :class:`_Composed` (caller holds ``_aux_lock``):
+        one table-size kernel and ONE two-scalar read (the largest and
+        smallest count over the universe decide ``rid_tab`` and depth
+        2).  Set-up: runs in a plan's first execution, never after."""
+        kind = "codes" if state is None else state[0]
+        if kind == "codes":
+            base, trans = None, pc.code_translation_to(ic)
+        elif kind == "dense":
+            base, trans = jnp.int32(state[1]), state[2]
+        else:
+            base, trans = state[1], state[2]
+        size = int(trans.shape[0])
+        with telemetry.stage("join:compose", size) as out:
+            shift = self.shifts[0]
+            lower, counts, rid, stats = _compose_probe_kernel(
+                trans, cum, jnp.int32(shift), jnp.int32(1) << shift
+            )
+            most, least = (int(v) for v in np.asarray(stats))
+            unique = most <= 1
+            out["unique"] = unique
+            out["holes"] = least < 1
+        self._compositions += 1
+        return _Composed(
+            base,
+            lower_tab=rid if unique else lower,
+            cnt_tab=None if unique else counts,
+            emit_ok=unique
+            and least >= 1
+            and kind != "sorted"
+            and size <= _EMIT_SLOTS_PER_BUILD_ROW * self.table.nrows,
+            key_ref=key_ref,
+            walks=(1 if unique else 2)
+            + (_searchsorted_rounds(size) + 1 if kind == "sorted" else 0),
+            col_tabs={},
+        )
+
+    def composed_columns(self, entry: "_Composed", names: Sequence[str]):
+        """``column.storage[rid_tab]`` per named build column over
+        *entry*'s universe (depth 2's emit tables), composed once per
+        column storage (a column that settles its lane dictionary later
+        swaps its storage and is composed again)."""
+        with self._aux_lock:
+            stale = [
+                n
+                for n in names
+                if n not in entry.col_tabs
+                or entry.col_tabs[n][0] is not self.table.columns[n].storage
+            ]
+            if stale:
+                srcs = tuple(self.table.columns[n].storage for n in stale)
+                with telemetry.stage("join:compose", entry.size) as out:
+                    out["columns"] = len(stale)
+                    got = _gather_cols(srcs, entry.lower_tab)
+                entry.col_tabs.update(zip(stale, zip(srcs, got)))
+                self._compositions += 1
+            return tuple(entry.col_tabs[n][1] for n in names)
+
+    def probe_slots(self, pc, nrows: int):
+        """Depth 2 of the composed probe by the one column *pc*:
+        ``(entry, slots, counts)`` where the index is unique and the
+        universe has no hole — ``counts`` by a range test, ``slots``
+        addressing :meth:`composed_columns` (and, through
+        ``_slots_to_rows``, the build rows) — else None: :meth:`probe`
+        answers."""
+        entry = self._composed_for(pc, nrows)
+        if entry is None or not entry.emit_ok:
+            return None
+        self.offer_build_sample()
+        with telemetry.stage("join:probe", nrows) as out:
+            out["tier"] = "direct-composed"
+            out["depth"] = 2
+            out["row_gathers"] = 0
+            ans = _probe_range_kernel(pc.storage, entry.base, jnp.int32(entry.size))
+            telemetry.barrier(ans)
+        return (entry,) + ans
 
     def probe(
         self, probe_cols: List[StringColumn], nrows: int,
@@ -603,6 +863,13 @@ class DeviceIndex:
         Fewer probe columns than key columns = a prefix probe matching the
         whole key range under the prefix.
 
+        A probe by ONE column on the direct tier, stream and tables whole
+        on one device, whose universe is at most a quarter of *nrows*
+        reads its answer from tables composed over that universe (tier
+        ``direct-composed``, see ``_composed_for``) instead of walking
+        the rows through translate, pack and the two ``cum`` gathers;
+        every other shape runs the staged kernels below.
+
         *part_info* is the multiway join's shared partitioned-tier state
         (``multiway_join`` threads ONE dict through every dimension's
         probe): the exchange capacity settled while probing one dimension
@@ -613,8 +880,19 @@ class DeviceIndex:
         assert self.supported
         self.offer_build_sample()
         k = len(probe_cols)
-        with telemetry.stage("join:translate", nrows):
-            codes = self._translated(probe_cols, k)
+        entry = self._composed_for(probe_cols[0], nrows) if k == 1 else None
+        if entry is not None:
+            with telemetry.stage("join:probe", nrows) as out:
+                out["tier"] = "direct-composed"
+                out["depth"] = 1
+                out["row_gathers"] = entry.walks
+                ans = _probe_composed_kernel(
+                    probe_cols[0].storage, entry.base, entry.lower_tab, entry.cnt_tab
+                )
+                telemetry.barrier(ans)
+            return ans
+        with telemetry.stage("join:translate", nrows) as out:
+            codes, out["row_gathers"] = self._translated(probe_cols, k)
             telemetry.barrier(codes)
         range_shift = self.shifts[k - 1] if k else 0
 
@@ -666,6 +944,7 @@ class DeviceIndex:
                 cum = self._lanes_for(qk, "direct_cum")
                 with telemetry.stage("join:probe", nrows) as out:
                     out["tier"] = "direct"
+                    out["row_gathers"] = 2
                     ans = _probe_kernel_direct(
                         cum, qk, jnp.int32(1) << range_shift
                     )
@@ -676,6 +955,7 @@ class DeviceIndex:
             # directly, so no O(n) host sync happens in the probe
             with telemetry.stage("join:probe", nrows) as out:
                 out["tier"] = "broadcast-i32"
+                out["row_gathers"] = 2 * _searchsorted_rounds(keys.shape[0])
                 ans = _probe_kernel_i32(keys, qk, jnp.int32(1) << range_shift)
                 telemetry.barrier(ans)
             return ans
@@ -867,6 +1147,93 @@ def _aligned_codes(dev_index: "DeviceIndex", name: str, codes, ids):
     return repl
 
 
+def _probe_dim(dev_index: "DeviceIndex", probe_cols, nrows: int, part_info=None):
+    """One dimension's probe answer ``(lower, counts, entry)``.  With
+    *entry* (depth 2 of the composed probe) ``lower`` holds SLOTS of the
+    entry's universe: they address ``dev_index.composed_columns`` as
+    they stand, and build rows through :func:`_slots_to_rows`."""
+    got = dev_index.probe_slots(probe_cols[0], nrows) if len(probe_cols) == 1 else None
+    if got is not None:
+        entry, slots, counts = got
+        return slots, counts, entry
+    lower, counts = dev_index.probe(probe_cols, nrows, part_info=part_info)
+    return lower, counts, None
+
+
+def _slots_to_rows(lowers, entries):
+    """Depth-2 slots -> build rows (read where the row matched only),
+    for the joins that did not match every row once: only the
+    all-matched emit reads slots."""
+    return tuple(
+        lo if e is None else _gather_take(e.lower_tab, lo)
+        for lo, e in zip(lowers, entries)
+    )
+
+
+def _kept_build_names(dev_index: "DeviceIndex", stream_cols) -> List[str]:
+    """The build columns the merge can read.  One whose name is on the
+    stream, where the stream's column has no absent cell, is never read
+    (``merge_with_fallback`` hands back the stream's column on its one
+    cached scalar) — so it is not gathered; :func:`_merge_fold` keeps
+    its place in the column order."""
+    return [
+        n
+        for n in dev_index.table.columns
+        if not (n in stream_cols and not stream_cols[n].has_absent)
+    ]
+
+
+def _build_sources(dev_index: "DeviceIndex", names, ids, entry):
+    """The arrays *ids* gather from, per kept build column: the composed
+    column tables where *ids* are depth-2 slots, the build columns'
+    storage otherwise.  Kind-agnostic storage (dictionary codes or typed
+    value lanes), so a typed payload column is never demoted by the
+    join."""
+    if entry is not None:
+        return dev_index.composed_columns(entry, names)
+    return tuple(
+        _aligned_codes(dev_index, n, dev_index.table.columns[n].storage, ids)
+        for n in names
+    )
+
+
+def _take_each(tables, ids):
+    """Eager per-array takes, each free to resolve its own placement
+    (mixed placements: the partitioned tier's numpy ids over a
+    mesh-sharded stream with a single-device build table)."""
+    idx = jnp.asarray(ids, dtype=jnp.int32)
+    return tuple(jnp.take(t, idx, axis=0) for t in tables)
+
+
+def _stream_side(cols, names, gathered):
+    """The stream's columns as the merge fold's first running result:
+    as they stand when no row moved, else over the gathered storage."""
+    if gathered is None:
+        return dict(cols)
+    return {n: cols[n].with_storage(g) for n, g in zip(names, gathered)}
+
+
+def _merge_fold(cur, sides):
+    """Fold the cascade's merge left to right over *sides* of
+    ``(dev_index, kept names, gathered storages)``: level d inserts
+    build side d's columns first (a column that was not gathered holds
+    its place), then overlays the running result with stream-wins /
+    absent-cell-fallback semantics — identical column order and values
+    to the cascade (elementwise merges commute with the row gathers
+    already applied)."""
+    for dev_index, names, gathered in sides:
+        new = dict.fromkeys(dev_index.table.columns)
+        for name, g in zip(names, gathered):
+            new[name] = dev_index.table.columns[name].with_storage(g)
+        for name, col in cur.items():  # the running result wins on collision...
+            if new.get(name) is not None:
+                # ...but an absent cell keeps the build side's value
+                col = merge_with_fallback(col, new[name])
+            new[name] = col
+        cur = new
+    return cur
+
+
 def join_tables(
     stream: DeviceTable, dev_index: "DeviceIndex", columns: Sequence[str]
 ) -> DeviceTable:
@@ -875,8 +1242,6 @@ def join_tables(
     row's value wins, but only for cells the stream row actually has
     (csvplus.go:560, 571-583); stream order preserved, matches emitted in
     index-sorted order (csvplus.go:559)."""
-    from ..columnar.table import merge_with_fallback
-
     if stream.nrows == 0:
         # per-row key validation never fires on an empty stream
         # (csvplus.go:553-556): empty result, no error
@@ -888,7 +1253,7 @@ def join_tables(
         return DeviceTable(out_cols, 0, stream.device)
 
     probe_cols = _checked_probe_cols(stream, columns)
-    lower, counts = dev_index.probe(probe_cols, stream.nrows)
+    lower, counts, entry = _probe_dim(dev_index, probe_cols, stream.nrows)
     probe_ids = build_ids = None
     with telemetry.stage("join:expand", stream.nrows) as _exp:
         if isinstance(lower, jax.Array):
@@ -901,27 +1266,30 @@ def join_tables(
             if maxc <= 1 and total == stream.nrows:
                 # every stream row matched exactly once: identity on the
                 # stream side (columns pass through ungathered, caches
-                # intact), build rows addressed by the probe's lower bounds
+                # intact), build rows addressed by the probe's lower
+                # bounds (or, at depth 2, the composed columns by slot)
                 build_ids = lower
                 _exp["path"] = "unique-identity"
-            elif maxc <= 1:
-                # unique but partial: compact the selection without the
-                # expansion scan; pow2 padding bounds recompiles
-                padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                if _whole_device(lower, counts):
-                    sel = _host_compact_ids(np.asarray(counts) > 0, padded)
-                else:
-                    sel = jnp.flatnonzero(
-                        counts > 0, size=padded, fill_value=0
-                    )
-                probe_ids = sel[:total].astype(jnp.int32)
-                build_ids = jnp.take(lower, probe_ids, axis=0)
-                _exp["path"] = "unique-partial"
             else:
-                probe_ids, build_ids = expand_matches_device(
-                    lower, counts, total
-                )
-                _exp["path"] = "fan-out"
+                (lower,), entry = _slots_to_rows((lower,), (entry,)), None  # slots -> build rows
+                if maxc <= 1:
+                    # unique but partial: compact the selection without the
+                    # expansion scan; pow2 padding bounds recompiles
+                    padded = 1 << max(total - 1, 0).bit_length() if total else 1
+                    if _whole_device(lower, counts):
+                        sel = _host_compact_ids(np.asarray(counts) > 0, padded)
+                    else:
+                        sel = jnp.flatnonzero(
+                            counts > 0, size=padded, fill_value=0
+                        )
+                    probe_ids = sel[:total].astype(jnp.int32)
+                    build_ids = jnp.take(lower, probe_ids, axis=0)
+                    _exp["path"] = "unique-partial"
+                else:
+                    probe_ids, build_ids = expand_matches_device(
+                        lower, counts, total
+                    )
+                    _exp["path"] = "fan-out"
             _exp["rows_out"] = total
         else:  # the partitioned (multi-chip) tier answers in numpy
             probe_ids, build_ids = expand_matches(lower, counts)
@@ -929,27 +1297,20 @@ def join_tables(
             _exp["rows_out"] = len(probe_ids)
         telemetry.barrier((probe_ids, build_ids))
 
-    build_names = list(dev_index.table.columns)
+    build_names = _kept_build_names(dev_index, stream.columns)
+    build_codes = _build_sources(dev_index, build_names, build_ids, entry)
     stream_names = list(stream.columns)
-    # kind-agnostic storage arrays: dictionary codes or typed value
-    # lanes — the row-materializing gathers below treat them alike, so
-    # a typed payload column is never demoted by the join
-    build_codes = tuple(
-        _aligned_codes(dev_index, n, dev_index.table.columns[n].storage, build_ids)
-        for n in build_names
-    )
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
+        g_stream = None
         if probe_ids is None:
             # all-matched unique fast path: stream columns pass through
             # untouched; only the build side gathers (one jit call)
             if same_placement(build_codes + (build_ids,)):
                 g_build = _gather_cols(build_codes, build_ids)
             else:
-                b = jnp.asarray(build_ids, dtype=jnp.int32)
-                g_build = tuple(jnp.take(c, b, axis=0) for c in build_codes)
-            g_stream = stream_codes
+                g_build = _take_each(build_codes, build_ids)
             n_out = stream.nrows
         elif same_placement(build_codes + stream_codes):
             # ALL row-materializing gathers in one jit call, not one
@@ -959,36 +1320,18 @@ def join_tables(
             )
             n_out = len(probe_ids)
         else:
-            # mixed placements (e.g. the partitioned tier's numpy ids over a
-            # mesh-sharded stream with a single-device build table): eager
-            # per-column takes, each free to resolve its own placement
-            g_build = tuple(
-                jnp.take(c, jnp.asarray(build_ids, dtype=jnp.int32), axis=0)
-                for c in build_codes
-            )
-            g_stream = tuple(
-                jnp.take(c, jnp.asarray(probe_ids, dtype=jnp.int32), axis=0)
-                for c in stream_codes
-            )
+            g_build = _take_each(build_codes, build_ids)
+            g_stream = _take_each(stream_codes, probe_ids)
             n_out = len(probe_ids)
+        _mrg["row_gathers"] = len(g_build) + len(g_stream or ())
 
-        out_cols = {}
-        for name, codes in zip(build_names, g_build):
-            src = dev_index.table.columns[name]
-            out_cols[name] = src.with_storage(codes)
-        for name, codes in zip(stream_names, g_stream):  # stream wins on collision...
-            g = (
-                stream.columns[name]
-                if probe_ids is None
-                else stream.columns[name].with_storage(codes)
-            )
-            if name in out_cols:
-                # ...but an absent stream cell keeps the index value
-                g = merge_with_fallback(g, out_cols[name])
-            out_cols[name] = g
+        cur = _merge_fold(
+            _stream_side(stream.columns, stream_names, g_stream),
+            [(dev_index, build_names, g_build)],
+        )
         _mrg["rows_out"] = n_out
-        telemetry.barrier(tuple(c.storage for c in out_cols.values()))
-    return DeviceTable(out_cols, n_out, stream.device)
+        telemetry.barrier(tuple(c.storage for c in cur.values()))
+    return DeviceTable(cur, n_out, stream.device)
 
 
 @register_kernel("join.gather_both_sides")
@@ -1205,6 +1548,45 @@ def _gather_multiway_both(build_codes, stream_codes, build_ids, probe_ids):  # a
     )
 
 
+def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
+    """The expansion decision shared by the multiway joins: one stats
+    sync (total, max fanout, intermediate rows avoided), then the
+    unique-identity / unique-partial / fan-out / host-expand ids.
+    Returns ``(probe_ids, build_ids, entries, total, inter)``;
+    ``probe_ids`` None = every row matched once in EVERY dimension
+    (then, and only then, *entries* survive: a depth-2 dimension's
+    ``build_ids`` are slots of its composed columns)."""
+    if all(isinstance(lo, jax.Array) for lo in lowers):
+        # (total, max fanout, intermediate rows avoided) in ONE
+        # host transfer; unique dimensions skip the expansion scan
+        total, maxp, inter = (
+            int(v) for v in np.asarray(_multiway_stats(counts))
+        )
+        if maxp <= 1 and total == nrows:
+            _exp["path"] = prefix + "-unique-identity"
+            return None, lowers, entries, total, inter
+        lowers = _slots_to_rows(lowers, entries)
+        padded = 1 << max(total - 1, 0).bit_length() if total else 1
+        if maxp <= 1:
+            probe_ids, build_ids = _compact_unique_partial(
+                lowers, counts, padded
+            )
+            _exp["path"] = prefix + "-unique-partial"
+        else:
+            probe_ids, build_ids = _multiway_expand_kernel(
+                lowers, counts, padded
+            )
+            _exp["path"] = prefix + "-fan-out"
+        probe_ids = probe_ids[:total]
+        build_ids = tuple(b[:total] for b in build_ids)
+    else:  # a host-answering tier: expand in numpy
+        probe_ids, build_ids, total, inter = _multiway_expand_host(
+            lowers, counts
+        )
+        _exp["path"] = prefix + "-host-expand"
+    return probe_ids, build_ids, (None,) * len(entries), total, inter
+
+
 def multiway_join(
     stream: DeviceTable,
     specs: "Sequence[Tuple[DeviceIndex, Sequence[str]]]",
@@ -1214,7 +1596,6 @@ def multiway_join(
     ``join_tables`` applied left to right, without materializing any
     intermediate table.  *specs* lists the cascade's (DeviceIndex, key
     columns) pairs in cascade order."""
-    from ..columnar.table import merge_with_fallback
     from ..obs.joinskew import joinskew
 
     if len(specs) == 1:  # degenerate run: exactly the binary join
@@ -1241,80 +1622,38 @@ def multiway_join(
     # later dimensions' keys are PRESENT before the run, so validating
     # them here raises exactly what the cascade's per-level checks would.
     part_info: dict = {}
-    answers = []
-    for dev_index, cols in specs:
-        probe_cols = _checked_probe_cols(stream, cols)
-        answers.append(
-            dev_index.probe(probe_cols, stream.nrows, part_info=part_info)
+    answers = [
+        _probe_dim(
+            dev_index, _checked_probe_cols(stream, cols), stream.nrows, part_info
         )
-    lowers = tuple(lo for lo, _ in answers)
-    counts = tuple(ct for _, ct in answers)
+        for dev_index, cols in specs
+    ]
+    lowers, counts, entries = (tuple(a[i] for a in answers) for i in range(3))
 
-    probe_ids = None
-    inter = 0
     with telemetry.stage("join:expand", stream.nrows) as _exp:
         _exp["dims"] = len(specs)
-        if all(isinstance(lo, jax.Array) for lo in lowers):
-            # (total, max fanout, intermediate rows avoided) in ONE
-            # host transfer; unique dimensions skip the expansion scan
-            total, maxp, inter = (
-                int(v) for v in np.asarray(_multiway_stats(counts))
-            )
-            if maxp <= 1 and total == stream.nrows:
-                # every stream row matched exactly once in EVERY
-                # dimension: stream columns pass through ungathered,
-                # each dimension's build rows are its lower bounds
-                build_ids = lowers
-                _exp["path"] = "multiway-unique-identity"
-            elif maxp <= 1:
-                padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                probe_ids, build_ids = _compact_unique_partial(
-                    lowers, counts, padded
-                )
-                probe_ids = probe_ids[:total]
-                build_ids = tuple(b[:total] for b in build_ids)
-                _exp["path"] = "multiway-unique-partial"
-            else:
-                padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                probe_ids, build_ids = _multiway_expand_kernel(
-                    lowers, counts, padded
-                )
-                probe_ids = probe_ids[:total]
-                build_ids = tuple(b[:total] for b in build_ids)
-                _exp["path"] = "multiway-fan-out"
-        else:  # a host-answering tier: expand in numpy
-            probe_ids, build_ids, total, inter = _multiway_expand_host(
-                lowers, counts
-            )
-            _exp["path"] = "multiway-host-expand"
+        probe_ids, build_ids, entries, total, inter = _multiway_ids(
+            lowers, counts, entries, stream.nrows, "multiway", _exp
+        )
         _exp["rows_out"] = total
         telemetry.barrier((probe_ids,) + tuple(build_ids))
 
-    build_names = [list(di.table.columns) for di, _ in specs]
+    build_names = [_kept_build_names(di, stream.columns) for di, _ in specs]
     build_codes = tuple(
-        tuple(
-            _aligned_codes(di, n, di.table.columns[n].storage, bid)
-            for n in names
-        )
-        for (di, _), names, bid in zip(specs, build_names, build_ids)
+        _build_sources(di, names, bid, e)
+        for (di, _), names, bid, e in zip(specs, build_names, build_ids, entries)
     )
     stream_names = list(stream.columns)
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
     flat_build = tuple(c for side in build_codes for c in side)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
+        g_stream = None
         if probe_ids is None:
             if same_placement(flat_build + tuple(build_ids)):
                 g_build = _gather_multiway(build_codes, build_ids)
             else:
-                g_build = tuple(
-                    tuple(
-                        jnp.take(c, jnp.asarray(b, dtype=jnp.int32), axis=0)
-                        for c in side
-                    )
-                    for side, b in zip(build_codes, build_ids)
-                )
-            g_stream = None
+                g_build = tuple(map(_take_each, build_codes, build_ids))
             n_out = stream.nrows
         elif same_placement(flat_build + stream_codes):
             g_build, g_stream = _gather_multiway_both(
@@ -1322,42 +1661,16 @@ def multiway_join(
             )
             n_out = total
         else:
-            # mixed placements: eager per-column takes, each free to
-            # resolve its own placement (the host-expand tier lands here)
-            g_build = tuple(
-                tuple(
-                    jnp.take(c, jnp.asarray(b, dtype=jnp.int32), axis=0)
-                    for c in side
-                )
-                for side, b in zip(build_codes, build_ids)
-            )
-            p_idx = jnp.asarray(probe_ids, dtype=jnp.int32)
-            g_stream = tuple(
-                jnp.take(c, p_idx, axis=0) for c in stream_codes
-            )
+            # mixed placements (the host-expand tier lands here)
+            g_build = tuple(map(_take_each, build_codes, build_ids))
+            g_stream = _take_each(stream_codes, probe_ids)
             n_out = total
+        _mrg["row_gathers"] = len(flat_build) + len(g_stream or ())
 
-        # fold the cascade's merge left to right: level d inserts build
-        # side d's columns first, then overlays the running result with
-        # stream-wins / absent-cell-fallback semantics — identical
-        # column order and values to the cascade (elementwise merges
-        # commute with the row gathers already applied)
-        if g_stream is None:
-            cur = dict(stream.columns)
-        else:
-            cur = {
-                name: stream.columns[name].with_storage(g)
-                for name, g in zip(stream_names, g_stream)
-            }
-        for (di, _), names, gathered in zip(specs, build_names, g_build):
-            new = {}
-            for name, g in zip(names, gathered):
-                new[name] = di.table.columns[name].with_storage(g)
-            for name, col in cur.items():
-                if name in new:
-                    col = merge_with_fallback(col, new[name])
-                new[name] = col
-            cur = new
+        cur = _merge_fold(
+            _stream_side(stream.columns, stream_names, g_stream),
+            [(di, names, g) for (di, _), names, g in zip(specs, build_names, g_build)],
+        )
         _mrg["rows_out"] = n_out
         telemetry.barrier(tuple(c.storage for c in cur.values()))
 
@@ -1425,7 +1738,6 @@ def multiway_join_selected(
     is the selected row-id array, *identity* asserts sel is the whole
     range in order (then per-column gathers pass through, exactly like
     ``materialize()``'s identity fast path)."""
-    from ..columnar.table import merge_with_fallback
     from ..obs.joinskew import joinskew
 
     n_sel = int(sel.shape[0])
@@ -1434,57 +1746,29 @@ def multiway_join_selected(
     # staged materialize would have produced, so probe answers (and the
     # shared partitioned-tier state threading) match the staged run
     part_info: dict = {}
-    answers = []
-    for dev_index, kcols in specs:
-        probe_cols = [
-            cols[c] if identity else cols[c].gather(sel) for c in kcols
-        ]
-        answers.append(dev_index.probe(probe_cols, n_sel, part_info=part_info))
-    lowers = tuple(lo for lo, _ in answers)
-    counts = tuple(ct for _, ct in answers)
+    answers = [
+        _probe_dim(
+            dev_index,
+            [cols[c] if identity else cols[c].gather(sel) for c in kcols],
+            n_sel,
+            part_info,
+        )
+        for dev_index, kcols in specs
+    ]
+    lowers, counts, entries = (tuple(a[i] for a in answers) for i in range(3))
 
-    probe_ids = None
-    inter = 0
     with telemetry.stage("join:expand", n_sel) as _exp:
         _exp["dims"] = len(specs)
-        if all(isinstance(lo, jax.Array) for lo in lowers):
-            total, maxp, inter = (
-                int(v) for v in np.asarray(_multiway_stats(counts))
-            )
-            if maxp <= 1 and total == n_sel:
-                build_ids = lowers
-                _exp["path"] = "fused-unique-identity"
-            elif maxp <= 1:
-                padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                probe_ids, build_ids = _compact_unique_partial(
-                    lowers, counts, padded
-                )
-                probe_ids = probe_ids[:total]
-                build_ids = tuple(b[:total] for b in build_ids)
-                _exp["path"] = "fused-unique-partial"
-            else:
-                padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                probe_ids, build_ids = _multiway_expand_kernel(
-                    lowers, counts, padded
-                )
-                probe_ids = probe_ids[:total]
-                build_ids = tuple(b[:total] for b in build_ids)
-                _exp["path"] = "fused-fan-out"
-        else:  # a host-answering tier: expand in numpy
-            probe_ids, build_ids, total, inter = _multiway_expand_host(
-                lowers, counts
-            )
-            _exp["path"] = "fused-host-expand"
+        probe_ids, build_ids, entries, total, inter = _multiway_ids(
+            lowers, counts, entries, n_sel, "fused", _exp
+        )
         _exp["rows_out"] = total
         telemetry.barrier((probe_ids,) + tuple(build_ids))
 
-    build_names = [list(di.table.columns) for di, _ in specs]
+    build_names = [_kept_build_names(di, cols) for di, _ in specs]
     build_codes = tuple(
-        tuple(
-            _aligned_codes(di, n, di.table.columns[n].storage, bid)
-            for n in names
-        )
-        for (di, _), names, bid in zip(specs, build_names, build_ids)
+        _build_sources(di, names, bid, e)
+        for (di, _), names, bid, e in zip(specs, build_names, build_ids, entries)
     )
     stream_names = list(cols)
     stream_codes = tuple(cols[n].storage for n in stream_names)
@@ -1498,22 +1782,13 @@ def multiway_join_selected(
             if same_placement(flat_build + tuple(build_ids)):
                 g_build = _gather_multiway(build_codes, build_ids)
             else:
-                g_build = tuple(
-                    tuple(
-                        jnp.take(c, jnp.asarray(b, dtype=jnp.int32), axis=0)
-                        for c in side
-                    )
-                    for side, b in zip(build_codes, build_ids)
-                )
+                g_build = tuple(map(_take_each, build_codes, build_ids))
             if identity:
                 g_stream = None
             elif same_placement(stream_codes + (sel,)):
                 g_stream = _gather_cols(stream_codes, sel)
             else:
-                s_idx = jnp.asarray(sel, dtype=jnp.int32)
-                g_stream = tuple(
-                    jnp.take(c, s_idx, axis=0) for c in stream_codes
-                )
+                g_stream = _take_each(stream_codes, sel)
             n_out = n_sel
         elif same_placement(flat_build + stream_codes):
             # the fused win: ONE composed gather from full-length
@@ -1530,35 +1805,15 @@ def multiway_join_selected(
                 jnp.asarray(probe_ids, dtype=jnp.int32),
                 axis=0,
             )
-            g_build = tuple(
-                tuple(
-                    jnp.take(c, jnp.asarray(b, dtype=jnp.int32), axis=0)
-                    for c in side
-                )
-                for side, b in zip(build_codes, build_ids)
-            )
-            g_stream = tuple(
-                jnp.take(c, e_idx, axis=0) for c in stream_codes
-            )
+            g_build = tuple(map(_take_each, build_codes, build_ids))
+            g_stream = _take_each(stream_codes, e_idx)
             n_out = total
+        _mrg["row_gathers"] = len(flat_build) + len(g_stream or ())
 
-        # the cascade's merge fold, verbatim from ``multiway_join``
-        if g_stream is None:
-            cur = dict(cols)
-        else:
-            cur = {
-                name: cols[name].with_storage(g)
-                for name, g in zip(stream_names, g_stream)
-            }
-        for (di, _), names, gathered in zip(specs, build_names, g_build):
-            new = {}
-            for name, g in zip(names, gathered):
-                new[name] = di.table.columns[name].with_storage(g)
-            for name, col in cur.items():
-                if name in new:
-                    col = merge_with_fallback(col, new[name])
-                new[name] = col
-            cur = new
+        cur = _merge_fold(
+            _stream_side(cols, stream_names, g_stream),
+            [(di, names, g) for (di, _), names, g in zip(specs, build_names, g_build)],
+        )
         _mrg["rows_out"] = n_out
         telemetry.barrier(tuple(c.storage for c in cur.values()))
 

@@ -861,6 +861,9 @@ def _kernel_examples():
         "serve.bounds_search": ((i, i), {}),
         "join.build_direct_cum": ((i,), {"total_bits": 4}),
         "join.pack_qk": (((i, i),), {"shifts": (4, 0)}),
+        "join.compose_probe": ((i, i, jnp.int32(0), jnp.int32(1)), {}),
+        "join.probe_composed": ((i, jnp.int32(0), i, None), {}),
+        "join.probe_composed_range": ((i, None, jnp.int32(8)), {}),
         "join.expand": ((i, i), {"padded_total": 16}),
         "join.gather_both_sides": (((i,), (i,), i, i), {}),
         "join.gather_cols": (((i, i), i), {}),
@@ -888,7 +891,7 @@ KERNELS_LOWERED_HERE = sorted([
     "join.multiway_expand", "join.gather_multiway", "join.gather_multiway_both",
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
     "typed.translate_empty", "table.gather_take", "table.apply_code_translation",
-    "table.sync_probe",
+    "table.sync_probe", "join.compose_probe", "join.probe_composed", "join.probe_composed_range",
 ])
 
 
