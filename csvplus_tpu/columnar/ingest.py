@@ -201,10 +201,12 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
     contiguous); finalize stitches the per-device segments into ONE
     row-sharded global array via boundary-sliver moves — no full-table
     single-device buffer ever exists, and per-device memory is bounded
-    by ~n/k plus a chunk.  Columns that would switch to device-LANE
-    dictionaries raise :class:`StreamFallback` under a mesh (the
-    whole-file tiers + ``with_sharding`` handle that shape); typed and
-    host-dictionary columns — every north-star column — shard natively.
+    by ~n/k plus a chunk.  A column that switches to device-LANE
+    dictionaries keeps its codes shard-resident like every other column;
+    its chunk dictionaries go to the ingest device as lanes and ship as
+    the deferred (unsorted-concat) dictionary, exactly as without a mesh
+    — :meth:`StringColumn._ensure_sorted_lanes` replicates the
+    translation table onto the codes' mesh when something settles it.
     """
     import jax
     import jax.numpy as jnp
@@ -301,18 +303,10 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
             and running_union[c] is not None
             and running_union[c].size >= lane_thresh
         ):
-            if shard_devs is not None:
-                # the deferred-lane representation cannot be built
-                # shard-resident chunk by chunk; the whole-file tiers +
-                # with_sharding handle this (rare now that typed lanes
-                # absorb high-cardinality numeric ids)
-                from ..native.scanner import StreamFallback
-
-                raise StreamFallback(
-                    f'column "{c}" crossed the lane threshold under sharded ingest'
-                )
             # lane mode (newly or already): host dictionaries
-            # convert to device lanes and are freed — the RSS bound
+            # convert to device lanes and are freed — the RSS bound.
+            # Under a mesh the lanes sit on the ingest device and only
+            # the codes stay on their shard
             running_union[c] = None
             if chunk_dicts[c]:
                 chunk_lanes[c] = [_to_lanes(p) for p in chunk_dicts[c]]
@@ -480,6 +474,7 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
             int_segs,
             int_prefix,
             chunk_dicts,
+            chunk_lanes,
             chunk_codes,
         )
 
@@ -706,12 +701,15 @@ def _finalize_sharded(
     int_vals,
     int_prefix,
     chunk_dicts,
+    chunk_lanes,
     chunk_codes,
 ):
     """Sharded-ingest finalize: every column becomes a globally
     row-sharded array assembled from its shard-resident chunks (typed
-    value lanes or dictionary codes; lane-dictionary columns were
-    excluded by StreamFallback upstream).
+    value lanes or dictionary codes).  A lane-dictionary column's codes
+    are shifted into the concatenated dictionary's slot space ON their
+    shard; its lanes concatenate on the device they were put on, the
+    union deferred as in the one-device finalize.
 
     Typed columns arrive PRE-SEALED — one int32 segment per shard,
     concatenated incrementally as the stream passed each shard boundary
@@ -748,6 +746,23 @@ def _finalize_sharded(
                 )
                 continue
             dicts, codes = chunk_dicts[c], chunk_codes[c]
+            if chunk_lanes[c]:
+                lanes_list = chunk_lanes[c]
+                one_chunk = len(lanes_list) == 1  # its dictionary is sorted as it stands
+                offset = 0
+                arrs = []
+                for ls, ck in zip(lanes_list, codes):
+                    arrs.append(ck.astype(jnp.int32) + np.int32(offset))
+                    offset += int(ls[0].shape[0])
+                out[c] = StringColumn(
+                    None,
+                    _assemble_rows_sharded(mesh, shard_devs, arrs, nrows, -2),
+                    dev_dictionary=lanes_list[0] if one_chunk else _concat_lanes_device(
+                        lanes_list, max(len(ls) for ls in lanes_list)
+                    ),
+                    dev_dict_sorted=one_chunk,
+                )
+                continue
             if len(dicts) == 1:
                 arrs = [
                     a if a.dtype == jnp.int32 else a.astype(jnp.int32)

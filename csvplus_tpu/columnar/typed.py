@@ -316,7 +316,10 @@ class IntColumn:
         IntColumn (demoted first: build sides are index tables whose key
         columns already hold code semantics).  The parsed state is
         cached on *other* per prefix, so repeated probes of the same
-        build side pay the host parse once."""
+        build side pay the host parse once.  A row-sharded probe gets
+        the tables replicated over its mesh, cached per (prefix, device
+        set): left on the default device they are uncommitted, and every
+        translation call would copy them whole onto each shard again."""
         if isinstance(other, IntColumn):
             other = other._demote()
         cache = getattr(other, "_affix_trans_cache", None)
@@ -326,7 +329,21 @@ class IntColumn:
         if hit is None:
             cand, vals = parse_affix_dictionary(other.dictionary, self.prefix)
             hit = cache[self.prefix] = self._build_translation(vals, cand)
-        return hit
+        sh = getattr(self.values, "sharding", None)
+        mesh = getattr(sh, "mesh", None)
+        if mesh is None or len(sh.device_set) <= 1:
+            return hit
+        key = (self.prefix, frozenset(sh.device_set))
+        placed = cache.get(key)
+        if placed is None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            repl = NamedSharding(mesh, P())
+            placed = cache[key] = tuple(
+                jax.device_put(a, repl) if isinstance(a, jax.Array) else a
+                for a in hit
+            )
+        return placed
 
     def renumbered_to_col(self, other) -> jax.Array:
         """Rows translated into *other*'s code space (the probe-side join

@@ -50,6 +50,9 @@ def dedup(source, key: str, policy="first"):
 def sharded_join(orders_reader, cust_index, shards: int, cust_col="cust_id"):
     """Config 5: the join with a row-sharded stream over an N-device mesh
     (probes route through the all_to_all partitioned path when the build
-    side is large; see ops.join.DeviceIndex.PARTITION_MIN_KEYS)."""
+    side is large; see ops.join.DeviceIndex.PARTITION_MIN_KEYS).  The
+    benchmark's four-chip cell ``lookupjoin-mesh4``
+    (``benchmark/queries/lookupjoin.py``) is this pipeline at upstream's
+    shapes, with the index built over a sharded people table."""
     stream = orders_reader.on_device(shards=shards)
     return stream.join(cust_index, cust_col)

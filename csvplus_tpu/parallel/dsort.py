@@ -60,7 +60,9 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.recompile import register_kernel
 from .mesh import row_spec
+
 _MASK31 = np.int32((1 << 31) - 1)
 
 
@@ -173,8 +175,8 @@ def _dsort_shard_kernel(
     return out_lanes + (out_p, out_v, n_here.reshape(1))
 
 
-@partial(
-    jax.jit,
+@register_kernel(
+    "dsort.spmd",
     static_argnames=("mesh", "n_shards", "capacity", "samples", "n_lanes", "n_true"),
 )
 def _dsort_spmd(  # analysis: allow[JIT001] — arity fixed per pipeline shape

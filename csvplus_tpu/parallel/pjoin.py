@@ -590,6 +590,14 @@ def _skew_sample_cap() -> int:
     return max(env_int("CSVPLUS_JOIN_SKEW_SAMPLE", 4096), 64)
 
 
+@register_kernel("pjoin.skew_sample")
+def _skew_sample(lane, at):
+    """The probe keys of one lane at the strided positions *at*: the
+    bounded sample the host reads for hot-key detection, taken by a
+    named program."""
+    return jnp.take(lane, at, axis=0)
+
+
 def _detect_hot(qk_dev, n_shards: int, wide: bool):
     """Sketch-driven heavy-hitter detection over a bounded strided
     device sample — a data-INDEPENDENT host transfer (bounded by the
@@ -634,17 +642,19 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
     with telemetry.stage("join:skew-detect", m) as _d:
         cap = _skew_sample_cap()
         step = max(1, -(-m // cap))  # ceil: the sample stays <= cap elements
+        at = np.arange(0, m, step, dtype=np.int32)
         # EXPLICIT device_get: the transfer-guard differential test pins
         # that the device path performs no *implicit* device->host
         # transfers
         if wide:
-            hi = jax.device_get(qk_dev[0][::step])
-            lo = jax.device_get(qk_dev[1][::step])
+            hi, lo = jax.device_get(
+                (_skew_sample(qk_dev[0], at), _skew_sample(qk_dev[1], at))
+            )
             telemetry.count_sync(hi.size + lo.size)
             sample = (hi.astype(np.int64) << 31) | np.where(lo >= 0, lo, 0)
             sample = sample[hi >= 0]
         else:
-            sample = jax.device_get(qk_dev[::step])
+            sample = jax.device_get(_skew_sample(qk_dev, at))
             telemetry.count_sync(sample.size)
             sample = sample[sample >= 0]
         _d["threshold"] = round(tau, 6)
@@ -768,10 +778,15 @@ def _hot_answers_device(mesh, hot: np.ndarray, prepared, wide: bool):
     return vals, ans_lo, ans_ct
 
 
-def _retry_probe_device(mesh: Mesh, m: int, capacity: "int | None", launch):
+def _retry_probe_device(
+    mesh: Mesh, m: int, capacity: "int | None", launch, exchanges: int = 3
+):
     """Shared retry driver for the device wrappers: geometric capacity
     doubling keyed off ONE overflow boolean per attempt (the only host
     sync in the loop), results re-committed to the named mesh.
+    *exchanges* is the number of ``(N, C)`` int32 ``all_to_all`` rounds
+    one attempt makes (key lanes out, ``lower`` and ``count`` back): the
+    stage's ``bytes_exchanged`` is reckoned from it, from shapes.
 
     Returns ``((lo, ct), rows_broadcast, capacity)``: when the launch
     carries the hot tier (4-tuple results) the broadcast row count
@@ -800,8 +815,12 @@ def _retry_probe_device(mesh: Mesh, m: int, capacity: "int | None", launch):
                 # one O(1) scalar sync per attempt
                 overflowed, rows_broadcast = bool(jax.device_get(overflow)), 0
             if not overflowed:
+                slots = n_shards * n_shards * capacity  # of the settled attempt, mesh-wide
                 _x["capacity"] = capacity
                 _x["retries"] = retries
+                _x["attempts"] = retries + 1  # one blocking host read each
+                _x["slot_fill"] = m / slots
+                _x["bytes_exchanged"] = 4 * exchanges * slots
                 out = _renamed_rows(mesh, lo), _renamed_rows(mesh, ct)
                 telemetry.barrier(out)
                 return out, rows_broadcast, capacity
@@ -928,7 +947,9 @@ def partitioned_probe_device_wide(
             hot_hi, hot_lo_lane, hot_ans_lo, hot_ans_ct,
         )
 
-    out, rows_broadcast, cap_used = _retry_probe_device(mesh, m, capacity, launch)
+    out, rows_broadcast, cap_used = _retry_probe_device(
+        mesh, m, capacity, launch, exchanges=4
+    )
     if hot is not None:
         _note_skew(
             label, m, int(hot.size), rows_broadcast, cap_used,

@@ -1,0 +1,207 @@
+"""BASELINE config 5 at upstream's shapes, small, on 4 of the 8 simulated
+devices: ``orders.Join(custIndex, "cust_id")`` with orders(cust_id,
+prod_id, qty, ts) streamed onto a mesh and people(id, name, surname)
+indexed by a shuffled unique ``id`` — the query of the benchmark's
+``lookupjoin-mesh4`` cell (``benchmark/queries/lookupjoin.py``).
+
+The result is held to three oracles: a plain numpy reference kept here
+(``row_of[cust]`` gathers over the generator's own arrays, no engine
+code), the host executor, and the one-device run (bitwise).  The sizes
+that select the streamed tier, the sample-sort and the all_to_all probe
+are lowered in the test only.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+
+from csvplus_tpu import FromFile, Take
+from csvplus_tpu.obs.recompile import RecompileWatch
+from csvplus_tpu.serve.plancache import PlanCache
+from csvplus_tpu.utils.observe import telemetry
+
+pytest.importorskip("csvplus_tpu.native.scanner")
+
+pytestmark = pytest.mark.skipif(
+    len(jax.devices()) < 4, reason="needs 4 of the simulated CPU devices"
+)
+
+N_PEOPLE, N_ORDERS, N_STOCK, SHARDS = 40_000, 100_000, 1_000, 4
+FIRST = ("Amelia", "Olivia", "Emily", "Ava", "Isla", "Oliver", "Jack", "Harry", "Jacob", "Charlie")
+LAST = ("Smith", "Jones", "Taylor", "Williams", "Brown", "Davies", "Evans", "Wilson", "Thomas",
+        "Roberts", "Johnson", "Lewis")
+COLUMNS = ("cust_id", "prod_id", "qty", "ts", "id", "name", "surname")
+
+
+class Corpus:
+    """Upstream's orders and people from a seed: numpy arrays and the
+    two CSV files written from them."""
+
+    def __init__(self, seed: int, root):
+        rng = np.random.default_rng(seed)
+        self.people_id = rng.permutation(N_PEOPLE)  # unique and shuffled: the index build sorts
+        self.cust = rng.integers(0, N_PEOPLE, N_ORDERS)
+        self.cust[rng.choice(N_ORDERS, N_PEOPLE, replace=False)] = np.arange(N_PEOPLE)
+        self.prod = rng.integers(0, N_STOCK, N_ORDERS)
+        self.qty = rng.integers(1, 101, N_ORDERS)
+        secs = rng.integers(0, 366 * 86400, N_ORDERS).astype("timedelta64[s]")
+        stamps = np.datetime_as_string(np.datetime64("2016-01-01T00:00:00") + secs, unit="s")
+        self.ts = np.char.add(stamps, "+01:00")  # 25 bytes, as the README prints it
+        self.people = str(root / f"people{seed}.csv")
+        self.orders = str(root / f"orders{seed}.csv")
+        rows = np.arange(N_PEOPLE)
+        self.name = np.array(FIRST)[rows % len(FIRST)]
+        self.surname = np.array(LAST)[(rows // len(FIRST)) % len(LAST)]
+        with open(self.people, "w") as f:
+            f.write("id,name,surname\n")
+            f.writelines(
+                f"c{i},{n},{s}\n" for i, n, s in zip(self.people_id, self.name, self.surname)
+            )
+        with open(self.orders, "w") as f:
+            f.write("cust_id,prod_id,qty,ts\n")
+            f.writelines(
+                f"c{c},p{p},{q},{t}\n" for c, p, q, t in zip(self.cust, self.prod, self.qty, self.ts)
+            )
+
+    def want(self) -> dict:
+        """The reference: each order beside the person its cust_id names."""
+        row_of = np.empty(N_PEOPLE, dtype=np.int64)
+        row_of[self.people_id] = np.arange(N_PEOPLE)
+        person = row_of[self.cust]
+        cust = np.char.add("c", self.cust.astype(str))
+        return {
+            "cust_id": cust, "prod_id": np.char.add("p", self.prod.astype(str)),
+            "qty": self.qty.astype(str), "ts": self.ts,
+            "id": cust, "name": self.name[person], "surname": self.surname[person],
+        }
+
+
+def lookup_join(corpus: Corpus, shards):
+    """(result table, stage records of the first execution, index-build
+    stages, kernels lowered by a second execution)."""
+    with telemetry.collect() as records:
+        orders = FromFile(corpus.orders).OnDevice(shards=shards)
+        people = FromFile(corpus.people).OnDevice(shards=shards)
+        cust_idx = people.UniqueIndexOn("id").sync()
+        build = [r.stage for r in records]
+    plan = orders.Join(cust_idx, "cust_id").plan
+    cache = PlanCache()
+    with telemetry.collect() as records:
+        first = cache.execute(plan).sync()
+        first_stages = list(records)
+    with RecompileWatch() as watch:
+        second = cache.execute(plan).sync()
+    assert cache.stats()["lowered"] == 1
+    assert column_strings(second) == column_strings(first)
+    return orders.plan.table, first, first_stages, build, watch
+
+
+def column_strings(table) -> dict:
+    rows = table.to_rows()
+    return {name: [r[name] for r in rows] for name in COLUMNS}
+
+
+@pytest.fixture(autouse=True)
+def small_thresholds(monkeypatch):
+    import csvplus_tpu.ops.join as J
+    import csvplus_tpu.ops.sort as S
+
+    monkeypatch.setenv("CSVPLUS_STREAM_MIN_BYTES", "1")
+    monkeypatch.setenv("CSVPLUS_STREAM_CHUNK_BYTES", str(512 * 1024))
+    # ts crosses into device-lane dictionaries mid-stream, as its millions
+    # of different values do at the benchmark's size
+    monkeypatch.setenv("CSVPLUS_DICT_DEVICE_MIN_DISTINCT", "20000")
+    monkeypatch.setattr(J.DeviceIndex, "PARTITION_MIN_KEYS", 1000)
+    monkeypatch.setattr(S, "DSORT_MIN_ROWS", 1000)
+
+
+@pytest.mark.parametrize("seed", [11, 2_200_000_027, 4_100_000_123])
+def test_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(seed, tmp_path):
+    corpus = Corpus(seed, tmp_path)
+    fact, result, stages, build, watch = lookup_join(corpus, SHARDS)
+
+    # the deployment's layout: stream pre-sharded, result left on the mesh
+    assert getattr(fact, "_pre_sharded", False)
+    for table in (fact, result):
+        for col in table.columns.values():
+            assert len(col.storage.sharding.device_set) == SHARDS
+    # the mesh sort built the index and the exchange answered the probe
+    assert "dsort" in build
+    exchange = [r for r in stages if r.stage == "join:all_to_all"]
+    assert len(exchange) == 1
+    extra = exchange[0].extra
+    assert extra["retries"] == 0 and extra["attempts"] == 1
+    assert extra["slot_fill"] == pytest.approx(N_ORDERS / (SHARDS**2 * extra["capacity"]))
+    assert extra["bytes_exchanged"] == 3 * 4 * SHARDS**2 * extra["capacity"]
+    assert [r for r in stages if r.stage == "join:skew-detect"][0].extra["hot_keys"] == 0
+    assert "join:partition" in [r.stage for r in stages]
+    watch.assert_zero()  # the second execution lowered nothing
+
+    got = column_strings(result)
+    # (a) the numpy reference
+    for name, want in corpus.want().items():
+        assert got[name] == want.tolist(), name
+    # (b) the host executor
+    host = Take(FromFile(corpus.orders)).Join(
+        Take(FromFile(corpus.people)).UniqueIndexOn("id"), "cust_id"
+    ).ToRows()
+    assert got == {name: [r[name] for r in host] for name in COLUMNS}
+    # (c) the one-device run: the same values everywhere, and bitwise the
+    # same lanes and dictionaries wherever the two keep a column alike (the
+    # one-device index build demotes the typed id lane, the mesh sort does not)
+    _, single, single_stages, single_build, _ = lookup_join(corpus, None)
+    assert "dsort" not in single_build
+    assert not [r for r in single_stages if r.stage == "join:all_to_all"]
+    assert result.nrows == single.nrows == N_ORDERS
+    assert got == column_strings(single)
+    bitwise = []
+    for name in COLUMNS:
+        a, b = result.columns[name], single.columns[name]
+        if type(a) is not type(b):
+            continue
+        np.testing.assert_array_equal(np.asarray(a.storage), np.asarray(b.storage), err_msg=name)
+        if getattr(a, "kind", "str") != "int":
+            np.testing.assert_array_equal(np.asarray(a.dictionary), np.asarray(b.dictionary))
+        bitwise.append(name)
+    assert set(bitwise) >= {"cust_id", "prod_id", "qty", "ts", "name", "surname"}
+    ts = result.columns["ts"]
+    assert ts._lane_state is not None and len(ts.dictionary) == len(set(corpus.ts.tolist()))
+
+
+@pytest.mark.parametrize("kind", ["dense", "sorted"])
+def test_translation_tables_are_placed_on_the_probe_mesh_once(kind, tmp_path, monkeypatch):
+    """The typed ``cust_id`` lane's translation into the index's id
+    dictionary runs in every execution: its tables sit replicated on the
+    stream's own four devices, committed, and are the same arrays call
+    after call — left uncommitted on the default device, jit copies them
+    whole onto every shard each time (two 20M-entry tables at the
+    benchmark's size)."""
+    from csvplus_tpu.columnar.typed import IntColumn
+
+    if kind == "sorted":
+        monkeypatch.setattr(IntColumn, "DENSE_RANGE_MAX", 1)
+    corpus = Corpus(7, tmp_path)
+    build_id = FromFile(corpus.people).OnDevice().plan.table.columns["id"]
+    probes = {
+        shards: FromFile(corpus.orders).OnDevice(shards=shards).plan.table.columns["cust_id"]
+        for shards in (SHARDS, None)
+    }
+    state = probes[SHARDS].translation_state_to(build_id)
+    assert state[0] == kind
+    tables = [a for a in state if isinstance(a, jax.Array)]
+    assert len(tables) == (1 if kind == "dense" else 2)
+    on = probes[SHARDS].values.sharding.device_set
+    assert len(on) == SHARDS
+    for a in tables:
+        assert a.committed and a.sharding.device_set == on and a.sharding.is_fully_replicated
+    again = probes[SHARDS].translation_state_to(build_id)
+    assert all(a is b for a, b in zip(state, again))
+    # one device: the tables stay where the host parse put them
+    single = probes[None].translation_state_to(build_id)
+    assert all(len(a.sharding.device_set) == 1 for a in single if isinstance(a, jax.Array))
+    np.testing.assert_array_equal(
+        np.asarray(probes[SHARDS].renumbered_to_col(build_id)),
+        np.asarray(probes[None].renumbered_to_col(build_id)),
+    )
+    assert (np.asarray(probes[None].renumbered_to_col(build_id)) >= 0).all()

@@ -129,17 +129,27 @@ def test_tiny_table_trailing_devices_all_padding(tmp_path):
 
 
 @needs_mesh
-def test_lane_threshold_falls_back_under_mesh(tmp_path, monkeypatch):
+def test_lane_threshold_lands_pre_sharded_under_mesh(tmp_path, monkeypatch):
     """A string column crossing the lane threshold under sharded ingest
-    falls back to the whole-file tiers + with_sharding — behavior
-    parity, only the placement strategy differs."""
+    keeps its codes on their shards and ships the deferred lane
+    dictionary; settling the dictionary afterwards leaves the values
+    and the placement as they were."""
     monkeypatch.setenv("CSVPLUS_DICT_DEVICE_MIN_DISTINCT", "50")
     monkeypatch.setenv("CSVPLUS_TYPED_LANES", "0")  # force dictionary mode
-    path = _write(
-        tmp_path, "k\n" + "".join(f"u{i}x\n" for i in range(400))
-    )
+    want = [f"u{(i * 7) % 400}x" for i in range(400)]  # unsorted, so settling moves codes
+    path = _write(tmp_path, "k\n" + "".join(v + "\n" for v in want))
     with telemetry.collect() as records:
         t = FromFile(path).on_device(shards=8).plan.table
-    assert not getattr(t, "_pre_sharded", False)
-    got = [r["k"] for r in t.to_rows()]
-    assert got == [f"u{i}x" for i in range(400)]
+    stages = [r.stage for r in records]
+    assert "ingest:streamed" in stages and "ingest:shard-assemble" in stages
+    assert getattr(t, "_pre_sharded", False)
+    col = t.columns["k"]
+    assert col._dictionary is None and col.dev_dictionary is not None
+    assert not col._dev_dict_sorted  # several chunks: the union is deferred
+    assert len(col.storage.sharding.device_set) == 8
+    assert [r["k"] for r in t.to_rows()] == want
+    col._ensure_sorted_lanes()
+    assert col._dev_dict_sorted and col.dict_size == 400
+    assert len(col.storage.sharding.device_set) == 8
+    assert [r["k"] for r in t.to_rows()] == want
+    assert col.dictionary.tolist() == sorted(v.encode() for v in set(want))
