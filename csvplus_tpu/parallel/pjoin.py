@@ -20,9 +20,19 @@ inside jit):
   + a scatter into an ``(N, C)`` slot buffer + ``lax.all_to_all`` (this
   is the ICI shuffle);
 * the owner answers every received probe with ``(global lower bound,
-  match count)`` from a vectorized local binary search, and a reverse
-  ``all_to_all`` returns answers through the same slots, so no
-  permutation metadata ever crosses the wire;
+  match count)``, and a reverse ``all_to_all`` returns answers through
+  the same slots, so no permutation metadata ever crosses the wire.
+  Where every shard's slice of unique keys spans at most
+  ``2 ** DeviceIndex.DIRECT_MAX_BITS`` values (a dictionary-coded key
+  column: every code occurs, so a slice is one contiguous run) the
+  owner answers **by position**: ``prepare_partitioned`` lays the
+  answers out over ``[first, first + span)`` and a received key reads
+  them at ``key - first`` — a range test and two gathers over the
+  slots, the one-chip direct tier's idea per owner (``owner_tier``
+  ``"positional"``).  A slice too sparse for that (multi-column packed
+  keys with wide gaps), and every 62-bit key, keeps the vectorized
+  local binary search over its unique keys (``"search"``:
+  ``search_rounds`` gather rounds over all ``N * C`` slots);
 * capacity ``C`` (slots per destination) is a static compile-time
   parameter; overflow is detected on device (-1 sentinel) and the probe
   retries with doubled capacity — the count -> allocate -> fill pattern
@@ -59,7 +69,7 @@ from __future__ import annotations
 import math
 import os
 from functools import partial
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import numpy as np
 
@@ -164,13 +174,99 @@ def partition_build_keys(
     return local, lower, count, splits
 
 
+def positional_tables(
+    local: np.ndarray, lower: np.ndarray, count: np.ndarray, max_span: int
+):
+    """The positional form of :func:`partition_build_keys`' slices: per
+    shard its first key and its answers laid out by ``key - first``.
+
+    Returns ``(span_max, tables)``: *span_max* is the widest shard's
+    ``last - first + 1`` over its real (unpadded) unique keys; *tables*
+    is ``(first[(N,)], lower_tab[(N, T)], count_tab[(N, T)])`` with
+    ``T = max(span_max, 1)`` and ``(-1, 0)`` in every hole and pad, or
+    None where *span_max* exceeds *max_span* (or the keys are 62-bit).
+    A dense slice of unique keys gives back its own ``lower``/``count``.
+    """
+    sizes = (local != _sentinel_for(local.dtype)).sum(axis=1)
+    rows = np.arange(local.shape[0])
+    first = local[:, 0]  # an empty shard's is the sentinel: nothing is in its range
+    last = local[rows, np.maximum(sizes, 1) - 1]
+    spans = np.where(sizes > 0, last.astype(np.int64) - first + 1, 0)
+    span_max = int(spans.max())
+    if local.dtype != np.int32 or span_max > max_span:
+        return span_max, None
+    T = max(span_max, 1)
+    lower_tab = np.full((local.shape[0], T), -1, dtype=np.int32)
+    count_tab = np.zeros((local.shape[0], T), dtype=np.int32)
+    for s, n in enumerate(sizes):
+        off = local[s, :n] - first[s]
+        lower_tab[s, off] = lower[s, :n]
+        count_tab[s, off] = count[s, :n]
+    return span_max, (first, lower_tab, count_tab)
+
+
+class Partitioned(NamedTuple):
+    """One index's build side as :func:`prepare_partitioned` leaves it
+    on a mesh — what every partitioned probe of that index is handed.
+
+    ``lower``/``count`` are each shard's answers (int32, row-sharded,
+    flattened ``(N * k,)``) and ``splits`` the replicated routing keys
+    (one int32 lane, or ``(hi, lo)`` for 62-bit keys).  How the owner
+    finds a received key's answer is in what else is there:
+
+    * ``first`` set (``(N,)`` row-sharded: a shard's first key) and
+      ``uniq`` empty — **positional**: the answers are laid out over
+      the slice's span and read at ``key - first``;
+    * ``first`` None — **search**: ``uniq`` holds each shard's sorted
+      unique keys (sentinel-padded; two lanes for 62-bit keys), the
+      answers lie beside them, and the owner binary-searches.
+    """
+
+    uniq: Tuple[jax.Array, ...]
+    first: "jax.Array | None"
+    lower: jax.Array
+    count: jax.Array
+    splits: Tuple[jax.Array, ...]
+
+    @property
+    def wide(self) -> bool:
+        return len(self.splits) == 2
+
+    @property
+    def positional(self) -> bool:
+        return self.first is not None
+
+    @property
+    def owner_tier(self) -> str:
+        return "positional" if self.positional else "search"
+
+    @property
+    def search_rounds(self) -> int:
+        """Gather rounds of the owner's search over its slice (0: none)."""
+        if self.positional:
+            return 0
+        from ..ops.join import _searchsorted_rounds
+
+        return _searchsorted_rounds(
+            self.uniq[0].shape[0] // self.splits[0].shape[0]
+        )
+
+    @property
+    def owner(self) -> jax.Array:
+        """The narrow shard kernel's owner operand: ``first``, else ``uniq``."""
+        return self.first if self.positional else self.uniq[0]
+
+
 def _probe_shard_kernel(
-    n_shards: int, capacity: int, axes, qk, uniq_local, lower_local, count_local, splits
+    n_shards: int, capacity: int, axes, positional: bool,
+    qk, owner, lower_local, count_local, splits,
 ):
     """Per-shard body (runs under shard_map): route, exchange, probe,
     route back.  All shapes static.  *axes* is the mesh's full axis-name
     tuple: the exchange spans the whole mesh (ICI within a slice, DCN
-    across slices on a 2-D mesh)."""
+    across slices on a 2-D mesh).  *owner* is this shard's first key
+    (shape ``(1,)``) where *positional*, else its sorted unique keys
+    (:class:`Partitioned`)."""
     N, C = n_shards, capacity
 
     valid = qk >= 0
@@ -205,12 +301,19 @@ def _probe_shard_kernel(
     # ICI shuffle: slot-aligned exchange
     recv = lax.all_to_all(buf, axes, split_axis=0, concat_axis=0, tiled=True)
 
-    # vectorized local search over this shard's unique-key slice; the
-    # answer (global lower, run length) is a precomputed per-key payload
+    # the answer (global lower, run length) is a precomputed payload:
+    # found by position where the slice's span has a table (holes and
+    # pads hold (-1, 0) there), else by a vectorized local search over
+    # this shard's unique keys
     q = recv.reshape(-1)
-    idx = jnp.searchsorted(uniq_local, q, side="left")
-    idx = jnp.minimum(idx, uniq_local.shape[0] - 1).astype(jnp.int32)
-    found = (jnp.take(uniq_local, idx, axis=0) == q) & (q >= 0)
+    if positional:
+        off = q - owner[0]  # q >= -1 and first >= 0: no int32 wrap
+        found = (q >= 0) & (off >= 0) & (off < lower_local.shape[0])
+        idx = jnp.clip(off, 0, lower_local.shape[0] - 1)
+    else:
+        idx = jnp.searchsorted(owner, q, side="left")
+        idx = jnp.minimum(idx, owner.shape[0] - 1).astype(jnp.int32)
+        found = (jnp.take(owner, idx, axis=0) == q) & (q >= 0)
     resp_lo = jnp.where(found, jnp.take(lower_local, idx, axis=0), -1)
     resp_ct = jnp.where(found, jnp.take(count_local, idx, axis=0), 0)
 
@@ -327,27 +430,34 @@ def _probe_spmd2(
     return f(qh, ql, uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo)
 
 
-@register_kernel("pjoin.probe_spmd", static_argnames=("mesh", "n_shards", "capacity"))
-def _probe_spmd(mesh, n_shards, capacity, qk_sharded, uniq, lower, count, splits):
+@register_kernel(
+    "pjoin.probe_spmd", static_argnames=("mesh", "n_shards", "capacity", "positional")
+)
+def _probe_spmd(
+    mesh, n_shards, capacity, positional, qk_sharded, owner, lower, count, splits
+):
     axes = tuple(mesh.axis_names)
     rows = P(axes)
     f = shard_map(
-        partial(_probe_shard_kernel, n_shards, capacity, axes),
+        partial(_probe_shard_kernel, n_shards, capacity, axes, positional),
         mesh=mesh,
         in_specs=(rows, rows, rows, rows, P()),
         out_specs=(rows, rows),
     )
-    return f(qk_sharded, uniq, lower, count, splits)
+    return f(qk_sharded, owner, lower, count, splits)
 
 
-def prepare_partitioned(mesh: Mesh, index_keys_sorted: np.ndarray):
+def prepare_partitioned(mesh: Mesh, index_keys_sorted: np.ndarray) -> Partitioned:
     """Range-partition + upload the build keys once; reusable across
     probes (see DeviceIndex._partitioned_for's cache).
 
-    int32 keys -> a 4-tuple (uniq, lower, count, splits); int64 (wide,
-    62-bit) keys -> a 6-tuple with the unique keys and splits as dual
-    31-bit lanes (uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo).
+    int32 keys whose every slice spans at most
+    ``2 ** DeviceIndex.DIRECT_MAX_BITS`` values take the positional form
+    of :class:`Partitioned` (what the keys show decides, nothing else);
+    sparser slices and int64 (wide, 62-bit) keys the search form, the
+    latter with the unique keys and splits as dual 31-bit lanes.
     """
+    from ..ops.join import DeviceIndex
     from ..utils.observe import telemetry
 
     n_shards = mesh.devices.size
@@ -357,35 +467,29 @@ def prepare_partitioned(mesh: Mesh, index_keys_sorted: np.ndarray):
         "join:partition", int(index_keys_sorted.shape[0])
     ) as _p:
         _p["n_shards"] = n_shards
-        if np.dtype(index_keys_sorted.dtype) == np.int64:
-            local, lower, count, splits = partition_build_keys(
-                index_keys_sorted, n_shards
-            )
-            lh, ll = split_lanes(local.reshape(-1))
-            sh, sl = split_lanes(splits)
-            return tuple(
-                telemetry.barrier(
-                    (
-                        jax.device_put(lh, rows),
-                        jax.device_put(ll, rows),
-                        jax.device_put(lower.reshape(-1), rows),
-                        jax.device_put(count.reshape(-1), rows),
-                        jax.device_put(sh, repl),
-                        jax.device_put(sl, repl),
-                    )
-                )
-            )
+        wide = np.dtype(index_keys_sorted.dtype) == np.int64
+        lanes = split_lanes if wide else (lambda x: (x,))
         local, lower, count, splits = partition_build_keys(
-            index_keys_sorted.astype(np.int32), n_shards
+            index_keys_sorted if wide else index_keys_sorted.astype(np.int32),
+            n_shards,
         )
-        return tuple(
-            telemetry.barrier(
-                (
-                    jax.device_put(local.reshape(-1), rows),
-                    jax.device_put(lower.reshape(-1), rows),
-                    jax.device_put(count.reshape(-1), rows),
-                    jax.device_put(splits, repl),
-                )
+        _p["span_max"], tables = positional_tables(
+            local, lower, count, 2 ** DeviceIndex.DIRECT_MAX_BITS
+        )
+        _p["positional"] = tables is not None
+        if tables is not None:
+            first, lower, count = tables
+            uniq, first = (), jax.device_put(first, rows)
+        else:
+            uniq = tuple(jax.device_put(x, rows) for x in lanes(local.reshape(-1)))
+            first = None
+        return telemetry.barrier(
+            Partitioned(
+                uniq,
+                first,
+                jax.device_put(lower.reshape(-1), rows),
+                jax.device_put(count.reshape(-1), rows),
+                tuple(jax.device_put(x, repl) for x in lanes(splits)),
             )
         )
 
@@ -412,7 +516,7 @@ def partitioned_probe(
     wide = np.dtype(stream_keys.dtype) == np.int64
     if prepared is None:
         prepared = prepare_partitioned(mesh, index_keys_sorted)
-    assert len(prepared) == (6 if wide else 4), "prepared/key dtype mismatch"
+    assert prepared.wide == wide, "prepared/key dtype mismatch"
     if wide:
         qh, ql = split_lanes(stream_keys)
         lo, ct = partitioned_probe_device_wide(
@@ -434,9 +538,12 @@ def partitioned_probe(
 # element hot-key sample and one boolean overflow scalar per retry.
 
 
-@register_kernel("pjoin.probe_spmd_dev", static_argnames=("mesh", "n_shards", "capacity", "n_hot"))
+@register_kernel(
+    "pjoin.probe_spmd_dev",
+    static_argnames=("mesh", "n_shards", "capacity", "n_hot", "positional"),
+)
 def _probe_spmd_dev(
-    mesh, n_shards, capacity, n_hot, qk, uniq, lower, count, splits,
+    mesh, n_shards, capacity, n_hot, positional, qk, owner, lower, count, splits,
     hot_vals, hot_lo, hot_ct,
 ):
     """One executable: hot-key mask -> pad -> all_to_all exchange ->
@@ -466,12 +573,12 @@ def _probe_spmd_dev(
         qk_cold, NamedSharding(mesh, rows)
     )
     f = shard_map(
-        partial(_probe_shard_kernel, n_shards, capacity, axes),
+        partial(_probe_shard_kernel, n_shards, capacity, axes, positional),
         mesh=mesh,
         in_specs=(rows, rows, rows, rows, P()),
         out_specs=(rows, rows),
     )
-    lo, ct = f(qk_cold, uniq, lower, count, splits)
+    lo, ct = f(qk_cold, owner, lower, count, splits)
     lo, ct = lo[:m], ct[:m]
     if n_hot:
         h_lo = jnp.take(hot_lo, idxc, axis=0)
@@ -723,7 +830,7 @@ def _note_skew(
     )
 
 
-def _hot_answers_device(mesh, hot: np.ndarray, prepared, wide: bool):
+def _hot_answers_device(mesh, hot: np.ndarray, prepared: Partitioned):
     """Answer the (few, distinct) hot values themselves through the same
     SPMD exchange — tiny arrays, so capacity = the full hot count can
     never overflow.  Returns device (vals..., lo, ct) padded to pow2
@@ -733,22 +840,25 @@ def _hot_answers_device(mesh, hot: np.ndarray, prepared, wide: bool):
     padded = max(n_hot, n_shards) if n_hot % n_shards else n_hot
     padded = padded + ((-padded) % n_shards)
     cap = _pow2(padded)  # worst case: every hot value routes to one shard
+    wide = prepared.wide
     if wide:
         hv = np.full(padded, -1, dtype=np.int64)
         hv[: hot.size] = hot
         qh, ql = split_lanes(hv)
         qh_d = shard_rows(mesh, qh)
         ql_d = shard_rows(mesh, ql)
-        uh, ul, lower, count, sh, sl = prepared
         lo, ct = _probe_spmd2(
-            mesh, n_shards, cap, qh_d, ql_d, uh, ul, lower, count, sh, sl
+            mesh, n_shards, cap, qh_d, ql_d, *prepared.uniq,
+            prepared.lower, prepared.count, *prepared.splits,
         )
     else:
         hv = np.full(padded, -1, dtype=np.int32)
         hv[: hot.size] = hot
         qk_d = shard_rows(mesh, hv)
-        uniq, lower, count, splits = prepared
-        lo, ct = _probe_spmd(mesh, n_shards, cap, qk_d, uniq, lower, count, splits)
+        lo, ct = _probe_spmd(
+            mesh, n_shards, cap, prepared.positional, qk_d,
+            prepared.owner, prepared.lower, prepared.count, *prepared.splits,
+        )
     repl = NamedSharding(mesh, P())
     # hot value lanes for the main kernel's membership search: sorted,
     # padded by REPEATING the last real value — duplicates at the tail
@@ -779,14 +889,15 @@ def _hot_answers_device(mesh, hot: np.ndarray, prepared, wide: bool):
 
 
 def _retry_probe_device(
-    mesh: Mesh, m: int, capacity: "int | None", launch, exchanges: int = 3
+    mesh: Mesh, m: int, capacity: "int | None", launch, prepared: Partitioned
 ):
     """Shared retry driver for the device wrappers: geometric capacity
     doubling keyed off ONE overflow boolean per attempt (the only host
     sync in the loop), results re-committed to the named mesh.
-    *exchanges* is the number of ``(N, C)`` int32 ``all_to_all`` rounds
-    one attempt makes (key lanes out, ``lower`` and ``count`` back): the
-    stage's ``bytes_exchanged`` is reckoned from it, from shapes.
+    *prepared* says what the stage records of the owner's step
+    (``owner_tier``, ``search_rounds``) and how many ``(N, C)`` int32
+    ``all_to_all`` rounds one attempt makes (key lanes out, ``lower``
+    and ``count`` back): ``bytes_exchanged`` is reckoned from shapes.
 
     Returns ``((lo, ct), rows_broadcast, capacity)``: when the launch
     carries the hot tier (4-tuple results) the broadcast row count
@@ -795,6 +906,7 @@ def _retry_probe_device(
     from ..utils.observe import telemetry
 
     n_shards = mesh.devices.size
+    exchanges = 4 if prepared.wide else 3
     if capacity is None:
         capacity = _default_capacity(m, n_shards)
     padded_m = m + ((-m) % n_shards)
@@ -821,6 +933,8 @@ def _retry_probe_device(
                 _x["attempts"] = retries + 1  # one blocking host read each
                 _x["slot_fill"] = m / slots
                 _x["bytes_exchanged"] = 4 * exchanges * slots
+                _x["owner_tier"] = prepared.owner_tier
+                _x["search_rounds"] = prepared.search_rounds
                 out = _renamed_rows(mesh, lo), _renamed_rows(mesh, ct)
                 telemetry.barrier(out)
                 return out, rows_broadcast, capacity
@@ -867,7 +981,6 @@ def partitioned_probe_device(
     accumulates this probe's settled capacity and hot-routing split for
     the multiway join's cross-dimension sharing (:func:`_note_part_info`)."""
     n_shards = mesh.devices.size
-    uniq, lower, count, splits = prepared
     m = int(qk.shape[0])
 
     hot, hot_share = _detect_hot(qk, n_shards, wide=False)
@@ -879,9 +992,7 @@ def partitioned_probe_device(
             # (shape-derived, log-bounded distinct values), never the
             # hot values themselves
             n_hot = _pow2(hot.size)
-            (hot_vals,), hot_lo, hot_ct = _hot_answers_device(
-                mesh, hot, prepared, wide=False
-            )
+            (hot_vals,), hot_lo, hot_ct = _hot_answers_device(mesh, hot, prepared)
             _b["n_hot"] = n_hot
             telemetry.barrier((hot_vals, hot_lo, hot_ct))
         if capacity is None:
@@ -893,11 +1004,14 @@ def partitioned_probe_device(
 
     def launch(cap):
         return _probe_spmd_dev(
-            mesh, n_shards, cap, n_hot,
-            qk, uniq, lower, count, splits, hot_vals, hot_lo, hot_ct,
+            mesh, n_shards, cap, n_hot, prepared.positional,
+            qk, prepared.owner, prepared.lower, prepared.count, *prepared.splits,
+            hot_vals, hot_lo, hot_ct,
         )
 
-    out, rows_broadcast, cap_used = _retry_probe_device(mesh, m, capacity, launch)
+    out, rows_broadcast, cap_used = _retry_probe_device(
+        mesh, m, capacity, launch, prepared
+    )
     if hot is not None:
         _note_skew(
             label, m, int(hot.size), rows_broadcast, cap_used,
@@ -919,7 +1033,6 @@ def partitioned_probe_device_wide(
     """Device-resident wide-key (62-bit dual-lane) partitioned probe.
     Invalid probes carry (-1, -1) lanes."""
     n_shards = mesh.devices.size
-    uh, ul, lower, count, sh, sl = prepared
     m = int(q_hi.shape[0])
 
     hot, hot_share = _detect_hot((q_hi, q_lo), n_shards, wide=True)
@@ -929,7 +1042,7 @@ def partitioned_probe_device_wide(
         with telemetry.stage("join:broadcast", int(hot.size)) as _b:
             n_hot = _pow2(hot.size)  # pow2 bucket: log-bounded statics
             (hot_hi, hot_lo_lane), hot_ans_lo, hot_ans_ct = (
-                _hot_answers_device(mesh, hot, prepared, wide=True)
+                _hot_answers_device(mesh, hot, prepared)
             )
             _b["n_hot"] = n_hot
             telemetry.barrier((hot_hi, hot_lo_lane, hot_ans_lo, hot_ans_ct))
@@ -943,12 +1056,12 @@ def partitioned_probe_device_wide(
     def launch(cap):
         return _probe_spmd_dev2(
             mesh, n_shards, cap, n_hot, q_hi, q_lo,
-            uh, ul, lower, count, sh, sl,
+            *prepared.uniq, prepared.lower, prepared.count, *prepared.splits,
             hot_hi, hot_lo_lane, hot_ans_lo, hot_ans_ct,
         )
 
     out, rows_broadcast, cap_used = _retry_probe_device(
-        mesh, m, capacity, launch, exchanges=4
+        mesh, m, capacity, launch, prepared
     )
     if hot is not None:
         _note_skew(

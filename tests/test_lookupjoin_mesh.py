@@ -135,7 +135,11 @@ def test_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(seed, tmp_path
     assert extra["slot_fill"] == pytest.approx(N_ORDERS / (SHARDS**2 * extra["capacity"]))
     assert extra["bytes_exchanged"] == 3 * 4 * SHARDS**2 * extra["capacity"]
     assert [r for r in stages if r.stage == "join:skew-detect"][0].extra["hot_keys"] == 0
-    assert "join:partition" in [r.stage for r in stages]
+    # people's ids are the codes of their own dictionary, so each shard owns one
+    # contiguous run of keys: the owner answers by position, with no search
+    (partition,) = [r.extra for r in stages if r.stage == "join:partition"]
+    assert partition["positional"] is True and partition["span_max"] == N_PEOPLE // SHARDS
+    assert extra["owner_tier"] == "positional" and extra["search_rounds"] == 0
     watch.assert_zero()  # the second execution lowered nothing
 
     got = column_strings(result)

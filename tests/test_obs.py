@@ -949,3 +949,44 @@ def test_warm_join_and_lookup_pass_recompiles_nothing(monkeypatch):
     w.assert_zero("warm join + lookup pass")
     counts = compile_counts()
     assert counts["serve.bounds_search"] >= 1 and counts["table.gather_take"] >= 1
+
+
+@pytest.mark.parametrize("index_keys", ["dense", "sparse"])
+def test_partitioned_join_says_how_the_owner_answered(index_keys, monkeypatch):
+    """``join:partition`` records each index's widest slice span and
+    whether it took the positional form, ``join:all_to_all`` the tier
+    the owner ran and the gather rounds of its search: a one-column key
+    is a contiguous run of dictionary codes a shard (positional, 0); a
+    two-column packed key has a gap of 32 between neighbours, past the
+    bound lowered here to 2**10 (search, and its rounds)."""
+    from csvplus_tpu.columnar.ingest import source_from_table
+    from csvplus_tpu.ops.join import DeviceIndex, _searchsorted_rounds
+    from csvplus_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(DeviceIndex, "PARTITION_MIN_KEYS", 1)
+    monkeypatch.setattr(DeviceIndex, "DIRECT_MAX_BITS", 10)
+    n_keys, shards = 1_200, 8
+    on = ("a",) if index_keys == "dense" else ("a", "b")
+    idx = cp.TakeRows(
+        [cp.Row({"a": f"c{i:04d}", "b": f"x{i % 31:02d}", "v": str(i)}) for i in range(n_keys)]
+    ).index_on(*on)
+    idx.on_device("cpu")
+    cust = np.random.default_rng(3).integers(0, n_keys, 4_000)
+    table = DeviceTable.from_pylists(
+        {"a": [f"c{v:04d}" for v in cust], "b": [f"x{v % 31:02d}" for v in cust]},
+        device="cpu",
+    )
+    want = cp.take(source_from_table(table)).join(idx, *on).to_rows()
+    with telemetry.collect() as recs:
+        got = source_from_table(table.with_sharding(make_mesh(shards))).join(idx, *on).to_rows()
+    assert got == want and len(got) == 4_000
+    (part,) = [r.extra for r in recs if r.stage == "join:partition"]
+    (exchange,) = [r.extra for r in recs if r.stage == "join:all_to_all"]
+    per_shard = n_keys // shards
+    if index_keys == "dense":
+        assert part["span_max"] == per_shard and part["positional"] is True
+        assert (exchange["owner_tier"], exchange["search_rounds"]) == ("positional", 0)
+    else:
+        assert part["span_max"] > 2**10 and part["positional"] is False
+        assert exchange["owner_tier"] == "search"
+        assert exchange["search_rounds"] == _searchsorted_rounds(per_shard) == 8

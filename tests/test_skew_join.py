@@ -178,9 +178,9 @@ def test_uniform_stream_is_pure_passthrough(monkeypatch, mesh):
     seen = []
     orig = PJ._probe_spmd_dev
 
-    def capture(mesh_, n_shards, capacity, n_hot, qk, *rest):
+    def capture(mesh_, n_shards, capacity, n_hot, positional, qk, *rest):
         seen.append((n_hot, capacity, int(qk.shape[0])))
-        return orig(mesh_, n_shards, capacity, n_hot, qk, *rest)
+        return orig(mesh_, n_shards, capacity, n_hot, positional, qk, *rest)
 
     monkeypatch.setattr(PJ, "_probe_spmd_dev", capture)
     with telemetry.collect() as records:
