@@ -33,6 +33,11 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 
 
+def reported_by(cell: str) -> set:
+    """The end-to-end metrics a cell reports, by ``BENCHMARK.json``."""
+    return {m["name"] for m in BENCHMARK["end_to_end"] if cell in m.get("workloads", CELLS)}
+
+
 @pytest.fixture(autouse=True)
 def small_mirror_cap(monkeypatch):
     """At rehearsal size every index is under the 16M mirror cap, and the
@@ -64,11 +69,7 @@ def test_cell_end_to_end_reports_its_end_to_end_metrics(cell):
     assert rc == 0
     assert set(result) == CONTRACT_KEYS
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
-    want = {
-        m["name"] for m in BENCHMARK["end_to_end"]
-        if cell in m.get("workloads", CELLS)
-    }
-    assert set(result["metrics"]) == want
+    assert set(result["metrics"]) == reported_by(cell)
     units = {m["name"]: m["unit"] for m in BENCHMARK["end_to_end"]}
     for name, m in result["metrics"].items():
         assert m["unit"] == units[name] and m["value"] > 0
@@ -205,6 +206,36 @@ def test_trace_reducer_by_hand():
     }
     assert device_trace.reduce_events({}, host) is None
     assert device_trace.reduce_events(device, [("bench:outer", 0, 100)]) is None
+
+
+PER_LAYER = {m["name"]: m for m in BENCHMARK["per_layer"]}
+LAYER_FILES = {
+    name[: -len(".json")] for name in os.listdir(os.path.join(BENCH, "layer_metrics"))
+}
+
+
+@pytest.mark.parametrize(
+    "kind,name",
+    [("per_layer", n) for n in sorted(set(PER_LAYER) | LAYER_FILES)] + [("cell", c) for c in CELLS],
+)
+def test_benchmark_json_is_consistent(kind, name):
+    """A per-layer metric has its entry and its file, and ``moves`` an
+    end-to-end metric that each of its cells reports; a cell reports
+    ``setup_s`` and exactly one rate, under the name its file gives."""
+    if kind == "per_layer":
+        assert name in PER_LAYER, f"layer_metrics/{name}.json has no entry in per_layer"
+        assert name in LAYER_FILES, f"per_layer entry {name} has no layer_metrics file"
+        m = PER_LAYER[name]
+        assert m["workloads"] and set(m["workloads"]) <= set(CELLS)
+        for cell in m["workloads"]:
+            assert m["moves"] in reported_by(cell), (name, m["moves"], cell)
+        return
+    rates = reported_by(name) - {"setup_s"}
+    assert "setup_s" in reported_by(name) and len(rates) == 1, rates
+    cell = run.load_json("workloads", f"{name}.json")
+    if cell["driver"] == "batch_query":
+        assert {cell.get("metric", "rows_per_s")} == rates
+    assert any(name in m["workloads"] and m["moves"] in rates for m in PER_LAYER.values())
 
 
 def test_benchmark_json_agrees_with_the_files_found_by_name():

@@ -10,6 +10,7 @@ collective's ``-start``/``-done`` pair is one call.
 
 from __future__ import annotations
 
+import copy
 import json
 import os
 import sys
@@ -20,6 +21,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 sys.path[:0] = [BENCH]
 
+import run  # noqa: E402
 from readers import collective_trace, device_trace, kernel_trace  # noqa: E402
 
 
@@ -60,6 +62,37 @@ def test_a_kernels_seconds_are_the_average_over_the_device_planes(fx):
     assert red["calls"]["csvplus.pjoin.probe_spmd_dev"] == 1  # one execution a plane
     assert red["calls"]["iota"] == 0.5  # on one plane of the two
     assert "lane_digest" not in red["kernels"]
+
+
+def test_the_translate_metric_reads_the_program_the_mesh_cell_runs(fx):
+    """``kernel.join_translate_device_s.mesh``'s selector matches by
+    prefix: it reads ``csvplus.typed.translate_sorted``, the while's body
+    nested in it counted once, per plane and averaged, per execution."""
+    with open(os.path.join(BENCH, "layer_metrics", "kernel.join_translate_device_s.mesh.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "kernel_trace" and metric["workloads"] == ["lookupjoin-mesh4"]
+    red = kernel_trace.reduce_kernels(fx["ops"], fx["modules"], fx["host"])
+    want = mean_s(fx["expect_ns"]["translate_per_plane"])
+    assert red["kernels"]["csvplus.typed.translate_sorted"] == pytest.approx(want)
+    h = _Harness({"kernel_trace": red, "facts": {"executions": 2}})
+    assert kernel_trace.read(h, None, None, metric["selector"]) == pytest.approx(want / 2)
+    # no translate program in the window (the composed tier, star3 since PR 26): left out
+    other = dict(red, kernels={k: s for k, s in red["kernels"].items() if "translate" not in k})
+    h = _Harness({"kernel_trace": other, "facts": {"executions": 2}})
+    assert kernel_trace.read(h, None, None, metric["selector"]) is None
+
+
+def test_the_probes_least_bytes_hold_no_read_of_the_key_lane():
+    """Keys read, answers written, the slice's two bounds: a quarter of
+    the mesh's, and nothing that follows the index's size."""
+    with open(os.path.join(BENCH, "configs", "orders-people-mesh4.json")) as f:
+        cfg = json.load(f)
+    probe = run.load_module("least_bytes", "lookupjoin_probe").least_bytes
+    assert probe(cfg, 20_000_000) == 4 * (5_000_000 + 2 * 5_000_000 + 2)
+    wider = copy.deepcopy(cfg)
+    wider["tables"]["people"]["rows"] *= 2
+    assert probe(wider, 20_000_000) == probe(cfg, 20_000_000)
+    assert probe(cfg, 20_000_000) < run.load_module("least_bytes", "lookupjoin").least_bytes(cfg, 20_000_000)
 
 
 def test_collective_seconds_and_the_start_done_pair(fx):
