@@ -19,8 +19,7 @@ differential contract:
   answer.  Every case runs under a watchdog timeout, so a hang IS a
   failure, not a stuck CI job.
 * the disarmed injection hooks must cost <= 1% of a served request
-  (measured here, recorded in the artifact — the same discipline as
-  `make trace-smoke`'s disabled-hook gate).
+  (measured here, recorded in the artifact).
 
 The ISSUE 12 window extends the matrix to the materialized-view tier:
 a crash at ``views:refresh`` inside a serving write cycle must leave
@@ -34,8 +33,8 @@ terminal windows: a dispatcher crash and a ``views:refresh`` crash
 must each leave an atomically-written flight dump that parses and
 names the firing fault site in its event timeline.
 
-Contract (matches the benches): diagnostics go to stderr, stdout
-carries ONE compact JSON line; CHAOS_r13.json records the full
+Contract: diagnostics go to stderr, stdout carries ONE compact JSON
+line; a temporary file, named on stderr, records the full
 evidence — per-case injection counts (``FaultPlan.snapshot``), recovery
 outcomes, serve retry/degrade metrics, telemetry counters
 (``ingest.worker_recovered``), flight-dump evidence, and the overhead
@@ -67,7 +66,6 @@ if "xla_force_host_platform_device_count" not in _flags:
 #: Watchdog bound per chaos case: a case that cannot finish inside this
 #: is a hang, which is exactly what the resilience layer must prevent.
 CASE_TIMEOUT_S = float(os.environ.get("CSVPLUS_CHAOS_CASE_TIMEOUT", 120))
-ARTIFACT = os.path.join(REPO, "CHAOS_r13.json")
 #: Disarmed-hook budget: injection sites on the serve path may cost at
 #: most this fraction of one served request.
 OVERHEAD_BUDGET_PCT = 1.0
@@ -417,7 +415,6 @@ def case_mesh_join_under_ingest_faults(tmp_root):
     """The 8-way sharded mesh join with crashing ingest workers under
     its streamed build: recovered ingest keeps the join bitwise-equal
     to the fault-free run."""
-    import csvplus_tpu.models.workloads as W
     from csvplus_tpu import Take, from_file
     from csvplus_tpu.resilience import faults
     from csvplus_tpu.resilience.faults import FaultPlan
@@ -436,7 +433,8 @@ def case_mesh_join_under_ingest_faults(tmp_root):
     def run_join():
         cust = Take(from_file(cust_path)).unique_index_on("id")
         cust.on_device("cpu")
-        return W.sharded_join(from_file(orders_path), cust, shards=8).to_rows()
+        stream = from_file(orders_path).on_device(shards=8)
+        return stream.join(cust, "cust_id").to_rows()
 
     # shrink the stream chunk so the ~60KB orders file really flows
     # through the staged multi-chunk ingest (default chunks are 64MB —
@@ -752,8 +750,8 @@ def case_view_refresh_crash():
 
 def case_disarmed_overhead(idx, ids):
     """The disarmed inject() fast path, priced against served requests
-    in BOTH regimes the sites actually run in (same discipline as
-    `make trace-smoke`).  The two serve-path sites (`serve:dispatch`,
+    in BOTH regimes the sites actually run in.
+    The two serve-path sites (`serve:dispatch`,
     `serve:bounds`) each fire once per dispatch CYCLE, so:
 
     - coalesced regime: the per-cycle site cost, amortized over the
@@ -890,10 +888,10 @@ def main() -> int:
     except Exception:
         pass
 
-    with open(ARTIFACT, "w") as f:
+    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as f:
         json.dump(record, f, indent=1)
         f.write("\n")
-    sys.stderr.write(f"chaos: artifact written to {ARTIFACT}\n")
+    sys.stderr.write(f"chaos: artifact written to {f.name}\n")
 
     compact = {
         k: record[k]

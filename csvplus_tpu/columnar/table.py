@@ -686,10 +686,6 @@ class DeviceTable:
         self.nrows = nrows
         self.device = device
         self.row_base = row_base
-        # set by producers whose construction already blocked on a scalar
-        # that depends on every column (e.g. the fused flagship join's
-        # match count): sync() is then a completed fact, not a round trip
-        self.already_forced = False
         # serializes the mirror-decode LRU (rows_from_mirror_many): the
         # serving tier made concurrent lookups real, and an OrderedDict
         # being reordered (move_to_end) while another thread inserts or
@@ -816,28 +812,6 @@ class DeviceTable:
             cols[name] = moved
         return DeviceTable(cols, self.nrows, mesh.devices.flat[0], self.row_base)
 
-    def shard_row_counts(self) -> "dict[str, int]":
-        """Rows resident per device for the first sharded column — the
-        placement-balance evidence the skew-aware join bench records.
-
-        This placement IS the broadcast tier's salt: a heavy key's fact
-        rows stay scattered across shards at their ingest positions
-        (instead of collapsing onto the key's range owner as the
-        hash-repartition exchange would force), each shard answers its
-        own hot rows from the replicated answer slots, and the
-        positional scatter-back at emit (``.at[pos].set`` in
-        ``parallel/pjoin.py``) folds the salt out again — which is why
-        the skew-aware result is bitwise-identical to the unsalted
-        path.  Empty dict when no column is sharded."""
-        for col in self.columns.values():
-            storage = col.storage
-            shards = getattr(storage, "addressable_shards", None)
-            if shards and len(shards) > 1:
-                return {
-                    str(s.device): int(s.data.shape[0]) for s in shards
-                }
-        return {}
-
     def short_desc(self) -> str:
         return f"{self.nrows}x{len(self.columns)}[{','.join(self.columns)}]"
 
@@ -849,8 +823,6 @@ class DeviceTable:
         every code array and sync its single scalar — it cannot complete
         before all inputs have.
         """
-        if self.already_forced:
-            return self
         cols = [c.storage for c in self.columns.values()]
         cols = [c for c in cols if c.shape[0]]
         if not cols:

@@ -8,7 +8,7 @@ dictionary codes:
 * both build sides (customers, products) are unique indexes, so each
   stream row matches at most one build row — the output is statically
   shaped ``(n_orders,)`` and the entire step (two vectorized binary
-  searches + attribute gathers + validity mask) fuses on device;
+  searches + validity mask) fuses on device;
 * the probe keys are the orders' key columns pre-translated into each
   index's dictionary space (host translation table + device gather at
   build time);
@@ -17,22 +17,17 @@ dictionary codes:
   with no collectives in the hot loop; the partitioned all-to-all path
   (:mod:`..parallel.pjoin`) covers build sides too large to replicate.
 
-``step`` is the jittable "forward step" exposed through
-``__graft_entry__.entry()``.
+``threeway_step`` is the jittable "forward step" exposed through
+``__graft_entry__.entry()``; the join itself runs through the plan path
+(``orders.join(cust, ...).join(prod, ...)``, :mod:`..ops.join`).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, Tuple
-
-import numpy as np
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from ..columnar.table import DeviceTable, StringColumn
-from ..ops.join import DeviceIndex
 
 
 @jax.jit
@@ -53,237 +48,6 @@ def threeway_step(
 
     valid = hit_c & hit_p
     return lo_c.astype(jnp.int32), lo_p.astype(jnp.int32), valid
-
-
-@jax.jit
-def gather_columns(ids: jax.Array, valid: jax.Array, *code_arrays: jax.Array):
-    """Gather attribute code columns by build row id, masking misses."""
-    out = []
-    for codes in code_arrays:
-        g = jnp.take(codes, jnp.where(valid, ids, 0), axis=0)
-        out.append(jnp.where(valid, g, -1))
-    return tuple(out)
-
-
-@jax.jit
-def _fused_unique_join(cum_c, cum_p, qk_c, qk_p, cust_codes, prod_codes):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """The whole all-matched flagship join as ONE dispatch: two
-    dictionary-direct probes (ops/join.direct_probe_parts — the single
-    definition of the direct tier's semantics), the validity reduction,
-    and every build-side attribute gather.  Returns the match count so
-    the caller syncs exactly one scalar."""
-    from ..ops.join import direct_probe_parts
-
-    def probe(cum, qk):
-        lo, cnt = direct_probe_parts(cum, qk, 1)
-        return lo, cnt > 0
-
-    lo_c, hit_c = probe(cum_c, qk_c)
-    lo_p, hit_p = probe(cum_p, qk_p)
-    valid = hit_c & hit_p
-    n_valid = jnp.sum(valid)
-    safe_c = jnp.where(valid, lo_c, 0)
-    safe_p = jnp.where(valid, lo_p, 0)
-    g_c = tuple(
-        jnp.where(valid, jnp.take(codes, safe_c, axis=0), -1)
-        for codes in cust_codes
-    )
-    g_p = tuple(
-        jnp.where(valid, jnp.take(codes, safe_p, axis=0), -1)
-        for codes in prod_codes
-    )
-    return n_valid, lo_c, lo_p, valid, g_c, g_p
-
-
-@jax.jit
-def _fused_direct_probe(cum_c, cum_p, qk_c, qk_p):
-    """Probe-only variant of :func:`_fused_unique_join` for padded
-    (mesh-sharded) streams, which always compact afterwards."""
-    from ..ops.join import direct_probe_parts
-
-    lo_c, cnt_c = direct_probe_parts(cum_c, qk_c, 1)
-    lo_p, cnt_p = direct_probe_parts(cum_p, qk_p, 1)
-    return lo_c, lo_p, (cnt_c > 0) & (cnt_p > 0)
-
-
-@dataclass
-class ThreewayJoin:
-    """Prepared flagship pipeline: upload once, step many times."""
-
-    cust: DeviceIndex
-    prod: DeviceIndex
-    qk_cust: jax.Array
-    qk_prod: jax.Array
-    orders_cols: Dict[str, StringColumn]
-    n_orders: int
-    # non-key orders columns are NOT inputs of the fused executable, so
-    # the match-count sync does not force them; block once (they are
-    # fixed at build time), then every run()'s output is fully settled
-    _orders_settled: bool = False
-
-    @classmethod
-    def build(
-        cls,
-        orders: DeviceTable,
-        cust_index: DeviceIndex,
-        prod_index: DeviceIndex,
-        cust_col: str = "cust_id",
-        prod_col: str = "prod_id",
-    ) -> "ThreewayJoin":
-        assert len(cust_index.key_columns) == 1 and len(prod_index.key_columns) == 1
-        qk_c = orders.columns[cust_col].renumbered_to_col(
-            cust_index.table.columns[cust_index.key_columns[0]]
-        )
-        qk_p = orders.columns[prod_col].renumbered_to_col(
-            prod_index.table.columns[prod_index.key_columns[0]]
-        )
-        return cls(
-            cust=cust_index,
-            prod=prod_index,
-            qk_cust=qk_c,
-            qk_prod=qk_p,
-            orders_cols=dict(orders.columns),
-            n_orders=orders.nrows,
-        )
-
-    def step(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """The fused probe step (jit-compiled, device-resident).
-
-        Key arrays go through the broadcast-replication cache so a mesh-
-        sharded stream probes replicated keys (no device mixing)."""
-        return threeway_step(
-            self.cust._keys_for(self.qk_cust),
-            self.prod._keys_for(self.qk_prod),
-            self.qk_cust,
-            self.qk_prod,
-        )
-
-    def run(self) -> DeviceTable:
-        """Full join: probe, compact to matches, merge columns.
-
-        Column merge semantics match the reference (csvplus.go:571-583):
-        both index's columns and stream's columns survive; stream wins on
-        name collision; stream row order is preserved.
-        """
-        names_c = list(self.cust.table.columns)
-        names_p = list(self.prod.table.columns)
-        names_o = list(self.orders_cols)
-
-        # A padded stream layout (mesh-sharded tables pad codes beyond
-        # nrows) must take the compaction path: probe arrays are padded-
-        # length there.  The scalar probe costs one extra tiny sync on
-        # the partial-match path, but saves transferring the full bool
-        # mask (nrows bytes) in the common all-matched case.
-        direct = (
-            self.cust.direct_cum is not None and self.prod.direct_cum is not None
-        )
-        # padded (mesh-sharded) streams always take the compaction path,
-        # so their fused call skips the speculative gathers entirely
-        unpadded = int(self.qk_cust.shape[0]) == self.n_orders
-        if direct and unpadded:
-            # one dispatch for probes + gathers + match count; the
-            # speculative gathers are wasted only on the rare
-            # partial-match path below
-            from ..ops.join import _aligned_codes
-
-            n_dev, lo_c, lo_p, valid, g_c, g_p = _fused_unique_join(
-                self.cust._lanes_for(self.qk_cust, "direct_cum"),
-                self.prod._lanes_for(self.qk_prod, "direct_cum"),
-                self.qk_cust,
-                self.qk_prod,
-                tuple(
-                    # a mesh-sharded stream gathers from build storage
-                    # (codes OR typed value lanes) replicated onto its
-                    # mesh (broadcast-join layout)
-                    _aligned_codes(
-                        self.cust, n, self.cust.table.columns[n].storage, self.qk_cust
-                    )
-                    for n in names_c
-                ),
-                tuple(
-                    _aligned_codes(
-                        self.prod, n, self.prod.table.columns[n].storage, self.qk_prod
-                    )
-                    for n in names_p
-                ),
-            )
-        elif direct:
-            # padded stream: direct probes (no speculative gathers)
-            lo_c, lo_p, valid = _fused_direct_probe(
-                self.cust._lanes_for(self.qk_cust, "direct_cum"),
-                self.prod._lanes_for(self.qk_prod, "direct_cum"),
-                self.qk_cust,
-                self.qk_prod,
-            )
-        else:
-            lo_c, lo_p, valid = self.step()
-        if not unpadded:
-            n_valid = -1
-        elif direct:
-            n_valid = int(n_dev)  # the one scalar sync
-        else:
-            n_valid = int(jnp.sum(valid))  # scalar sync
-        if n_valid == self.n_orders:
-            # every stream row matched (the referential-integrity common
-            # case): no compaction — build attributes were gathered by
-            # the fused kernel (direct) or gather here; stream columns
-            # pass through untouched
-            if not direct:
-                ones = jnp.ones(self.n_orders, dtype=bool)
-                g_c = gather_columns(
-                    lo_c, ones, *(self.cust.table.columns[n].storage for n in names_c)
-                )
-                g_p = gather_columns(
-                    lo_p, ones, *(self.prod.table.columns[n].storage for n in names_p)
-                )
-            g_o = tuple(self.orders_cols[n].storage for n in names_o)
-            n_out = self.n_orders
-        else:
-            # compaction path (unmatched rows or padded/sharded stream):
-            # device mask -> compacted selection (only its SIZE syncs to
-            # host), then device gathers; sharded probe results are
-            # resharded device-to-device onto each build side's device,
-            # so no row data ever round-trips through host numpy
-            sel = jnp.flatnonzero(valid)
-            ids_c = jnp.take(lo_c, sel, axis=0)
-            ids_p = jnp.take(lo_p, sel, axis=0)
-            dev_c = self.cust.table.device
-            dev_p = self.prod.table.device
-            ids_c = jax.device_put(ids_c, dev_c)
-            ids_p = jax.device_put(ids_p, dev_p)
-            g_c = tuple(
-                jnp.take(self.cust.table.columns[n].storage, ids_c, axis=0)
-                for n in names_c
-            )
-            g_p = tuple(
-                jnp.take(self.prod.table.columns[n].storage, ids_p, axis=0)
-                for n in names_p
-            )
-            g_o = tuple(
-                jnp.take(self.orders_cols[n].storage, sel, axis=0)
-                for n in names_o
-            )
-            n_out = int(sel.shape[0])
-
-        out: Dict[str, StringColumn] = {}
-        for name, codes in zip(names_c, g_c):
-            out[name] = self.cust.table.columns[name].with_storage(codes)
-        for name, codes in zip(names_p, g_p):
-            out[name] = self.prod.table.columns[name].with_storage(codes)
-        for name, codes in zip(names_o, g_o):  # stream wins
-            out[name] = self.orders_cols[name].with_storage(codes)
-        device = next(iter(out.values())).storage.device if out else None
-        table = DeviceTable(out, n_out, device)
-        if direct and unpadded and n_valid == self.n_orders:
-            # the int(n_dev) sync above blocked on the fused executable,
-            # which produced every gathered column atomically; the pass-
-            # through stream columns are settled once (first run) below
-            if not self._orders_settled:
-                for col in self.orders_cols.values():
-                    col.storage.block_until_ready()
-                self._orders_settled = True
-            table.already_forced = True
-        return table
 
 
 def example_step_args(n_orders: int = 4096, n_cust: int = 512, n_prod: int = 64):

@@ -11,8 +11,8 @@ diagnosis (r05 warm join, r06 mesh RSS) turned out to need it:
   registered module-level kernels (:class:`RecompileWatch`);
 * :mod:`~csvplus_tpu.obs.memory` — RSS/device-memory watermark
   sampling attachable to any span, plus the bench-artifact host header;
-* :mod:`~csvplus_tpu.obs.diff` — the stage-table AND bench-record
-  regression differs behind ``python -m csvplus_tpu.obs diff``;
+* :mod:`~csvplus_tpu.obs.diff` — the stage-table regression
+  differ behind ``python -m csvplus_tpu.obs diff``;
 * :mod:`~csvplus_tpu.obs.metrics` — the production telemetry plane
   (ISSUE 13): typed metric registry, Prometheus text exposition +
   optional HTTP endpoint, the JSONL metrics pump, tail-sampled request
@@ -30,8 +30,6 @@ active in the calling context.
 """
 
 from .diff import (
-    diff_bench_files,
-    diff_bench_records,
     diff_files,
     diff_stage_tables,
     load_stage_table,
@@ -95,8 +93,6 @@ __all__ = [
     "compile_counts",
     "register_kernel",
     "registered_kernels",
-    "diff_bench_files",
-    "diff_bench_records",
     "diff_files",
     "diff_stage_tables",
     "load_stage_table",

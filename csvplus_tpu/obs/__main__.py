@@ -1,20 +1,16 @@
 """CLI for the observability subsystem.
 
-``python -m csvplus_tpu.obs diff A.json B.json [--mode auto|stages|bench]
+``python -m csvplus_tpu.obs diff A.json B.json
 [--threshold N] [--min-share 0.005] [--key stage_table] [--json]
 [--fail-on-flag]``
-    Compare two bench artifacts.  ``stages`` mode diffs embedded stage
-    tables (the r05->r06 warm-join diagnosis as a command); ``bench``
-    mode diffs ANY two same-family bench records leaf by leaf (the
-    wal/delta/serve/view families, e.g. BENCH_WAL_r11.json vs
-    BENCH_WAL_r12.json).  ``auto`` (default) tries stage tables first
-    and falls back to the bench-record diff.  ``--fail-on-flag`` exits
-    2 when anything is flagged; load/shape errors exit 1.
+    Diff the stage tables two artifacts embed (the r05->r06 warm-join
+    diagnosis as a command).  ``--fail-on-flag`` exits 2 when anything
+    is flagged; load/shape errors (no stage table among them) exit 1.
 
 ``python -m csvplus_tpu.obs skew ARTIFACT.json [--top N] [--side
 probe|build] [--json]``
     Render the heavy-hitter report from an artifact carrying sketch
-    snapshots — a flight-recorder dump, an ``obs-smoke`` record, or any
+    snapshots — a flight-recorder dump or any
     JSON embedding a ``skew`` section (``{probe: {index: snapshot},
     build: {...}}``) or a bare sketch ``snapshot()`` dict.
 """
@@ -26,53 +22,19 @@ import json
 import sys
 from typing import Any, Dict, List, Tuple
 
-from .diff import (
-    DEFAULT_BENCH_THRESHOLD,
-    DEFAULT_MIN_SHARE,
-    DEFAULT_THRESHOLD,
-    diff_bench_files,
-    diff_files,
-    format_bench_diff,
-    format_diff,
-)
+from .diff import DEFAULT_MIN_SHARE, DEFAULT_THRESHOLD, diff_files, format_diff
 from .sketch import skew_report
 
 
 def _run_diff(args) -> int:
-    result = None
-    if args.mode in ("auto", "stages"):
-        try:
-            result = diff_files(
-                args.artifact_a,
-                args.artifact_b,
-                threshold=args.threshold or DEFAULT_THRESHOLD,
-                min_share=args.min_share,
-                key=args.key,
-            )
-            label = format_diff
-        except ValueError:
-            if args.mode == "stages":
-                raise
-    if result is None:
-        result = diff_bench_files(
-            args.artifact_a,
-            args.artifact_b,
-            threshold=args.threshold or DEFAULT_BENCH_THRESHOLD,
-        )
-        label = format_bench_diff
-        if (
-            not result["rows"]
-            and result["family_a"] is None
-            and result["family_b"] is None
-        ):
-            raise ValueError(
-                "nothing comparable: no stage tables, no shared numeric"
-                " leaves, and neither artifact declares a metric family"
-            )
+    result = diff_files(
+        args.artifact_a, args.artifact_b,
+        threshold=args.threshold, min_share=args.min_share, key=args.key,
+    )
     if args.json:
         print(json.dumps(result))
     else:
-        print(label(result, args.artifact_a, args.artifact_b))
+        print(format_diff(result, args.artifact_a, args.artifact_b))
     if args.fail_on_flag and result["flagged"]:
         return 2
     return 0
@@ -133,18 +95,10 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="python -m csvplus_tpu.obs")
     sub = parser.add_subparsers(dest="cmd", required=True)
 
-    d = sub.add_parser("diff", help="diff two bench artifacts")
+    d = sub.add_parser("diff", help="diff two artifacts' stage tables")
     d.add_argument("artifact_a")
     d.add_argument("artifact_b")
-    d.add_argument(
-        "--mode", choices=("auto", "stages", "bench"), default="auto",
-        help="stage-table diff, bench-record diff, or auto-detect",
-    )
-    d.add_argument(
-        "--threshold", type=float, default=None,
-        help=f"flag ratio (default {DEFAULT_THRESHOLD} for stages,"
-             f" {DEFAULT_BENCH_THRESHOLD} for bench)",
-    )
+    d.add_argument("--threshold", type=float, default=DEFAULT_THRESHOLD)
     d.add_argument("--min-share", type=float, default=DEFAULT_MIN_SHARE)
     d.add_argument("--key", default=None, help="artifact key holding the table")
     d.add_argument("--json", action="store_true", help="machine output")

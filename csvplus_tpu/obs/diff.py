@@ -3,7 +3,7 @@
 Productizes the r05 -> r06 diagnosis workflow: the warm-join regression
 was found by comparing two runs' per-stage tables by hand and noticing
 ``join:translate`` / ``join:pack`` had grown from noise to dominant.
-This module does that comparison mechanically over two bench artifacts:
+This module does that comparison mechanically over two artifacts:
 
 * a stage's **time share** (its seconds over the table's total) and its
   **per-row time** (seconds over rows) are both computed per side — the
@@ -205,150 +205,3 @@ def diff_files(
         threshold=threshold,
         min_share=min_share,
     )
-
-
-# -- bench-record mode (ISSUE 13 satellite) --------------------------------
-#
-# The mesh artifact is the only family carrying a stage table; the
-# wal/delta/serve/view bench records are nested dicts of scalar
-# measurements (rows_per_sec, p99_ms, fsyncs...).  ``diff_bench_records``
-# mechanizes regression triage for THOSE: flatten both records to dotted
-# numeric leaves, ratio every shared leaf, flag symmetric movement
-# beyond the threshold.  Direction is reported, not judged — whether
-# "higher" is a regression depends on the metric (rows/s vs p99_ms), so
-# each flagged row says which side is higher and the reader applies the
-# sign.
-
-#: Flattened-path substrings excluded from the bench diff: host-shape
-#: facts and identifiers, not measurements.
-BENCH_DIFF_SKIP = (
-    "host_cpus",
-    "jax_device_count",
-    "schema_version",
-)
-
-DEFAULT_BENCH_THRESHOLD = 1.5
-
-
-def flatten_numeric(obj: Any, prefix: str = "") -> Dict[str, float]:
-    """Dotted-path -> value map of every numeric leaf (bools excluded;
-    list elements indexed)."""
-    out: Dict[str, float] = {}
-    if isinstance(obj, bool):
-        return out
-    if isinstance(obj, (int, float)):
-        out[prefix or "value"] = float(obj)
-        return out
-    if isinstance(obj, dict):
-        for k, v in obj.items():
-            p = f"{prefix}.{k}" if prefix else str(k)
-            out.update(flatten_numeric(v, p))
-    elif isinstance(obj, list):
-        for i, v in enumerate(obj):
-            out.update(flatten_numeric(v, f"{prefix}[{i}]"))
-    return out
-
-
-def diff_bench_records(
-    rec_a: Dict[str, Any],
-    rec_b: Dict[str, Any],
-    *,
-    threshold: float = DEFAULT_BENCH_THRESHOLD,
-) -> Dict[str, Any]:
-    """Compare two same-family bench records leaf by leaf.  Returns a
-    JSON-safe dict: per-metric ``rows`` (a, b, ratio b/a, symmetric
-    movement, flagged, higher side), ``flagged`` sorted worst first,
-    one-sided metric lists, and a family note when the records' top
-    ``metric`` keys disagree."""
-    fam_a, fam_b = rec_a.get("metric"), rec_b.get("metric")
-    fa = {
-        k: v for k, v in flatten_numeric(rec_a).items()
-        if not any(s in k for s in BENCH_DIFF_SKIP)
-    }
-    fb = {
-        k: v for k, v in flatten_numeric(rec_b).items()
-        if not any(s in k for s in BENCH_DIFF_SKIP)
-    }
-    rows: List[Dict[str, Any]] = []
-    flagged: List[Dict[str, Any]] = []
-    for metric in [k for k in fa if k in fb]:
-        a, b = fa[metric], fb[metric]
-        ratio = _ratio(b, a)  # b over a: >1 = grew in B
-        movement = max(ratio, 1.0 / ratio) if ratio else 1.0
-        flag = ratio is not None and movement >= threshold
-        row = {
-            "metric": metric,
-            "a": a,
-            "b": b,
-            "ratio": None if ratio is None else round(ratio, 4),
-            "movement": round(movement, 2),
-            "flagged": flag,
-            "higher_in": (
-                None if ratio is None or ratio == 1.0
-                else ("B" if ratio > 1.0 else "A")
-            ),
-        }
-        rows.append(row)
-        if flag:
-            flagged.append(row)
-    flagged.sort(key=lambda r: -r["movement"])
-    return {
-        "mode": "bench",
-        "family_a": fam_a,
-        "family_b": fam_b,
-        "family_match": (fam_a == fam_b) if (fam_a and fam_b) else None,
-        "threshold": threshold,
-        "rows": rows,
-        "flagged": flagged,
-        "only_in_a": [k for k in fa if k not in fb],
-        "only_in_b": [k for k in fb if k not in fa],
-    }
-
-
-def format_bench_diff(
-    result: Dict[str, Any], label_a: str, label_b: str
-) -> str:
-    """Human-readable bench-record report (flagged rows only, plus
-    one-sided metrics — a full leaf table would be hundreds of lines)."""
-    lines = [
-        f"bench diff: A={label_a}  B={label_b}",
-        f"family A={result['family_a']!r} B={result['family_b']!r}"
-        + ("" if result["family_match"] in (True, None)
-           else "  (FAMILY MISMATCH)"),
-        f"threshold {result['threshold']}x over"
-        f" {len(result['rows'])} shared metrics",
-    ]
-    if result["flagged"]:
-        lines.append("")
-        lines.append(
-            f"{'metric':<48} {'A':>12} {'B':>12} {'move':>6}  higher"
-        )
-        for r in result["flagged"]:
-            lines.append(
-                f"{r['metric']:<48} {r['a']:>12.4g} {r['b']:>12.4g}"
-                f" {r['movement']:>5.2f}x  {r['higher_in']}"
-            )
-    else:
-        lines.append("flagged: none")
-    for side in ("a", "b"):
-        only = result[f"only_in_{side}"]
-        if only:
-            shown = ", ".join(only[:8]) + (" ..." if len(only) > 8 else "")
-            lines.append(f"only in {side.upper()}: {shown}")
-    return "\n".join(lines)
-
-
-def diff_bench_files(
-    path_a: str,
-    path_b: str,
-    *,
-    threshold: float = DEFAULT_BENCH_THRESHOLD,
-) -> Dict[str, Any]:
-    """Load two bench artifacts and diff their numeric leaves."""
-    with open(path_a) as f:
-        rec_a = json.load(f)
-    with open(path_b) as f:
-        rec_b = json.load(f)
-    if not isinstance(rec_a, dict) or not isinstance(rec_b, dict):
-        raise ValueError("bench diff needs dict-shaped artifacts")
-    return diff_bench_records(rec_a, rec_b, threshold=threshold)

@@ -5,7 +5,10 @@ SelectColumns, Filter, Like, Map, ToCsvFile, UniqueIndexOn, IndexOn,
 Find, Join, ResolveDuplicates) — pin that every one exists and behaves.
 """
 
+import glob
 import io
+import os
+import re
 
 import pytest
 
@@ -143,3 +146,46 @@ def test_stream_backed_on_device():
     dev = csvplus.from_reader("a,b\nx,1\ny,2\n").on_device("cpu")
     assert dev.plan is not None
     assert dev.to_rows() == rows
+
+
+# -- the checkout's top level ------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_top_level_holds_one_harness_and_no_records():
+    """Speed is measured by `benchmark/` on the chip and recorded in
+    PERF_LEDGER.jsonl: no second bench script, floor or per-round record
+    grows back beside it.  Lists the directory (a copy may carry no
+    `.git`)."""
+    top = os.listdir(REPO)
+    scripts = {n for n in top if n.endswith(".py")}
+    assert scripts == {"chip_smoke.py", "chaos.py", "__graft_entry__.py"}
+    records = [
+        n for n in top
+        if re.search(r"_r[0-9]+\.json$", n) or n.endswith("_floor.json")
+    ]
+    assert records == []
+
+
+def test_documents_name_only_targets_and_scripts_that_exist():
+    """Every `make <target>` and every `python <script>.py` that
+    README.md or docs/*.md names exists."""
+    with open(os.path.join(REPO, "Makefile")) as f:
+        targets = set(re.findall(r"^([a-z][a-z-]*):", f.read(), re.M))
+    docs = [os.path.join(REPO, "README.md")] + sorted(
+        glob.glob(os.path.join(REPO, "docs", "*.md"))
+    )
+    named_targets, named_scripts = set(), set()
+    for path in docs:
+        with open(path) as f:
+            text = f.read()
+        # a code span `make x`, or a command line of a code block
+        named_targets.update(re.findall(r"`make\s+([a-z][a-z-]*)`", text))
+        named_targets.update(re.findall(r"^\s*make\s+([a-z][a-z-]*)\s*$", text, re.M))
+        named_scripts.update(re.findall(r"python3? ((?:[\w.-]+/)*[\w-]+\.py)", text))
+    assert {"check", "chaos", "lint"} <= named_targets  # the patterns still bite
+    assert named_targets - targets == set()
+    assert "chip_smoke.py" in named_scripts and "benchmark/run.py" in named_scripts
+    missing = {s for s in named_scripts if not os.path.exists(os.path.join(REPO, s))}
+    assert missing == set()

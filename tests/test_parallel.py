@@ -1,5 +1,5 @@
 """Sharded execution layer (M4): mesh sharding, broadcast + partitioned
-all-to-all probes, and the fused flagship 3-way join — differential vs
+all-to-all probes, and the flagship 3-way join — differential vs
 host oracle, on the virtual 8-device CPU mesh."""
 
 import numpy as np
@@ -182,10 +182,7 @@ def test_broadcast_probe_sharded(mesh):
 
 
 def test_flagship_threeway_matches_host(people_csv, stock_csv, orders_csv):
-    """The fused flagship step reproduces the generic host 3-way join."""
-    from csvplus_tpu.columnar.exec import execute_plan
-    from csvplus_tpu.models.flagship import ThreewayJoin
-
+    """The flagship join on the device reproduces the host 3-way join."""
     host_rows = (
         Take(from_file(orders_csv).select_columns("cust_id", "prod_id", "qty", "ts"))
         .join(
@@ -214,14 +211,14 @@ def test_flagship_threeway_matches_host(people_csv, stock_csv, orders_csv):
         .select_columns("prod_id", "product", "price")
         .unique_index_on("prod_id")
     )
-    orders = execute_plan(
+    dev_rows = (
         from_file(orders_csv)
         .on_device("cpu")
         .select_columns("cust_id", "prod_id", "qty", "ts")
-        .plan
+        .join(cust, "cust_id")
+        .join(prod, "prod_id")
+        .to_rows()
     )
-    tw = ThreewayJoin.build(orders, cust.device_table, prod.device_table)
-    dev_rows = tw.run().to_rows()
     assert dev_rows == host_rows
 
 
@@ -235,6 +232,33 @@ def test_dryrun_multichip_runs():
     fn, args = __graft_entry__.entry()
     out = jax.jit(fn)(*args)
     assert len(out) == 3
+
+
+def test_threeway_step_matches_numpy_searchsorted(corpus):
+    """The exported probe step on the people/stock/orders fixture: the
+    positions and validity mask a numpy ``searchsorted`` over the same
+    keys gives, with build keys missing and the -1 miss code present."""
+    from csvplus_tpu.models.flagship import threeway_step
+
+    people = np.arange(len(corpus["people"]), dtype=np.int32)
+    cust_keys = people[people % 7 != 0]  # every seventh customer absent
+    prod_keys = np.arange(len(corpus["stock"]), dtype=np.int32)
+    qk_c = np.array([o.cust_id for o in corpus["orders"]], dtype=np.int32)
+    qk_p = np.array([o.prod_id for o in corpus["orders"]], dtype=np.int32)
+    qk_p[::11] = -1  # untranslatable probe keys
+
+    lo_c, lo_p, valid = map(np.asarray, threeway_step(cust_keys, prod_keys, qk_c, qk_p))
+
+    def oracle(keys, qk):
+        lo = np.minimum(np.searchsorted(keys, qk, side="left"), len(keys) - 1)
+        return lo, (keys[lo] == qk) & (qk >= 0)
+
+    olo_c, hit_c = oracle(cust_keys, qk_c)
+    olo_p, hit_p = oracle(prod_keys, qk_p)
+    assert (lo_c == olo_c).all() and (lo_p == olo_p).all()
+    assert (valid == (hit_c & hit_p)).all()
+    assert 0 < valid.sum() < len(valid) and not hit_c.all() and not hit_p.all()
+    assert lo_c.dtype == lo_p.dtype == np.int32
 
 
 def test_two_d_mesh_pipeline_parity(people_csv, orders_csv):
@@ -569,13 +593,11 @@ def test_partitioned_probe_hot_key_short_circuit(mesh, monkeypatch):
 
 
 def test_flagship_partial_matches(people_csv, stock_csv):
-    """Flagship run() with unmatched stream keys compacts exactly like
-    the host join (the non-all-valid path)."""
+    """The flagship join with unmatched stream keys compacts exactly
+    like the host join (the non-all-valid path)."""
     from csvplus_tpu import Row, Take, TakeRows, from_file
-    from csvplus_tpu.columnar.exec import execute_plan
     from csvplus_tpu.columnar.ingest import source_from_table
     from csvplus_tpu.columnar.table import DeviceTable
-    from csvplus_tpu.models.flagship import ThreewayJoin
 
     orders_rows = [
         Row({"cust_id": "5", "prod_id": "1", "qty": "2"}),
@@ -593,19 +615,17 @@ def test_flagship_partial_matches(people_csv, stock_csv):
     cust.on_device("cpu")
     prod.on_device("cpu")
     orders_t = DeviceTable.from_rows(orders_rows, device="cpu")
-    tw = ThreewayJoin.build(orders_t, cust.device_table, prod.device_table)
-    assert tw.run().to_rows() == host
+    dev = source_from_table(orders_t).join(cust, "cust_id").join(prod, "prod_id")
+    assert dev.to_rows() == host
     assert len(host) == 2
 
 
 def test_flagship_padded_sharded_stream(people_csv, stock_csv, mesh):
-    """Flagship run() on a mesh-sharded (padded) orders table takes the
-    compaction path and stays exact (review regression)."""
+    """The flagship join over a mesh-sharded (padded) orders table
+    stays exact: no pad row joins (review regression)."""
     from csvplus_tpu import Row, Take, TakeRows, from_file
+    from csvplus_tpu.columnar.ingest import source_from_table
     from csvplus_tpu.columnar.table import DeviceTable
-    from csvplus_tpu.models.flagship import ThreewayJoin
-    from csvplus_tpu.ops.join import DeviceIndex
-    from csvplus_tpu.ops.sort import sort_table
 
     orders_rows = [
         Row({"cust_id": str(i % 120), "prod_id": str(i % 8), "qty": str(i)})
@@ -621,8 +641,8 @@ def test_flagship_padded_sharded_stream(people_csv, stock_csv, mesh):
     cust.on_device("cpu")
     prod.on_device("cpu")
     orders_t = DeviceTable.from_rows(orders_rows, device="cpu").with_sharding(mesh)
-    tw = ThreewayJoin.build(orders_t, cust.device_table, prod.device_table)
-    assert tw.run().to_rows() == host and len(host) == 6
+    dev = source_from_table(orders_t).join(cust, "cust_id").join(prod, "prod_id")
+    assert dev.to_rows() == host and len(host) == 6
 
 
 def test_partitioned_executor_join_randomized(monkeypatch, mesh):

@@ -494,6 +494,57 @@ def test_probe_fuse_empty_fact_and_zero_selection():
         _bitwise_equal(staged, fused)
 
 
+def _scrape_family_totals(prefix):
+    """{family: sum over its series} of a rendered metrics scrape."""
+    from csvplus_tpu.obs.metrics import TelemetryPlane
+
+    totals = {}
+    for line in TelemetryPlane().registry.render().splitlines():
+        if line.startswith(prefix):
+            series, value = line.rsplit(" ", 1)
+            family = series.split("{", 1)[0]
+            totals[family] = totals.get(family, 0.0) + float(value)
+    return totals
+
+
+@pytest.mark.parametrize(
+    "prefix, families, stat, build_plan",
+    [
+        (
+            "csvplus_join_multiway_",
+            ("total", "rows_in_total", "rows_out_total",
+             "intermediate_rows_avoided_total"),
+            "fused",
+            lambda: P.Join(
+                P.Join(P.Scan(_fact()), _dim(), ("id",)), _cat_dim(), ("cat",)
+            ),
+        ),
+        (
+            "csvplus_plan_fusion_",
+            ("total", "rows_full_total", "rows_selected_total",
+             "rows_out_total"),
+            "fused_chains",
+            lambda: _fused_shape(_fact()),
+        ),
+    ],
+    ids=["multiway", "fusion"],
+)
+def test_fused_execution_counters_ride_a_scrape(
+    prefix, families, stat, build_plan
+):
+    """One fused execution through the serving cache lands in the
+    process-global counters, and a metrics scrape carries the whole
+    family: executions up by one, fact rows in up by the stream."""
+    before = _scrape_family_totals(prefix)
+    cache = PlanCache(size=8)
+    cache.execute(build_plan())
+    assert cache.stats()[stat] == 1
+    after = _scrape_family_totals(prefix)
+    assert set(after) >= {prefix + f for f in families}
+    assert after[prefix + families[0]] == before.get(prefix + families[0], 0) + 1
+    assert after[prefix + families[1]] == before.get(prefix + families[1], 0) + N
+
+
 def test_probe_fuse_opaque_predicate_refused():
     """An opaque predicate (no static column footprint) bounds the
     absorbable run: the rewriter refuses with a typed probe-fuse

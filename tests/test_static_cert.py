@@ -215,6 +215,35 @@ def test_env_registry_and_docs_in_sync():
     assert env_global_findings() == []
 
 
+def test_every_env_name_in_code_is_registered():
+    """Beside the lint of the package's reads: every ``CSVPLUS_*`` name
+    a ``*.py`` at the root, under ``csvplus_tpu/`` or under
+    ``examples/`` mentions is in the registry, but for the chaos
+    driver's own two."""
+    import glob
+    import os
+    import re
+
+    from csvplus_tpu.utils.env import ENV_REGISTRY
+
+    chaos_only = {"CSVPLUS_CHAOS_CASE_TIMEOUT", "CSVPLUS_WAL_CHILD_TEAR"}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = glob.glob(os.path.join(repo, "*.py"))
+    for sub in ("csvplus_tpu", "examples"):
+        files += glob.glob(os.path.join(repo, sub, "**", "*.py"), recursive=True)
+    named = {}
+    for path in files:
+        with open(path) as f:
+            for name in re.findall(r"CSVPLUS_[A-Z0-9_]*[A-Z0-9]", f.read()):
+                named.setdefault(name, os.path.relpath(path, repo))
+    assert len(files) > 80 and chaos_only <= set(named)
+    unregistered = {
+        n: p for n, p in named.items()
+        if n not in ENV_REGISTRY and n not in chaos_only
+    }
+    assert unregistered == {}
+
+
 # -- plan-space certifier ----------------------------------------------
 
 

@@ -1,7 +1,7 @@
 """Telemetry plane (ISSUE 13): metric registry + Prometheus
 exposition, always-on tail sampling, the crash flight recorder,
 Space-Saving key-skew sketches, the JSONL metrics pump, the pinned
-``ServingMetrics.snapshot`` schema, and the bench-record diff mode.
+``ServingMetrics.snapshot`` schema, and the obs CLI.
 
 The serving-tier integration tests drive a real :class:`LookupServer`
 (the plane is always on — every server owns one) and assert on the
@@ -11,7 +11,6 @@ contract an operator's dashboard consumes.
 
 import json
 import os
-import sys
 import urllib.request
 
 import numpy as np
@@ -20,12 +19,6 @@ import pytest
 import csvplus_tpu as cp
 from csvplus_tpu.columnar.table import DeviceTable
 from csvplus_tpu.obs.__main__ import main as obs_main
-from csvplus_tpu.obs.diff import (
-    diff_bench_files,
-    diff_bench_records,
-    flatten_numeric,
-    format_bench_diff,
-)
 from csvplus_tpu.obs.flight import DUMP_SCHEMA_VERSION, FlightRecorder
 from csvplus_tpu.obs.metrics import (
     Histogram,
@@ -41,11 +34,16 @@ from csvplus_tpu.obs.sketch import SpaceSaving, skew_report
 from csvplus_tpu.serve import LookupServer
 from csvplus_tpu.serve.metrics import SNAPSHOT_SCHEMA_VERSION
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
 
-from bench import zipf_probe_values  # noqa: E402
+def zipf_probe_values(ids, n_probes: int, *, s: float = 1.1, seed: int = 0):
+    """Deterministic Zipf(s)-skewed draws from ``ids`` (an int array):
+    rank-k of ``ids`` (in array order) is drawn with weight 1/k^s, so a
+    handful of keys absorb most of the traffic."""
+    ranks = np.arange(1, len(ids) + 1, dtype=np.float64)
+    weights = ranks ** -float(s)
+    weights /= weights.sum()
+    rng = np.random.default_rng(seed)
+    return rng.choice(np.asarray(ids), size=n_probes, p=weights)
 
 
 def _index(n=64):
@@ -402,85 +400,20 @@ def test_snapshot_schema_version_and_pinned_cell_keys():
                 assert f"{prefix}_{key}" in rendered
 
 
-# -- bench-record diff (satellite 1) ----------------------------------------
-
-
-def test_diff_bench_wal_r11_vs_r12():
-    result = diff_bench_files(
-        os.path.join(REPO, "BENCH_WAL_r11.json"),
-        os.path.join(REPO, "BENCH_WAL_r12.json"),
-    )
-    assert result["mode"] == "bench"
-    assert result["family_a"] == result["family_b"]
-    assert result["family_match"] is True
-    assert result["rows"], "same-family artifacts must share leaves"
-    by_metric = {r["metric"]: r for r in result["rows"]}
-    assert "value" in by_metric  # the headline wal append rows/s leaf
-    for row in result["rows"]:
-        if row["ratio"] is not None:
-            # ratios are rounded to 4 decimals in the artifact
-            assert row["ratio"] == pytest.approx(
-                row["b"] / row["a"], abs=5.1e-5
-            )
-    for row in result["flagged"]:
-        assert row["movement"] >= result["threshold"]
-    text = format_bench_diff(result, "r11", "r12")
-    assert "r11" in text and "r12" in text
-
-
-def test_diff_bench_flags_and_orders_regressions():
-    a = {"metric": "m", "value": 100.0, "sub": {"x_ms": 10.0, "y_ms": 5.0}}
-    b = {"metric": "m", "value": 100.0, "sub": {"x_ms": 40.0, "y_ms": 5.5}}
-    result = diff_bench_records(a, b, threshold=1.5)
-    flagged = result["flagged"]
-    assert [r["metric"] for r in flagged] == ["sub.x_ms"]
-    assert flagged[0]["ratio"] == pytest.approx(4.0)
-    assert not [r for r in result["rows"]
-                if r["metric"] == "value" and r["flagged"]]
-
-
-def test_diff_bench_family_mismatch_and_disjoint_leaves():
-    a = {"metric": "fam_a", "value": 1.0, "only_a": 2.0}
-    b = {"metric": "fam_b", "value": 2.0, "only_b": 3.0}
-    result = diff_bench_records(a, b)
-    assert result["family_match"] is False
-    assert "only_a" in result["only_in_a"]
-    assert "only_b" in result["only_in_b"]
-
-
-def test_flatten_numeric_paths():
-    flat = flatten_numeric(
-        {"a": 1, "b": {"c": 2.5, "d": "skip", "e": True},
-         "f": [10, {"g": 20}]}
-    )
-    assert flat == {"a": 1, "b.c": 2.5, "f[0]": 10, "f[1].g": 20}
-
-
 # -- the obs CLI ------------------------------------------------------------
 
 
-def test_obs_cli_diff_bench_mode(capsys):
-    rc = obs_main([
-        "diff",
-        os.path.join(REPO, "BENCH_WAL_r11.json"),
-        os.path.join(REPO, "BENCH_WAL_r12.json"),
-        "--mode", "bench", "--json",
-    ])
-    assert rc == 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["mode"] == "bench" and out["family_match"] is True
-
-
-def test_obs_cli_diff_auto_falls_back_to_bench(capsys):
-    # WAL records carry no stage tables: auto mode must fall back
-    rc = obs_main([
-        "diff",
-        os.path.join(REPO, "BENCH_WAL_r11.json"),
-        os.path.join(REPO, "BENCH_WAL_r12.json"),
-        "--json",
-    ])
-    assert rc == 0
-    assert json.loads(capsys.readouterr().out)["mode"] == "bench"
+def test_obs_cli_diff_without_stage_tables_is_an_error(tmp_path, capsys):
+    """`diff` has one mode: two artifacts that carry no stage table are
+    an error (exit 1, the probed keys named), not a second format."""
+    a, b = tmp_path / "a.json", tmp_path / "b.json"
+    a.write_text(json.dumps({"metric": "m", "value": 1.0}))
+    b.write_text(json.dumps({"metric": "m", "value": 2.0}))
+    rc = obs_main(["diff", str(a), str(b), "--json"])
+    assert rc == 1
+    captured = capsys.readouterr()
+    assert captured.out == ""
+    assert "no stage table under stage_table" in captured.err
 
 
 def test_obs_cli_skew_renders_plane_snapshot(tmp_path, capsys):

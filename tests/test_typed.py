@@ -251,14 +251,12 @@ def test_typed_index_sort_find_dedup(joined_files):
 
 def test_typed_sharding_pads_never_alias_prefix_zero(tmp_path):
     """Review r5 regression: a 0-valued pad would alias a real 'c0'/'p0'
-    build key and fabricate phantom rows through the flagship padded-
-    stream compaction.  Pads must translate to -2 like string pads."""
+    build key and fabricate phantom rows through the flagship join's
+    padded stream.  Pads must translate to -2 like string pads."""
     import jax
 
+    from csvplus_tpu.columnar.ingest import source_from_table
     from csvplus_tpu.columnar.table import DeviceTable
-    from csvplus_tpu.models.flagship import ThreewayJoin
-    from csvplus_tpu.ops.join import DeviceIndex
-    from csvplus_tpu.ops.sort import sort_table
     from csvplus_tpu.parallel.mesh import make_mesh
 
     if len(jax.devices()) < 2:
@@ -275,14 +273,14 @@ def test_typed_sharding_pads_never_alias_prefix_zero(tmp_path):
         {"id": ["c0", "c1", "c2"], "name": ["n0", "n1", "n2"]}
     )
     prod = DeviceTable.from_pylists({"prod_id": ["p0", "p1"], "product": ["a", "b"]})
-    tw = ThreewayJoin.build(
-        sharded,
-        DeviceIndex.build(sort_table(cust, ["id"]), ["id"]),
-        DeviceIndex.build(sort_table(prod, ["prod_id"]), ["prod_id"]),
+    out = (
+        source_from_table(sharded)
+        .join(source_from_table(cust).unique_index_on("id"), "cust_id")
+        .join(source_from_table(prod).unique_index_on("prod_id"), "prod_id")
+        .to_rows()
     )
-    out = tw.run()
-    assert out.nrows == 3, f"phantom pad rows joined: {out.to_rows()}"
-    got = sorted(r["order_id"] for r in out.to_rows())
+    assert len(out) == 3, f"phantom pad rows joined: {out}"
+    got = sorted(r["order_id"] for r in out)
     assert got == ["o1", "o2", "o3"]
     # demotion of a padded typed column must not invent a 'c<PAD>' entry
     col = sharded.columns["cust_id"]
