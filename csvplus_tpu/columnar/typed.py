@@ -252,26 +252,41 @@ class IntColumn:
     def decode_codes(self, codes: np.ndarray) -> List[Optional[str]]:
         return self._demote().decode_codes(codes)
 
-    # dense translation tables are built when the build-side value range
-    # is at most this multiple of its distinct count (and > 0 entries):
-    # one O(range) int32 array turns the per-row translation into a
-    # single gather instead of a ~log2(U)-round searchsorted
+    # A dense translation table (``table[value - lo] = code``) turns the
+    # per-row translation into ONE gather where the sorted pair costs a
+    # ~log2(U)-round searchsorted and two more.  It is admitted where it
+    # is small (the range at most this multiple of the distinct count,
+    # under 2**24 slots), or, whatever its size, where it places no more
+    # bytes than the pair it replaces.
     DENSE_RANGE_FACTOR = 16
-    DENSE_RANGE_MAX = 1 << 24  # 64MB of int32 at the cap
+
+    @staticmethod
+    def _dense_admitted(size: int, lo: int, hi: int) -> bool:
+        """Does a build side of *size* distinct values spanning
+        ``[lo, hi]`` get the dense table?  A pure function of the three
+        (no array is read), so a 20M-key side can be asked for free."""
+        if size <= 0:
+            return False
+        rng = hi - lo + 1
+        small = rng <= (1 << 24) and rng <= max(
+            size * IntColumn.DENSE_RANGE_FACTOR, 1024
+        )
+        # one int32 table of rng entries against two of size: never
+        # larger than the sorted pair; the slot ``value - lo`` is int32
+        # on the device and in the host scatter below
+        no_larger = rng <= 2 * size and rng < 2**31
+        return small or no_larger
 
     @staticmethod
     def _build_translation(vals: np.ndarray, cand: np.ndarray):
         """Device translation state from (values, codes) of the build
-        side: ('dense', base, table) when the value range is compact,
-        else ('sorted', sorted_vals, code_of)."""
+        side: ('dense', base, table) where :meth:`_dense_admitted` says
+        so, else ('sorted', sorted_vals, code_of)."""
         if vals.size == 0:
             return ("sorted", jax.device_put(vals), jax.device_put(cand))
         lo, hi = int(vals.min()), int(vals.max())
-        rng = hi - lo + 1
-        if rng <= IntColumn.DENSE_RANGE_MAX and rng <= max(
-            vals.size * IntColumn.DENSE_RANGE_FACTOR, 1024
-        ):
-            table = np.full(rng, -1, dtype=np.int32)
+        if IntColumn._dense_admitted(vals.size, lo, hi):
+            table = np.full(hi - lo + 1, -1, dtype=np.int32)
             table[vals - lo] = cand
             return ("dense", lo, jax.device_put(table))
         order = np.argsort(vals, kind="stable")

@@ -270,15 +270,17 @@ def _searchsorted_rounds(n: int) -> int:
     return max(int(n).bit_length(), 1)
 
 
-def _translation_walks(pc, ic) -> int:
-    """Full-length gathers of one staged ``pc.renumbered_to_col(ic)``."""
+def _translation_walks(pc, ic) -> Tuple[int, str]:
+    """(full-length gathers of one staged ``pc.renumbered_to_col(ic)``,
+    the tier that runs them: ``codes`` for a string column, else the
+    typed state's ``dense`` | ``sorted``)."""
     if pc.kind != "int":
-        return 1 if pc.dict_size else 0
+        return (1 if pc.dict_size else 0), "codes"
     kind, _, table = pc.translation_state_to(ic)  # cached on ic
     if kind == "dense":
-        return 1
+        return 1, kind
     n = int(table.shape[0])
-    return _searchsorted_rounds(n) + 2 if n else 0
+    return (_searchsorted_rounds(n) + 2 if n else 0), kind
 
 
 def _universe_slot(storage, base, size):
@@ -722,14 +724,18 @@ class DeviceIndex:
 
     def _translated(self, probe_cols: List[StringColumn], n_key_cols: int):
         """(per-column probe codes translated into the build
-        dictionaries, the full-length gathers that took)."""
+        dictionaries, the full-length gathers that took, the tier of
+        each column's translation joined by ``,``)."""
         out = []
         walks = 0
+        tiers = []
         for pc, ic_name in zip(probe_cols, self.key_columns[:n_key_cols]):
             ic = self.table.columns[ic_name]
             out.append(pc.renumbered_to_col(ic))
-            walks += _translation_walks(pc, ic)
-        return out, walks
+            n, tier = _translation_walks(pc, ic)
+            walks += n
+            tiers.append(tier)
+        return out, walks, ",".join(tiers)
 
     def _composed_for(self, pc, nrows: int) -> "Optional[_Composed]":
         """The composed tables for probing this index by the one column
@@ -892,7 +898,7 @@ class DeviceIndex:
                 telemetry.barrier(ans)
             return ans
         with telemetry.stage("join:translate", nrows) as out:
-            codes, out["row_gathers"] = self._translated(probe_cols, k)
+            codes, out["row_gathers"], out["tier"] = self._translated(probe_cols, k)
             telemetry.barrier(codes)
         range_shift = self.shifts[k - 1] if k else 0
 

@@ -183,8 +183,8 @@ def test_translation_tables_are_placed_on_the_probe_mesh_once(kind, tmp_path, mo
     benchmark's size)."""
     from csvplus_tpu.columnar.typed import IntColumn
 
-    if kind == "sorted":
-        monkeypatch.setattr(IntColumn, "DENSE_RANGE_MAX", 1)
+    if kind == "sorted":  # 40,000 ids over 40,000 slots are dense by every rule: refuse the table
+        monkeypatch.setattr(IntColumn, "_dense_admitted", staticmethod(lambda size, lo, hi: False))
     corpus = Corpus(7, tmp_path)
     build_id = FromFile(corpus.people).OnDevice().plan.table.columns["id"]
     probes = {

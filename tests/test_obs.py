@@ -990,3 +990,46 @@ def test_partitioned_join_says_how_the_owner_answered(index_keys, monkeypatch):
         assert part["span_max"] > 2**10 and part["positional"] is False
         assert exchange["owner_tier"] == "search"
         assert exchange["search_rounds"] == _searchsorted_rounds(per_shard) == 8
+
+
+@pytest.mark.parametrize(
+    "case, tier, walks",
+    [
+        ("dense", "dense", 1),  # ids 0..49: one table read by position
+        ("sorted", "sorted", 8),  # 40 ids 5,000 apart: bit_length(40) + 2 rounds
+        ("codes", "codes", 1),  # a string probe column: its code translation
+        ("two-columns", "dense,codes", 2),  # one name per key column, in key order
+    ],
+)
+def test_translate_stage_says_which_tier_ran(case, tier, walks):
+    """``join:translate`` names the table each probe column went through
+    beside the ``row_gathers`` that took (``owner_tier`` on
+    ``join:all_to_all`` is its like): a typed column's ``dense`` |
+    ``sorted`` state, ``codes`` for a string column.  Streams short
+    enough that nothing composes, so the staged stage runs."""
+    import jax.numpy as jnp
+
+    from csvplus_tpu.columnar.table import StringColumn
+    from csvplus_tpu.columnar.typed import IntColumn
+    from csvplus_tpu.ops.join import DeviceIndex
+    from csvplus_tpu.ops.sort import sort_table
+
+    ids = [i * 5000 for i in range(40)] if case == "sorted" else list(range(50))
+    keys = ["id", "name"] if case == "two-columns" else ["id"]
+    people = DeviceTable.from_pylists(
+        {"id": [f"c{i}" for i in ids], "name": [f"n{i % 7}" for i in ids]}, device="cpu"
+    )
+    di = DeviceIndex.build(sort_table(people, keys), keys)
+    n = 60
+    picks = np.random.default_rng(32).choice(ids, n)
+    if case == "codes":
+        probe = [StringColumn.from_values([f"c{v}" for v in picks], None)]
+    else:
+        probe = [IntColumn(b"c", jnp.asarray(picks.astype(np.int32)))]
+    if case == "two-columns":
+        probe.append(StringColumn.from_values([f"n{v % 7}" for v in picks], None))
+    with telemetry.collect() as recs:
+        _, counts = di.probe(probe, n)
+    (translate,) = [r.extra for r in recs if r.stage == "join:translate"]
+    assert (translate["tier"], translate["row_gathers"]) == (tier, walks)
+    assert np.asarray(counts).tolist() == [1] * n
