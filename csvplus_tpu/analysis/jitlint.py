@@ -103,7 +103,7 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "host_sync_elements steady-state transfer guard by design"
     ),
     # -- ops -----------------------------------------------------------
-    "join.py:build": (
+    "join.py:_build": (
         "deliberate one-time host int64 key mirror at index BUILD "
         "(serves point_bounds and the partitioned-path preparation); "
         "the probe path does no transfer"
@@ -170,9 +170,10 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "two O(1) syncs per index build; no transfer of key data"
     ),
     "sort.py:run_starts": (
-        "host bool run-starts mask is this helper's CONTRACT (feeds "
-        "host grouping); deliberate O(n) transfer at index-build time, "
-        "outside the host_sync_elements steady-state guard"
+        "the CALLBACK resolver's host bool run-starts mask (it groups "
+        "on the host): O(n) transfer, counted by count_sync(nrows); the "
+        "named policies never come here (compact_runs: one scalar "
+        "host read, count_sync(1))"
     ),
 }
 #: RETRACE002's allowlist, same key/citation contract as

@@ -30,10 +30,12 @@ TPU-native execution: an index built from a device-planned source is
 ``lax.sort`` over dictionary codes (:mod:`..ops.sort`), the uniqueness
 check is one adjacent-equality reduction, ``find``/``sub_index`` binary-
 search the packed key array and decode *only the matching range*, and
-``resolve_duplicates`` with a named policy ("first"/"last") compacts via
-a run-boundary mask without ever materializing host rows.  Host rows are
-decoded on demand the first time a host-only operation (arbitrary
-callback, persistence, host join) needs them.
+``resolve_duplicates`` with a named policy ("first"/"last") compacts on
+the device — run-boundary mask, prefix sum, one scatter of the kept
+positions, one gather per column — with one scalar host read (the kept
+rows' count) and nothing row-proportional crossing the host in either
+direction.  Host rows are decoded on demand the first time a host-only
+operation (arbitrary callback, persistence, host join) needs them.
 """
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ from .errors import CsvPlusError, DataSourceError
 from .obs.span import tracer
 from .row import Row, all_columns_unique, equal_rows
 from .source import DataSource, RowFunc, iterate, take_rows
+from .utils.observe import telemetry
 
 _MAGIC = "csvplus-tpu-index"
 _VERSION = 1
@@ -438,8 +441,13 @@ class Index:
           index order), equivalent to ``lambda g: g[0]``;
         * ``"last"`` — keep the last row, equivalent to ``lambda g: g[-1]``.
 
-        Named policies on a device-lazy index compact via a run-boundary
-        mask on device without materializing host rows.
+        Named policies on a device-lazy index stay on the device
+        (``ops/sort.py:compact_runs``): the run-boundary mask, the kept
+        rows' positions and every gathered column are formed there, and
+        the host reads back ONE scalar, the kept rows' count, which the
+        result's shape needs.  Stages ``dedup:runs``, ``dedup:compact``
+        (``tier: device``), ``index:pack``.  A callback groups on the
+        host from the mask read back whole (``tier: host``).
         """
         impl = self._impl
         if isinstance(resolve, str):
@@ -458,21 +466,12 @@ class Index:
 
     def _device_policy_dedup(self, policy: str) -> None:
         from .ops.join import DeviceIndex
-        from .ops.sort import run_starts
+        from .ops.sort import compact_runs
 
         impl = self._impl
-        table = impl.dev.table
-        starts = run_starts(table, impl.columns)
-        if policy == "first":
-            keep = starts
-        else:  # "last": a row is kept when the NEXT row starts a new run
-            keep = np.roll(starts, -1)
-            if keep.size:
-                keep[-1] = True
-        if keep.all():
+        new_table = compact_runs(impl.dev.table, impl.columns, policy)
+        if new_table is None:
             return  # no duplicates; nothing to do
-        sel = np.flatnonzero(keep).astype(np.int64)
-        new_table = table.gather(sel)
         impl.dev = DeviceIndex.build(new_table, impl.columns)
         impl._rows = None
         impl._invalidate()
@@ -540,8 +539,17 @@ class Index:
                 keep[s : s + l] = False
                 if d is not None:
                     keep[s + int(d)] = True
-            sel = np.flatnonzero(keep).astype(np.int64)
-            new_table = table.gather(sel)
+            with telemetry.stage("dedup:compact", table.nrows) as st:
+                sel = np.flatnonzero(keep).astype(np.int64)
+                new_table = table.gather(sel)
+                # the run mask came to the host whole (run_starts) and
+                # the selection goes back up: row-proportional both ways
+                st.update(
+                    rows=table.nrows, rows_out=int(sel.size), policy="callback",
+                    kept=int(sel.size), tier="host", row_gathers=len(new_table.columns),
+                    host_sync_elements=table.nrows,
+                )
+                telemetry.barrier([c.storage for c in new_table.columns.values()])
             impl.dev = DeviceIndex.build(new_table, impl.columns)
             impl._rows = None
             impl._invalidate()
@@ -780,27 +788,31 @@ def _create_index_device(plan, columns: Tuple[str, ...]) -> Index:
     from .ops.join import DeviceIndex
     from .ops.sort import sort_table
 
-    view = execute_plan_view(plan)
-    if view.deferred_error is not None:
-        # index build consumes every row, so the host stream always
-        # reaches the first row failing a terminal Validate
-        raise view.deferred_error[1]
-    if view.sel.shape[0] == 0:
-        # the host build validates per-row (csvplus.go:722-733), so an
-        # empty source yields an empty index without any column check
-        return Index(IndexImpl([], columns))
-    # the host build raises at the first streamed row lacking a key cell
-    # (row-major, columns in argument order within the row), numbered by
-    # the ORIGINATING source (reader record numbers / 0-based slice
-    # positions) — first_missing_cell reproduces exactly that
     from .columnar.exec import first_missing_cell
 
-    bad = first_missing_cell(view, columns)
-    if bad is not None:
-        raise DataSourceError(
-            bad[0], f'missing column "{bad[1]}" while creating an index'
-        )
-    table = view.materialize()
+    with telemetry.stage("index:view", 0) as st:
+        view = execute_plan_view(plan)
+        if view.deferred_error is not None:
+            # index build consumes every row, so the host stream always
+            # reaches the first row failing a terminal Validate
+            raise view.deferred_error[1]
+        if view.sel.shape[0] == 0:
+            # the host build validates per-row (csvplus.go:722-733), so an
+            # empty source yields an empty index without any column check
+            return Index(IndexImpl([], columns))
+        # the host build raises at the first streamed row lacking a key cell
+        # (row-major, columns in argument order within the row), numbered by
+        # the ORIGINATING source (reader record numbers / 0-based slice
+        # positions) — first_missing_cell reproduces exactly that
+        bad = first_missing_cell(view, columns)
+        if bad is not None:
+            raise DataSourceError(
+                bad[0], f'missing column "{bad[1]}" while creating an index'
+            )
+        gathers = 0 if view.identity else len(view.cols)
+        table = view.materialize()
+        st.update(rows=table.nrows, rows_out=table.nrows, row_gathers=gathers)
+        telemetry.barrier([c.storage for c in table.columns.values()])
     sorted_table = sort_table(table, list(columns))
     dev = DeviceIndex.build(sorted_table, list(columns))
     return Index(IndexImpl(None, columns, dev=dev))

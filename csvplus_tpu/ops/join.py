@@ -414,7 +414,19 @@ class DeviceIndex:
 
     @classmethod
     def build(cls, table: DeviceTable, key_columns: Sequence[str]) -> "DeviceIndex":
-        key_columns = list(key_columns)
+        with telemetry.stage("index:pack", table.nrows) as st:
+            dev = cls._build(table, list(key_columns))
+            # wide keys mirror every key code to a host int64 (_build)
+            mirrored = 0 if dev.packed_i64 is None else table.nrows * len(dev.key_columns)
+            st.update(
+                rows=table.nrows, keys=len(dev.key_columns), row_gathers=0,
+                host_sync_elements=mirrored,
+            )
+            telemetry.barrier([dev.packed_i32, dev.packed_hi, dev.packed_lo])
+        return dev
+
+    @classmethod
+    def _build(cls, table: DeviceTable, key_columns: List[str]) -> "DeviceIndex":
         cols = [table.columns[c] for c in key_columns]
         for c in cols:
             # packed keys assume code order == value order and one code

@@ -13,22 +13,33 @@ differential tests can require exact equality.
 
 A trailing iota operand rides along as the permutation, used to gather
 every non-key column once after the sort.
+
+Duplicate resolution by a named policy (:func:`compact_runs`) stays on
+the device as well: one program marks the row each equal-key run keeps
+and counts them, one ranks the kept rows by a prefix sum and scatters
+their positions, and every column is gathered by those positions.  The
+host reads ONE scalar, the count, which the result's shape needs.
+
+Stages: ``index:sort`` / ``index:permute`` (:func:`sort_table`),
+``dedup:runs`` / ``dedup:compact`` (:func:`compact_runs`).  Programs:
+``jit_csvplus.index.sort``, ``.index.adjacent_dup``, ``.dedup.runs``,
+``.dedup.compact``; the lane gathers are ``.table.gather_take``.
 """
 
 from __future__ import annotations
 
-import os
-from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..columnar.table import DeviceTable, StringColumn
+from ..columnar.table import DeviceTable
+from ..obs.recompile import register_kernel
 from ..utils.env import env_int
+from ..utils.observe import telemetry
 
 
-@partial(jax.jit, static_argnames=("num_keys",))
+@register_kernel("index.sort", static_argnames=("num_keys",))
 def _sort_kernel(operands: Tuple[jax.Array, ...], num_keys: int):
     """Stable lexicographic sort; last operand is the row permutation."""
     return jax.lax.sort(operands, num_keys=num_keys, is_stable=True)
@@ -109,33 +120,39 @@ def sort_table(table: DeviceTable, key_columns: Sequence[str]) -> DeviceTable:
             lanes = _packed_sort_lanes(key_cols)
             if lanes is not None:
                 from ..parallel.dsort import distributed_sort_device
-                from ..utils.observe import telemetry
 
-                with telemetry.stage("dsort", table.nrows):
-                    iota = jnp.arange(table.nrows, dtype=jnp.int32)
-                    _, perm = distributed_sort_device(mesh, lanes, iota)
-                out = {
-                    name: col.gather(perm) for name, col in table.columns.items()
-                }
-                return DeviceTable(out, table.nrows, table.device)
+                with telemetry.stage("index:sort", table.nrows) as st:
+                    st.update(rows=table.nrows, keys=len(key_cols), tier="dsort", row_gathers=0)
+                    with telemetry.stage("dsort", table.nrows):
+                        iota = jnp.arange(table.nrows, dtype=jnp.int32)
+                        _, perm = distributed_sort_device(mesh, lanes, iota)
+                    telemetry.barrier(perm)
+                return _permuted(table, perm, {})
 
-    iota = jnp.arange(table.nrows, dtype=jnp.int32)
-    operands = tuple(c.codes for c in key_cols) + (iota,)
-    sorted_ops = _sort_kernel(operands, num_keys=len(key_cols))
-    perm = sorted_ops[-1]
-
-    out = {}
+    with telemetry.stage("index:sort", table.nrows) as st:
+        st.update(rows=table.nrows, keys=len(key_cols), tier="lax", row_gathers=0)
+        iota = jnp.arange(table.nrows, dtype=jnp.int32)
+        operands = tuple(c.codes for c in key_cols) + (iota,)
+        sorted_ops = telemetry.barrier(_sort_kernel(operands, num_keys=len(key_cols)))
+    # key columns come out of the sort already permuted
     sorted_keys = dict(zip(key_columns, sorted_ops[: len(key_cols)]))
-    for name, col in table.columns.items():
-        if name in sorted_keys:
-            # key columns come out of the sort already permuted
-            out[name] = col.with_codes(sorted_keys[name])
-        else:
-            out[name] = col.gather(perm)
+    return _permuted(table, sorted_ops[-1], sorted_keys)
+
+
+def _permuted(table: DeviceTable, perm: jax.Array, sorted_keys: dict) -> DeviceTable:
+    """*table*'s rows in the order *perm* gives: one full-length gather
+    per column but those whose sorted codes the sort already returned."""
+    with telemetry.stage("index:permute", table.nrows) as st:
+        out = {
+            name: col.with_codes(sorted_keys[name]) if name in sorted_keys else col.gather(perm)
+            for name, col in table.columns.items()
+        }
+        st.update(rows=table.nrows, row_gathers=len(out) - len(sorted_keys))
+        telemetry.barrier([c.storage for c in out.values()])
     return DeviceTable(out, table.nrows, table.device)
 
 
-@jax.jit
+@register_kernel("index.adjacent_dup")
 def _adjacent_dup_kernel(*key_codes: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(any_dup, first_dup_index) over sorted key columns.
 
@@ -164,18 +181,68 @@ def find_adjacent_duplicate(
     return None
 
 
-@jax.jit
-def _run_starts_kernel(*key_codes: jax.Array) -> jax.Array:
-    """Boolean mask: True where row i starts a new key run (i=0 included)."""
+@register_kernel("dedup.runs", static_argnames=("policy",))
+def _keep_mask_kernel(*key_codes: jax.Array, policy: str) -> Tuple[jax.Array, jax.Array]:
+    """(keep mask, kept count) over sorted key columns: of every
+    equal-key run the first row (``policy="first"``: the row that starts
+    the run) or the last (``"last"``: the row the next run follows)."""
     n = key_codes[0].shape[0]
     neq = jnp.zeros(n - 1, dtype=bool)
     for k in key_codes:
         neq = neq | (k[1:] != k[:-1])
-    return jnp.concatenate([jnp.ones(1, dtype=bool), neq])
+    edge = jnp.ones(1, dtype=bool)
+    keep = jnp.concatenate([edge, neq] if policy == "first" else [neq, edge])
+    return keep, jnp.sum(keep, dtype=jnp.int32)
+
+
+@register_kernel("dedup.compact")
+def _kept_positions_kernel(keep: jax.Array) -> jax.Array:
+    """int32[n]: the positions of *keep*'s True cells, ascending, then
+    zeros.  A prefix sum ranks the kept rows and one scatter writes each
+    kept row's position at its rank (a dropped row scatters out of
+    bounds and is dropped)."""
+    n = keep.shape[0]
+    rank = jnp.cumsum(keep, dtype=jnp.int32)
+    dest = jnp.where(keep, rank - 1, n)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    return jnp.zeros(n, dtype=jnp.int32).at[dest].set(rows, mode="drop")
+
+
+def compact_runs(
+    table: DeviceTable, key_columns: Sequence[str], policy: str
+) -> "Optional[DeviceTable]":
+    """*table* (sorted by *key_columns*) with one row kept of every
+    equal-key run, the ``"first"`` or the ``"last"``; None when no key
+    repeats (nothing is gathered).  Every row-proportional array stays
+    on the device: the host reads one scalar, the kept rows' count,
+    which the result's shape needs (``telemetry.host_sync_elements``)."""
+    n = table.nrows
+    if n < 2:
+        return None
+    codes = tuple(table.columns[c].codes for c in key_columns)
+    with telemetry.stage("dedup:runs", n) as st:
+        keep, count = telemetry.barrier(_keep_mask_kernel(*codes, policy=policy))
+        kept = int(count)  # the one host read
+        telemetry.count_sync(1)
+        st.update(rows=n, rows_out=kept, row_gathers=0, host_sync_elements=1)
+    if kept == n:
+        return None
+    with telemetry.stage("dedup:compact", n) as st:
+        new_table = table.gather(_kept_positions_kernel(keep)[:kept])
+        st.update(
+            rows=n, rows_out=kept, policy=policy, kept=kept, tier="device",
+            row_gathers=len(new_table.columns),
+        )
+        telemetry.barrier([c.storage for c in new_table.columns.values()])
+    return new_table
 
 
 def run_starts(table: DeviceTable, key_columns: Sequence[str]):
-    """Host bool array marking the first row of each equal-key run."""
+    """Host bool array marking the first row of each equal-key run, for
+    the CALLBACK resolver, which groups and selects on the host: the
+    mask is formed on the device and read to the host whole, one bool a
+    row (counted in ``telemetry.host_sync_elements``).  The named
+    policies never come here (:func:`compact_runs`)."""
     import numpy as np
 
     if table.nrows == 0:
@@ -183,4 +250,7 @@ def run_starts(table: DeviceTable, key_columns: Sequence[str]):
     if table.nrows == 1:
         return np.ones(1, dtype=bool)
     codes = tuple(table.columns[c].codes for c in key_columns)
-    return np.asarray(_run_starts_kernel(*codes))
+    mask, _ = _keep_mask_kernel(*codes, policy="first")
+    telemetry.barrier(mask)
+    telemetry.count_sync(table.nrows)
+    return np.asarray(mask)

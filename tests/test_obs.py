@@ -880,6 +880,10 @@ def _kernel_examples():
         "table.gather_take": ((i, i), {}),
         "table.apply_code_translation": ((i, i), {}),
         "table.sync_probe": ((i, i), {}),
+        "index.sort": (((i, i, i),), {"num_keys": 2}),
+        "index.adjacent_dup": ((i, i), {}),
+        "dedup.runs": ((i, i), {"policy": "last"}),
+        "dedup.compact": ((i > 3,), {}),
     }
 
 
@@ -892,6 +896,7 @@ KERNELS_LOWERED_HERE = sorted([
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
     "typed.translate_empty", "table.gather_take", "table.apply_code_translation",
     "table.sync_probe", "join.compose_probe", "join.probe_composed", "join.probe_composed_range",
+    "index.sort", "index.adjacent_dup", "dedup.runs", "dedup.compact",
 ])
 
 
@@ -902,6 +907,7 @@ def test_registered_kernel_lowers_under_its_scope_and_program_name(name):
     operation's op_name starts under the csvplus.<name> scope."""
     import csvplus_tpu.columnar.typed  # noqa: F401 — registration side effect
     import csvplus_tpu.ops.join  # noqa: F401
+    import csvplus_tpu.ops.sort  # noqa: F401
 
     args, kwargs = _kernel_examples()[name]
     text = registered_kernels()[name].lower(*args, **kwargs).as_text(debug_info=True)
