@@ -442,12 +442,14 @@ class Index:
         * ``"last"`` — keep the last row, equivalent to ``lambda g: g[-1]``.
 
         Named policies on a device-lazy index stay on the device
-        (``ops/sort.py:compact_runs``): the run-boundary mask, the kept
-        rows' positions and every gathered column are formed there, and
-        the host reads back ONE scalar, the kept rows' count, which the
-        result's shape needs.  Stages ``dedup:runs``, ``dedup:compact``
-        (``tier: device``), ``index:pack``.  A callback groups on the
-        host from the mask read back whole (``tier: host``).
+        (``ops/sort.py:compact_runs``): the run-boundary mask is formed
+        there, one sort keyed on (its complement, the row number) moves the
+        kept rows of every column to the front (the columns ride the sort as
+        operands; nothing is gathered), and the host reads back ONE
+        scalar, the kept rows' count, which the result's shape needs.
+        Stages ``dedup:runs``, ``dedup:compact`` (``tier: device``,
+        ``form: sort``), ``index:pack``.  A callback groups on the host
+        from the mask read back whole (``tier: host``).
         """
         impl = self._impl
         if isinstance(resolve, str):

@@ -16,14 +16,18 @@ every non-key column once after the sort.
 
 Duplicate resolution by a named policy (:func:`compact_runs`) stays on
 the device as well: one program marks the row each equal-key run keeps
-and counts them, one ranks the kept rows by a prefix sum and scatters
-their positions, and every column is gathered by those positions.  The
-host reads ONE scalar, the count, which the result's shape needs.
+and counts them, and one sort keyed on (drop flag, row number) moves
+the kept rows of EVERY column to the front in their order — the lanes
+ride the sort as operands, nothing is gathered (on a v5e a sort carries
+a 50M-row int32 lane in a tenth of the time XLA's gather takes to move
+it; ``PERF.md`` §6, PR 37).  The host reads ONE scalar, the count,
+which the result's shape needs.
 
 Stages: ``index:sort`` / ``index:permute`` (:func:`sort_table`),
 ``dedup:runs`` / ``dedup:compact`` (:func:`compact_runs`).  Programs:
 ``jit_csvplus.index.sort``, ``.index.adjacent_dup``, ``.dedup.runs``,
-``.dedup.compact``; the lane gathers are ``.table.gather_take``.
+``.dedup.compact``, ``.dedup.head``; the permutation's lane gathers are
+``.table.gather_take``.
 """
 
 from __future__ import annotations
@@ -196,16 +200,23 @@ def _keep_mask_kernel(*key_codes: jax.Array, policy: str) -> Tuple[jax.Array, ja
 
 
 @register_kernel("dedup.compact")
-def _kept_positions_kernel(keep: jax.Array) -> jax.Array:
-    """int32[n]: the positions of *keep*'s True cells, ascending, then
-    zeros.  A prefix sum ranks the kept rows and one scatter writes each
-    kept row's position at its rank (a dropped row scatters out of
-    bounds and is dropped)."""
-    n = keep.shape[0]
-    rank = jnp.cumsum(keep, dtype=jnp.int32)
-    dest = jnp.where(keep, rank - 1, n)
-    rows = jnp.arange(n, dtype=jnp.int32)
-    return jnp.zeros(n, dtype=jnp.int32).at[dest].set(rows, mode="drop")
+def _compact_kernel(keep: jax.Array, lanes: Tuple[jax.Array, ...]) -> Tuple[jax.Array, ...]:
+    """*lanes* with the rows *keep* marks first, in their order, and the
+    others behind them: ONE sort, every lane an operand.  The key is the
+    row number with the drop flag in its top bit — what a stable sort on
+    the flag compares, as one uint32 with no tie (a stable ``lax.sort``
+    carries a hidden iota operand for its ties: one lane more to move,
+    twice the compile; ``PERF.md`` §6, PR 37)."""
+    n = keep.shape[0]  # a row number is an int32 everywhere: n < 2**31
+    order = jnp.arange(n, dtype=jnp.uint32) | ((~keep).astype(jnp.uint32) << 31)
+    return tuple(jax.lax.sort((order,) + tuple(lanes), num_keys=1, is_stable=False)[1:])
+
+
+@register_kernel("dedup.head", static_argnames=("kept",))
+def _head_kernel(lanes: Tuple[jax.Array, ...], kept: int) -> Tuple[jax.Array, ...]:  # analysis: allow[JIT001] retrace is per table width, not per data length
+    """The first *kept* rows of every lane.  A program of its own so
+    that the sort compiles once per row count, not per kept count."""
+    return tuple(lane[:kept] for lane in lanes)
 
 
 def compact_runs(
@@ -213,9 +224,13 @@ def compact_runs(
 ) -> "Optional[DeviceTable]":
     """*table* (sorted by *key_columns*) with one row kept of every
     equal-key run, the ``"first"`` or the ``"last"``; None when no key
-    repeats (nothing is gathered).  Every row-proportional array stays
-    on the device: the host reads one scalar, the kept rows' count,
-    which the result's shape needs (``telemetry.host_sync_elements``)."""
+    repeats (nothing is sorted).  Every column's row-indexed storage is
+    one int32 lane (dictionary codes or typed values; a dictionary is
+    not row-indexed and stays where it is), and all of them ride one
+    sort on (drop flag, row number).
+    Every row-proportional array stays on the device: the host reads one
+    scalar, the kept rows' count, which the result's shape needs
+    (``telemetry.host_sync_elements``)."""
     n = table.nrows
     if n < 2:
         return None
@@ -228,13 +243,24 @@ def compact_runs(
     if kept == n:
         return None
     with telemetry.stage("dedup:compact", n) as st:
-        new_table = table.gather(_kept_positions_kernel(keep)[:kept])
+        # a string column's (codes, settled flag) pair is ONE snapshot: a
+        # sibling may settle the shared lane dictionary meanwhile
+        states = [getattr(c, "_codes_state", (c.storage, None)) for c in table.columns.values()]
+        # the result's length IS the kept count: whatever slices the lanes
+        # lowers once per count; a slice compiles in milliseconds, and the
+        # sort, which does not, never sees the count
+        moved = _compact_kernel(keep, tuple(lane for lane, _ in states))
+        lanes = _head_kernel(moved, kept=kept)  # analysis: allow[RETRACE002]
         st.update(
             rows=n, rows_out=kept, policy=policy, kept=kept, tier="device",
-            row_gathers=len(new_table.columns),
+            row_gathers=0, form="sort", lanes=len(lanes),
         )
-        telemetry.barrier([c.storage for c in new_table.columns.values()])
-    return new_table
+        telemetry.barrier(lanes)
+    out = {
+        name: col.with_storage(lane) if flag is None else col.with_codes(lane, flag)
+        for (name, col), (_, flag), lane in zip(table.columns.items(), states, lanes)
+    }
+    return DeviceTable(out, kept, table.device)
 
 
 def run_starts(table: DeviceTable, key_columns: Sequence[str]):

@@ -38,15 +38,23 @@ def _sizes(kind: str, ids: int) -> np.ndarray:
     return 1 + np.arange(ids) % 5
 
 
-def _people(tmp_path, key="c%d", sizes="tenth", placement="scattered", ids=450, two_column=False, seed=20160914):
+def _people(
+    tmp_path, key="c%d", sizes="tenth", placement="scattered", ids=450, two_column=False, seed=20160914,
+    payload=None, row_values=None,
+):
     """(path, rows as dicts in file order, key columns): a seeded people
     file.  *placement*: ``scattered`` shuffles every row, ``adjacent``
     writes a key's copies one after another, ``far`` writes every key
-    once and then the copies, so a pair lies about half a file apart."""
+    once and then the copies, so a pair lies about half a file apart.
+    *row_values* gives the rows' key values outright; *payload* replaces
+    (name, surname) by that many columns ``p0``…, numbers and names in
+    turn, all by the row's number."""
     rng = np.random.default_rng(seed)
     count = _sizes(sizes, ids)
     values = rng.permutation(ids)  # which keys repeat
-    if placement == "far":
+    if row_values is not None:
+        row_values = np.asarray(row_values)
+    elif placement == "far":
         row_values = np.concatenate(
             [rng.permutation(values)] + [rng.permutation(values[count > k]) for k in range(1, int(count.max()))]
         )
@@ -57,7 +65,11 @@ def _people(tmp_path, key="c%d", sizes="tenth", placement="scattered", ids=450, 
     rows = []
     for r, v in enumerate(row_values.tolist()):
         row = {"dept": DEPTS[v % 3]} if two_column else {}
-        row.update(id=key % v, name=PEOPLE_NAMES[r % 10], surname=PEOPLE_SURNAMES[(r // 10) % 12])
+        row["id"] = key % v
+        if payload is None:
+            row.update(name=PEOPLE_NAMES[r % 10], surname=PEOPLE_SURNAMES[(r // 10) % 12])
+        else:
+            row.update((f"p{j}", PEOPLE_NAMES[(r + j) % 10] if j % 2 else str(r * (j + 3))) for j in range(payload))
         rows.append(row)
     path = tmp_path / "people.csv"
     with open(path, "w") as f:
@@ -183,8 +195,9 @@ def test_named_policy_stays_on_the_device_and_says_so(tmp_path, monkeypatch):
     compact = by["dedup:compact"]
     assert compact.extra["tier"] == "device" and compact.extra["policy"] == "first"
     assert compact.extra["kept"] == compact.rows_out == by["dedup:runs"].rows_out == kept
-    assert compact.extra["row_gathers"] == 3
-    assert sum(r.extra["row_gathers"] for r in ours) == 5
+    # the survivors of all three lanes ride one sort on (drop flag, row number): nothing is gathered but the permutation
+    assert (compact.extra["row_gathers"], compact.extra["form"], compact.extra["lanes"]) == (0, "sort", 3)
+    assert sum(r.extra["row_gathers"] for r in ours) == 2
     # the resolution reads one scalar to the host, the count; it is counted
     assert sum(r.extra.get("host_sync_elements", 0) for r in ours) == synced == 1
     for r in ours[1:]:
@@ -232,32 +245,106 @@ def test_second_execution_lowers_nothing_and_does_not_demote_again(tmp_path):
     assert _rows(second) == _rows(first)
 
 
+def _shape_values(shape, key, two_column):
+    """Key values by row for an edge shape of the compaction."""
+    if shape == "mixed":  # 1 to 5 copies of 40 keys, shuffled
+        return None
+    if shape == "no-duplicate":
+        return [5, 3, 9, 1, 7, 11, 0]
+    if shape == "one-key":  # every row one key
+        return [7] * 6
+    if shape == "n2":
+        return [5, 5]
+    if shape == "n1":  # the early return: a single row has no run to resolve
+        return [5]
+    assert shape == "ends"  # only the least and the greatest key of the index order repeat
+    order = sorted(range(12), key=lambda v: ((DEPTS[v % 3],) if two_column else ()) + (key % v,))
+    return [4, order[-1], 8, order[0], 2, order[0], 6, 10, order[-1], 0, 1, 3, 5, 7, 9, 11]
+
+
+KEYS = {"typed": dict(key="c%d"), "string": dict(key="k%x"), "two-column": dict(key="k%x", two_column=True)}
+
+
 @pytest.mark.parametrize("policy", ["first", "last"])
-@pytest.mark.parametrize(
-    "ids",
-    [[5, 3, 9, 1, 7], [4, 4, 4, 4], [8], [2, 2, 6, 1, 1, 1, 6, 3]],
-    ids=["no-duplicate", "all-duplicate", "single-row", "mixed"],
-)
-def test_device_compaction_equals_the_host_flatnonzero_form(ids, policy):
-    """``compact_runs`` (mask, prefix sum and scatter on the device, one
-    scalar read) against the host form it replaced: ``np.flatnonzero``
-    of the run-boundary mask ``run_starts`` still gives the callback."""
-    table = sort_ops.sort_table(
-        DeviceTable.from_pylists(
-            {"id": [f"k{v:x}" for v in ids], "n": [str(i) for i in range(len(ids))]}, device="cpu"
-        ),
-        ["id"],
+@pytest.mark.parametrize("payload", [1, 3, 9])
+@pytest.mark.parametrize("key_kind", list(KEYS))
+@pytest.mark.parametrize("shape", ["mixed", "no-duplicate", "one-key", "ends", "n2", "n1"])
+def test_sort_compaction_equals_the_gather_it_replaced_and_the_host_executor(
+    tmp_path, shape, key_kind, payload, policy
+):
+    """``compact_runs`` (every lane rides one sort on (drop flag, row
+    number)) bit for bit against the form it replaced, kept here as the
+    reference — ``table.gather`` by ``np.flatnonzero`` of the host's run
+    mask — and, through ``resolve_duplicates``, against the host
+    executor."""
+    values = _shape_values(shape, KEYS[key_kind]["key"], KEYS[key_kind].get("two_column", False))
+    path, rows, key = _people(
+        tmp_path, sizes="mixed", ids=40, payload=payload, row_values=values, **KEYS[key_kind]
     )
-    keep = sort_ops.run_starts(table, ["id"])
+    people = from_file(path).on_device("cpu")
+    table = people.index_on(*key)._impl.dev.table  # sorted by the key
+    assert table.columns["p0"].kind == "int" and (payload == 1 or table.columns["p1"].kind == "str")
+    keep = sort_ops.run_starts(table, key)
     if policy == "last":
         keep = np.append(keep[1:], True)
-    got = sort_ops.compact_runs(table, ["id"], policy)
-    if keep.all():
-        assert got is None
+    with telemetry.collect() as records:
+        got = sort_ops.compact_runs(table, key, policy)
+        stages = {r.stage: r.extra for r in records}
+    if shape == "n1":
+        assert got is None and not stages  # nothing dispatched
+    elif shape == "no-duplicate":
+        assert got is None and list(stages) == ["dedup:runs"]  # nothing sorted
     else:
-        assert got.nrows == int(keep.sum())
-        assert got.to_rows() == table.to_rows(np.flatnonzero(keep))
-        assert got.columns["n"].storage.dtype == np.int32
+        want = table.gather(np.flatnonzero(keep))
+        assert got.nrows == want.nrows == int(keep.sum()) < table.nrows
+        assert list(got.columns) == list(want.columns)
+        for name, col in got.columns.items():
+            assert type(col) is type(want.columns[name])
+            lane, ref = np.asarray(col.storage), np.asarray(want.columns[name].storage)
+            assert lane.dtype == ref.dtype == np.int32 and np.array_equal(lane, ref), name
+        assert got.to_rows() == want.to_rows()
+        extra = stages["dedup:compact"]
+        assert (extra["form"], extra["lanes"], extra["row_gathers"]) == ("sort", len(table.columns), 0)
+    want_rows = _reference(rows, key, policy)
+    pick = (lambda g: g[0]) if policy == "first" else (lambda g: g[-1])
+    assert _rows(_dedup(people, key, policy)) == want_rows == _rows(_dedup(Take(from_file(path)), key, pick))
+
+
+@pytest.mark.parametrize("policy", ["first", "last"])
+def test_a_mesh_sharded_table_compacts_by_the_same_sort(tmp_path, policy):
+    """One form: rows sharded over the 8-device mesh (121 rows, padded to
+    128) ride the same sort as a single device's."""
+    path, rows, key = _people(tmp_path, sizes="mixed", ids=41)
+    people = from_file(path).on_device("cpu", shards=8)
+    assert len(rows) == 121
+    with telemetry.collect() as records:
+        dev = _dedup(people, key, policy)
+        forms = [(r.extra["form"], r.extra["lanes"]) for r in records if r.stage == "dedup:compact"]
+    assert forms == [("sort", 3)] and _rows(dev) == _reference(rows, key, policy)
+
+
+def test_the_programs_the_yardstick_reads_still_run(tmp_path):
+    """What ``kernel.index_sort_*`` and ``kernel.dedup_gather_*`` time in
+    ``dedup-resident`` (``PERF.md`` §3): an ``IndexOn`` of a non-unique
+    key and a ``ResolveDuplicates("first")`` dispatch ``csvplus.index.sort``
+    once, ``csvplus.table.gather_take`` once per column the sort does not
+    return, and ``csvplus.dedup.compact`` once.  On a row count no other
+    test uses each lowers exactly once more."""
+    path, rows, key = _people(tmp_path, key="k%x", row_values=[(i * 7) % 1000 for i in range(1283)])  # 283 ids twice
+    people = from_file(path).on_device("cpu")
+    with RecompileWatch() as w, telemetry.collect() as records:
+        index = _dedup(people, key, "first").sync()
+        by = {}
+        for r in records:
+            by.setdefault(r.stage, []).append(r.extra)
+    grew = w.delta()
+    assert len(index) == 1000
+    assert {k: grew.get(k) for k in ("index.sort", "table.gather_take", "dedup.compact", "dedup.head")} == {
+        "index.sort": 1, "table.gather_take": 1, "dedup.compact": 1, "dedup.head": 1,
+    }  # the two payload gathers share one lowering
+    assert [e["tier"] for e in by["index:sort"]] == ["lax"]
+    assert [e["row_gathers"] for e in by["index:permute"]] == [2]
+    assert [(e["form"], e["lanes"], e["row_gathers"]) for e in by["dedup:compact"]] == [("sort", 3, 0)]
 
 
 def test_without_duplicates_nothing_is_gathered(tmp_path):

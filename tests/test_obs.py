@@ -883,7 +883,8 @@ def _kernel_examples():
         "index.sort": (((i, i, i),), {"num_keys": 2}),
         "index.adjacent_dup": ((i, i), {}),
         "dedup.runs": ((i, i), {"policy": "last"}),
-        "dedup.compact": ((i > 3,), {}),
+        "dedup.compact": ((i > 3, (i, i)), {}),
+        "dedup.head": (((i, i),), {"kept": 3}),
     }
 
 
@@ -897,6 +898,7 @@ KERNELS_LOWERED_HERE = sorted([
     "typed.translate_empty", "table.gather_take", "table.apply_code_translation",
     "table.sync_probe", "join.compose_probe", "join.probe_composed", "join.probe_composed_range",
     "index.sort", "index.adjacent_dup", "dedup.runs", "dedup.compact",
+    "dedup.head",
 ])
 
 
