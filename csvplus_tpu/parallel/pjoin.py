@@ -81,7 +81,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.recompile import register_kernel
 from ..utils.env import env_int, env_str
-from .mesh import row_spec, shard_rows
+from .mesh import replicate, row_spec
 
 _SENTINEL = np.int32(np.iinfo(np.int32).max)
 
@@ -414,37 +414,55 @@ def _probe_shard_kernel2(
     return got_lo, got_ct
 
 
-@register_kernel("pjoin.probe_spmd2", static_argnames=("mesh", "n_shards", "capacity"))
-def _probe_spmd2(
-    mesh, n_shards, capacity, qh, ql, uniq_hi, uniq_lo, lower, count, splits_hi,
-    splits_lo,
-):
+def _answer_hot_lanes(mesh, kernel, static_tail, k, operands):
+    """The hot values' own answers, all of the broadcast tier's device
+    work: the *k* replicated hot lanes that lead *operands* (their
+    length is the pow2 bucket of the hot count), padded with never-valid
+    -1 to whole rows a shard, ride the probes' own per-shard exchange
+    *kernel* — its capacity is a shard's whole share of them, so it
+    cannot overflow wherever they route — over the rest of *operands*
+    (that kernel's build-side arguments), and the bucket's answers come
+    back replicated, ready for the main kernel's merge."""
+    n_shards = mesh.devices.size
     axes = tuple(mesh.axis_names)
     rows = P(axes)
+    n_hot = operands[0].shape[0]
+    pad = (-n_hot) % n_shards
+    hot = [
+        jnp.concatenate([x, jnp.full(pad, -1, x.dtype)]) if pad else x
+        for x in operands[:k]
+    ]
     f = shard_map(
-        partial(_probe_shard_kernel2, n_shards, capacity, axes),
+        partial(kernel, n_shards, (n_hot + pad) // n_shards, axes, *static_tail),
         mesh=mesh,
-        in_specs=(rows, rows, rows, rows, rows, rows, P(), P()),
+        in_specs=(rows,) * (2 * k + 2) + (P(),) * k,
         out_specs=(rows, rows),
     )
-    return f(qh, ql, uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo)
+    lo, ct = f(*hot, *operands[k:])
+    repl = NamedSharding(mesh, P())
+    return tuple(
+        jax.lax.with_sharding_constraint(x[:n_hot], repl) for x in (lo, ct)
+    )
 
 
-@register_kernel(
-    "pjoin.probe_spmd", static_argnames=("mesh", "n_shards", "capacity", "positional")
-)
-def _probe_spmd(
-    mesh, n_shards, capacity, positional, qk_sharded, owner, lower, count, splits
+@register_kernel("pjoin.hot_answers", static_argnames=("mesh", "positional"))
+def _hot_answers_spmd(mesh, positional, hot, owner, lower, count, splits):
+    """:func:`_answer_hot_lanes` for narrow keys, one program."""
+    return _answer_hot_lanes(
+        mesh, _probe_shard_kernel, (positional,), 1,
+        (hot, owner, lower, count, splits),
+    )
+
+
+@register_kernel("pjoin.hot_answers2", static_argnames=("mesh",))
+def _hot_answers_spmd2(
+    mesh, hot_hi, hot_lo, uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo
 ):
-    axes = tuple(mesh.axis_names)
-    rows = P(axes)
-    f = shard_map(
-        partial(_probe_shard_kernel, n_shards, capacity, axes, positional),
-        mesh=mesh,
-        in_specs=(rows, rows, rows, rows, P()),
-        out_specs=(rows, rows),
+    """:func:`_answer_hot_lanes` for 62-bit keys (dual 31-bit lanes)."""
+    return _answer_hot_lanes(
+        mesh, _probe_shard_kernel2, (), 2,
+        (hot_hi, hot_lo, uniq_hi, uniq_lo, lower, count, splits_hi, splits_lo),
     )
-    return f(qk_sharded, owner, lower, count, splits)
 
 
 def prepare_partitioned(mesh: Mesh, index_keys_sorted: np.ndarray) -> Partitioned:
@@ -758,11 +776,13 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
                 (_skew_sample(qk_dev[0], at), _skew_sample(qk_dev[1], at))
             )
             telemetry.count_sync(hi.size + lo.size)
+            _d["host_sync_elements"] = int(hi.size + lo.size)
             sample = (hi.astype(np.int64) << 31) | np.where(lo >= 0, lo, 0)
             sample = sample[hi >= 0]
         else:
             sample = jax.device_get(_skew_sample(qk_dev, at))
             telemetry.count_sync(sample.size)
+            _d["host_sync_elements"] = int(sample.size)
             sample = sample[sample >= 0]
         _d["threshold"] = round(tau, 6)
         _d["sample"] = int(sample.size)
@@ -831,61 +851,34 @@ def _note_skew(
 
 
 def _hot_answers_device(mesh, hot: np.ndarray, prepared: Partitioned):
-    """Answer the (few, distinct) hot values themselves through the same
-    SPMD exchange — tiny arrays, so capacity = the full hot count can
-    never overflow.  Returns device (vals..., lo, ct) padded to pow2
-    with never-matching sentinels (padded to a mesh multiple first)."""
-    n_shards = mesh.devices.size
-    n_hot = _pow2(hot.size)
-    padded = max(n_hot, n_shards) if n_hot % n_shards else n_hot
-    padded = padded + ((-padded) % n_shards)
-    cap = _pow2(padded)  # worst case: every hot value routes to one shard
-    wide = prepared.wide
-    if wide:
-        hv = np.full(padded, -1, dtype=np.int64)
-        hv[: hot.size] = hot
-        qh, ql = split_lanes(hv)
-        qh_d = shard_rows(mesh, qh)
-        ql_d = shard_rows(mesh, ql)
-        lo, ct = _probe_spmd2(
-            mesh, n_shards, cap, qh_d, ql_d, *prepared.uniq,
-            prepared.lower, prepared.count, *prepared.splits,
+    """Answer the (few, distinct) hot values themselves: one upload of
+    their lanes and one launch of :func:`_hot_answers_spmd` (``…spmd2``
+    for 62-bit keys).  Returns device ``(vals..., lo, ct)``, each the
+    pow2 bucket of the hot count long and replicated; nothing is read
+    back to the host.
+
+    The value lanes — also the main kernel's membership table — are
+    sorted and padded by REPEATING the last real value: duplicates at
+    the tail keep the array sorted, and searchsorted-left always lands
+    on the first (real) slot, whose answer the pads share, so a probe
+    key equal to any conceivable pad value can never be answered
+    wrongly from a pad slot."""
+    pad = _pow2(hot.size) - hot.size
+    lanes = split_lanes(hot) if prepared.wide else (hot,)
+    vals = replicate(
+        mesh, tuple(np.concatenate([x, np.full(pad, x[-1], np.int32)]) for x in lanes)
+    )
+    if prepared.wide:
+        lo, ct = _hot_answers_spmd2(
+            mesh, *vals, *prepared.uniq, prepared.lower, prepared.count,
+            *prepared.splits,
         )
     else:
-        hv = np.full(padded, -1, dtype=np.int32)
-        hv[: hot.size] = hot
-        qk_d = shard_rows(mesh, hv)
-        lo, ct = _probe_spmd(
-            mesh, n_shards, cap, prepared.positional, qk_d,
-            prepared.owner, prepared.lower, prepared.count, *prepared.splits,
+        lo, ct = _hot_answers_spmd(
+            mesh, prepared.positional, *vals, prepared.owner, prepared.lower,
+            prepared.count, *prepared.splits,
         )
-    repl = NamedSharding(mesh, P())
-    # hot value lanes for the main kernel's membership search: sorted,
-    # padded by REPEATING the last real value — duplicates at the tail
-    # keep the array sorted, and searchsorted-left always lands on the
-    # first (real, correctly-answered) slot, so a probe key equal to
-    # any conceivable pad value can never be answered from a pad slot
-    if wide:
-        hh, hl = split_lanes(hot)
-        pad_hi = np.full(n_hot, hh[-1], np.int32)
-        pad_lo = np.full(n_hot, hl[-1], np.int32)
-        pad_hi[: hot.size] = hh
-        pad_lo[: hot.size] = hl
-        vals = (jax.device_put(pad_hi, repl), jax.device_put(pad_lo, repl))
-    else:
-        pad_v = np.full(n_hot, hot[-1], np.int32)
-        pad_v[: hot.size] = hot
-        vals = (jax.device_put(pad_v, repl),)
-    ans_lo = jax.device_put(jnp.asarray(lo[: hot.size]), repl)
-    ans_ct = jax.device_put(jnp.asarray(ct[: hot.size]), repl)
-    # pad answers to n_hot so gather indices stay in range
-    if hot.size < n_hot:
-        fill = jnp.full(n_hot - hot.size, -1, jnp.int32)
-        ans_lo = jnp.concatenate([ans_lo, fill])
-        ans_ct = jnp.concatenate([ans_ct, jnp.zeros(n_hot - hot.size, jnp.int32)])
-        ans_lo = jax.device_put(ans_lo, repl)
-        ans_ct = jax.device_put(ans_ct, repl)
-    return vals, ans_lo, ans_ct
+    return vals, lo, ct
 
 
 def _retry_probe_device(
@@ -910,7 +903,7 @@ def _retry_probe_device(
     if capacity is None:
         capacity = _default_capacity(m, n_shards)
     padded_m = m + ((-m) % n_shards)
-    retries = 0
+    retries = synced = 0
     # the exchange stage covers the whole shard_map launch: all_to_all
     # key shuffle + per-shard local probe + answer return + hot merge
     # (one fused SPMD executable, not separable from outside)
@@ -921,9 +914,11 @@ def _retry_probe_device(
             if len(res) > 3:
                 ov, hits = jax.device_get((overflow, res[3]))
                 telemetry.count_sync(2)
+                synced += 2
                 overflowed, rows_broadcast = bool(ov), int(hits)
             else:
                 telemetry.count_sync(1)
+                synced += 1
                 # one O(1) scalar sync per attempt
                 overflowed, rows_broadcast = bool(jax.device_get(overflow)), 0
             if not overflowed:
@@ -931,6 +926,7 @@ def _retry_probe_device(
                 _x["capacity"] = capacity
                 _x["retries"] = retries
                 _x["attempts"] = retries + 1  # one blocking host read each
+                _x["host_sync_elements"] = synced
                 _x["slot_fill"] = m / slots
                 _x["bytes_exchanged"] = 4 * exchanges * slots
                 _x["owner_tier"] = prepared.owner_tier

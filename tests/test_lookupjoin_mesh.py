@@ -9,6 +9,11 @@ The result is held to three oracles: a plain numpy reference kept here
 code), the host executor, and the one-device run (bitwise).  The sizes
 that select the streamed tier, the sample-sort and the all_to_all probe
 are lowered in the test only.
+
+The same three oracles hold the skewed stream of the benchmark's
+``lookupjoin-mesh4-zipf`` cell (``ZipfCorpus``: Zipf(1.1) ``cust_id``
+over a permuted rank -> customer map), where the probe's hot-key tier
+answers the heavy customers and the exchange's capacity shrinks.
 """
 
 import numpy as np
@@ -41,8 +46,7 @@ class Corpus:
     def __init__(self, seed: int, root):
         rng = np.random.default_rng(seed)
         self.people_id = rng.permutation(N_PEOPLE)  # unique and shuffled: the index build sorts
-        self.cust = rng.integers(0, N_PEOPLE, N_ORDERS)
-        self.cust[rng.choice(N_ORDERS, N_PEOPLE, replace=False)] = np.arange(N_PEOPLE)
+        self.cust = self.draw_cust(rng)
         self.prod = rng.integers(0, N_STOCK, N_ORDERS)
         self.qty = rng.integers(1, 101, N_ORDERS)
         secs = rng.integers(0, 366 * 86400, N_ORDERS).astype("timedelta64[s]")
@@ -64,17 +68,54 @@ class Corpus:
                 f"c{c},p{p},{q},{t}\n" for c, p, q, t in zip(self.cust, self.prod, self.qty, self.ts)
             )
 
+    def draw_cust(self, rng) -> np.ndarray:
+        """Uniform over the people, every one occurring."""
+        cust = rng.integers(0, N_PEOPLE, N_ORDERS)
+        cust[rng.choice(N_ORDERS, N_PEOPLE, replace=False)] = np.arange(N_PEOPLE)
+        return cust
+
     def want(self) -> dict:
-        """The reference: each order beside the person its cust_id names."""
+        """The reference: each order beside the person its cust_id names;
+        an order of a customer who is not among the people is dropped."""
         row_of = np.empty(N_PEOPLE, dtype=np.int64)
         row_of[self.people_id] = np.arange(N_PEOPLE)
-        person = row_of[self.cust]
-        cust = np.char.add("c", self.cust.astype(str))
+        keep = self.cust < N_PEOPLE
+        person = row_of[self.cust[keep]]
+        cust = np.char.add("c", self.cust[keep].astype(str))
         return {
-            "cust_id": cust, "prod_id": np.char.add("p", self.prod.astype(str)),
-            "qty": self.qty.astype(str), "ts": self.ts,
+            "cust_id": cust, "prod_id": np.char.add("p", self.prod[keep].astype(str)),
+            "qty": self.qty[keep].astype(str), "ts": self.ts[keep],
             "id": cust, "name": self.name[person], "surname": self.surname[person],
         }
+
+
+LAYOUT = 39  # the skeleton's seed: which rows hold which rank, whatever the corpus's seed
+
+
+class ZipfCorpus(Corpus):
+    """``cust_id`` Zipf(*s*) over ranks 1..N_PEOPLE, rank -> customer by a
+    seeded permutation (``benchmark/gen/orders_zipf.py``'s draw, kept
+    plain here): a rank's row count is its expected count (the rounded
+    running sum, no draw), a skeleton from the fixed ``LAYOUT`` says
+    which rows hold which rank, and the corpus's seed which customer has
+    which rank.  *heavy* moves that share of the orders onto rank 1;
+    *absent* gives rank 1 to a customer who is not among the people."""
+
+    def __init__(self, seed: int, root, s: float = 1.1, heavy: float = 0.0, absent: bool = False):
+        self.s, self.heavy, self.absent = s, int(heavy * N_ORDERS), absent
+        super().__init__(seed, root)
+
+    def draw_cust(self, rng) -> np.ndarray:
+        cum = np.cumsum(np.arange(1, N_PEOPLE + 1, dtype=np.float64) ** -self.s)
+        edges = np.rint(cum * ((N_ORDERS - self.heavy) / cum[-1])).astype(np.int64)
+        self.rank_rows = np.diff(edges, prepend=0)
+        self.rank_rows[0] += self.heavy
+        rank_of_row = np.repeat(np.arange(N_PEOPLE), self.rank_rows)
+        np.random.default_rng(LAYOUT).shuffle(rank_of_row)
+        self.customer_of_rank = rng.permutation(N_PEOPLE)
+        if self.absent:
+            self.customer_of_rank[0] = N_PEOPLE + 7
+        return self.customer_of_rank[rank_of_row]
 
 
 def lookup_join(corpus: Corpus, shards):
@@ -116,6 +157,40 @@ def small_thresholds(monkeypatch):
     monkeypatch.setattr(S, "DSORT_MIN_ROWS", 1000)
 
 
+def stage_extras(stages, name) -> list:
+    return [r.extra for r in stages if r.stage == name]
+
+
+def equals_three_oracles(corpus: Corpus, result) -> None:
+    """*result* (the mesh run) against (a) the numpy reference, (b) the
+    host executor and (c) the one-device run: the same values everywhere,
+    and bitwise the same lanes and dictionaries wherever the two keep a
+    column alike (the one-device index build demotes the typed id lane,
+    the mesh sort does not)."""
+    got = column_strings(result)
+    for name, want in corpus.want().items():
+        assert got[name] == want.tolist(), name
+    host = Take(FromFile(corpus.orders)).Join(
+        Take(FromFile(corpus.people)).UniqueIndexOn("id"), "cust_id"
+    ).ToRows()
+    assert got == {name: [r[name] for r in host] for name in COLUMNS}
+    _, single, single_stages, single_build, _ = lookup_join(corpus, None)
+    assert "dsort" not in single_build
+    assert not stage_extras(single_stages, "join:all_to_all")
+    assert result.nrows == single.nrows == len(host)
+    assert got == column_strings(single)
+    bitwise = []
+    for name in COLUMNS:
+        a, b = result.columns[name], single.columns[name]
+        if type(a) is not type(b):
+            continue
+        np.testing.assert_array_equal(np.asarray(a.storage), np.asarray(b.storage), err_msg=name)
+        if getattr(a, "kind", "str") != "int":
+            np.testing.assert_array_equal(np.asarray(a.dictionary), np.asarray(b.dictionary))
+        bitwise.append(name)
+    assert set(bitwise) >= {"cust_id", "prod_id", "qty", "ts", "name", "surname"}
+
+
 @pytest.mark.parametrize("seed", [11, 2_200_000_027, 4_100_000_123])
 def test_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(seed, tmp_path):
     corpus = Corpus(seed, tmp_path)
@@ -142,33 +217,7 @@ def test_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(seed, tmp_path
     assert extra["owner_tier"] == "positional" and extra["search_rounds"] == 0
     watch.assert_zero()  # the second execution lowered nothing
 
-    got = column_strings(result)
-    # (a) the numpy reference
-    for name, want in corpus.want().items():
-        assert got[name] == want.tolist(), name
-    # (b) the host executor
-    host = Take(FromFile(corpus.orders)).Join(
-        Take(FromFile(corpus.people)).UniqueIndexOn("id"), "cust_id"
-    ).ToRows()
-    assert got == {name: [r[name] for r in host] for name in COLUMNS}
-    # (c) the one-device run: the same values everywhere, and bitwise the
-    # same lanes and dictionaries wherever the two keep a column alike (the
-    # one-device index build demotes the typed id lane, the mesh sort does not)
-    _, single, single_stages, single_build, _ = lookup_join(corpus, None)
-    assert "dsort" not in single_build
-    assert not [r for r in single_stages if r.stage == "join:all_to_all"]
-    assert result.nrows == single.nrows == N_ORDERS
-    assert got == column_strings(single)
-    bitwise = []
-    for name in COLUMNS:
-        a, b = result.columns[name], single.columns[name]
-        if type(a) is not type(b):
-            continue
-        np.testing.assert_array_equal(np.asarray(a.storage), np.asarray(b.storage), err_msg=name)
-        if getattr(a, "kind", "str") != "int":
-            np.testing.assert_array_equal(np.asarray(a.dictionary), np.asarray(b.dictionary))
-        bitwise.append(name)
-    assert set(bitwise) >= {"cust_id", "prod_id", "qty", "ts", "name", "surname"}
+    equals_three_oracles(corpus, result)
     ts = result.columns["ts"]
     assert ts._lane_state is not None and len(ts.dictionary) == len(set(corpus.ts.tolist()))
 
@@ -209,3 +258,81 @@ def test_translation_tables_are_placed_on_the_probe_mesh_once(kind, tmp_path, mo
         np.asarray(probes[None].renumbered_to_col(build_id)),
     )
     assert (np.asarray(probes[None].renumbered_to_col(build_id)) >= 0).all()
+
+
+ZIPF_CASES = {
+    # name: (corpus arguments, hot keys at least, capacity as a share of the uniform stream's)
+    "zipf-1.1-seed-11": (dict(seed=11), 1, 1 / 2),
+    "zipf-1.1-seed-2200000027": (dict(seed=2_200_000_027), 1, 1 / 2),
+    "zipf-1.1-seed-4100000123": (dict(seed=4_100_000_123), 1, 1 / 2),
+    "one-key-over-60-percent": (dict(seed=5, heavy=0.62), 1, 1 / 4),  # the capacity shrinks twice
+    "hot-key-absent-from-the-build-side": (dict(seed=6, absent=True), 0, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ZIPF_CASES))
+def test_skewed_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(case, tmp_path):
+    from csvplus_tpu.parallel.pjoin import _default_capacity
+
+    kwargs, hot_at_least, capacity_share = ZIPF_CASES[case]
+    corpus = ZipfCorpus(root=tmp_path, **kwargs)
+    fact, result, stages, build, watch = lookup_join(corpus, SHARDS)
+
+    assert getattr(fact, "_pre_sharded", False) and "dsort" in build
+    for col in result.columns.values():
+        assert len(col.storage.sharding.device_set) == SHARDS
+    (detect,) = stage_extras(stages, "join:skew-detect")
+    (exchange,) = stage_extras(stages, "join:all_to_all")
+    assert detect["hot_keys"] >= hot_at_least
+    assert detect["host_sync_elements"] >= detect["sample"] > 0
+    # the hot keys are the heaviest customers that exist on the build side, and the
+    # broadcast tier answered exactly their orders: the exchange carried the rest
+    present = corpus.customer_of_rank < N_PEOPLE
+    hot_rows = int(corpus.rank_rows[present][: detect["hot_keys"]].sum())
+    if detect["hot_keys"]:
+        (broadcast,) = stage_extras(stages, "join:broadcast")
+        (skew,) = stage_extras(stages, "join:skew")
+        assert broadcast["n_hot"] >= detect["hot_keys"]
+        assert skew["hot_keys"] == detect["hot_keys"]
+        assert skew["rows_broadcast"] == hot_rows
+        assert skew["rows_repartitioned"] == N_ORDERS - hot_rows
+        assert skew["capacity"] == exchange["capacity"]
+        assert exchange["host_sync_elements"] == 2 * exchange["attempts"]
+    else:
+        assert not stage_extras(stages, "join:broadcast") and not stage_extras(stages, "join:skew")
+    if capacity_share is not None:
+        # the settled capacity is the sketch's, and it held: no retry
+        assert exchange["retries"] == 0
+        assert exchange["capacity"] == capacity_share * _default_capacity(N_ORDERS, SHARDS)
+    assert exchange["slot_fill"] == pytest.approx(N_ORDERS / (SHARDS**2 * exchange["capacity"]))
+    assert exchange["owner_tier"] == "positional"
+    watch.assert_zero()  # the second execution lowered nothing, the hot answers' program included
+    assert result.nrows == int(corpus.rank_rows[present].sum())
+    equals_three_oracles(corpus, result)  # whatever the retries, the result is exact
+
+
+def test_a_second_seed_of_one_skewed_layout_lowers_nothing(tmp_path):
+    """Which customer is hot is the seed's; how many are, the exchange's
+    capacity and so every program of the join are the layout's: a second
+    seed's executions, the first included, find every kernel lowered."""
+    first = ZipfCorpus(11, tmp_path)
+    _, _, stages, _, _ = lookup_join(first, SHARDS)
+    second = ZipfCorpus(4_100_000_123, tmp_path)
+    assert first.customer_of_rank[0] != second.customer_of_rank[0]
+    assert (first.rank_rows == second.rank_rows).all()
+    orders = FromFile(second.orders).OnDevice(shards=SHARDS)
+    cust_idx = FromFile(second.people).OnDevice(shards=SHARDS).UniqueIndexOn("id").sync()
+    plan = orders.Join(cust_idx, "cust_id").plan
+    cache = PlanCache()
+    with RecompileWatch() as watch, telemetry.collect() as records:
+        result = cache.execute(plan).sync()
+        again = list(records)
+        cache.execute(plan).sync()
+    watch.assert_zero("a second seed of one layout")
+    for name in ("join:skew-detect", "join:broadcast", "join:skew"):
+        (a,), (b,) = stage_extras(stages, name), stage_extras(again, name)
+        assert {k: v for k, v in a.items() if k != "wait_s"} == {k: v for k, v in b.items() if k != "wait_s"}, name
+    assert stage_extras(stages, "join:all_to_all")[0]["capacity"] == stage_extras(again, "join:all_to_all")[0]["capacity"]
+    got = column_strings(result)
+    for name, want in second.want().items():
+        assert got[name] == want.tolist(), name

@@ -562,17 +562,18 @@ def test_executor_join_partitioned_path(people_csv, orders_csv, monkeypatch):
 
 def test_partitioned_probe_hot_key_short_circuit(mesh, monkeypatch):
     """Heavy probe keys are answered via the sampled hot-key cache: one
-    SPMD call (no capacity retries), exact results on a hot/cold mix."""
+    launch of the hot values' own answers (no capacity retries), exact
+    results on a hot/cold mix."""
     import csvplus_tpu.parallel.pjoin as PJ
 
     calls = {"n": 0}
-    orig = PJ._probe_spmd
+    orig = PJ._hot_answers_spmd
 
     def counting(*a, **k):
         calls["n"] += 1
         return orig(*a, **k)
 
-    monkeypatch.setattr(PJ, "_probe_spmd", counting)
+    monkeypatch.setattr(PJ, "_hot_answers_spmd", counting)
 
     rng = np.random.default_rng(9)
     keys = np.sort(rng.integers(0, 2000, size=16_000).astype(np.int32))
