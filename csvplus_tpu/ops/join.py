@@ -890,10 +890,10 @@ class DeviceIndex:
 
         *part_info* is the multiway join's shared partitioned-tier state
         (``multiway_join`` threads ONE dict through every dimension's
-        probe): the exchange capacity settled while probing one dimension
-        seeds the next dimension's first attempt, and each dimension's
-        skew-routing evidence accumulates into the same dict — see
-        ``partitioned_probe_device``'s *info* contract.
+        probe): each dimension's settled exchange capacity and
+        skew-routing evidence accumulate into the same dict — see
+        ``partitioned_probe_device``'s *info* contract.  Every probe
+        counts its own exchange; nothing of one seeds the next.
         """
         assert self.supported
         self.offer_build_sample()
@@ -949,11 +949,11 @@ class DeviceIndex:
 
                 # device-resident end to end: the probe keys, exchange,
                 # hot-key merge and answers never leave the mesh; the
-                # only host syncs are a bounded hot-key sample and one
-                # O(1) scalar sync per capacity attempt
+                # only host syncs are a bounded hot-key sample with the
+                # exchange's route count beside it (and one O(1) flag per
+                # attempt the count does not guarantee)
                 return partitioned_probe_device(
                     qk_sh.mesh, qk, self._partitioned_for(qk_sh),
-                    capacity=(part_info or {}).get("capacity"),
                     label=",".join(self.key_columns),
                     info=part_info,
                 )
@@ -1007,7 +1007,6 @@ class DeviceIndex:
             q_lo_m = jnp.where(ok, q_lo, jnp.int32(-1))
             return partitioned_probe_device_wide(
                 qk_sh.mesh, q_hi_m, q_lo_m, self._partitioned_for(qk_sh),
-                capacity=(part_info or {}).get("capacity"),
                 label=",".join(self.key_columns),
                 info=part_info,
             )

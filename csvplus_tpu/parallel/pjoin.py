@@ -34,9 +34,16 @@ inside jit):
   local binary search over its unique keys (``"search"``:
   ``search_rounds`` gather rounds over all ``N * C`` slots);
 * capacity ``C`` (slots per destination) is a static compile-time
-  parameter; overflow is detected on device (-1 sentinel) and the probe
-  retries with doubled capacity — the count -> allocate -> fill pattern
-  with a geometric backoff instead of a second counting pass.
+  parameter, and it is COUNTED: count -> allocate -> fill.  Before the
+  exchange a small program (``pjoin.route_count``) routes every probe
+  key as the exchange will (:func:`_route_dest`) and returns the
+  fullest (source, owner) pair, ``pair_max``; the host reads that one
+  int32 in the same ``device_get`` as the hot-key sample, and ``C`` is
+  its power-of-two bucket (one program a bucket).  An exchange at a
+  capacity that holds ``pair_max`` cannot overflow, so its overflow
+  flag is not read.  Overflow detection on device (-1 sentinel) and the
+  retry with doubled capacity stay as the net under a capacity the
+  count does not guarantee: the sketch's, below, or a caller's.
 
 Skew (ISSUE 15): PROBE-side heavy hitters are detected by a sketch pass
 over a bounded strided sample (``_detect_hot``: SpaceSaving count−err
@@ -50,14 +57,17 @@ salted broadcast, with the existing row placement acting as the salt
 collapsing onto the key's range owner) and the positional scatter-back
 at emit (``.at[pos].set``) folding the salt out so row order and
 checksums stay bitwise-identical to the unsalted path.  The tail rides
-the hash-repartition exchange unchanged, with its slot capacity shrunk
-by the sketch's hot-share estimate (``_skew_capacity``); residual
-imbalance is absorbed by the geometric capacity retry, and
+the hash-repartition exchange unchanged.  The count is taken before
+the hot keys are known and holds their rows too, so with hot keys it
+is an upper bound only, and the sketch's hot-share estimate may shrink
+the capacity under it (``_skew_capacity``); an undershoot of that
+estimate is absorbed by the geometric capacity retry, and
 ``CSVPLUS_JOIN_SKEW=0`` disables the whole tier (the parity hatch and
-skew-naive bench baseline).  BUILD-side skew is eliminated
-structurally: because a probe answer is just ``(global lower bound,
-run length)`` — the actual match rows are gathered later by global
-position — shards never need a heavy key's duplicate copies at all.
+skew-naive bench baseline: the capacity is then the count's).
+BUILD-side skew is eliminated structurally: because a probe answer is
+just ``(global lower bound, run length)`` — the actual match rows are
+gathered later by global position — shards never need a heavy key's
+duplicate copies at all.
 The build side is partitioned over its UNIQUE keys, each carrying a
 precomputed (lower, count) payload, so a key that owns 50% of the
 build rows costs its owner exactly one slot (a build-side
@@ -257,6 +267,25 @@ class Partitioned(NamedTuple):
         return self.first if self.positional else self.uniq[0]
 
 
+def _route_dest(n_shards: int, q, splits):
+    """The owning shard of every probe key — lanes *q*, one int32 lane
+    or ``(hi, lo)`` — against the replicated, non-decreasing *splits*:
+    how many split keys past the first are <= the key, which is
+    ``searchsorted(splits, q, side="right") - 1`` clipped into the mesh,
+    in N - 1 compares and no loop.  An invalid probe (``q < 0``: absent,
+    or answered by the hot tier) gets *n_shards*: it goes nowhere and
+    takes no slot.  The exchange kernels and the count pass
+    (:func:`_route_count`) both route by this function, so the capacity
+    the count guarantees is the capacity the exchange needs."""
+    dest = jnp.zeros(q[0].shape, jnp.int32)
+    for i in range(1, n_shards):
+        past = True  # split i <= key, lexicographic over the lanes: the last lane first
+        for lane, split in zip(reversed(q), reversed(splits)):
+            past = (split[i] < lane) | ((split[i] == lane) & past)
+        dest = dest + past.astype(jnp.int32)
+    return jnp.where(q[0] >= 0, dest, jnp.int32(n_shards))
+
+
 def _probe_shard_kernel(
     n_shards: int, capacity: int, axes, positional: bool,
     qk, owner, lower_local, count_local, splits,
@@ -269,12 +298,10 @@ def _probe_shard_kernel(
     (:class:`Partitioned`)."""
     N, C = n_shards, capacity
 
-    valid = qk >= 0
-    dest = jnp.clip(jnp.searchsorted(splits, qk, side="right") - 1, 0, N - 1)
     # invalid probes (absent keys / hot-key short-circuited) get dest N:
     # they consume NO exchange slots and answer (−1, 0)
-    dest = jnp.where(valid, dest, N).astype(jnp.int32)
-    routed = valid
+    dest = _route_dest(N, (qk,), (splits,))
+    routed = qk >= 0
 
     # rank of each query within its destination group, in original row
     # order, via a one-hot running count — N is small (mesh size), so
@@ -357,12 +384,8 @@ def _probe_shard_kernel2(
 
     N, C = n_shards, capacity
 
-    valid = qh >= 0
-    dest = jnp.clip(
-        _searchsorted2(splits_hi, splits_lo, qh, ql, side="right") - 1, 0, N - 1
-    )
-    dest = jnp.where(valid, dest, N).astype(jnp.int32)
-    routed = valid
+    dest = _route_dest(N, (qh, ql), (splits_hi, splits_lo))
+    routed = qh >= 0
 
     # within-destination rank in original row order via one-hot running
     # count — same slot assignment as the stable sort it replaces, ~8x
@@ -553,7 +576,8 @@ def partitioned_probe(
 # array every capacity retry — O(n) host traffic per probe.  The
 # functions below keep the probe keys, answers, hot-key merge, padding,
 # and overflow detection ON DEVICE: the only host syncs are a <=4096-
-# element hot-key sample and one boolean overflow scalar per retry.
+# element hot-key sample with the route count beside it, and one boolean
+# overflow scalar per attempt the count does not guarantee.
 
 
 @register_kernel(
@@ -658,6 +682,50 @@ def _probe_spmd_dev2(
     return lo, ct, jnp.any(ct < 0)
 
 
+def _route_count(mesh, q, splits):
+    """``pair_max``: the most probe rows any one shard sends any one
+    owner — what the exchange's capacity must hold.  Runs under
+    ``shard_map`` over the probe's own row sharding (the same pad of
+    never-valid -1 to whole rows a shard, so a shard here holds the rows
+    it holds there) and routes by the exchange's own
+    :func:`_route_dest`: per shard the rows bound for each owner, their
+    maximum over owners and, by ``lax.pmax``, over the mesh.  One int32,
+    replicated.  Rows the hot tier will answer in place are still
+    counted (the count runs before they are known): an upper bound."""
+    n_shards = mesh.devices.size
+    axes = tuple(mesh.axis_names)
+    rows = row_spec(mesh)
+    pad = (-q[0].shape[0]) % n_shards
+    if pad:
+        q = tuple(jnp.concatenate([x, jnp.full(pad, -1, x.dtype)]) for x in q)
+    q = tuple(
+        jax.lax.with_sharding_constraint(x, NamedSharding(mesh, rows)) for x in q
+    )
+
+    def fullest(q, splits):
+        dest = _route_dest(n_shards, q, splits)
+        to_owner = jnp.stack(
+            [jnp.sum(dest == d, dtype=jnp.int32) for d in range(n_shards)]
+        )
+        return lax.pmax(jnp.max(to_owner), axes)
+
+    # one spec a tuple of lanes: the probe's rows, the replicated splits
+    f = shard_map(fullest, mesh=mesh, in_specs=(rows, P()), out_specs=P())
+    return f(q, tuple(splits))
+
+
+@register_kernel("pjoin.route_count", static_argnames=("mesh",))
+def _route_count_spmd(mesh, qk, splits):
+    """:func:`_route_count` for narrow keys, one program."""
+    return _route_count(mesh, (qk,), (splits,))
+
+
+@register_kernel("pjoin.route_count2", static_argnames=("mesh",))
+def _route_count_spmd2(mesh, qh, ql, splits_hi, splits_lo):
+    """:func:`_route_count` for 62-bit keys (dual 31-bit lanes)."""
+    return _route_count(mesh, (qh, ql), (splits_hi, splits_lo))
+
+
 def _pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
@@ -676,16 +744,20 @@ def _renamed_rows(mesh: Mesh, x: jax.Array) -> jax.Array:
 
 
 def _default_capacity(m: int, n_shards: int) -> int:
+    """Twice the mean (source, owner) pair, as a power of two: the clamp
+    of :func:`_skew_capacity` and nothing else — the exchange's own
+    capacity is counted (:func:`_exchange_capacity`)."""
     m_per_shard = (m + n_shards - 1) // n_shards
     return _pow2(max(64, 2 * ((m_per_shard + n_shards - 1) // n_shards)))
 
 
 def skew_enabled() -> bool:
     """``CSVPLUS_JOIN_SKEW=0`` disables ALL hot-key handling (the
-    parity hatch): no detection, no broadcast tier, default tail
-    capacity — the skew-naive baseline the bench gate compares
-    against.  Read per call so one process can flip it between
-    passes (the bench measures both modes in the same run)."""
+    parity hatch): no detection, no broadcast tier, the exchange at
+    its counted capacity (hot rows and all) — the skew-naive baseline
+    the bench gate compares against.  Read per call so one process can
+    flip it between passes (the bench measures both modes in the same
+    run)."""
     return env_str("CSVPLUS_JOIN_SKEW", "1") != "0"
 
 
@@ -723,10 +795,13 @@ def _skew_sample(lane, at):
     return jnp.take(lane, at, axis=0)
 
 
-def _detect_hot(qk_dev, n_shards: int, wide: bool):
+def _detect_hot(qk_dev, n_shards: int, wide: bool, count):
     """Sketch-driven heavy-hitter detection over a bounded strided
     device sample — a data-INDEPENDENT host transfer (bounded by the
-    sample cap, not the probe length).
+    sample cap, not the probe length).  *count* is the route count's
+    device scalar (:func:`_route_count`): it comes down in the sample's
+    own ``device_get`` — the exchange's capacity costs no blocking read
+    of its own — and alone where no sample is taken.
 
     The sample's (value, count) aggregate feeds a :class:`SpaceSaving`
     sketch with ``k = ceil(4/τ)`` tracked keys; a key is classified
@@ -744,9 +819,10 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
     sketch counts are exact (err 0) and the predicate reduces to the
     plain frequency threshold.
 
-    Returns ``(hot, hot_share)``: sorted distinct hot values as int64
-    (wide) / int32 or None, plus the hot keys' aggregate share of the
-    sample — the planner's capacity hint for the tail exchange.
+    Returns ``(hot, hot_share, pair_max)``: sorted distinct hot values
+    as int64 (wide) / int32 or None, the hot keys' aggregate share of
+    the sample — the planner's capacity hint for the tail exchange —
+    and *count* as a host int.
 
     Fused probe passes (ISSUE 19) need no special handling here: the
     sample is drawn from whatever packed key array reaches the
@@ -758,11 +834,11 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
     from ..obs.sketch import SpaceSaving
     from ..utils.observe import telemetry
 
-    if not skew_enabled():
-        return None, 0.0
-    m = int(qk_dev[0].shape[0] if wide else qk_dev.shape[0])
-    if m < 4 * n_shards:
-        return None, 0.0
+    lanes = qk_dev if wide else (qk_dev,)
+    m = int(lanes[0].shape[0])
+    if not skew_enabled() or m < 4 * n_shards:
+        telemetry.count_sync(1)  # no sample to ride with: the count alone
+        return None, 0.0, int(jax.device_get(count))
     tau = skew_threshold(n_shards)
     with telemetry.stage("join:skew-detect", m) as _d:
         cap = _skew_sample_cap()
@@ -771,24 +847,25 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
         # EXPLICIT device_get: the transfer-guard differential test pins
         # that the device path performs no *implicit* device->host
         # transfers
+        *sampled, pair_max = jax.device_get(
+            (*(_skew_sample(x, at) for x in lanes), count)
+        )
+        pair_max = int(pair_max)
+        synced = sum(x.size for x in sampled) + 1
+        telemetry.count_sync(synced)
+        _d["host_sync_elements"] = synced
         if wide:
-            hi, lo = jax.device_get(
-                (_skew_sample(qk_dev[0], at), _skew_sample(qk_dev[1], at))
-            )
-            telemetry.count_sync(hi.size + lo.size)
-            _d["host_sync_elements"] = int(hi.size + lo.size)
+            hi, lo = sampled
             sample = (hi.astype(np.int64) << 31) | np.where(lo >= 0, lo, 0)
             sample = sample[hi >= 0]
         else:
-            sample = jax.device_get(_skew_sample(qk_dev, at))
-            telemetry.count_sync(sample.size)
-            _d["host_sync_elements"] = int(sample.size)
+            (sample,) = sampled
             sample = sample[sample >= 0]
         _d["threshold"] = round(tau, 6)
         _d["sample"] = int(sample.size)
         _d["hot_keys"] = 0
         if not sample.size:
-            return None, 0.0
+            return None, 0.0, pair_max
         vals, cnts = np.unique(sample, return_counts=True)
         sk = SpaceSaving(k=min(max(int(math.ceil(4.0 / tau)), 8), 4096))
         sk.offer_counts(vals, cnts)
@@ -796,13 +873,13 @@ def _detect_hot(qk_dev, n_shards: int, wide: bool):
         hot_list = [key for key, c, e in sk.topk() if (c - e) >= bar]
         _d["hot_keys"] = len(hot_list)
         if not hot_list:
-            return None, 0.0
+            return None, 0.0, pair_max
         hot = np.sort(np.asarray(hot_list, dtype=np.int64 if wide else np.int32))
         # hot share from the EXACT sample counts (not the sketch
         # estimates): the tail-capacity hint must never overshoot
         hot_share = float(cnts[np.isin(vals, hot)].sum()) / float(sample.size)
         _d["hot_share"] = round(hot_share, 4)
-        return hot, hot_share
+        return hot, hot_share, pair_max
 
 
 def _skew_capacity(m: int, n_shards: int, hot_share: float) -> int:
@@ -881,51 +958,76 @@ def _hot_answers_device(mesh, hot: np.ndarray, prepared: Partitioned):
     return vals, lo, ct
 
 
+def _exchange_capacity(
+    capacity: "int | None", m: int, n_shards: int, pair_max: int,
+    hot_share: "float | None",
+) -> Tuple[int, str]:
+    """The first attempt's slot capacity and where it is from: the
+    caller's if one was given (``caller``); else the power-of-two bucket
+    of the counted ``pair_max`` (``count``: a static shape, one program
+    a bucket, and a capacity that cannot overflow) — unless hot keys
+    were found (*hot_share* not None) and the sketch's tail capacity is
+    smaller (``sketch``): the count holds the hot rows too, so there it
+    is an upper bound that the sketch may shrink, with the retry as its
+    net."""
+    if capacity is not None:
+        return int(capacity), "caller"
+    counted = _pow2(max(64, pair_max))
+    if hot_share is not None:
+        sketch = _skew_capacity(m, n_shards, hot_share)
+        if sketch < counted:
+            return sketch, "sketch"
+    return counted, "count"
+
+
 def _retry_probe_device(
-    mesh: Mesh, m: int, capacity: "int | None", launch, prepared: Partitioned
+    mesh: Mesh, m: int, capacity: int, capacity_from: str, pair_max: int,
+    launch, prepared: Partitioned,
 ):
-    """Shared retry driver for the device wrappers: geometric capacity
-    doubling keyed off ONE overflow boolean per attempt (the only host
-    sync in the loop), results re-committed to the named mesh.
-    *prepared* says what the stage records of the owner's step
-    (``owner_tier``, ``search_rounds``) and how many ``(N, C)`` int32
-    ``all_to_all`` rounds one attempt makes (key lanes out, ``lower``
-    and ``count`` back): ``bytes_exchanged`` is reckoned from shapes.
+    """Shared driver of the device wrappers' exchange.  An attempt at a
+    capacity that holds the counted ``pair_max`` cannot overflow and its
+    overflow flag is not read; that is every attempt whose capacity is
+    the count's.  Under a capacity the count does not guarantee — the
+    sketch's, a caller's — the geometric retry is the net: ONE overflow
+    boolean read per such attempt, the capacity doubled until it holds.
+    Results are re-committed to the named mesh.  *prepared* says what
+    the stage records of the owner's step (``owner_tier``,
+    ``search_rounds``) and how many ``(N, C)`` int32 ``all_to_all``
+    rounds one attempt makes (key lanes out, ``lower`` and ``count``
+    back): ``bytes_exchanged`` is reckoned from shapes.
 
     Returns ``((lo, ct), rows_broadcast, capacity)``: when the launch
-    carries the hot tier (4-tuple results) the broadcast row count
-    rides the same device_get as the overflow flag — still one host
-    round per attempt."""
+    carries the hot tier (4-tuple results) the broadcast row count rides
+    the same device_get as the overflow flag — at most one host round
+    per attempt."""
     from ..utils.observe import telemetry
 
     n_shards = mesh.devices.size
     exchanges = 4 if prepared.wide else 3
-    if capacity is None:
-        capacity = _default_capacity(m, n_shards)
     padded_m = m + ((-m) % n_shards)
     retries = synced = 0
     # the exchange stage covers the whole shard_map launch: all_to_all
     # key shuffle + per-shard local probe + answer return + hot merge
     # (one fused SPMD executable, not separable from outside)
     with telemetry.stage("join:all_to_all", m) as _x:
+        _x["pair_max"] = pair_max
+        _x["capacity_from"] = capacity_from
         while True:
             res = launch(capacity)
-            lo, ct, overflow = res[0], res[1], res[2]
-            if len(res) > 3:
-                ov, hits = jax.device_get((overflow, res[3]))
-                telemetry.count_sync(2)
-                synced += 2
-                overflowed, rows_broadcast = bool(ov), int(hits)
-            else:
-                telemetry.count_sync(1)
-                synced += 1
-                # one O(1) scalar sync per attempt
-                overflowed, rows_broadcast = bool(jax.device_get(overflow)), 0
+            lo, ct = res[0], res[1]
+            guaranteed = pair_max <= capacity
+            reads = res[3:] if guaranteed else res[2:]  # (overflow?, hits?)
+            if reads:
+                reads = jax.device_get(tuple(reads))
+                telemetry.count_sync(len(reads))
+                synced += len(reads)
+            overflowed = not guaranteed and bool(reads[0])
+            rows_broadcast = int(reads[-1]) if len(res) > 3 else 0
             if not overflowed:
                 slots = n_shards * n_shards * capacity  # of the settled attempt, mesh-wide
                 _x["capacity"] = capacity
                 _x["retries"] = retries
-                _x["attempts"] = retries + 1  # one blocking host read each
+                _x["attempts"] = retries + 1  # launches of the exchange
                 _x["host_sync_elements"] = synced
                 _x["slot_fill"] = m / slots
                 _x["bytes_exchanged"] = 4 * exchanges * slots
@@ -945,13 +1047,12 @@ def _retry_probe_device(
 def _note_part_info(info, capacity, hot, rows_broadcast) -> None:
     """Accumulate one partitioned probe's outcome into the multiway
     join's shared *info* dict (the sharded-multiway contract, ISSUE 17):
-    ``capacity`` is the max settled exchange capacity so far — the next
-    dimension's probe seeds its FIRST attempt with it, so similar
-    fanouts pay at most one geometric retry round across ALL dimensions
-    instead of one per dimension — and the hot-routing tallies sum over
-    dimensions (hot keys of EITHER dimension ride the broadcast tier;
-    the tail crosses the exchange once per dimension over the original
-    fact rows, never over a materialized intermediate)."""
+    ``capacity`` is the max settled exchange capacity so far — a record
+    only: every dimension's probe counts its own exchange — and the
+    hot-routing tallies sum over dimensions (hot keys of EITHER
+    dimension ride the broadcast tier; the tail crosses the exchange
+    once per dimension over the original fact rows, never over a
+    materialized intermediate)."""
     if info is None:
         return
     info["capacity"] = max(int(capacity), int(info.get("capacity") or 0))
@@ -970,8 +1071,11 @@ def partitioned_probe_device(
     invalid) stays on device end to end; answers come back as device
     arrays ready for the device fan-out expansion and fused gathers.
 
-    Host syncs per call: one bounded hot-key sample + one O(1) scalar
-    sync per capacity attempt (VERDICT round-2 weak #3).  *label*
+    Host syncs per call: one bounded hot-key sample with the route
+    count beside it (one blocking read; the count sets the capacity,
+    :func:`_exchange_capacity`), the broadcast tier's hit count where
+    hot keys were found, and one overflow boolean per attempt only
+    under a capacity the count does not guarantee.  *label*
     names the probed index in the skew-routing evidence
     (``csvplus_join_*`` counters, ``join:skew`` stage row).  *info*
     accumulates this probe's settled capacity and hot-routing split for
@@ -979,7 +1083,8 @@ def partitioned_probe_device(
     n_shards = mesh.devices.size
     m = int(qk.shape[0])
 
-    hot, hot_share = _detect_hot(qk, n_shards, wide=False)
+    count = _route_count_spmd(mesh, qk, *prepared.splits)
+    hot, hot_share, pair_max = _detect_hot(qk, n_shards, False, count)
     if hot is not None:
         from ..utils.observe import telemetry
 
@@ -991,12 +1096,13 @@ def partitioned_probe_device(
             (hot_vals,), hot_lo, hot_ct = _hot_answers_device(mesh, hot, prepared)
             _b["n_hot"] = n_hot
             telemetry.barrier((hot_vals, hot_lo, hot_ct))
-        if capacity is None:
-            capacity = _skew_capacity(m, n_shards, hot_share)
     else:
         z = jnp.zeros(1, jnp.int32)
         hot_vals = hot_lo = hot_ct = z
         n_hot = 0
+    capacity, capacity_from = _exchange_capacity(
+        capacity, m, n_shards, pair_max, None if hot is None else hot_share
+    )
 
     def launch(cap):
         return _probe_spmd_dev(
@@ -1006,7 +1112,7 @@ def partitioned_probe_device(
         )
 
     out, rows_broadcast, cap_used = _retry_probe_device(
-        mesh, m, capacity, launch, prepared
+        mesh, m, capacity, capacity_from, pair_max, launch, prepared
     )
     if hot is not None:
         _note_skew(
@@ -1031,7 +1137,8 @@ def partitioned_probe_device_wide(
     n_shards = mesh.devices.size
     m = int(q_hi.shape[0])
 
-    hot, hot_share = _detect_hot((q_hi, q_lo), n_shards, wide=True)
+    count = _route_count_spmd2(mesh, q_hi, q_lo, *prepared.splits)
+    hot, hot_share, pair_max = _detect_hot((q_hi, q_lo), n_shards, True, count)
     if hot is not None:
         from ..utils.observe import telemetry
 
@@ -1042,12 +1149,13 @@ def partitioned_probe_device_wide(
             )
             _b["n_hot"] = n_hot
             telemetry.barrier((hot_hi, hot_lo_lane, hot_ans_lo, hot_ans_ct))
-        if capacity is None:
-            capacity = _skew_capacity(m, n_shards, hot_share)
     else:
         z = jnp.zeros(1, jnp.int32)
         hot_hi = hot_lo_lane = hot_ans_lo = hot_ans_ct = z
         n_hot = 0
+    capacity, capacity_from = _exchange_capacity(
+        capacity, m, n_shards, pair_max, None if hot is None else hot_share
+    )
 
     def launch(cap):
         return _probe_spmd_dev2(
@@ -1057,7 +1165,7 @@ def partitioned_probe_device_wide(
         )
 
     out, rows_broadcast, cap_used = _retry_probe_device(
-        mesh, m, capacity, launch, prepared
+        mesh, m, capacity, capacity_from, pair_max, launch, prepared
     )
     if hot is not None:
         _note_skew(

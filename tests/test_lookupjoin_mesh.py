@@ -161,6 +161,28 @@ def stage_extras(stages, name) -> list:
     return [r.extra for r in stages if r.stage == name]
 
 
+def rows_per_owner(corpus: Corpus) -> np.ndarray:
+    """How many orders each of the SHARDS owners is sent, by numpy: an
+    id's packed key is the rank of ``c<id>`` among the people's ids in
+    string order, an owner holds one equal run of those ranks, and an
+    order of a customer who is not among the people goes nowhere."""
+    order = np.argsort(np.arange(N_PEOPLE).astype(str))
+    rank = np.empty(N_PEOPLE, dtype=np.int64)
+    rank[order] = np.arange(N_PEOPLE)
+    present = corpus.cust[corpus.cust < N_PEOPLE]
+    return np.bincount(rank[present] // (N_PEOPLE // SHARDS), minlength=SHARDS)
+
+
+def holds_the_count(exchange: dict, corpus: Corpus) -> None:
+    """``pair_max`` is the fullest (source, owner) pair: at least the
+    fullest owner's rows over the sources (some source holds the mean),
+    at most all of them; a capacity that holds it read no flag."""
+    fullest = int(rows_per_owner(corpus).max())
+    assert -(-fullest // SHARDS) <= exchange["pair_max"] <= fullest
+    if exchange["pair_max"] <= exchange["capacity"]:
+        assert exchange["host_sync_elements"] in (0, 1)  # the broadcast count at most
+
+
 def equals_three_oracles(corpus: Corpus, result) -> None:
     """*result* (the mesh run) against (a) the numpy reference, (b) the
     host executor and (c) the one-device run: the same values everywhere,
@@ -207,6 +229,13 @@ def test_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(seed, tmp_path
     assert len(exchange) == 1
     extra = exchange[0].extra
     assert extra["retries"] == 0 and extra["attempts"] == 1
+    # the capacity is the count's: the pow2 bucket of the fullest pair, near the mean
+    # pair of a uniform stream, half of what twice the mean gave; no flag is read
+    from csvplus_tpu.parallel.pjoin import _default_capacity, _pow2
+
+    holds_the_count(extra, corpus)
+    assert extra["capacity_from"] == "count" and extra["host_sync_elements"] == 0
+    assert extra["capacity"] == _pow2(extra["pair_max"]) == _default_capacity(N_ORDERS, SHARDS) // 2
     assert extra["slot_fill"] == pytest.approx(N_ORDERS / (SHARDS**2 * extra["capacity"]))
     assert extra["bytes_exchanged"] == 3 * 4 * SHARDS**2 * extra["capacity"]
     assert [r for r in stages if r.stage == "join:skew-detect"][0].extra["hot_keys"] == 0
@@ -261,20 +290,21 @@ def test_translation_tables_are_placed_on_the_probe_mesh_once(kind, tmp_path, mo
 
 
 ZIPF_CASES = {
-    # name: (corpus arguments, hot keys at least, capacity as a share of the uniform stream's)
-    "zipf-1.1-seed-11": (dict(seed=11), 1, 1 / 2),
-    "zipf-1.1-seed-2200000027": (dict(seed=2_200_000_027), 1, 1 / 2),
-    "zipf-1.1-seed-4100000123": (dict(seed=4_100_000_123), 1, 1 / 2),
-    "one-key-over-60-percent": (dict(seed=5, heavy=0.62), 1, 1 / 4),  # the capacity shrinks twice
-    "hot-key-absent-from-the-build-side": (dict(seed=6, absent=True), 0, None),
+    # name: (corpus arguments, hot keys at least, whose capacity settles: the count holds the
+    # hot rows too, so the sketch's tail capacity is the smaller wherever a key is hot)
+    "zipf-1.1-seed-11": (dict(seed=11), 1, "sketch"),
+    "zipf-1.1-seed-2200000027": (dict(seed=2_200_000_027), 1, "sketch"),
+    "zipf-1.1-seed-4100000123": (dict(seed=4_100_000_123), 1, "sketch"),
+    "one-key-over-60-percent": (dict(seed=5, heavy=0.62), 1, "sketch"),  # an eighth of the count's
+    "hot-key-absent-from-the-build-side": (dict(seed=6, absent=True), 0, "count"),
 }
 
 
 @pytest.mark.parametrize("case", sorted(ZIPF_CASES))
 def test_skewed_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(case, tmp_path):
-    from csvplus_tpu.parallel.pjoin import _default_capacity
+    from csvplus_tpu.parallel.pjoin import _pow2, _skew_capacity
 
-    kwargs, hot_at_least, capacity_share = ZIPF_CASES[case]
+    kwargs, hot_at_least, capacity_from = ZIPF_CASES[case]
     corpus = ZipfCorpus(root=tmp_path, **kwargs)
     fact, result, stages, build, watch = lookup_join(corpus, SHARDS)
 
@@ -297,13 +327,20 @@ def test_skewed_lookup_join_on_the_mesh_equals_numpy_host_and_one_device(case, t
         assert skew["rows_broadcast"] == hot_rows
         assert skew["rows_repartitioned"] == N_ORDERS - hot_rows
         assert skew["capacity"] == exchange["capacity"]
-        assert exchange["host_sync_elements"] == 2 * exchange["attempts"]
+        # the broadcast count, and with it the overflow flag unless the count guarantees the capacity
+        guaranteed = exchange["pair_max"] <= exchange["capacity"]
+        assert exchange["host_sync_elements"] == (1 if guaranteed else 2) * exchange["attempts"]
     else:
         assert not stage_extras(stages, "join:broadcast") and not stage_extras(stages, "join:skew")
-    if capacity_share is not None:
-        # the settled capacity is the sketch's, and it held: no retry
-        assert exchange["retries"] == 0
-        assert exchange["capacity"] == capacity_share * _default_capacity(N_ORDERS, SHARDS)
+    # the first attempt's capacity held: the counted bucket, or the sketch's where it is smaller
+    holds_the_count(exchange, corpus)
+    counted = _pow2(exchange["pair_max"])
+    sketch = _skew_capacity(N_ORDERS, SHARDS, detect["hot_share"]) if detect["hot_keys"] else counted
+    assert exchange["retries"] == 0 and exchange["attempts"] == 1
+    assert exchange["capacity"] == min(counted, sketch)
+    assert exchange["capacity_from"] == capacity_from == ("sketch" if sketch < counted else "count")
+    if case == "one-key-over-60-percent":  # one source sends the key's owner 62% of its rows
+        assert exchange["capacity"] == counted // 8
     assert exchange["slot_fill"] == pytest.approx(N_ORDERS / (SHARDS**2 * exchange["capacity"]))
     assert exchange["owner_tier"] == "positional"
     watch.assert_zero()  # the second execution lowered nothing, the hot answers' program included

@@ -750,7 +750,9 @@ def test_partitioned_probe_device_hot_keys_one_attempt(mesh, monkeypatch):
 def test_partitioned_join_sync_telemetry(people_csv, orders_csv, monkeypatch):
     """VERDICT round-2 #2's done criterion: a mesh-sharded filter->join
     pipeline through the partitioned path syncs only the hot-key sample
-    and O(1) overflow scalars — counted at the actual device_get sites."""
+    and the exchange's route count beside it — counted at the actual
+    device_get sites.  The capacity is the count's, so the attempt
+    cannot overflow and no overflow scalar is read at all."""
     import csvplus_tpu.ops.join as J
     from csvplus_tpu import Like, Not, Take, from_file
     from csvplus_tpu.utils.observe import telemetry
@@ -778,9 +780,138 @@ def test_partitioned_join_sync_telemetry(people_csv, orders_csv, monkeypatch):
         synced = telemetry.host_sync_elements
     assert dev_rows == host_rows
     assert any(r.stage == "Join" for r in records)
-    # hot-key sample (<=4096) + a handful of overflow scalars; an O(n)
-    # sync of the 10_000-row probe would trip this bound
-    assert 0 < synced <= 4096 + 16
+    (detect,) = [r.extra for r in records if r.stage == "join:skew-detect"]
+    (exchange,) = [r.extra for r in records if r.stage == "join:all_to_all"]
+    # hot-key sample (<=4096) + the route count, one read; an O(n) sync
+    # of the 10_000-row probe would trip this bound
+    assert detect["sample"] + 1 <= detect["host_sync_elements"] <= 4096 + 1
+    assert detect["host_sync_elements"] <= synced <= 4096 + 16
+    assert exchange["capacity_from"] == "count" and exchange["pair_max"] <= exchange["capacity"]
+    assert exchange["attempts"] == 1 and exchange["host_sync_elements"] == 0
+
+
+# -- the exchange's capacity is counted (ISSUE 40) -------------------------
+
+
+def _owner_skewed_queries(rng, per_owner_keys: int, rows_per_shard: int, to_first: int):
+    """8 source shards of *rows_per_shard* probes each over the dense keys
+    ``0 .. 8 * per_owner_keys``: every source sends owner 0 exactly
+    *to_first* rows and spreads the rest evenly over the other seven,
+    keys uniform within an owner's run — skew of OWNERS, no key anywhere
+    near the hot bar."""
+    shards = []
+    for _ in range(8):
+        rest = rows_per_shard - to_first
+        sizes = [to_first] + [rest // 7 + (o < rest % 7) for o in range(7)]
+        rows = np.concatenate(
+            [o * per_owner_keys + rng.integers(0, per_owner_keys, n) for o, n in enumerate(sizes)]
+        )
+        rng.shuffle(rows)
+        shards.append(rows)
+    return np.concatenate(shards).astype(np.int32)
+
+
+def _count_case(name: str):
+    """(sorted build keys, probe keys, what the exchange must say)."""
+    rng = np.random.default_rng(40)
+    dense = np.arange(8000, dtype=np.int32)  # owner o holds [1000 o, 1000 (o + 1))
+    if name == "uniform":
+        keys = np.sort(rng.integers(0, 5000, size=20_000).astype(np.int32))
+        q = rng.integers(-50, 6000, size=16_384).astype(np.int32)
+        q[q < 0] = -1
+        return keys, q, dict(capacity_from="count")
+    if name == "fullest-pair-1.75x-the-mean":  # mean pair 256: twice the mean held it too
+        return dense, _owner_skewed_queries(rng, 1000, 2048, 448), dict(
+            capacity_from="count", pair_max=448, capacity=512
+        )
+    if name == "fullest-pair-2.5x-the-mean":  # twice the mean (512) overflowed, then retried
+        return dense, _owner_skewed_queries(rng, 1000, 2048, 640), dict(
+            capacity_from="count", pair_max=640, capacity=1024
+        )
+    if name in ("one-key-over-60-percent", "one-key-over-60-percent-skew-off"):
+        q = rng.integers(0, 8000, size=16_384).astype(np.int32)
+        q[rng.random(q.size) < 0.62] = 4321
+        off = name.endswith("skew-off")
+        return dense, q, dict(capacity_from="count" if off else "sketch")
+    if name == "all-probes-invalid":
+        return dense, np.full(4096, -1, np.int32), dict(
+            capacity_from="count", pair_max=0, capacity=64
+        )
+    if name == "rows-not-a-multiple-of-the-mesh":
+        return dense, rng.integers(0, 8000, size=16_381).astype(np.int32), dict(capacity_from="count")
+    assert name == "wide-keys"
+    keys = np.sort(rng.integers(0, 1 << 40, size=6000).astype(np.int64))
+    q = keys[rng.integers(0, 6000, size=8192)].copy()
+    q[::7] = -1
+    q[1::7] += 1  # mostly misses, routed all the same
+    return keys, q, dict(capacity_from="count")
+
+
+@pytest.mark.parametrize("mesh_kind", ["1d-8", "2d-2x4"])
+@pytest.mark.parametrize(
+    "case",
+    [
+        "uniform",
+        "fullest-pair-1.75x-the-mean",
+        "fullest-pair-2.5x-the-mean",
+        "one-key-over-60-percent",
+        "one-key-over-60-percent-skew-off",
+        "all-probes-invalid",
+        "rows-not-a-multiple-of-the-mesh",
+        "wide-keys",
+    ],
+)
+def test_exchange_capacity_is_counted(case, mesh_kind, monkeypatch):
+    """The capacity of the ``(N, C)`` slot buffers comes from a count of
+    the exchange itself: ``pair_max`` equals a numpy count of the same
+    routing, its pow2 bucket settles the FIRST attempt with no overflow
+    flag read (where twice the mean overflowed and retried), the sketch
+    shrinks it only where a key is hot, and the answers are the host
+    oracle's."""
+    import csvplus_tpu.parallel.pjoin as PJ
+    from csvplus_tpu.parallel.mesh import make_mesh_2d
+    from csvplus_tpu.utils.observe import telemetry
+
+    mesh = make_mesh(8) if mesh_kind == "1d-8" else make_mesh_2d(2, 4)
+    keys, q, want = _count_case(case)
+    if case.endswith("skew-off"):
+        monkeypatch.setenv("CSVPLUS_JOIN_SKEW", "0")
+
+    with telemetry.collect() as records:
+        lo, ct = partitioned_probe(mesh, q, keys)
+        synced = telemetry.host_sync_elements
+    (x,) = [r.extra for r in records if r.stage == "join:all_to_all"]
+    detect = [r.extra for r in records if r.stage == "join:skew-detect"]
+
+    # the count, by numpy: route as the exchange does, shard as it does
+    splits = partition_build_keys(keys, 8)[3]
+    dest = np.clip(np.searchsorted(splits, q, side="right") - 1, 0, 7)
+    dest = np.concatenate([np.where(q >= 0, dest, 8), np.full((-q.size) % 8, 8)])
+    pair_max = max(int(np.bincount(rows, minlength=9)[:8].max()) for rows in dest.reshape(8, -1))
+    assert x["pair_max"] == pair_max == want.get("pair_max", pair_max)
+
+    counted = PJ._pow2(max(64, pair_max))
+    assert x["capacity_from"] == want["capacity_from"]
+    assert x["attempts"] == 1 and x["retries"] == 0
+    if want["capacity_from"] == "count":
+        assert x["capacity"] == counted == want.get("capacity", counted)
+        assert x["host_sync_elements"] == 0  # guaranteed: the flag is not read
+        assert not any(r.stage in ("join:broadcast", "join:skew") for r in records)
+    else:  # the count holds the hot rows: the sketch's tail capacity is the smaller
+        assert x["capacity"] == PJ._skew_capacity(q.size, 8, detect[0]["hot_share"]) < counted
+        assert detect[0]["hot_keys"] == 1 and x["host_sync_elements"] == 2
+    lanes = 2 if keys.dtype == np.int64 else 1
+    if detect:  # the count rides the sample's read
+        assert detect[0]["host_sync_elements"] == lanes * -(-q.size // max(1, -(-q.size // 4096))) + 1
+        assert synced == detect[0]["host_sync_elements"] + x["host_sync_elements"]
+    else:  # CSVPLUS_JOIN_SKEW=0: read alone
+        assert synced == 1
+
+    olo = np.searchsorted(keys, q, side="left")
+    oct_ = np.searchsorted(keys, q, side="right") - olo
+    oct_[q < 0] = 0
+    assert (ct == oct_).all()
+    assert (lo[ct > 0] == olo[ct > 0]).all()
 
 
 # -- distributed sample-sort (explicit all_to_all scale-out path) ---------

@@ -241,3 +241,10 @@ def test_the_positional_program_has_no_loop_over_the_received_slots(mesh, monkey
     assert _whiles_over(texts["positional"], N * capacity) == 0
     for text in texts.values():
         assert len(re.findall(r"stablehlo\.all_to_all\b", text)) == 3
+    # routing is N - 1 compares (``_route_dest``), in the exchange and in the
+    # count that sizes it: no loop anywhere in either program, and the count
+    # exchanges nothing
+    count = PJ._route_count_spmd.lower(mesh, qk, *p.splits).as_text()
+    for text in (texts["positional"], count):
+        assert not re.search(r"stablehlo\.while\b", text)
+    assert not re.search(r"stablehlo\.all_to_all\b", count)

@@ -10,12 +10,14 @@ The contract under test (pjoin.py module docstring, "Skew (ISSUE 15)"):
   the unsharded reference AND to the CSVPLUS_JOIN_SKEW=0 run — the
   "salt" is the existing row placement and the positional scatter-back
   at emit folds it out;
-* uniform data is a pure passthrough: n_hot=0, default capacity, the
-  exact executables the pre-skew path compiled, no skew stages;
+* uniform data is a pure passthrough: n_hot=0, the capacity counted
+  from the exchange itself (the pow2 bucket of its fullest pair), the
+  no-hot executable, no skew stages;
 * warm re-executions recompile nothing (RecompileWatch over the
   registered pjoin.* kernels).
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -165,9 +167,9 @@ def test_heavy_key_absent_on_build_side(monkeypatch, mesh):
 
 
 def test_uniform_stream_is_pure_passthrough(monkeypatch, mesh):
-    """Uniform keys: no hot tier (n_hot=0), the DEFAULT capacity, and no
-    skew stages — i.e. the probe launches the exact executables the
-    pre-skew path compiled."""
+    """Uniform keys: no hot tier (n_hot=0), the COUNTED capacity — the
+    pow2 bucket of the fullest (source, owner) pair, settled on the
+    first attempt with no overflow flag read — and no skew stages."""
     monkeypatch.setattr(J.DeviceIndex, "PARTITION_MIN_KEYS", 1)
     n_rows, n_keys = 16_000, 2_000
     rng = np.random.default_rng(31)
@@ -186,9 +188,13 @@ def test_uniform_stream_is_pure_passthrough(monkeypatch, mesh):
     with telemetry.collect() as records:
         source_from_table(table.with_sharding(mesh)).join(idx, "k").to_rows()
     assert seen, "partition tier did not engage"
-    for n_hot, capacity, m in seen:
+    exchanges = [r.extra for r in records if r.stage == "join:all_to_all"]
+    assert len(exchanges) == len(seen)  # one launch an exchange: no retry
+    for (n_hot, capacity, m), x in zip(seen, exchanges):
         assert n_hot == 0
-        assert capacity == PJ._default_capacity(m, 8)
+        assert m / 64 < x["pair_max"] < 2 * m / 64  # uniform: near the mean pair
+        assert capacity == x["capacity"] == PJ._pow2(max(64, x["pair_max"]))
+        assert x["capacity_from"] == "count" and x["host_sync_elements"] == 0
     stages = {r.stage for r in records}
     assert "join:broadcast" not in stages
     assert "join:skew" not in stages
@@ -341,23 +347,24 @@ def test_detect_hot_sound_predicate(monkeypatch, mesh):
     rng.shuffle(qk)
     qk_dev = shard_rows(mesh, qk)
 
-    hot, share = PJ._detect_hot(qk_dev, 8, wide=False)
+    count = jnp.int32(4321)  # the route count's scalar rides every read
+    hot, share, pair_max = PJ._detect_hot(qk_dev, 8, False, count)
     assert hot is not None and 777 in hot.tolist()
-    assert 0.2 < share < 0.45
+    assert 0.2 < share < 0.45 and pair_max == 4321
 
     monkeypatch.setenv("CSVPLUS_JOIN_SKEW_THRESHOLD", "0.8")
-    hot2, _ = PJ._detect_hot(qk_dev, 8, wide=False)
-    assert hot2 is None
+    hot2, _, pair_max = PJ._detect_hot(qk_dev, 8, False, count)
+    assert hot2 is None and pair_max == 4321
 
     monkeypatch.delenv("CSVPLUS_JOIN_SKEW_THRESHOLD")
     monkeypatch.setenv("CSVPLUS_JOIN_SKEW", "0")
-    hot3, _ = PJ._detect_hot(qk_dev, 8, wide=False)
-    assert hot3 is None
+    hot3, _, pair_max = PJ._detect_hot(qk_dev, 8, False, count)
+    assert hot3 is None and pair_max == 4321  # read alone
 
     monkeypatch.delenv("CSVPLUS_JOIN_SKEW")
     neg = np.full(m, -1, np.int32)  # all never-match: nothing to detect
-    hot4, _ = PJ._detect_hot(shard_rows(mesh, neg), 8, wide=False)
-    assert hot4 is None
+    hot4, _, pair_max = PJ._detect_hot(shard_rows(mesh, neg), 8, False, count)
+    assert hot4 is None and pair_max == 4321
 
 
 def test_skew_capacity_bounds():
