@@ -21,7 +21,7 @@
     comparison (which form the rewriter chooses and why), and the
     rewrite decision for the named example chains (all of them with no
     names; ``--list`` prints the names) — the same fixed-width-table
-    CLI shape as ``obs diff``.  Unknown names exit 2.
+    CLI shape as ``python -m csvplus_tpu.obs``.  Unknown names exit 2.
 
 ``python -m csvplus_tpu.analysis lint [--json] [paths...]``
     Explicit lint entry point: same behavior as the bare invocation but

@@ -294,7 +294,7 @@ def _colset(v) -> str:
 def explain_text(name: str, root) -> str:
     """Human-readable per-node provenance/cost/placement tables for one
     plan — the ``explain`` CLI's default output (same fixed-width table
-    idiom as ``obs diff``)."""
+    idiom as the ``obs`` CLI)."""
     d = plan_analysis_json(root)
     lines = [
         f"explain: {name}",

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import os
 import time
+from collections import Counter
 
 from ..source import DataSource
 from .table import DeviceTable
@@ -59,7 +60,34 @@ def reader_to_device(
 
     ``shards=N`` (or an explicit ``mesh``) lays the columns row-sharded
     over a 1-D device mesh so the whole downstream pipeline runs SPMD.
+
+    The whole of it is one ``ingest`` milestone (``obs/span.py``): in the
+    process journal where no trace is open, with the tier's stages
+    beneath it and the tier, bytes, rows and columns by kind on it.
     """
+    from ..obs.span import tracer
+
+    with tracer.milestone("ingest") as at:
+        src = _reader_to_source(reader, device, shards, mesh, opts, at)
+        table = src.plan.table
+        kinds = Counter(getattr(c, "kind", "str") for c in table.columns.values())
+        at.update(
+            rows=int(table.nrows),
+            columns=",".join(f"{k}:{n}" for k, n in sorted(kinds.items())),
+            shards=int(mesh.devices.size) if mesh is not None else int(shards or 1),
+        )
+        path = getattr(reader, "_path", None)
+        if path is not None:
+            try:
+                at["bytes"] = os.path.getsize(path)
+            except OSError:
+                pass
+    return src
+
+
+def _reader_to_source(reader, device, shards, mesh, opts, at) -> DataSource:
+    """The tiers, first that takes the file; *at* (the milestone's attrs)
+    is told which (``tier``)."""
     from ..utils.observe import telemetry
 
     # source row number of data record 0, matching the host Reader's
@@ -86,6 +114,7 @@ def reader_to_device(
                     table = _stream_to_table(reader, path, device, mesh=mesh)
                     table.row_base = row_base
                     _t["rows_out"] = table.nrows
+                    at["tier"] = "streamed"
                 return source_from_table(_maybe_shard(table, shards, mesh))
             except (ImportError, StreamFallback):
                 pass
@@ -103,6 +132,7 @@ def reader_to_device(
                     )
                     table.row_base = row_base
                     _t["rows_out"] = nrows
+                    at["tier"] = "device-parsed"
                 else:
                     _t["discard"] = True
             if enc is not None:
@@ -123,6 +153,7 @@ def reader_to_device(
                     )
                     table.row_base = row_base
                     _t["rows_out"] = nrows
+                    at["tier"] = "native-encoded"
                 else:
                     _t["discard"] = True  # tier declined; python tier records
             if enc is not None:
@@ -134,6 +165,7 @@ def reader_to_device(
         table = DeviceTable.from_pylists({n: data[n] for n in names}, device=device)
         table.row_base = row_base
         _t["rows_out"] = table.nrows
+        at["tier"] = "python"
     return source_from_table(_maybe_shard(table, shards, mesh))
 
 
@@ -244,6 +276,10 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
     max_width: "dict[str, int]" = {}
     host_only: "dict[str, bool]" = {}  # width > lane cap: never switch
     nrows = 0
+    # seconds of host dictionary work, recorded once at the end like the
+    # other totals: the running union kept per chunk (inside "place"), and
+    # the last np.unique + searchsorted remap
+    t_dict = t_union = 0.0
 
     def _to_lanes(d: "np.ndarray") -> tuple:
         lanes = lanes_for_width(max_width[c])
@@ -269,6 +305,7 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
         upload) — shared by the normal path and typed-chunk demotion.
         *tgt* is the device this chunk's codes live on (the chunk's
         shard under a mesh, the single ingest device otherwise)."""
+        nonlocal t_dict
         max_width[c] = max(max_width[c], d.dtype.itemsize)
         if max_width[c] > 32:  # past the lane cap (ops/lanes.py)
             host_only[c] = True
@@ -286,8 +323,10 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
             if ru is None:
                 running_union[c] = d
             else:
+                _t0 = time.perf_counter()
                 dt = np.dtype(f"S{max_width[c]}")
                 running_union[c] = np.union1d(ru.astype(dt), d.astype(dt))
+                t_dict += time.perf_counter() - _t0
         if isinstance(codes, np.ndarray):
             # narrow the upload to the smallest dtype the chunk's
             # dictionary needs (codes are nonnegative slot numbers):
@@ -455,6 +494,7 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
         prefetch=prefetch_depth,
     )
     telemetry.add_stage("ingest:place", nrows, nrows, t_place)
+    telemetry.add_stage("ingest:dictionary", nrows, nrows, t_dict)
 
     if shard_devs is not None:
         # seal the last shard, then stitch: with every shard already one
@@ -523,6 +563,7 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
                 only = only.astype(jnp.int32)
             out[c] = (dicts[0], only)
             continue
+        _t0 = _pc()
         width = max(d.dtype.itemsize for d in dicts)
         dt = np.dtype(f"S{width}")
         union = np.unique(np.concatenate([d.astype(dt) for d in dicts]))
@@ -530,9 +571,11 @@ def _stream_to_table(reader, path: str, device, mesh=None) -> DeviceTable:
             jax.device_put(np.searchsorted(union, d.astype(dt)).astype(np.int32), dev)
             for d in dicts
         ]
+        t_union += _pc() - _t0
         # all chunks remap + concatenate in ONE jit call: eager, each
         # chunk shape would compile its own take and materialize twice
         out[c] = (union, _remap_concat(mappings, codes))
+    telemetry.add_stage("ingest:union", nrows, nrows, t_union)
     return DeviceTable.from_encoded(out, nrows, device=dev)
 
 
@@ -543,11 +586,15 @@ def _prefetch_iter(gen, depth: int):
     DataSourceError, ...) re-raise in the consumer at the position they
     occurred; abandoning the iterator stops the producer promptly so a
     fallback path cannot leak a thread pinning chunk memory."""
+    from ..obs.span import tracer
     from ..utils.relay import relay_iter
 
+    ctx = tracer.capture()  # the producer's closing stage totals join our tree
+
     def run(emit) -> None:
-        for item in gen:
-            emit(item)
+        with tracer.adopt(ctx):
+            for item in gen:
+                emit(item)
 
     return relay_iter(run, maxsize=depth)
 
@@ -726,6 +773,7 @@ def _finalize_sharded(
     from .typed import IntColumn
 
     out = {}
+    t_union = 0.0  # the host's np.unique + searchsorted of dictionary columns
     with telemetry.stage("ingest:shard-assemble", nrows) as _t:
         _t["n_shards"] = len(shard_devs)
         _t["max_shard_rows"] = -(-nrows // len(shard_devs))
@@ -773,24 +821,21 @@ def _finalize_sharded(
                     _assemble_rows_sharded(mesh, shard_devs, arrs, nrows, -2),
                 )
                 continue
+            _t0 = time.perf_counter()
             width = max(d.dtype.itemsize for d in dicts)
             dt = np.dtype(f"S{width}")
             union = np.unique(np.concatenate([d.astype(dt) for d in dicts]))
+            maps = [np.searchsorted(union, d.astype(dt)).astype(np.int32) for d in dicts]
+            t_union += time.perf_counter() - _t0
             # remap each chunk ON ITS SHARD (the mapping table is tiny)
             arrs = [
-                jnp.take(
-                    jax.device_put(
-                        np.searchsorted(union, d.astype(dt)).astype(np.int32),
-                        ck.device,
-                    ),
-                    ck.astype(jnp.int32),
-                    axis=0,
-                )
-                for d, ck in zip(dicts, codes)
+                jnp.take(jax.device_put(m, ck.device), ck.astype(jnp.int32), axis=0)
+                for m, ck in zip(maps, codes)
             ]
             out[c] = StringColumn(
                 union, _assemble_rows_sharded(mesh, shard_devs, arrs, nrows, -2)
             )
+        telemetry.add_stage("ingest:union", nrows, nrows, t_union)
     table = DeviceTable(out, nrows, shard_devs[0])
     table._pre_sharded = True
     _trim_host_staging()

@@ -230,10 +230,15 @@ class StringColumn:
         if self._dictionary is None:
             from ..ops.lanes import unpack_host
 
-            self._ensure_sorted_lanes()
-            self._dictionary = unpack_host(
-                [np.asarray(l) for l in self._lane_state.lanes]
-            )
+            # once per column: a milestone of the process journal
+            # (``obs/span.py``) — the deferred sort, then the download
+            with tracer.milestone(
+                "lane-dict:materialize", entries=int(self._lane_state.lanes[0].shape[0])
+            ):
+                self._ensure_sorted_lanes()
+                self._dictionary = unpack_host(
+                    [np.asarray(l) for l in self._lane_state.lanes]
+                )
         return self._dictionary
 
     def _ensure_sorted_lanes(self) -> None:

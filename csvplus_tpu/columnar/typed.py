@@ -188,35 +188,49 @@ class IntColumn:
             from ..utils.observe import telemetry
             from .table import StringColumn
 
-            with telemetry.stage("typed:demote", int(self.values.shape[0])):
-                u = jnp.unique(self.values)  # device sort+dedup
-                uu = np.asarray(u)
+            n = int(self.values.shape[0])
+            with telemetry.stage("typed:demote", n) as st:
+                # the four parts, each a stage: the device's sort + dedup
+                # and the download of the unique values; the host's
+                # format; its lex argsort; the per-row remap (a search of
+                # every row in the unique values, then one gather)
+                with telemetry.stage("typed:demote:unique", n) as sub:
+                    u = jnp.unique(self.values)  # device sort+dedup
+                    uu = np.asarray(u)
+                    sub["rows_out"] = int(uu.size)
                 # sharding pads (PAD_VALUE sorts first) never enter the
                 # dictionary; their rows code as -2 below
                 has_pad = bool(uu.size) and uu[0] == PAD_VALUE
                 if has_pad:
                     uu = uu[1:]
                     u = u[1:]
-                strs = self._format_host(uu)
-                order = np.argsort(strs, kind="stable")  # numeric -> lex
-                dictionary = strs[order]
-                if uu.size == 0:  # empty (or all-pad) column
-                    codes = jnp.full(
-                        self.values.shape, -2 if has_pad else -1, jnp.int32
-                    )
-                else:
-                    code_of = np.empty(uu.shape[0], dtype=np.int32)
-                    code_of[order] = np.arange(uu.shape[0], dtype=np.int32)
-                    # numeric rank per row, then numeric-slot -> lex code
-                    pos = jnp.searchsorted(u, self.values)
-                    pos = jnp.minimum(pos, int(uu.shape[0]) - 1)
-                    codes = jnp.take(jax.device_put(code_of), pos, axis=0)
-                    if has_pad:
-                        codes = jnp.where(
-                            self.values == jnp.int32(PAD_VALUE),
-                            jnp.int32(-2),
-                            codes,
+                st["entries"] = int(uu.size)
+                with telemetry.stage("typed:demote:format", int(uu.size)):
+                    strs = self._format_host(uu)
+                with telemetry.stage("typed:demote:order", int(uu.size)):
+                    order = np.argsort(strs, kind="stable")  # numeric -> lex
+                    dictionary = strs[order]
+                with telemetry.stage("typed:demote:remap", n):
+                    if uu.size == 0:  # empty (or all-pad) column
+                        codes = jnp.full(
+                            self.values.shape, -2 if has_pad else -1, jnp.int32
                         )
+                    else:
+                        code_of = np.empty(uu.shape[0], dtype=np.int32)
+                        code_of[order] = np.arange(uu.shape[0], dtype=np.int32)
+                        # numeric rank per row, then numeric-slot -> lex code
+                        pos = jnp.searchsorted(u, self.values)
+                        pos = jnp.minimum(pos, int(uu.shape[0]) - 1)
+                        codes = jnp.take(jax.device_put(code_of), pos, axis=0)
+                        if has_pad:
+                            codes = jnp.where(
+                                self.values == jnp.int32(PAD_VALUE),
+                                jnp.int32(-2),
+                                codes,
+                            )
+                    # collecting, the search's device time lands here and
+                    # not under whichever stage first reads the codes
+                    telemetry.barrier(codes)
                 self._demoted = StringColumn(
                     dictionary, codes, _has_absent=False if not has_pad else None
                 )
@@ -342,8 +356,15 @@ class IntColumn:
             cache = other._affix_trans_cache = {}
         hit = cache.get(self.prefix)
         if hit is None:
-            cand, vals = parse_affix_dictionary(other.dictionary, self.prefix)
-            hit = cache[self.prefix] = self._build_translation(vals, cand)
+            from ..utils.observe import telemetry
+
+            entries = int(other.dictionary.shape[0])
+            with telemetry.stage("typed:parse-dictionary", entries) as st:
+                cand, vals = parse_affix_dictionary(other.dictionary, self.prefix)
+                st.update(entries=entries, rows_out=int(cand.size))
+            with telemetry.stage("typed:build-translation", int(cand.size)) as st:
+                hit = cache[self.prefix] = self._build_translation(vals, cand)
+                st.update(entries=int(cand.size), tier=hit[0])
         sh = getattr(self.values, "sharding", None)
         mesh = getattr(sh, "mesh", None)
         if mesh is None or len(sh.device_set) <= 1:

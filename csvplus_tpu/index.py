@@ -754,19 +754,24 @@ def _validate_index_columns(columns: Sequence[str]) -> Tuple[str, ...]:
     return columns
 
 
-def create_index(src, columns: Sequence[str]) -> Index:
+def create_index(src, columns: Sequence[str], *, milestone: bool = True) -> Index:
     """Materialize and sort an index (csvplus.go:707-738).
 
     A device-planned source builds the index entirely on device: fused
-    multi-key ``lax.sort`` over dictionary codes, no host rows.
+    multi-key ``lax.sort`` over dictionary codes, no host rows.  That
+    build is an ``index:build`` milestone (``obs/span.py``) unless the
+    caller says it is no once-per-object work (*milestone* false: the
+    storage tier's delta of every append, which would crowd the process
+    journal).
     """
     columns = _validate_index_columns(columns)
 
     if getattr(src, "plan", None) is not None:
         from .columnar.exec import UnsupportedPlan
 
+        build = _create_index_device if milestone else _build_index_device
         try:
-            return _create_index_device(src.plan, columns)
+            return build(src.plan, columns)
         except UnsupportedPlan:
             pass  # fall through to the host build
 
@@ -786,6 +791,16 @@ def create_index(src, columns: Sequence[str]) -> Index:
 
 
 def _create_index_device(plan, columns: Tuple[str, ...]) -> Index:
+    """The device build, one ``index:build`` milestone (``obs/span.py``):
+    in the process journal where no trace is open, the ``index:*`` stages
+    (and a first build's ``typed:demote``) beneath it."""
+    with tracer.milestone("index:build", keys=",".join(columns)) as at:
+        index = _build_index_device(plan, columns)
+        at["rows"] = len(index)
+    return index
+
+
+def _build_index_device(plan, columns: Tuple[str, ...]) -> Index:
     from .columnar.exec import execute_plan_view
     from .ops.join import DeviceIndex
     from .ops.sort import sort_table

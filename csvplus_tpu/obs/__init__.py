@@ -4,15 +4,15 @@ What grew out of ``utils/observe.py``'s 211-line helper once every hard
 diagnosis (r05 warm join, r06 mesh RSS) turned out to need it:
 
 * :mod:`~csvplus_tpu.obs.span` — hierarchical per-query spans with
-  ``contextvars`` trace isolation (:data:`tracer`);
+  ``contextvars`` trace isolation (:data:`tracer`), and beside the
+  traces the process journal of once-per-object work
+  (``tracer.milestone``, ``tracer.journal``);
 * :mod:`~csvplus_tpu.obs.export` — Chrome-trace/Perfetto JSON +
-  span JSON-lines exporters and the trace-smoke schema validator;
+  span JSON-lines exporters and the Chrome-trace schema validator;
 * :mod:`~csvplus_tpu.obs.recompile` — jit-lowering accounting for the
   registered module-level kernels (:class:`RecompileWatch`);
-* :mod:`~csvplus_tpu.obs.memory` — RSS/device-memory watermark
-  sampling attachable to any span, plus the bench-artifact host header;
-* :mod:`~csvplus_tpu.obs.diff` — the stage-table regression
-  differ behind ``python -m csvplus_tpu.obs diff``;
+* :mod:`~csvplus_tpu.obs.memory` — RSS and device-memory probes, plus
+  the bench-artifact host header;
 * :mod:`~csvplus_tpu.obs.metrics` — the production telemetry plane
   (ISSUE 13): typed metric registry, Prometheus text exposition +
   optional HTTP endpoint, the JSONL metrics pump, tail-sampled request
@@ -29,11 +29,6 @@ machinery: ``telemetry.stage()`` opens a span whenever a trace is
 active in the calling context.
 """
 
-from .diff import (
-    diff_files,
-    diff_stage_tables,
-    load_stage_table,
-)
 from .flight import FlightRecorder, recorder
 from .metrics import (
     Counter,
@@ -56,12 +51,10 @@ from .export import (
     write_spans_jsonl,
 )
 from .memory import (
-    MemoryWatermark,
     device_memory_stats,
     host_header,
     peak_rss_mb,
     rss_mb,
-    watch_memory,
 )
 from .recompile import (
     RecompileWatch,
@@ -83,19 +76,14 @@ __all__ = [
     "validate_chrome_trace",
     "write_chrome_trace",
     "write_spans_jsonl",
-    "MemoryWatermark",
     "device_memory_stats",
     "host_header",
     "peak_rss_mb",
     "rss_mb",
-    "watch_memory",
     "RecompileWatch",
     "compile_counts",
     "register_kernel",
     "registered_kernels",
-    "diff_files",
-    "diff_stage_tables",
-    "load_stage_table",
     "FlightRecorder",
     "recorder",
     "Counter",

@@ -1,12 +1,5 @@
 """CLI for the observability subsystem.
 
-``python -m csvplus_tpu.obs diff A.json B.json
-[--threshold N] [--min-share 0.005] [--key stage_table] [--json]
-[--fail-on-flag]``
-    Diff the stage tables two artifacts embed (the r05->r06 warm-join
-    diagnosis as a command).  ``--fail-on-flag`` exits 2 when anything
-    is flagged; load/shape errors (no stage table among them) exit 1.
-
 ``python -m csvplus_tpu.obs skew ARTIFACT.json [--top N] [--side
 probe|build] [--json]``
     Render the heavy-hitter report from an artifact carrying sketch
@@ -22,22 +15,7 @@ import json
 import sys
 from typing import Any, Dict, List, Tuple
 
-from .diff import DEFAULT_MIN_SHARE, DEFAULT_THRESHOLD, diff_files, format_diff
 from .sketch import skew_report
-
-
-def _run_diff(args) -> int:
-    result = diff_files(
-        args.artifact_a, args.artifact_b,
-        threshold=args.threshold, min_share=args.min_share, key=args.key,
-    )
-    if args.json:
-        print(json.dumps(result))
-    else:
-        print(format_diff(result, args.artifact_a, args.artifact_b))
-    if args.fail_on_flag and result["flagged"]:
-        return 2
-    return 0
 
 
 def _find_sketches(obj: Any) -> List[Tuple[str, Dict[str, Any]]]:
@@ -95,19 +73,6 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="python -m csvplus_tpu.obs")
     sub = parser.add_subparsers(dest="cmd", required=True)
 
-    d = sub.add_parser("diff", help="diff two artifacts' stage tables")
-    d.add_argument("artifact_a")
-    d.add_argument("artifact_b")
-    d.add_argument("--threshold", type=float, default=DEFAULT_THRESHOLD)
-    d.add_argument("--min-share", type=float, default=DEFAULT_MIN_SHARE)
-    d.add_argument("--key", default=None, help="artifact key holding the table")
-    d.add_argument("--json", action="store_true", help="machine output")
-    d.add_argument(
-        "--fail-on-flag",
-        action="store_true",
-        help="exit 2 when anything is flagged",
-    )
-
     s = sub.add_parser("skew", help="heavy-hitter report from sketch snapshots")
     s.add_argument("artifact")
     s.add_argument("--top", type=int, default=10)
@@ -116,8 +81,6 @@ def main(argv=None) -> int:
 
     args = parser.parse_args(argv)
     try:
-        if args.cmd == "diff":
-            return _run_diff(args)
         return _run_skew(args)
     except (OSError, ValueError) as e:
         print(f"obs {args.cmd}: {e}", file=sys.stderr)

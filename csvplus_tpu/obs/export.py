@@ -10,17 +10,18 @@ Two consumers, two formats:
   host-side span trace and the JAX device trace of one run land side by
   side and open in the same Perfetto session.  Timestamps count from
   the first trace's anchor (``Trace.t_anchor``, which the profiler's
-  trace shows as a ``csvplus:anchor`` annotation): hand
-  :func:`anchor_in_profile`'s reading to ``anchor_ts_us`` and spans
-  written after the fact lie on the device trace's own axis.
+  trace shows as a ``csvplus:anchor`` annotation, or places through any
+  root-level span's ``perf_counter``): hand :func:`anchor_in_profile`'s
+  reading to ``anchor_ts_us`` and spans written after the fact lie on
+  the device trace's own axis.  The process journal
+  (``tracer.journal``) is a :class:`Trace` like any other here.
 * :func:`spans_to_json` / :func:`write_spans_jsonl` emit one flat JSON
-  object per span — the shape the bench artifacts embed and the
-  ``obs diff`` tooling consumes.
+  object per span — the shape the bench artifacts embed.
 
-:func:`validate_chrome_trace` is the schema check the ``make
-trace-smoke`` gate runs over the emitted file: it returns a list of
-problems (empty = valid) rather than raising, so the gate can print
-every violation at once.
+:func:`validate_chrome_trace` is the schema check
+``tests/test_obs.py::test_chrome_trace_export_validates`` runs over the
+emitted file: it returns a list of problems (empty = valid) rather than
+raising, so a caller can print every violation at once.
 """
 
 from __future__ import annotations
@@ -120,11 +121,9 @@ def chrome_trace_events(
     return events
 
 
-def anchor_in_profile(xplane_path: str, trace_id: int) -> Optional[float]:
-    """Microseconds, on the profiler's clock, at which the
-    ``csvplus:anchor`` annotation of trace *trace_id* starts in the
-    ``.xplane.pb`` the JAX profiler wrote; None when the profile does
-    not hold it (the trace opened before the profiler started)."""
+def _csvplus_annotations(xplane_path: str):
+    """(name, start in microseconds, stats) of every ``csvplus:``
+    annotation on the host planes of the ``.xplane.pb``."""
     from jax.profiler import ProfileData
 
     for plane in ProfileData.from_file(xplane_path).planes:
@@ -132,9 +131,42 @@ def anchor_in_profile(xplane_path: str, trace_id: int) -> Optional[float]:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name == "csvplus:anchor" and dict(ev.stats).get("trace_id") == trace_id:
-                    return ev.start_ns / 1e3
-    return None
+                if ev.name.startswith("csvplus:"):
+                    yield ev.name, ev.start_ns / 1e3, dict(ev.stats)
+
+
+def profile_clock_offsets(xplane_path: str) -> List[float]:
+    """For every ``csvplus:`` annotation of the ``.xplane.pb`` that
+    carries its own ``perf_counter`` (a trace's anchor, a root, a span
+    directly under a root): its start on the profiler's clock less that
+    ``perf_counter`` value, in microseconds.  One clock, so they agree
+    to the few microseconds between reading the counter and the
+    annotation's construction."""
+    return [
+        start_us - float(stats["perf_counter"]) * 1e6
+        for _, start_us, stats in _csvplus_annotations(xplane_path)
+        if "perf_counter" in stats
+    ]
+
+
+def anchor_in_profile(xplane_path: str, trace: Union[Trace, int]) -> Optional[float]:
+    """Microseconds, on the profiler's clock, at which *trace* opened
+    (its ``t_anchor``) in the ``.xplane.pb`` the JAX profiler wrote.  By
+    the trace's own ``csvplus:anchor`` annotation where the profile holds
+    it; for a :class:`Trace` that opened before the profiler started (the
+    harness's order), through the annotations that carry their own
+    ``perf_counter`` (the median of their offsets).  None when the profile
+    holds neither (given a bare trace id, only the annotation can answer)."""
+    trace_id = trace if isinstance(trace, int) else trace.trace_id
+    offsets = []
+    for name, start_us, stats in _csvplus_annotations(xplane_path):
+        if name == "csvplus:anchor" and stats.get("trace_id") == trace_id:
+            return start_us
+        if "perf_counter" in stats:
+            offsets.append(start_us - float(stats["perf_counter"]) * 1e6)
+    if isinstance(trace, int) or not offsets:
+        return None
+    return sorted(offsets)[len(offsets) // 2] + trace.t_anchor * 1e6
 
 
 def write_chrome_trace(

@@ -161,6 +161,18 @@ class FlightRecorder:
 recorder = FlightRecorder()
 
 
+def _journal_tail() -> object:
+    """The process journal's last milestones (``obs/span.py``): "what
+    was the process doing before it died" includes "it was 40 s into a
+    recovery"."""
+    from .span import tracer
+
+    return {"dropped": tracer.journal.dropped, "recent": tracer.journal.recent()}
+
+
+recorder.attach("journal", _journal_tail)
+
+
 def note(kind: str, **fields: object) -> None:
     """Append one event to the process-global ring."""
     recorder.note(kind, **fields)

@@ -24,8 +24,10 @@ Layers, bottom up:
 * :class:`TailSampler` — always-on tail-sampled tracing: every request
   is offered (one lock round per dispatch cycle), but full records are
   RETAINED only for errors, deadline misses, and latency above a
-  rolling p99 threshold, in a bounded ring — the trace-smoke ≤2%
-  overhead budget applies (``make obs-smoke`` asserts it).
+  rolling p99 threshold, in a bounded ring — the tracer's ≤2%
+  disabled-path budget (``tests/test_journal.py::
+  test_disabled_path_costs_under_two_percent_of_a_micro_lookup``) is
+  the rule here too.
 * :class:`TelemetryPlane` — the bundle :class:`LookupServer` owns:
   registry + tail sampler + per-index probe/build-key
   :class:`~csvplus_tpu.obs.sketch.SpaceSaving` sketches + the global
@@ -446,8 +448,8 @@ class TailSampler:
     clears a rolling p99 threshold computed over a bounded window of
     recent latencies.  Threshold recomputation is amortized (every
     *recompute* offers), so the per-record cost is a few comparisons —
-    the ≤2% disarmed-overhead budget ``trace-smoke`` enforces applies
-    to this path via ``make obs-smoke``.
+    the ≤2% disabled-path budget the tracer is held to
+    (``tests/test_journal.py``) is this path's rule too.
 
     Records are the extended serve sample tuples
     ``(latency_s, wait_s, outcome, kind, index, error)`` — trailing
@@ -480,8 +482,7 @@ class TailSampler:
         common case (ok outcome, under-threshold latency) is a handful
         of local-variable ops per record — attribute state is hoisted
         once per batch, written back once (this path rides EVERY
-        dispatch cycle; ``make obs-smoke`` holds it to the ≤2%
-        budget)."""
+        dispatch cycle, under the ≤2% budget above)."""
         t = time.time()
         with self._lock:
             window = self._window

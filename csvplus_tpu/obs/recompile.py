@@ -56,6 +56,9 @@ def register_kernel(name: str, **jit_kwargs) -> Callable:
     wrapper runs at trace time only — zero call-path overhead."""
     import jax  # here, not at import: ``import csvplus_tpu`` stays jax-free
 
+    from .span import journal_compiles
+
+    journal_compiles()  # jax's compile events join the process journal
     scope = f"csvplus.{name}"
 
     def deco(fn):

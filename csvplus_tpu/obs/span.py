@@ -33,14 +33,33 @@ the only place in the package that writes into the profiler's host
 trace), so a device profile taken over the same stretch shows what the
 host did in each gap.  Spans keep ``perf_counter`` times; a :class:`Trace`
 emits one ``csvplus:anchor`` annotation when it opens, carrying its id
-and the ``perf_counter`` value of that moment, which is what places
-spans written after the fact (:meth:`Tracer.record_span`) on the
-profiler's clock.
+and the ``perf_counter`` value of that moment, and every span opened as
+a root or directly under one (``plan:execute``, ``serve:cycle``, a
+milestone) carries ``perf_counter=<its start>`` in its annotation too:
+any profile that holds one such annotation holds an anchor, whenever
+the profiler was started, which is what places spans written after the
+fact (:meth:`Tracer.record_span`) on the profiler's clock.
 
-Disabled-path cost: with no active trace, :meth:`Tracer.span` is one
-``ContextVar.get`` and a shared do-nothing context manager — the ``make
-trace-smoke`` gate holds this under 2% on the micro lookup shape.  No
-annotation is constructed and no span object made.
+The journal beside the trace: a trace is opened by whoever wants one
+stretch measured and is finished when it ends; the work a process does
+ONCE per object (an ingest, an index build, a plan's admission and
+first run, a server's start, a WAL recovery) mostly happens where no
+trace is open.  :meth:`Tracer.milestone` marks such work: inside a
+trace it is a plain child span; outside one it opens a root in
+:attr:`Tracer.journal`, a process-lifetime :class:`Trace` that is never
+finished, bounded by spans (:data:`MAX_JOURNAL_SPANS`; whole oldest
+trees go first, counted in ``journal.dropped``), and makes it the
+current context, so everything its body reaches through
+``telemetry.stage`` / ``add_stage`` / :meth:`Tracer.span` lands beneath
+it with no new call site.  jax's compile events join it as ``compile``
+spans (:func:`journal_compiles`).  ``reset()`` leaves the journal alone.
+
+Disabled-path cost: with no active trace and no open milestone,
+:meth:`Tracer.span` is one ``ContextVar.get`` and a shared do-nothing
+context manager — ``tests/test_journal.py::
+test_disabled_path_costs_under_two_percent_of_a_micro_lookup`` holds
+this under 2% on the micro lookup shape.  No annotation is constructed
+and no span object made.
 """
 
 from __future__ import annotations
@@ -48,6 +67,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import itertools
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -55,6 +75,10 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 #: Finished traces kept for export before the oldest are dropped.
 MAX_FINISHED_TRACES = 512
+
+#: Spans the process journal keeps; past it, whole oldest milestone
+#: trees are dropped (down to 7/8, so trimming is rare).
+MAX_JOURNAL_SPANS = 8192
 
 #: The current (trace, open span_id) — per-thread / per-context by
 #: ``contextvars`` semantics, which is what isolates concurrent queries.
@@ -66,9 +90,12 @@ _CURRENT: "contextvars.ContextVar[Optional[Tuple[Trace, int]]]" = (
 def _annotate(name: str, **meta):
     """An entered ``jax.profiler.TraceAnnotation("csvplus:<name>")``: the
     one place in the package that writes into the profiler's host trace.
-    Only reached while a trace is active (so ``import csvplus_tpu`` stays
-    jax-free); with no profiler session running it costs one flag test
-    inside jax."""
+    Only reached while a trace or a milestone is active; a process that
+    has not imported jax (a host-only WAL recovery) gets a do-nothing
+    stand-in, so ``import csvplus_tpu`` and the journal stay jax-free.
+    With no profiler session running it costs one flag test inside jax."""
+    if "jax" not in sys.modules:
+        return _NO_SPAN
     from jax.profiler import TraceAnnotation
 
     ann = TraceAnnotation(f"csvplus:{name}", **meta)
@@ -115,19 +142,25 @@ class Trace:
     flat telemetry list this module replaces).
     """
 
-    __slots__ = ("trace_id", "name", "spans", "t_anchor", "_lock")
+    __slots__ = ("trace_id", "name", "spans", "t_anchor", "root_id", "_lock")
 
     def __init__(self, trace_id: int, name: str):
         self.trace_id = trace_id
         self.name = name
         self.spans: List[Span] = []
+        # the span id of the root, once it is open: a span opened directly
+        # under it carries its own perf_counter into the profiler's trace
+        self.root_id = 0
         # the anchor: this perf_counter value and the annotation's start
         # on the profiler's clock are the same moment
         self.t_anchor = time.perf_counter()
-        _annotate("anchor", trace_id=trace_id, perf_counter=self.t_anchor).__exit__(
-            None, None, None
-        )
+        self._announce()
         self._lock = threading.Lock()
+
+    def _announce(self) -> None:
+        _annotate(
+            "anchor", trace_id=self.trace_id, perf_counter=self.t_anchor
+        ).__exit__(None, None, None)
 
     def add(self, span: Span) -> None:
         with self._lock:
@@ -159,6 +192,75 @@ class Trace:
         }
 
 
+class Journal(Trace):
+    """The process journal: a :class:`Trace` that is never finished.
+
+    Its roots are milestones (and the compile events that fall outside
+    any); it announces no anchor (it opens at import, before jax), holds
+    instead the wall clock of its opening beside ``t_anchor`` so an
+    exporter can place it, and is bounded: past :data:`MAX_JOURNAL_SPANS`
+    spans, whole oldest trees whose root has closed are dropped and
+    counted in :attr:`dropped`; spans of a tree still open go, oldest
+    first, only where that alone does not make room.
+    """
+
+    __slots__ = ("t_anchor_ns", "dropped", "limit")
+
+    def __init__(self, trace_id: int, limit: int = MAX_JOURNAL_SPANS):
+        super().__init__(trace_id, "journal")
+        self.t_anchor_ns = time.time_ns()
+        self.dropped = 0  # milestone trees (a lone span is a tree of one)
+        self.limit = int(limit)
+
+    def _announce(self) -> None:
+        return None
+
+    def add(self, span: Span) -> None:
+        with self._lock:
+            self.spans.append(span)
+            if len(self.spans) > self.limit:
+                self._trim(self.limit - self.limit // 8)
+
+    def _trim(self, keep: int) -> None:
+        """Drop oldest closed trees until at most *keep* spans are left
+        (the caller holds the lock)."""
+        spans = self.spans
+        # span id -> the id of its closed root (0: its tree is still open);
+        # a parent closes, and so is appended, after its children
+        root_of: Dict[int, int] = {}
+        for s in reversed(spans):
+            root_of[s.span_id] = (
+                s.span_id if s.parent_id is None else root_of.get(s.parent_id, 0)
+            )
+        size: Dict[int, int] = {}
+        for r in root_of.values():
+            size[r] = size.get(r, 0) + 1
+        excess = len(spans) - keep
+        gone = set()
+        for s in spans:  # roots in the order they closed
+            if excess <= 0:
+                break
+            if s.parent_id is None:
+                gone.add(s.span_id)
+                excess -= size[s.span_id]
+        self.dropped += len(gone)
+        spans[:] = [s for s in spans if root_of[s.span_id] not in gone]
+        if len(spans) > keep:  # one open tree outgrew the journal
+            self.dropped += 1
+            del spans[: len(spans) - keep]
+
+    def recent(self, n: int = 16) -> List[Dict[str, Any]]:
+        """The last *n* closed roots, oldest first, each with how many
+        spans lie beneath it: what a flight dump attaches."""
+        with self._lock:
+            spans = list(self.spans)
+        kids: Dict[Optional[int], int] = {}
+        for s in spans:
+            kids[s.parent_id] = kids.get(s.parent_id, 0) + 1
+        roots = [s for s in spans if s.parent_id is None][-n:]
+        return [dict(s.to_json(), children=kids.get(s.span_id, 0)) for s in roots]
+
+
 class _OpenSpan:
     """Handle for a span opened via the low-level open/close API."""
 
@@ -175,10 +277,11 @@ class _SharedSpans:
     """Where the spans of one :meth:`Tracer.shared` region collect until
     the region ends (list.append is atomic: adopted workers may add)."""
 
-    __slots__ = ("trace_id", "spans")
+    __slots__ = ("trace_id", "root_id", "spans")
 
     def __init__(self) -> None:
         self.trace_id = 0
+        self.root_id = 0  # the region's root alone carries its perf_counter
         self.spans: List[Span] = []
 
     def add(self, span: Span) -> None:
@@ -208,6 +311,9 @@ class Tracer:
         self._ids = itertools.count(1)
         self._finished: List[Trace] = []
         self._dropped = 0
+        #: the process journal (module docstring): milestones land here
+        #: when no trace is open; never finished, never reset
+        self.journal = Journal(next(self._ids))
 
     # -- context -----------------------------------------------------------
 
@@ -267,8 +373,9 @@ class Tracer:
         :class:`Trace` and registers it in the finished list on exit."""
         t = Trace(next(self._ids), name)
         root = self._start(t.trace_id, None, name, attrs)
+        t.root_id = root.span_id
         token = _CURRENT.set((t, root.span_id))
-        ann = _annotate(name)
+        ann = _annotate(name, perf_counter=root.t_start)
         try:
             yield t
         finally:
@@ -288,10 +395,19 @@ class Tracer:
         ctx = _CURRENT.get()
         if ctx is None:
             return None
-        t, parent = ctx
+        return self._open(ctx[0], ctx[1], name, attrs)
+
+    def _open(self, t, parent: Optional[int], name: str, attrs) -> _OpenSpan:
+        """A live span of *t* under *parent*, made the current context.
+        A root, or a span directly under its trace's root, carries its
+        own ``perf_counter`` into the profiler's trace: an anchor."""
         span = self._start(t.trace_id, parent, name, attrs)
         token = _CURRENT.set((t, span.span_id))
-        return _OpenSpan(t, span, token, _annotate(name))
+        if parent is None or parent == t.root_id:
+            ann = _annotate(name, perf_counter=span.t_start)
+        else:
+            ann = _annotate(name)
+        return _OpenSpan(t, span, token, ann)
 
     def close_span(self, handle: Optional[_OpenSpan], **attrs) -> None:
         if handle is None:
@@ -308,13 +424,24 @@ class Tracer:
         that yields the span's attrs dict (the body may annotate it).
         With no trace active it is one ``ContextVar.get`` and a shared
         do-nothing manager yielding a throwaway dict."""
-        if _CURRENT.get() is None:
+        ctx = _CURRENT.get()
+        if ctx is None:
             return _NO_SPAN
-        return self._live_span(name, attrs)
+        return self._live_span(name, attrs, ctx)
+
+    def milestone(self, name: str, **attrs):
+        """Once-per-object work (an ingest, an index build, an admission,
+        a recovery), as a context manager yielding the span's attrs.
+        Inside a trace it is :meth:`span`; outside one it opens a root
+        in :attr:`journal` and makes it the current context, so the
+        stages and spans its body reaches become its children.  It
+        forces no device sync: ``telemetry.barrier`` stays keyed on
+        collection."""
+        return self._live_span(name, attrs, _CURRENT.get() or (self.journal, None))
 
     @contextlib.contextmanager
-    def _live_span(self, name: str, attrs: Dict[str, Any]) -> Iterator[Dict[str, Any]]:
-        handle = self.open_span(name, **attrs)
+    def _live_span(self, name: str, attrs: Dict[str, Any], ctx) -> Iterator[Dict[str, Any]]:
+        handle = self._open(ctx[0], ctx[1], name, attrs)
         try:
             yield handle.span.attrs
         except BaseException as e:
@@ -337,7 +464,7 @@ class Tracer:
         region = _SharedSpans()
         root = self._start(region.trace_id, None, name, attrs)
         token = _CURRENT.set((region, root.span_id))
-        ann = _annotate(name)
+        ann = _annotate(name, perf_counter=root.t_start)
         try:
             yield root.attrs
         finally:
@@ -440,3 +567,61 @@ class Tracer:
 
 #: Process-global tracer (mirrors the ``telemetry`` singleton pattern).
 tracer = Tracer()
+
+
+# -- jax's compile events, as ``compile`` spans ------------------------------
+
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_OUTCOMES = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# the persistent cache's verdict on the program this thread is compiling:
+# jax reports it before the ``backend_compile`` duration that holds it
+_PENDING = threading.local()
+_LISTENING = False
+
+
+def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
+    if event.startswith("/jax/core/compile/"):
+        kind = event.rsplit("/", 1)[1].removesuffix("_duration")
+    elif event == _CACHE_RETRIEVAL:
+        kind = "cache_retrieval"
+    else:
+        return
+    attrs = {"kind": kind}
+    if "fun_name" in kw:
+        attrs["fun_name"] = str(kw["fun_name"])
+    if kind == "backend_compile":
+        cache = _PENDING.__dict__.pop("cache", None)
+        if cache is not None:
+            attrs["cache"] = cache
+    end = time.perf_counter()
+    t, parent = _CURRENT.get() or (tracer.journal, None)
+    tracer.record_span(t, parent, "compile", end - float(seconds), end, **attrs)
+
+
+def _on_compile_event(event: str, **kw) -> None:
+    outcome = _CACHE_OUTCOMES.get(event)
+    if outcome is not None:
+        _PENDING.cache = outcome
+
+
+def journal_compiles() -> None:
+    """Listen to ``jax.monitoring`` (once a process; called where the
+    package first imports jax, ``obs/recompile.py:register_kernel``): each
+    duration jax reports on its compile path — ``jaxpr_trace``,
+    ``jaxpr_to_mlir_module``, ``backend_compile``, the persistent cache's
+    ``cache_retrieval`` — becomes one ``compile`` span ending now, with
+    its ``kind``, the ``fun_name`` jax passes and, on ``backend_compile``,
+    ``cache`` ``hit`` | ``miss``; under the current context where one is
+    open, else a root of the journal.  A warm execution compiles nothing
+    and so records nothing."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    import jax.monitoring as mon
+
+    mon.register_event_duration_secs_listener(_on_compile_seconds)
+    mon.register_event_listener(_on_compile_event)

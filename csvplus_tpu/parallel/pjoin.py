@@ -903,7 +903,7 @@ def _note_skew(
     threshold: float,
 ) -> None:
     """The routing-split evidence for one skew-engaged probe: a
-    ``join:skew`` row in the span stage table (so ``obs diff`` can
+    ``join:skew`` row in the span stage table (so a stage-table reader can
     attribute the win) plus the process-global counters
     ``TelemetryPlane`` exports.  ``seconds=0``: this row is an
     accounting record — detection and hot-answer time are already

@@ -53,7 +53,8 @@ through the tree at existing span/stage boundaries:
 
 DISCIPLINE: the disarmed path is one module-global ``None`` check per
 site (:func:`inject`), the same budget rule as the tracing subsystem's
-disabled hooks (``make trace-smoke``'s 2% gate); ``make chaos``
+disabled hooks (the 2% of ``tests/test_journal.py::
+test_disabled_path_costs_under_two_percent_of_a_micro_lookup``); ``make chaos``
 measures it against a 1% budget and records it in the chaos artifact.
 
 Determinism: firing decisions depend only on the plan (specs + seed)

@@ -307,11 +307,12 @@ class LookupServer:
             if self._open:
                 return self
             self._open = True
-        t = threading.Thread(
-            target=self._dispatch_loop, name="csvplus-serve-dispatch", daemon=True
-        )
-        self._thread = t
-        t.start()
+        with tracer.milestone("serve:start", indexes=len(self._indexes)):
+            t = threading.Thread(
+                target=self._dispatch_loop, name="csvplus-serve-dispatch", daemon=True
+            )
+            self._thread = t
+            t.start()
         return self
 
     def stop(self) -> None:

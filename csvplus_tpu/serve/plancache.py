@@ -51,6 +51,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from .. import plan as P
 from ..errors import CsvPlusError
+from ..obs.span import tracer
 from ..utils.env import env_int
 
 #: Default LRU bound (entries), overridden via ``CSVPLUS_PLANCACHE_SIZE``.
@@ -196,9 +197,18 @@ class PlanExecutable:
 
     def run(self, root: P.PlanNode):
         """Execute and materialize; returns the result DeviceTable."""
+        self.runs += 1  # stats only; a lost increment under races is benign
+        if self.runs == 1:
+            # a new shape's first execution is where its tables demote,
+            # compose and partition and its programs compile: a milestone
+            # (``obs/span.py``); every later run pays this one test
+            with tracer.milestone("plan:first-run", nodes=len(self.key)):
+                return self._run(root)
+        return self._run(root)
+
+    def _run(self, root: P.PlanNode):
         from ..columnar.exec import execute_plan_view
 
-        self.runs += 1  # stats only; a lost increment under races is benign
         if self.recipe is not None:
             from ..analysis.rewrite import apply_recipe, leaf_presence_ok
 
@@ -254,36 +264,11 @@ class PlanCache:
                 self._entries.move_to_end(key)
                 self.hits += 1
                 return exe
-        # verification runs unlocked: pure, possibly slow, and a racing
-        # duplicate verify of one new shape is cheaper than holding the
-        # cache lock across it
-        from ..analysis.verify import verify_plan
-
-        report = verify_plan(root)
-        if not report.ok:
-            with self._lock:
-                self.misses += 1
-                self.rejected += 1
-            raise PlanRejected(report.errors)
-        recipe = None
-        fusion_refused_flag = False
-        from ..analysis.rewrite import optimize_enabled, optimize_plan
-
-        if optimize_enabled():
-            try:
-                result = optimize_plan(root, report)
-                recipe = result.recipe
-                fusion_refused_flag = any(
-                    d.rule == "probe-fuse" for d in result.blocked
-                )
-            except Exception:
-                # The rewriter is advisory: a prover bug (verdict
-                # mismatch, unexpected node) must never cost an
-                # admission.  The shape runs unrewritten; the counter
-                # keeps the failure visible in stats().
-                with self._lock:
-                    self.optimize_failed += 1
-        exe = PlanExecutable(key, report, recipe)
+        # the miss path is one ``plan:admit`` milestone (``obs/span.py``)
+        with tracer.milestone("plan:admit", nodes=len(key)) as at:
+            exe, fusion_refused_flag = self._admit(root, key)
+            at["optimized"] = exe.recipe is not None
+        recipe = exe.recipe
         with self._lock:
             self.misses += 1
             existing = self._entries.get(key)
@@ -307,6 +292,41 @@ class PlanCache:
                 self._entries.popitem(last=False)
                 self.evictions += 1
         return exe
+
+    def _admit(self, root: P.PlanNode, key: Tuple):
+        """Verify and optimize a new shape; ``(executable, whether the
+        rewriter refused a probe fusion)``.  Runs unlocked: pure,
+        possibly slow, and a racing duplicate verify of one new shape is
+        cheaper than holding the cache lock across it."""
+        from ..analysis.verify import verify_plan
+
+        with tracer.span("plan:verify"):
+            report = verify_plan(root)
+        if not report.ok:
+            with self._lock:
+                self.misses += 1
+                self.rejected += 1
+            raise PlanRejected(report.errors)
+        recipe = None
+        fusion_refused_flag = False
+        from ..analysis.rewrite import optimize_enabled, optimize_plan
+
+        if optimize_enabled():
+            with tracer.span("plan:optimize"):
+                try:
+                    result = optimize_plan(root, report)
+                    recipe = result.recipe
+                    fusion_refused_flag = any(
+                        d.rule == "probe-fuse" for d in result.blocked
+                    )
+                except Exception:
+                    # The rewriter is advisory: a prover bug (verdict
+                    # mismatch, unexpected node) must never cost an
+                    # admission.  The shape runs unrewritten; the counter
+                    # keeps the failure visible in stats().
+                    with self._lock:
+                        self.optimize_failed += 1
+        return PlanExecutable(key, report, recipe), fusion_refused_flag
 
     def execute(self, root: P.PlanNode):
         """Admit (or hit) and execute in one call; the common serving

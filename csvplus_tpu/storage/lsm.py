@@ -107,6 +107,7 @@ import numpy as np
 
 from ..index import Index, create_index, load_index
 from ..obs import flight as _flight
+from ..obs.span import tracer
 from ..resilience import faults
 from ..row import Row
 from ..source import take_rows
@@ -543,26 +544,38 @@ class MutableIndex:
             self._applied_lsn = int(man["applied_lsn"])  # type: ignore[arg-type]
             self._base_file = str(man["base"])
             self._next_seq = self._applied_lsn + 1
-            wal, replay, info = Wal.open(
-                directory, self._applied_lsn, sync=wal_sync,
-                columns=self._columns,
-            )
-            self._wal = wal
-            for doc in replay:
-                lsn = int(doc["lsn"])
-                if doc.get("op") == "del":
-                    delta = DeltaTier(lsn, None, (tuple(doc["key"]),))
-                else:
-                    rows = [Row(r) for r in doc["rows"]]
-                    idx = self._build_delta_index(rows)
-                    # no seal-time pruner: the first probe builds it
-                    # (same lazy rule as the live append path)
-                    delta = DeltaTier(lsn, idx)
-                ts = self._tiers
-                self._tiers = TierSet(ts.epoch + 1, ts.base,
-                                      ts.deltas + (delta,),
-                                      base_pruner=ts.base_pruner)
-                self._next_seq = lsn + 1
+            # one ``storage:recover`` milestone (``obs/span.py``): the
+            # ``recover_s`` of a durable index, in the process journal
+            with tracer.milestone("storage:recover") as at:
+                wal, replay, info = Wal.open(
+                    directory, self._applied_lsn, sync=wal_sync,
+                    columns=self._columns,
+                )
+                self._wal = wal
+                with tracer.span("storage:replay", records=len(replay)):
+                    for doc in replay:
+                        lsn = int(doc["lsn"])
+                        if doc.get("op") == "del":
+                            delta = DeltaTier(lsn, None, (tuple(doc["key"]),))
+                        else:
+                            rows = [Row(r) for r in doc["rows"]]
+                            idx = self._build_delta_index(rows)
+                            # no seal-time pruner: the first probe builds it
+                            # (same lazy rule as the live append path)
+                            delta = DeltaTier(lsn, idx)
+                        ts = self._tiers
+                        self._tiers = TierSet(ts.epoch + 1, ts.base,
+                                              ts.deltas + (delta,),
+                                              base_pruner=ts.base_pruner)
+                        self._next_seq = lsn + 1
+                at.update(
+                    records=len(replay), segments=len(info["segments"]),
+                    truncated_bytes=info["truncated_bytes"],
+                    bytes=sum(
+                        os.path.getsize(os.path.join(directory, name))
+                        for name in info["segments"]
+                    ),
+                )
             self.recovered_records = len(replay)
             self.recovery_info = info
             mf.remove_stale(directory, man)
@@ -889,7 +902,7 @@ class MutableIndex:
         from ..columnar.table import DeviceTable
 
         table = DeviceTable.from_rows(rows, device=self._device)
-        return create_index(source_from_table(table), self._columns)
+        return create_index(source_from_table(table), self._columns, milestone=False)
 
     def _make_pruner(self, idx: Index) -> Optional[TierPruner]:
         """Fences + filter for a freshly sealed tier (None when pruning
@@ -920,7 +933,7 @@ class MutableIndex:
 
         if table.nrows == 0:
             return 0
-        idx = create_index(source_from_table(table), self._columns)
+        idx = create_index(source_from_table(table), self._columns, milestone=False)
         self._push_delta(idx, None)
         return table.nrows
 

@@ -135,7 +135,12 @@ class Telemetry:
         (:data:`csvplus_tpu.obs.span.tracer`), the stage also opens a
         child span there — the hierarchical view needs no new call
         sites — and the span carries the ``csvplus:<stage>`` profiler
-        annotation.  The span keeps even discarded/failed stages
+        annotation.  An open milestone (``tracer.milestone``: an ingest,
+        an index build, a plan's first run) is such a context too, with
+        collection off as well as on: the stage then lands in the process
+        journal beneath its milestone, and is still no record of the
+        table.  With neither a trace nor a milestone open and collection
+        off, a stage costs one ``ContextVar.get``.  The span keeps even discarded/failed stages
         (annotated), because a trace records what HAPPENED, while the
         table records what counted.  A stage inside which
         :meth:`barrier` blocked says so: ``synced`` and ``wait_s`` in

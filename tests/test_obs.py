@@ -15,11 +15,7 @@ Contracts under test:
   carries every span; the JSON-lines sink drains incrementally;
 * recompile accounting — registered kernels report zero lowerings over
   a warm repeat and nonzero when a new shape lowers;
-* memory watermarks — the sampler observes a forced RSS excursion and
-  writes its summary into span/stage attrs;
-* the stage-table differ — on the checked-in r05/r06 mesh artifacts it
-  flags exactly the stages the r06 diagnosis found (join:translate,
-  join:pack), plus synthetic direction/threshold/min-share cases;
+* memory probes — current and peak RSS, the bench-artifact host header;
 * telemetry hygiene — lock-guarded counters under thread hammering,
   ``merged_stages`` accumulable-extras, ``barrier`` as a strict no-op
   when disabled, and ``report``/``to_json`` carrying counters +
@@ -41,7 +37,6 @@ from csvplus_tpu.obs import (
     SpanJsonlSink,
     chrome_trace_events,
     compile_counts,
-    diff_stage_tables,
     host_header,
     peak_rss_mb,
     register_kernel,
@@ -49,11 +44,8 @@ from csvplus_tpu.obs import (
     rss_mb,
     tracer,
     validate_chrome_trace,
-    watch_memory,
     write_chrome_trace,
 )
-from csvplus_tpu.obs.diff import diff_files, format_diff
-from csvplus_tpu.obs.__main__ import main as obs_main
 from csvplus_tpu.serve import LookupServer
 from csvplus_tpu.utils.observe import StageRecord, telemetry
 
@@ -274,9 +266,12 @@ def test_serve_plan_spans_nest_executor_stages():
     dsp = next(s for s in spans if s.name == "serve:dispatch")
     assert dsp.attrs["kind"] == "plan"
     # the executor's plan:execute grouping span runs INSIDE the adopted
-    # dispatch span, in the submitter's trace
+    # dispatch span, in the submitter's trace; a new shape's first run is
+    # a milestone, which inside a trace is a plain span between the two
     pe = next(s for s in spans if s.name == "plan:execute")
-    assert by_id[pe.parent_id] is dsp
+    first = by_id[pe.parent_id]
+    assert first.name == "plan:first-run" and by_id[first.parent_id] is dsp
+    assert [s.name for s in spans if by_id.get(s.parent_id) is dsp][0] == "plan:admit"
     # and the per-node stages (telemetry.stage shim) nest under it
     sel = next(s for s in spans if s.name == "SelectCols")
     assert by_id[sel.parent_id] is pe
@@ -429,110 +424,12 @@ def test_rss_probes_report_positive_mb():
     assert peak >= cur * 0.5  # same order; VmHWM can't be far below current
 
 
-def test_watch_memory_observes_an_rss_excursion():
-    with tracer.trace("mem") as tr:
-        with tracer.span("alloc") as attrs:
-            with watch_memory(attrs, interval_s=0.002):
-                ballast = np.ones((64, 1 << 20), dtype=np.uint8)  # 64MB
-                time.sleep(0.05)
-                ballast[:] = 7  # touch every page
-                del ballast
-    alloc = next(s for s in tr.snapshot() if s.name == "alloc")
-    a = alloc.attrs
-    assert a["rss_samples"] >= 1
-    assert a["rss_peak_mb"] >= a["rss_start_mb"]
-    assert a["watched_s"] > 0
-
-
 def test_host_header_shape():
     h = host_header()
     assert h["host_cpus"] >= 1
     assert h["platform"] == "cpu"
     assert h["device_kind"] == "cpu"
     assert h["jax_device_count"] >= 1
-
-
-# ---------------------------------------------------------------------------
-# stage-table differ
-# ---------------------------------------------------------------------------
-
-
-def test_diff_flags_the_r05_r06_warm_join_regression():
-    """ACCEPTANCE: the differ reproduces the r06 diagnosis mechanically —
-    join:translate and join:pack are the flagged stages, regressed in
-    the r05 (pre-fix) stage table, and nothing else crosses 2x.  The two
-    tables are fixtures under tests/data/."""
-    data = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-    result = diff_files(
-        os.path.join(data, "stage_table_pre_fix.json"),
-        os.path.join(data, "stage_table_post_fix.json"),
-    )
-    flagged = {r["stage"]: r for r in result["flagged"]}
-    assert set(flagged) == {"join:translate", "join:pack"}
-    assert all(r["regressed_in"] == "A" for r in flagged.values())
-    assert flagged["join:pack"]["movement"] > flagged["join:translate"]["movement"]
-    assert result["only_in_a"] == [] and result["only_in_b"] == []
-    # the per-row metric is what crosses tiers: 10M-row vs 100M-row runs
-    assert flagged["join:translate"]["ns_per_row_a"] > flagged[
-        "join:translate"
-    ]["ns_per_row_b"]
-    report = format_diff(result, "r05", "r06")
-    assert "REGRESSED in A" in report
-
-
-def test_diff_direction_threshold_and_min_share():
-    a = [
-        {"stage": "big", "rows_in": 1000, "seconds": 1.0},
-        {"stage": "fast", "rows_in": 1000, "seconds": 0.30},
-        {"stage": "tiny", "rows_in": 1000, "seconds": 0.001},
-        {"stage": "gone", "rows_in": 10, "seconds": 0.01},
-    ]
-    b = [
-        {"stage": "big", "rows_in": 1000, "seconds": 1.0},
-        {"stage": "fast", "rows_in": 1000, "seconds": 0.90},  # 3x slower in B
-        {"stage": "tiny", "rows_in": 1000, "seconds": 0.008},  # 8x but tiny
-        {"stage": "new", "rows_in": 10, "seconds": 0.01},
-    ]
-    r = diff_stage_tables(a, b)
-    flagged = {x["stage"]: x for x in r["flagged"]}
-    assert set(flagged) == {"fast"}
-    assert flagged["fast"]["regressed_in"] == "B"
-    assert r["only_in_a"] == ["gone"] and r["only_in_b"] == ["new"]
-    # "tiny" moved 8x but is under min_share on both sides
-    tiny = next(x for x in r["rows"] if x["stage"] == "tiny")
-    assert tiny["movement"] >= 7 and not tiny["flagged"]
-    # a looser threshold does not resurrect it; a lower min_share does
-    assert {
-        x["stage"] for x in diff_stage_tables(a, b, min_share=0.0)["flagged"]
-    } == {"fast", "tiny"}
-    assert diff_stage_tables(a, b, threshold=4.0)["flagged"] == []
-
-
-def test_diff_rss_column_participates():
-    a = [{"stage": "s", "rows_in": 10, "seconds": 1.0, "rss_peak_mb": 100}]
-    b = [{"stage": "s", "rows_in": 10, "seconds": 1.0, "rss_peak_mb": 500}]
-    r = diff_stage_tables(a, b)
-    assert [x["stage"] for x in r["flagged"]] == ["s"]
-    assert r["flagged"][0]["rss_peak_mb_b"] == 500
-
-
-def test_obs_cli_diff(tmp_path, capsys):
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps({"stage_table": [
-        {"stage": "s", "rows_in": 10, "seconds": 1.0}]}))
-    b.write_text(json.dumps({"stage_table": [
-        {"stage": "s", "rows_in": 10, "seconds": 5.0}]}))
-    assert obs_main(["diff", str(a), str(b), "--json"]) == 0
-    out = json.loads(capsys.readouterr().out)
-    # equal shares (each side's only stage) — the per-row metric flags
-    assert out["flagged"][0]["stage"] == "s"
-    assert out["flagged"][0]["regressed_in"] == "B"
-    assert obs_main(["diff", str(a), str(b), "--fail-on-flag"]) == 2
-    assert obs_main(["diff", str(a), str(tmp_path / "missing.json")]) == 1
-    bad = tmp_path / "bad.json"
-    bad.write_text("{}")
-    assert obs_main(["diff", str(a), str(bad)]) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -748,6 +645,60 @@ def test_anchor_places_recorded_spans_on_the_profilers_clock(tmp_path):
         e for e in chrome_trace_events([tr], anchor_ts_us=at) if e["name"] == "live"
     )
     assert placed["ts"] == pytest.approx(seen[0], abs=500.0)  # microseconds
+
+
+def test_a_profile_started_after_the_trace_still_holds_an_anchor(tmp_path):
+    """The harness's order: ``tracer.trace`` opens, THEN the profiler
+    starts, so ``csvplus:anchor`` is in no profile.  Every span opened
+    directly under the root carries its own ``perf_counter``, and
+    ``anchor_in_profile`` places the trace through those."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from csvplus_tpu.obs.export import anchor_in_profile, profile_clock_offsets
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tracer.trace("bench-window") as tr:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(3):
+                with tracer.span("plan:execute"):
+                    with tracer.span("deeper"):  # depth 2: no anchor of its own
+                        time.sleep(0.001)
+            t0 = time.perf_counter()
+            time.sleep(0.001)
+            late = tracer.record_span(tr, tr.root_id, "serve:queue-wait", t0, t0 + 0.001)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = [
+        ev
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("csvplus:")
+    ]
+    assert not any(ev.name == "csvplus:anchor" for ev in events)
+    assert anchor_in_profile(path, tr.trace_id) is None  # a bare id: the annotation only
+    offsets = profile_clock_offsets(path)
+    assert len(offsets) == 3  # the three depth-1 spans, not the deeper ones
+    assert max(offsets) - min(offsets) < 500.0  # microseconds; one clock
+    at = anchor_in_profile(path, tr)
+    assert at is not None
+    seen = sorted(ev.start_ns / 1e3 for ev in events if ev.name == "csvplus:plan:execute")
+    placed = sorted(
+        e["ts"] for e in chrome_trace_events([tr], anchor_ts_us=at)
+        if e["name"] == "plan:execute"
+    )
+    assert placed == pytest.approx(seen, abs=500.0)
+    # and the span written after the fact lies between them on that axis
+    after = next(
+        e for e in chrome_trace_events([tr], anchor_ts_us=at) if e["name"] == late.name
+    )
+    assert after["ts"] > placed[-1]
 
 
 def test_shared_region_lands_once_in_every_context(annotations):

@@ -403,17 +403,16 @@ def test_snapshot_schema_version_and_pinned_cell_keys():
 # -- the obs CLI ------------------------------------------------------------
 
 
-def test_obs_cli_diff_without_stage_tables_is_an_error(tmp_path, capsys):
-    """`diff` has one mode: two artifacts that carry no stage table are
-    an error (exit 1, the probed keys named), not a second format."""
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps({"metric": "m", "value": 1.0}))
-    b.write_text(json.dumps({"metric": "m", "value": 2.0}))
-    rc = obs_main(["diff", str(a), str(b), "--json"])
-    assert rc == 1
-    captured = capsys.readouterr()
-    assert captured.out == ""
-    assert "no stage table under stage_table" in captured.err
+def test_obs_cli_has_one_mode(tmp_path, capsys):
+    """`skew` is the CLI's one mode: the stage-table differ went with the
+    last producer of a stage-table artifact (PR 41), so `diff` is refused
+    by the parser (exit 2), not answered with a second format."""
+    a = tmp_path / "a.json"
+    a.write_text(json.dumps({"stage_table": []}))
+    with pytest.raises(SystemExit) as e:
+        obs_main(["diff", str(a), str(a)])
+    assert e.value.code == 2
+    assert "invalid choice: 'diff'" in capsys.readouterr().err
 
 
 def test_obs_cli_skew_renders_plane_snapshot(tmp_path, capsys):
