@@ -92,6 +92,15 @@ SYNC001_ALLOWED: Dict[str, str] = {
         "deliberate cached scalar presence probe, once per column "
         "lifetime; no transfer of cell data"
     ),
+    "table.py:take_rows": (
+        "THE lookup batch's blocking read: every column's values at the "
+        "batch's row positions (and, where the bounds stayed on the "
+        "device, the bounds with them) in ONE int32 array of "
+        "(head + columns) x bucket elements, counted by count_sync at "
+        "the site and as host_syncs/elements/one_trip on "
+        "serve:gather:readback; one read more per device set where a "
+        "joined table's columns cannot enter one program"
+    ),
     "table.py:sync": (
         "THE deliberate completion sync: one scalar round trip "
         "replacing per-buffer readiness pings; no transfer of column "

@@ -514,6 +514,10 @@ class StringColumn:
             out = [None if c < 0 else v for c, v in zip(codes.tolist(), out)]
         return out
 
+    # the kind-agnostic name (shared protocol with IntColumn): decode a
+    # host copy of ``storage`` values
+    decode_storage = decode_codes
+
     def decode(self) -> List[Optional[str]]:
         """Materialize values on host; absent cells become None."""
         self._ensure_sorted_lanes()  # BEFORE the code snapshot below
@@ -623,6 +627,22 @@ def _gather_take(storage: jax.Array, idx: jax.Array) -> jax.Array:
     permutation, a selection) as one named program instead of an eager
     ``jnp.take``, which a device profile shows as ``jit__take``."""
     return jnp.take(storage, idx, axis=0)
+
+
+@register_kernel("table.gather_take_rows")
+def _gather_take_rows(head: jax.Array, lanes: Tuple[jax.Array, ...]) -> jax.Array:  # analysis: allow[JIT001] — arity is the table's column count
+    """A lookup batch's rows in one program and one array: *head* is
+    ``int32[h, B]`` whose first row holds one row position per query (a
+    bounds search's ``lower`` left on the device with its ``upper``
+    beneath it, or positions the host formed), *lanes* every column's
+    row-indexed ``storage``.  Returns ``int32[h + len(lanes), B]``:
+    *head* as it came, then per lane its values at those positions.  A
+    position one past the last row (the ``lower`` of a probe beyond the
+    last key) reads the last row; the caller drops it by its bounds."""
+    rows = head[0]
+    return jnp.concatenate(
+        [head] + [jnp.take(lane, rows, axis=0, mode="clip")[None] for lane in lanes]
+    )
 
 
 @register_kernel("table.apply_code_translation")
@@ -849,34 +869,68 @@ class DeviceTable:
         """Decode (a selection of) the table back into host Rows; absent
         cells are omitted from their row, matching the host path's
         heterogeneous dicts."""
-        cols = self.columns
-        if sel is not None:
-            # a point lookup's rows (``Index.rows_for_bounds`` past the
-            # mirror cap): one gather dispatched per column, then one
-            # blocking read per column, then the decode on the host
-            with tracer.span("serve:gather:take") as span:
-                cols = {n: c.gather(sel) for n, c in cols.items()}
-                span["dispatches"] = len(cols)
-            n = int(len(sel))
-            with tracer.span("serve:gather:readback") as span:
-                for c in cols.values():
-                    c._ensure_sorted_lanes()  # as decode() does first: read the final codes
-                    np.asarray(c.storage)  # jax keeps the host copy, decode() reads that
-                telemetry.count_sync(n * len(cols))
-                span["host_syncs"], span["elements"] = len(cols), n * len(cols)
-        else:
-            n = self.nrows
+        if sel is None:
+            with tracer.span("serve:gather:rows"):
+                return self._rows_of(
+                    [c.decode() for c in self.columns.values()], self.nrows
+                )
+        # row positions the host formed (``Index.rows_for_bounds`` past
+        # the mirror cap where the bounds had to be read): one upload of
+        # the positions, padded to their power-of-two bucket so that a
+        # length compiles once a bucket, one program over every column,
+        # one blocking read
+        k = int(len(sel))
+        if k == 0:
+            return []
+        head = np.zeros((1, 1 << max(k - 1, 0).bit_length()), dtype=np.int32)
+        head[0, :k] = sel
+        got = self.take_rows(head)
+        return self.decode_rows(got[1:, :k])
+
+    def take_rows(self, head) -> np.ndarray:
+        """``csvplus.table.gather_take_rows`` over every column at the
+        positions in *head*'s first row, read back in ONE blocking read
+        (one per device set where a joined table's columns lie on
+        several and cannot enter one program).  The read is the batch's
+        only one (``one_trip``) where *head* is a search's answer still
+        on the device, its second where the host formed *head*."""
+        cols = list(self.columns.values())
+        with tracer.span("serve:gather:take") as span:
+            for c in cols:
+                c._ensure_sorted_lanes()  # read the final codes, as decode() does
+            lanes = tuple(c.storage for c in cols)
+            parts = [lanes] if same_placement(lanes) else [(lane,) for lane in lanes]
+            out = _gather_take_rows(head, parts[0])
+            rest = [_gather_take_rows(head, part) for part in parts[1:]]
+            span["dispatches"] = len(parts)
+        with tracer.span("serve:gather:readback") as span:
+            got = np.asarray(out)
+            if rest:  # each program echoes head above its one lane
+                got = np.concatenate([got] + [np.asarray(r)[-1:] for r in rest])
+            telemetry.count_sync(int(got.size))
+            span["host_syncs"], span["elements"] = len(parts), int(got.size)
+            span["one_trip"] = int(isinstance(head, jax.Array) and not rest)
+        return got
+
+    def decode_rows(self, lanes_host: np.ndarray) -> List[Row]:
+        """Rows from host copies of the columns' ``storage`` values (one
+        row of *lanes_host* per column, in the table's column order)."""
         with tracer.span("serve:gather:rows"):
-            decoded = {name: c.decode() for name, c in cols.items()}
-            names = list(decoded)
-            out = []
-            for i in range(n):
-                row = Row()
-                for name in names:
-                    v = decoded[name][i]
-                    if v is not None:
-                        row[name] = v
-                out.append(row)
+            return self._rows_of(
+                [c.decode_storage(v) for c, v in zip(self.columns.values(), lanes_host)],
+                lanes_host.shape[1],
+            )
+
+    def _rows_of(self, decoded: List[list], n: int) -> List[Row]:
+        names = list(self.columns)
+        out = []
+        for i in range(n):
+            row = Row()
+            for name, vals in zip(names, decoded):
+                v = vals[i]
+                if v is not None:
+                    row[name] = v
+            out.append(row)
         return out
 
     def rows_from_mirror(self, lower: int, upper: int) -> List[Row]:

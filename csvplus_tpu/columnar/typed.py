@@ -135,17 +135,20 @@ class IntColumn:
             got = self._values_host = np.asarray(self.values)
         return got
 
-    def decode_slice(self, lo: int, hi: int) -> List[Optional[str]]:
-        digits = self.values_host()[lo:hi].astype(np.str_)
+    def decode_storage(self, values: np.ndarray) -> List[Optional[str]]:
+        """Decode a host copy of ``storage`` values (the kind-agnostic
+        name, shared with ``StringColumn``)."""
+        digits = values.astype(np.str_)
         p = self._prefix_str()
         return (np.char.add(p, digits) if p else digits).tolist()
+
+    def decode_slice(self, lo: int, hi: int) -> List[Optional[str]]:
+        return self.decode_storage(self.values_host()[lo:hi])
 
     def decode_take(self, idx: np.ndarray) -> List[Optional[str]]:
         """Arbitrary-index decode off the host mirror (the batched
         lookup engine's gather-then-decode path)."""
-        digits = self.values_host()[idx].astype(np.str_)
-        p = self._prefix_str()
-        return (np.char.add(p, digits) if p else digits).tolist()
+        return self.decode_storage(self.values_host()[idx])
 
     def equality_term(self, value: str):
         """The int32 target *value* compares equal to on this column, or

@@ -226,6 +226,17 @@ class IndexImpl:
                     prev, lo = v, lower
         return out  # type: ignore[return-value]
 
+    def bounds_handle(self, probes: Sequence[Sequence[str]]):
+        """:meth:`bounds_many` for a caller whose next call is
+        :meth:`rows_for_bounds`: the same list, or — a unique
+        device-lazy index past the mirror cap, every probe naming the
+        full key — an opaque ``DeviceBounds`` whose search has been
+        dispatched and not read (``DeviceIndex.point_bounds_many``), so
+        that the batch's rows and bounds come back in one read."""
+        if self._rows is None and self.dev is not None and self.dev.supported:
+            return self.dev.point_bounds_many(probes, chain=True)
+        return self.bounds_many(probes)
+
     def find_rows(self, values: Sequence[str]) -> List[Row]:
         """Row range matching the key prefix (csvplus.go:870-891).
 
@@ -241,12 +252,11 @@ class IndexImpl:
         """Batched :meth:`find_rows`: all bounds in one vectorized pass
         (:meth:`bounds_many`), then ONE amortized decode over the union
         of matched ranges (:meth:`rows_for_bounds`)."""
-        return self.rows_for_bounds(self.bounds_many(probes))
+        return self.rows_for_bounds(self.bounds_handle(probes))
 
-    def rows_for_bounds(
-        self, bounds: Sequence[Tuple[int, int]]
-    ) -> List[List[Row]]:
-        """Decode one row block per [lower, upper) range.
+    def rows_for_bounds(self, bounds) -> List[List[Row]]:
+        """Decode one row block per [lower, upper) range (*bounds*: what
+        :meth:`bounds_many` or :meth:`bounds_handle` returned).
 
         On a device-lazy index the matched ranges decode together: the
         mirror tier batches through the LRU-cached
@@ -254,9 +264,23 @@ class IndexImpl:
         the above-cap tier pays ONE device gather + decode for the whole
         batch instead of a transfer per probe.
         """
-        if self._rows is None and self.dev is not None:
-            from .ops.join import DeviceIndex
+        from .ops.join import DeviceBounds, DeviceIndex
 
+        if isinstance(bounds, DeviceBounds):
+            # the chain: the search's answer feeds the gather where it
+            # lies, and ONE read brings bounds and rows (a hit is the row
+            # at ``lower``; a miss's and a pad query's slot are dropped by
+            # position)
+            table = self.dev.table
+            got = table.take_rows(bounds.res)
+            bounds.settle(got)
+            hit = np.flatnonzero(bounds.upper > bounds.lower)
+            rows = table.decode_rows(got[2:, hit])
+            out = [[] for _ in range(bounds.ok.shape[0])]
+            for i, row in zip(hit.tolist(), rows):
+                out[i] = [row]
+            return out
+        if self._rows is None and self.dev is not None:
             table = self.dev.table
             # gate on total CELLS, not rows: the mirror transfers every
             # column, so a wide table must not blow the transfer budget
@@ -389,7 +413,7 @@ class Index:
         norm = [
             (p,) if isinstance(p, str) else tuple(p) for p in probes
         ]
-        bounds = impl.bounds_many(norm)
+        bounds = impl.bounds_handle(norm)
         groups = impl.rows_for_bounds(bounds)
         device_tier = (
             impl._rows is None and impl.dev is not None and impl.dev.supported
@@ -855,6 +879,7 @@ def create_unique_index(src, columns: Sequence[str]) -> Index:
                 "duplicate value while creating unique index: "
                 + str(row.select_existing(*cols))
             )
+        impl.dev.unique = True  # a full-key probe hits at most the row at its lower bound
         return index
 
     rows = impl.rows

@@ -379,6 +379,35 @@ def device_index_static_info(index):
     )
 
 
+class DeviceBounds:
+    """One lookup batch's bounds, left on the device.
+
+    Opaque handle between :meth:`DeviceIndex.point_bounds_many` and
+    ``IndexImpl.rows_for_bounds`` (the precedent: ``storage/lsm.py``'s
+    ``MultiBounds``): *res* is the search's raw ``int32[2, B]`` answer
+    (``lower`` over ``upper``; B the batch length's power-of-two bucket)
+    and feeds the rows' gather un-read; the masks the host applies to
+    read bounds — a value the dictionary lacks, the one-past-top probe
+    of a 31-bit universe — wait in *ok* / *over* for :meth:`settle`.
+    Iterates as the batch's ``(lower, upper)`` pairs once settled."""
+
+    __slots__ = ("res", "ok", "over", "n", "lower", "upper")
+
+    def __init__(self, res: jax.Array, ok: np.ndarray, over: np.ndarray, n: int):
+        self.res, self.ok, self.over, self.n = res, ok, over, n
+        self.lower = self.upper = None
+
+    def settle(self, head: np.ndarray) -> None:
+        """*head*: the host copy of ``res`` that came back with the rows."""
+        m = self.ok.shape[0]
+        upper = np.where(self.over, self.n, head[1, :m])
+        self.lower = np.where(self.ok, head[0, :m], 0).astype(np.int64)
+        self.upper = np.where(self.ok, upper, 0).astype(np.int64)
+
+    def __iter__(self):
+        return zip(self.lower.tolist(), self.upper.tolist())
+
+
 @dataclass
 class DeviceIndex:
     """Columnar build side of a join: table + packed sorted keys."""
@@ -473,6 +502,9 @@ class DeviceIndex:
         self._aux_lock = threading.Lock()
         self._composed: Dict[tuple, _Composed] = {}
         self._compositions = 0  # table-size compositions run (set-up work)
+        # set by create_unique_index once its adjacent-duplicate check has
+        # passed: a full-key hit is then exactly one row (_chains)
+        self.unique = False
 
     @property
     def supported(self) -> bool:
@@ -618,13 +650,20 @@ class DeviceIndex:
         return lower, upper
 
     def point_bounds_many(
-        self, probes: Sequence[Sequence[str]]
-    ) -> List[Tuple[int, int]]:
+        self, probes: Sequence[Sequence[str]], chain: bool = False
+    ) -> "List[Tuple[int, int]] | DeviceBounds":
         """Batched :meth:`point_bounds`: one vectorized code translation
         per key column (``find_codes``) and ONE searchsorted pass per
         storage tier over all probes, instead of per-probe binary
         searches and device dispatches.  Semantics match a loop of
         single ``point_bounds`` calls exactly.
+
+        *chain*: the caller hands the answer straight to
+        ``DeviceTable.take_rows``.  Where a hit is then exactly the row
+        at ``lower`` — a unique index past the mirror cap, every probe
+        naming the full key — the search's answer stays on the device in
+        a :class:`DeviceBounds` and nothing is read here; anywhere else,
+        the list as ever.
         """
         assert self.supported
         self.offer_build_sample()
@@ -666,13 +705,22 @@ class DeviceIndex:
                         np.where(over, 0, top).astype(np.int32), side="left"
                     )
                 else:
-                    qt = np.concatenate([qk, np.where(over, 0, top)]).astype(np.int32)
-                    res = np.asarray(
-                        _bounds_search_kernel(self.packed_i32, jnp.asarray(qt))
-                    )
+                    # padded to the batch length's power-of-two bucket (a
+                    # pad query searches key 0 and is dropped by position),
+                    # so a length compiles once a bucket
+                    qt = np.zeros((2, 1 << max(m - 1, 0).bit_length()), dtype=np.int32)
+                    qt[0, :m], qt[1, :m] = qk, np.where(over, 0, top)
+                    res = _bounds_search_kernel(self.packed_i32, qt)
+                    if chain and int(karr.min()) == len(self.key_columns) and self._chains():
+                        # a hit is the row at ``lower``: the rows' gather
+                        # takes the answer where it lies, and the one read
+                        # after it brings the bounds along
+                        span["host_syncs"] = 0
+                        return DeviceBounds(res, ok, over, n)
+                    res = np.asarray(res)
                     telemetry.count_sync(2 * m)
                     span["host_syncs"], span["elements"] = 1, 2 * m
-                    lower, upper = res[:m], res[m:]
+                    lower, upper = res[0, :m], res[1, :m]
                 upper = np.where(over, n, upper)
             else:
                 lower = np.searchsorted(self.packed_i64, qk, side="left")
@@ -685,6 +733,19 @@ class DeviceIndex:
         # tolist() converts to native ints in C — a python int() pair per
         # probe costs more than the searchsorted itself at 10K probes
         return list(zip(lower.tolist(), upper.tolist()))
+
+    def _chains(self) -> bool:
+        """Whether a full-key batch's bounds may stay on the device: the
+        index is unique (so a hit is one row, the one at ``lower``) and
+        the search's answer can enter one program with every column
+        (one device set: a fact of the build, read once)."""
+        if not self.unique:
+            return False
+        got = getattr(self, "_one_device_set", None)
+        if got is None:
+            lanes = [c.storage for c in self.table.columns.values()]
+            got = self._one_device_set = same_placement([self.packed_i32] + lanes)
+        return got
 
     def _partitioned_for(self, qk_sh):
         """Range-partitioned build keys for *qk_sh*'s mesh, cached per

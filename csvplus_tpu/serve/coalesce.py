@@ -731,7 +731,9 @@ class LookupServer:
             with tracer.span("serve:bounds"):
                 if fault_site is not None:
                     faults.inject(fault_site)
-                bounds = source.bounds_many(probes)
+                # an Index may leave its bounds on the device for the
+                # gather (an opaque handle, as a MutableIndex's is)
+                bounds = getattr(source, "bounds_handle", source.bounds_many)(probes)
             with tracer.span("serve:gather-decode"):
                 groups = source.rows_for_bounds(bounds)
             return groups, bounds

@@ -829,6 +829,7 @@ def _kernel_examples():
         "typed.translate_sorted": ((i, i, i), {}),
         "typed.translate_empty": ((i,), {}),
         "table.gather_take": ((i, i), {}),
+        "table.gather_take_rows": ((jnp.stack([i, i]), (i, i)), {}),
         "table.apply_code_translation": ((i, i), {}),
         "table.sync_probe": ((i, i), {}),
         "index.sort": (((i, i, i),), {"num_keys": 2}),
@@ -846,7 +847,8 @@ KERNELS_LOWERED_HERE = sorted([
     "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.multiway_select",
     "join.multiway_expand", "join.gather_multiway", "join.gather_multiway_both",
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
-    "typed.translate_empty", "table.gather_take", "table.apply_code_translation",
+    "typed.translate_empty", "table.gather_take", "table.gather_take_rows",
+    "table.apply_code_translation",
     "table.sync_probe", "join.compose_probe", "join.probe_composed", "join.probe_composed_range",
     "index.sort", "index.adjacent_dup", "dedup.runs", "dedup.compact",
     "dedup.head",
@@ -907,7 +909,7 @@ def test_warm_join_and_lookup_pass_recompiles_nothing(monkeypatch):
         assert one_pass() == cold
     w.assert_zero("warm join + lookup pass")
     counts = compile_counts()
-    assert counts["serve.bounds_search"] >= 1 and counts["table.gather_take"] >= 1
+    assert counts["serve.bounds_search"] >= 1 and counts["table.gather_take_rows"] >= 1
 
 
 @pytest.mark.parametrize("index_keys", ["dense", "sparse"])

@@ -664,10 +664,19 @@ def test_untraced_dispatch_opens_no_span_and_no_annotation(served, past_mirror_c
     assert [m for m in made if m != ("span", "compile")] == []
 
 
-def test_traced_cycle_lands_whole_in_every_requests_tree(served, past_mirror_cap):
+@pytest.mark.parametrize("form", ["one-trip", "two-trips"])
+def test_traced_cycle_lands_whole_in_every_requests_tree(served, past_mirror_cap, form):
+    """*one-trip*: a unique index and full-key probes, so the bounds stay
+    on the device and the cycle reads once, no row position formed on
+    the host.  *two-trips*: the same table under a plain ``index_on``,
+    which cannot know that a hit is one row: bounds read, positions
+    formed, rows read."""
     from csvplus_tpu.obs.span import tracer
 
     idx, ids = served
+    one_trip = form == "one-trip"
+    if one_trip:
+        idx = cp.take(idx._impl.dev.table).unique_index_on("id").sync()
     n_clients = 12
     traces = [None] * n_clients
     with LookupServer(idx) as srv:
@@ -698,8 +707,10 @@ def test_traced_cycle_lands_whole_in_every_requests_tree(served, past_mirror_cap
         for name, parent in CYCLE_TREE.items():
             found = [s for s in spans if s.name == name]
             # the accounts are two: the index's counters after its
-            # lookups, the cycle's own at its end
-            assert len(found) == (2 if name == "serve:account" else 1), (name, len(found))
+            # lookups, the cycle's own at its end; no position is formed
+            # on the host where the bounds never come to it alone
+            times = {"serve:account": 2, "serve:gather:index": 0 if one_trip else 1}
+            assert len(found) == times.get(name, 1), (name, len(found))
             for s in found:
                 up = by_id[s.parent_id]
                 assert up.name == parent, name
@@ -711,14 +722,21 @@ def test_traced_cycle_lands_whole_in_every_requests_tree(served, past_mirror_cap
         assert qw.parent_id == dsp.parent_id == root.span_id
         assert qw.t_end == dsp.t_start and cycle.t_start <= dsp.t_start
         assert dsp.t_end <= cycle.t_end  # the cycle goes on after this reply
-        # counts at the boundaries: one read for the bounds, one per column
+        # counts at the boundaries: the search read or left on the device,
+        # one program and one read for both columns, padded to the bucket
         attrs = {s.name: s.attrs for s in spans}
         m = cycle.attrs["batch"]
-        assert attrs["serve:bounds:search"]["host_syncs"] == 1
-        assert attrs["serve:bounds:search"]["elements"] == 2 * m
-        assert attrs["serve:gather:take"]["dispatches"] == 2
-        assert attrs["serve:gather:readback"]["host_syncs"] == 2
-        assert attrs["serve:gather:readback"]["elements"] == 2 * m
+        bucket = 1 << (m - 1).bit_length()
+        if one_trip:
+            assert attrs["serve:bounds:search"] == {"host_syncs": 0}
+        else:
+            assert attrs["serve:bounds:search"] == {"host_syncs": 1, "elements": 2 * m}
+        assert attrs["serve:gather:take"]["dispatches"] == 1
+        assert attrs["serve:gather:readback"]["host_syncs"] == 1
+        assert attrs["serve:gather:readback"]["one_trip"] == int(one_trip)
+        if one_trip:  # lower, upper and two columns a query
+            assert attrs["serve:gather:readback"]["elements"] == 4 * bucket
+        assert sum(a.get("host_syncs", 0) for a in attrs.values()) == (1 if one_trip else 2)
         by_cycle.setdefault((cycle.t_start, cycle.t_end), []).append(spans)
     # timestamps are equal across the requests of a batch
     assert len(by_cycle) < n_clients  # some requests did share a cycle
