@@ -135,23 +135,17 @@ SYNC001_ALLOWED: Dict[str, str] = {
     ),
     "join.py:join_tables": (
         "deliberate stats-sync fast path: (total, max run) in ONE "
-        "2-scalar transfer decides the unique fast paths; the "
-        "unique-partial mask transfer is the _host_compact_ids trade "
-        "(cheaper than the serialized device scatter it replaces), "
-        "accounted as join:expand stage elements alongside the "
-        "host_sync_elements guard"
-    ),
-    "join.py:_compact_unique_partial": (
-        "multiway unique-partial host compaction (see "
-        "_host_compact_ids): deliberate mask transfer replacing the "
-        "serialized device scatter, accounted as join:expand stage "
-        "elements; the host_sync_elements guard excludes this "
-        "stats-synced path by design"
+        "2-scalar transfer decides the unique fast paths and sizes the "
+        "result, counted by count_sync at the site and as join:expand's "
+        "host_sync_elements; the len() calls read host tuples and "
+        "shapes; no transfer of row data (the unique-partial compaction "
+        "is the device program join.compact_partial)"
     ),
     "join.py:_multiway_ids": (
         "deliberate multiway stats sync (multiway_join and the fused "
         "multiway_join_selected): (total, max fanout, rows avoided) in "
-        "ONE 3-scalar transfer; no transfer of row data"
+        "ONE 3-scalar transfer, counted by count_sync at the site and "
+        "as join:expand's host_sync_elements; no transfer of row data"
     ),
     "join.py:_compose": (
         "set-up only: the largest and smallest count over a composed "

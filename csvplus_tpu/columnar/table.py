@@ -107,12 +107,16 @@ class _LaneState:
     every other copy remap its codes with one cheap gather instead of
     re-sorting the full dictionary."""
 
-    __slots__ = ("lanes", "sorted", "trans", "lock")
+    __slots__ = ("lanes", "sorted", "trans", "host", "lock")
 
     def __init__(self, lanes: tuple, sorted_: bool):
         self.lanes = lanes
         self.sorted = sorted_
         self.trans = None
+        # the settled lanes unpacked on the host, once a copy has read
+        # ``.dictionary``: every other copy (a join's gathered result
+        # column is a new one per execution) reads the same array
+        self.host = None
         # sibling copies may settle concurrently (ingest runs a prefetch
         # producer thread plus encode pools); the union sort + remap must
         # be serialized so it runs once and trans is never read half-set
@@ -228,17 +232,23 @@ class StringColumn:
         """The host dictionary — lazily materialized (download + unpack)
         for device-lane columns, then cached."""
         if self._dictionary is None:
-            from ..ops.lanes import unpack_host
+            st = self._lane_state
+            if st.host is None:
+                from ..ops.lanes import unpack_host
 
-            # once per column: a milestone of the process journal
-            # (``obs/span.py``) — the deferred sort, then the download
-            with tracer.milestone(
-                "lane-dict:materialize", entries=int(self._lane_state.lanes[0].shape[0])
-            ):
+                # once per shared lane state: a milestone of the process
+                # journal (``obs/span.py``) — the deferred sort, then the
+                # download
+                with tracer.milestone(
+                    "lane-dict:materialize", entries=int(st.lanes[0].shape[0])
+                ):
+                    self._ensure_sorted_lanes()
+                    with st.lock:  # settled lanes never change again
+                        if st.host is None:
+                            st.host = unpack_host([np.asarray(l) for l in st.lanes])
+            else:  # a sibling paid the download: this copy's codes settle alone
                 self._ensure_sorted_lanes()
-                self._dictionary = unpack_host(
-                    [np.asarray(l) for l in self._lane_state.lanes]
-                )
+            self._dictionary = st.host
         return self._dictionary
 
     def _ensure_sorted_lanes(self) -> None:

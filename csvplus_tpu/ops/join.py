@@ -1248,6 +1248,12 @@ def _slots_to_rows(lowers, entries):
     )
 
 
+def _slot_gathers(entries) -> int:
+    """The gathers :func:`_slots_to_rows` dispatches: one per depth-2
+    dimension."""
+    return sum(e is not None for e in entries)
+
+
 def _kept_build_names(dev_index: "DeviceIndex", stream_cols) -> List[str]:
     """The build columns the merge can read.  One whose name is on the
     stream, where the stream's column has no absent cell, is never read
@@ -1341,38 +1347,45 @@ def join_tables(
             total, maxc = (
                 int(v) for v in np.asarray(_probe_stats(lower, counts))
             )
+            telemetry.count_sync(2)  # THE blocking read of the stage
+            _exp.update(tier="device", host_sync_elements=2)
             if maxc <= 1 and total == stream.nrows:
                 # every stream row matched exactly once: identity on the
                 # stream side (columns pass through ungathered, caches
                 # intact), build rows addressed by the probe's lower
                 # bounds (or, at depth 2, the composed columns by slot)
                 build_ids = lower
-                _exp["path"] = "unique-identity"
+                _exp.update(path="unique-identity", form="identity", padded=0, row_gathers=0)
             else:
-                (lower,), entry = _slots_to_rows((lower,), (entry,)), None  # slots -> build rows
+                padded = 1 << max(total - 1, 0).bit_length() if total else 1
                 if maxc <= 1:
                     # unique but partial: compact the selection without the
                     # expansion scan; pow2 padding bounds recompiles
-                    padded = 1 << max(total - 1, 0).bit_length() if total else 1
-                    if _whole_device(lower, counts):
-                        sel = _host_compact_ids(np.asarray(counts) > 0, padded)
-                    else:
-                        sel = jnp.flatnonzero(
-                            counts > 0, size=padded, fill_value=0
-                        )
-                    probe_ids = sel[:total].astype(jnp.int32)
-                    build_ids = jnp.take(lower, probe_ids, axis=0)
-                    _exp["path"] = "unique-partial"
-                else:
-                    probe_ids, build_ids = expand_matches_device(
-                        lower, counts, total
+                    probe_ids, (build_ids,) = _compact_unique_partial(
+                        (lower,), (counts,), (entry,), padded
                     )
-                    _exp["path"] = "fan-out"
-            _exp["rows_out"] = total
+                    probe_ids, build_ids = probe_ids[:total], build_ids[:total]
+                    _exp.update(
+                        path="unique-partial", form=COMPACT_FORM,
+                        row_gathers=_slot_gathers((entry,)),
+                    )
+                else:
+                    (lower,) = _slots_to_rows((lower,), (entry,))
+                    probe_ids, build_ids = expand_matches_device(lower, counts, total)
+                    _exp.update(
+                        path="fan-out", form="prefix-scatter",
+                        row_gathers=2 + _slot_gathers((entry,)),
+                    )
+                entry = None  # the ids are build rows now, not depth-2 slots
+                _exp["padded"] = padded
         else:  # the partitioned (multi-chip) tier answers in numpy
             probe_ids, build_ids = expand_matches(lower, counts)
-            _exp["path"] = "host-expand"
-            _exp["rows_out"] = len(probe_ids)
+            total = len(probe_ids)
+            _exp.update(
+                path="host-expand", tier="host", form="numpy", padded=0,
+                row_gathers=0, host_sync_elements=0,
+            )
+        _exp.update(rows_out=total, emitted=total)
         telemetry.barrier((probe_ids, build_ids))
 
     build_names = _kept_build_names(dev_index, stream.columns)
@@ -1381,26 +1394,15 @@ def join_tables(
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        g_stream = None
-        if probe_ids is None:
-            # all-matched unique fast path: stream columns pass through
-            # untouched; only the build side gathers (one jit call)
-            if same_placement(build_codes + (build_ids,)):
-                g_build = _gather_cols(build_codes, build_ids)
-            else:
-                g_build = _take_each(build_codes, build_ids)
-            n_out = stream.nrows
-        elif same_placement(build_codes + stream_codes):
-            # ALL row-materializing gathers in one jit call, not one
-            # eager dispatch per column
-            g_build, g_stream = _gather_both_sides(
-                build_codes, stream_codes, build_ids, probe_ids
-            )
-            n_out = len(probe_ids)
+        # the build side's gathers in one jit call; where every row
+        # matched once the stream's columns pass through untouched, else
+        # its survivors move a program a lane (``_gather_lanes``)
+        if same_placement(build_codes + (build_ids,)):
+            g_build = _gather_cols(build_codes, build_ids)
         else:
             g_build = _take_each(build_codes, build_ids)
-            g_stream = _take_each(stream_codes, probe_ids)
-            n_out = len(probe_ids)
+        g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
+        n_out = stream.nrows if probe_ids is None else len(probe_ids)
         _mrg["row_gathers"] = len(g_build) + len(g_stream or ())
 
         cur = _merge_fold(
@@ -1412,14 +1414,23 @@ def join_tables(
     return DeviceTable(cur, n_out, stream.device)
 
 
-@register_kernel("join.gather_both_sides")
-def _gather_both_sides(build_codes, stream_codes, build_ids, probe_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    b_idx = jnp.asarray(build_ids, dtype=jnp.int32)
-    p_idx = jnp.asarray(probe_ids, dtype=jnp.int32)
-    return (
-        tuple(jnp.take(c, b_idx, axis=0) for c in build_codes),
-        tuple(jnp.take(c, p_idx, axis=0) for c in stream_codes),
-    )
+@register_kernel("join.gather_lane")
+def _gather_lane(storage, ids):
+    """One stream lane's surviving rows: a program of its own, because a
+    program gets ONE cross-program prefetch — a lane gathered here is
+    read from fast memory, where of four lanes gathered by one program
+    three are read where they lie, at a third of the rate (``PERF.md``
+    §5, PR 43)."""
+    return jnp.take(storage, ids, axis=0)
+
+
+def _gather_lanes(stream_codes, probe_ids):
+    """The stream's lanes at *probe_ids* (the joins that did not match
+    every row once).  Mixed placements (the partitioned tier's numpy ids
+    over a mesh-sharded stream) take the eager per-array path."""
+    if same_placement(stream_codes + (probe_ids,)):
+        return tuple(_gather_lane(c, probe_ids) for c in stream_codes)
+    return _take_each(stream_codes, probe_ids)
 
 
 @register_kernel("join.gather_cols")
@@ -1482,20 +1493,8 @@ def _multiway_stats(counts):  # analysis: allow[JIT001] retrace is per join ARIT
     return jnp.stack([total, maxp, inter])
 
 
-@register_kernel("join.multiway_select", static_argnames=("padded",))
-def _multiway_select_kernel(lowers, counts, padded: int):  # analysis: allow[JIT001] retrace is per join ARITY, not per data length
-    """Unique-but-partial fast path: every dimension matched <= once, so
-    the surviving fact rows compact by one pow2-padded flatnonzero and
-    each dimension's build row IS its lower bound — no expansion scan."""
-    _, prod = _fanout_products(counts)
-    sel = jnp.flatnonzero(prod > 0, size=padded, fill_value=0).astype(jnp.int32)
-    build = tuple(jnp.take(lo.astype(jnp.int32), sel, axis=0) for lo in lowers)
-    return sel, build
-
-
 def _whole_device(*arrays) -> bool:
-    """True when every probe answer sits whole on a single device — the
-    host compaction below reads them without a cross-device gather."""
+    """True when every array sits whole on a single device."""
     for a in arrays:
         sh = getattr(a, "sharding", None)
         if sh is None or len(sh.device_set) != 1:
@@ -1503,35 +1502,48 @@ def _whole_device(*arrays) -> bool:
     return True
 
 
-def _host_compact_ids(mask_np, padded: int) -> jax.Array:
-    """Ascending ids of the set mask positions, zero-padded to *padded*.
-
-    The unique-partial compaction is one linear scan, but XLA lowers the
-    flatnonzero form to cumsum + scatter and the host backend serializes
-    the scatter (~45ms per million rows — it dominated both macro-bench
-    legs).  The fast-path decision has already paid a stats sync, so the
-    mask costs one transfer: numpy scans it and only the padded id
-    vector ships back.  Bitwise-identical to the device kernel."""
-    ids = np.zeros(padded, dtype=np.int32)
-    nz = np.flatnonzero(mask_np)
-    ids[: nz.shape[0]] = nz
-    return jnp.asarray(ids)
+#: ``join:expand``'s ``form`` on the unique-partial paths
+COMPACT_FORM = "sort"
 
 
-def _compact_unique_partial(lowers, counts, padded: int):
-    """(probe_ids, per-dim build_ids) for the multiway unique-partial
-    shape — host compaction when the answers allow it (see
-    ``_host_compact_ids``), the jitted select kernel otherwise."""
-    if _whole_device(*lowers, *counts):
-        mask = np.asarray(counts[0]) > 0
-        for ct in counts[1:]:
-            mask &= np.asarray(ct) > 0
-        sel = _host_compact_ids(mask, padded)
-        build = tuple(
-            jnp.take(lo.astype(jnp.int32), sel, axis=0) for lo in lowers
-        )
-        return sel, build
-    return _multiway_select_kernel(lowers, counts, padded)
+@register_kernel("join.compact_partial", static_argnames=("padded",))
+def _compact_partial_kernel(lowers, counts, padded: int):  # analysis: allow[JIT001] retrace is per join ARITY, not per data length
+    """The unique-but-partial compaction, on the device for every
+    placement: every dimension matched <= once, so the surviving fact
+    rows are the rows whose every count is set, in order, and each
+    dimension's build row (or depth-2 slot) IS its lower bound at those
+    rows — no expansion scan.  Returns ``(probe_ids, per-dimension
+    build_ids)``, each *padded* long: the ascending survivors, bit for
+    bit ``np.flatnonzero``'s, then zeros.
+
+    ONE unstable ``lax.sort``, every dimension's lower bounds riding as
+    operands, keyed on the row number with the miss flag in its top bit
+    — what a stable sort on the flag compares, as one ``uint32`` with no
+    tie and no hidden iota operand (``ops/sort.py``'s ``dedup.compact``)
+    — then the first *padded* rows.  Of the forms measured (``PERF.md``
+    §5) the one whose time does not grow with the survivors."""
+    _, prod = _fanout_products(counts)
+    n = prod.shape[0]  # a row number is an int32 everywhere: n < 2**31
+    miss = jnp.uint32(1) << 31
+    order = jnp.arange(n, dtype=jnp.uint32) | jnp.where(prod > 0, jnp.uint32(0), miss)
+    moved = jax.lax.sort(
+        (order,) + tuple(lo.astype(jnp.int32) for lo in lowers), num_keys=1, is_stable=False
+    )
+    head = tuple(m[: min(padded, n)] for m in moved)
+    kept = head[0] < miss
+    out = tuple(jnp.where(kept, h, 0).astype(jnp.int32) for h in head)
+    if padded > n:  # the bucket of a total near a stream length that is no power of two
+        out = tuple(jnp.pad(o, (0, padded - n)) for o in out)
+    return out[0], out[1:]
+
+
+def _compact_unique_partial(lowers, counts, entries, padded: int):
+    """``(probe_ids, per-dimension build ROW ids)`` of the unique-partial
+    shape, *padded* long: the compaction program, then, for a depth-2
+    dimension, slots -> build rows over the padded survivors only (never
+    over the stream's rows)."""
+    probe_ids, build_ids = _compact_partial_kernel(lowers, counts, padded)
+    return probe_ids, _slots_to_rows(build_ids, entries)
 
 
 @register_kernel("join.multiway_expand", static_argnames=("padded_total",))
@@ -1603,7 +1615,8 @@ def _multiway_expand_host(lowers, counts):
 @register_kernel("join.gather_multiway")
 def _gather_multiway(build_codes, build_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
     """All build sides' row-materializing gathers in ONE jit call (the
-    unique-identity path: stream columns pass through untouched)."""
+    dimension tables are small: each is copied to fast memory inside
+    the program)."""
     out = []
     for codes, ids in zip(build_codes, build_ids):
         idx = jnp.asarray(ids, dtype=jnp.int32)
@@ -1611,58 +1624,53 @@ def _gather_multiway(build_codes, build_ids):  # analysis: allow[JIT001] — ari
     return tuple(out)
 
 
-@register_kernel("join.gather_multiway_both")
-def _gather_multiway_both(build_codes, stream_codes, build_ids, probe_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """Every side's gathers — N build sides + the stream — fused into
-    one executable, the multiway form of ``_gather_both_sides``."""
-    out_b = []
-    for codes, ids in zip(build_codes, build_ids):
-        idx = jnp.asarray(ids, dtype=jnp.int32)
-        out_b.append(tuple(jnp.take(c, idx, axis=0) for c in codes))
-    p_idx = jnp.asarray(probe_ids, dtype=jnp.int32)
-    return (
-        tuple(out_b),
-        tuple(jnp.take(c, p_idx, axis=0) for c in stream_codes),
-    )
-
-
 def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
     """The expansion decision shared by the multiway joins: one stats
     sync (total, max fanout, intermediate rows avoided), then the
-    unique-identity / unique-partial / fan-out / host-expand ids.
+    unique-identity / unique-partial / fan-out / host-expand ids, and
+    the ``join:expand`` extras (``docs/OBSERVABILITY.md``).
     Returns ``(probe_ids, build_ids, entries, total, inter)``;
     ``probe_ids`` None = every row matched once in EVERY dimension
     (then, and only then, *entries* survive: a depth-2 dimension's
     ``build_ids`` are slots of its composed columns)."""
+    dims = len(entries)
     if all(isinstance(lo, jax.Array) for lo in lowers):
         # (total, max fanout, intermediate rows avoided) in ONE
         # host transfer; unique dimensions skip the expansion scan
         total, maxp, inter = (
             int(v) for v in np.asarray(_multiway_stats(counts))
         )
+        telemetry.count_sync(3)  # THE blocking read of the stage
+        _exp.update(tier="device", host_sync_elements=3, rows_out=total, emitted=total)
         if maxp <= 1 and total == nrows:
-            _exp["path"] = prefix + "-unique-identity"
+            _exp.update(path=prefix + "-unique-identity", form="identity", padded=0, row_gathers=0)
             return None, lowers, entries, total, inter
-        lowers = _slots_to_rows(lowers, entries)
         padded = 1 << max(total - 1, 0).bit_length() if total else 1
         if maxp <= 1:
             probe_ids, build_ids = _compact_unique_partial(
-                lowers, counts, padded
+                lowers, counts, entries, padded
             )
-            _exp["path"] = prefix + "-unique-partial"
+            _exp.update(path=prefix + "-unique-partial", form=COMPACT_FORM, row_gathers=0)
         else:
             probe_ids, build_ids = _multiway_expand_kernel(
-                lowers, counts, padded
+                _slots_to_rows(lowers, entries), counts, padded
             )
-            _exp["path"] = prefix + "-fan-out"
+            # the scan's segment starts, then per dimension its radix,
+            # its wrap (but the major digit's) and its lower bounds
+            _exp.update(path=prefix + "-fan-out", form="prefix-scatter", row_gathers=3 * dims)
+        _exp["padded"] = padded
+        _exp["row_gathers"] += _slot_gathers(entries)
         probe_ids = probe_ids[:total]
         build_ids = tuple(b[:total] for b in build_ids)
     else:  # a host-answering tier: expand in numpy
         probe_ids, build_ids, total, inter = _multiway_expand_host(
             lowers, counts
         )
-        _exp["path"] = prefix + "-host-expand"
-    return probe_ids, build_ids, (None,) * len(entries), total, inter
+        _exp.update(
+            path=prefix + "-host-expand", tier="host", form="numpy", padded=0,
+            row_gathers=0, host_sync_elements=0, rows_out=total, emitted=total,
+        )
+    return probe_ids, build_ids, (None,) * dims, total, inter
 
 
 def multiway_join(
@@ -1713,7 +1721,6 @@ def multiway_join(
         probe_ids, build_ids, entries, total, inter = _multiway_ids(
             lowers, counts, entries, stream.nrows, "multiway", _exp
         )
-        _exp["rows_out"] = total
         telemetry.barrier((probe_ids,) + tuple(build_ids))
 
     build_names = [_kept_build_names(di, stream.columns) for di, _ in specs]
@@ -1726,23 +1733,12 @@ def multiway_join(
     flat_build = tuple(c for side in build_codes for c in side)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        g_stream = None
-        if probe_ids is None:
-            if same_placement(flat_build + tuple(build_ids)):
-                g_build = _gather_multiway(build_codes, build_ids)
-            else:
-                g_build = tuple(map(_take_each, build_codes, build_ids))
-            n_out = stream.nrows
-        elif same_placement(flat_build + stream_codes):
-            g_build, g_stream = _gather_multiway_both(
-                build_codes, stream_codes, build_ids, probe_ids
-            )
-            n_out = total
-        else:
-            # mixed placements (the host-expand tier lands here)
+        if same_placement(flat_build + tuple(build_ids)):
+            g_build = _gather_multiway(build_codes, build_ids)
+        else:  # mixed placements (the host-expand tier lands here)
             g_build = tuple(map(_take_each, build_codes, build_ids))
-            g_stream = _take_each(stream_codes, probe_ids)
-            n_out = total
+        g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
+        n_out = stream.nrows if probe_ids is None else total
         _mrg["row_gathers"] = len(flat_build) + len(g_stream or ())
 
         cur = _merge_fold(
@@ -1786,8 +1782,8 @@ def multiway_join(
 
 @register_kernel("join.gather_fused_both")
 def _gather_fused_both(build_codes, stream_codes, build_ids, probe_ids, sel):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """The fused-emit form of ``_gather_multiway_both``: stream columns
-    gather from FULL-length storage by the composed ``sel[probe_ids]``
+    """The fused emit: every build side's gathers, and the stream columns
+    gathered from FULL-length storage by the composed ``sel[probe_ids]``
     index — gather associativity is the whole fusion win (one gather
     instead of materialize-then-gather)."""
     out_b = []
@@ -1840,7 +1836,6 @@ def multiway_join_selected(
         probe_ids, build_ids, entries, total, inter = _multiway_ids(
             lowers, counts, entries, n_sel, "fused", _exp
         )
-        _exp["rows_out"] = total
         telemetry.barrier((probe_ids,) + tuple(build_ids))
 
     build_names = [_kept_build_names(di, cols) for di, _ in specs]

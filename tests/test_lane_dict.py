@@ -312,3 +312,28 @@ def test_deferred_lanes_survive_mesh_sharding(tmp_path, monkeypatch):
     assert idx.find("ord-000321").to_rows() == host_idx.find("ord-000321").to_rows()
     # and full decode parity through the sharded path
     assert dev.to_rows() == Take(from_file(str(p))).to_rows()
+
+
+def test_gathered_copies_share_one_materialized_dictionary(highcard_csv, monkeypatch):
+    """A join's result column over a lane dictionary is a NEW column per
+    execution (``gather`` / ``with_storage``): the host dictionary is
+    downloaded and unpacked once per shared lane state, not once per copy
+    — reading ``.dictionary`` of every selective result must not pay the
+    whole dictionary again."""
+    from csvplus_tpu.columnar.exec import execute_plan
+
+    table = execute_plan(from_file(highcard_csv).on_device().plan)
+    col = table.columns["order_id"]
+    assert col.dev_dictionary is not None and col._dictionary is None
+    calls = []
+    real = L.unpack_host
+    monkeypatch.setattr(L, "unpack_host", lambda lanes: calls.append(1) or real(lanes))
+    sel = np.arange(0, 400, 7)
+    copies = [col.gather(sel), col.gather(sel[::-1].copy()), col.with_storage(col.storage[:5])]
+    first = copies[0].dictionary
+    assert len(calls) == 1 and first.shape == (400,)
+    for c in copies[1:] + [col]:
+        assert c.dictionary is first  # the same array, no second download
+    assert len(calls) == 1
+    assert copies[0].decode() == [f"ord-{i:06d}" for i in sel]
+    assert copies[1].decode() == [f"ord-{i:06d}" for i in sel[::-1]]

@@ -816,14 +816,13 @@ def _kernel_examples():
         "join.probe_composed": ((i, jnp.int32(0), i, None), {}),
         "join.probe_composed_range": ((i, None, jnp.int32(8)), {}),
         "join.expand": ((i, i), {"padded_total": 16}),
-        "join.gather_both_sides": (((i,), (i,), i, i), {}),
+        "join.gather_lane": ((i, i), {}),
         "join.gather_cols": (((i, i), i), {}),
         "join.probe_stats": ((i, i), {}),
         "join.multiway_stats": (((i, i),), {}),
-        "join.multiway_select": (((i, i), (i, i)), {"padded": 8}),
+        "join.compact_partial": (((i, i), (i, i)), {"padded": 8}),
         "join.multiway_expand": (((i, i), (i, i)), {"padded_total": 16}),
         "join.gather_multiway": ((((i,), (i, i)), (i, i)), {}),
-        "join.gather_multiway_both": ((((i,), (i,)), (i, i), (i, i), i), {}),
         "join.gather_fused_both": ((((i,), (i,)), (i, i), (i, i), i, i), {}),
         "typed.translate_dense": ((i, jnp.int32(0), i), {}),
         "typed.translate_sorted": ((i, i, i), {}),
@@ -843,9 +842,9 @@ def _kernel_examples():
 # the names of _kernel_examples(), spelled out so that collection touches no array
 KERNELS_LOWERED_HERE = sorted([
     "join.probe_i32pair", "join.probe_direct", "join.probe_i32", "serve.bounds_search",
-    "join.build_direct_cum", "join.pack_qk", "join.expand", "join.gather_both_sides",
-    "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.multiway_select",
-    "join.multiway_expand", "join.gather_multiway", "join.gather_multiway_both",
+    "join.build_direct_cum", "join.pack_qk", "join.expand", "join.gather_lane",
+    "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.compact_partial",
+    "join.multiway_expand", "join.gather_multiway",
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
     "typed.translate_empty", "table.gather_take", "table.gather_take_rows",
     "table.apply_code_translation",
