@@ -57,6 +57,7 @@ from ..obs.recompile import register_kernel
 from ..obs.span import tracer
 from ..utils.env import env_int
 from ..utils.observe import telemetry
+from .gather import take_small, vmem_gather_selected, whole_device
 
 
 def _bits_for(n: int) -> int:
@@ -215,7 +216,11 @@ def _build_direct_cum(keys: jax.Array, total_bits: int) -> jax.Array:
 #
 # An XLA gather on the TPU costs per INDEX walked, not per byte: 10M
 # indices take 0.05-0.075 s whether the table has 1,000 entries or
-# 100,000 (PERF.md section 5).  The staged probe of one key column walks
+# 100,000 (PERF.md section 5).  That no longer sets the price where the
+# composed tables are read: ``take_small`` (``gather.py``) serves tables
+# of up to ``VMEM_GATHER_MAX_ENTRIES`` entries from VMEM, at a cost that
+# grows with the TABLE (PERF.md section 6, PR 44); a walk saved is still
+# a walk saved.  The staged probe of one key column walks
 # the rows three times — ``trans[v - lo]``, ``cum[q]``, ``cum[q + range]``
 # — and each build column is one more walk through the row id.  Every
 # one of those pointers is a function of the key's VALUE alone, and the
@@ -311,19 +316,20 @@ def _compose_probe_kernel(trans, cum, shift, range_size):
     return lower, counts, rid, jnp.stack([jnp.max(counts), jnp.min(counts)])
 
 
-@register_kernel("join.probe_composed")
-def _probe_composed_kernel(storage, base, lower_tab, cnt_tab):  # analysis: allow[JIT001] retrace is per slot rule (three: the rank of ``base``) and per table arity (two), not per data length
+@register_kernel("join.probe_composed", static_argnames=("vmem",))
+def _probe_composed_kernel(storage, base, lower_tab, cnt_tab, vmem=False):  # analysis: allow[JIT001] retrace is per slot rule (three: the rank of ``base``), per table arity (two) and per gather form (``vmem``), not per data length
     """Depth 1: a row's (lower, counts) from the composed tables — one
     walk where the index is unique (*cnt_tab* None; ``lower`` of an
     unmatched row then reads 0, not the insertion point: no consumer
-    reads it), two otherwise."""
+    reads it), two otherwise.  *vmem*: ``vmem_gather_selected``'s answer
+    for the tables (``ops/gather.py``)."""
     slot, ok = _universe_slot(storage, base, lower_tab.shape[0])
     if cnt_tab is None:
-        rid = jnp.where(ok, jnp.take(lower_tab, slot, axis=0), -1)
+        (rid,) = take_small((lower_tab,), slot, vmem=vmem)
+        rid = jnp.where(ok, rid, -1)
         return jnp.maximum(rid, 0), (rid >= 0).astype(jnp.int32)
-    lower = jnp.where(ok, jnp.take(lower_tab, slot, axis=0), 0)
-    counts = jnp.where(ok, jnp.take(cnt_tab, slot, axis=0), 0)
-    return lower, counts
+    lower, counts = take_small((lower_tab, cnt_tab), slot, vmem=vmem)
+    return jnp.where(ok, lower, 0), jnp.where(ok, counts, 0)
 
 
 @register_kernel("join.probe_composed_range")
@@ -820,7 +826,7 @@ class DeviceIndex:
         nothing invalidates them."""
         if self.packed_i32 is None or self.direct_bits is None:
             return None
-        if not _whole_device(pc.storage):
+        if not whole_device(pc.storage):
             return None
         ic = self.table.columns[self.key_columns[0]]
         if pc.kind == "int":
@@ -922,7 +928,7 @@ class DeviceIndex:
         with telemetry.stage("join:probe", nrows) as out:
             out["tier"] = "direct-composed"
             out["depth"] = 2
-            out["row_gathers"] = 0
+            out["row_gathers"] = out["vmem_gathers"] = 0
             ans = _probe_range_kernel(pc.storage, entry.base, jnp.int32(entry.size))
             telemetry.barrier(ans)
         return (entry,) + ans
@@ -965,8 +971,12 @@ class DeviceIndex:
                 out["tier"] = "direct-composed"
                 out["depth"] = 1
                 out["row_gathers"] = entry.walks
+                tabs = (entry.lower_tab,) + (() if entry.cnt_tab is None else (entry.cnt_tab,))
+                vmem = vmem_gather_selected(tabs, probe_cols[0].storage)
+                out["vmem_gathers"] = len(tabs) if vmem else 0
                 ans = _probe_composed_kernel(
-                    probe_cols[0].storage, entry.base, entry.lower_tab, entry.cnt_tab
+                    probe_cols[0].storage, entry.base, entry.lower_tab, entry.cnt_tab,
+                    vmem=vmem,  # analysis: allow[RETRACE002] read off shapes and placement: three values
                 )
                 telemetry.barrier(ans)
             return ans
@@ -1023,7 +1033,7 @@ class DeviceIndex:
                 cum = self._lanes_for(qk, "direct_cum")
                 with telemetry.stage("join:probe", nrows) as out:
                     out["tier"] = "direct"
-                    out["row_gathers"] = 2
+                    out["row_gathers"], out["vmem_gathers"] = 2, 0
                     ans = _probe_kernel_direct(
                         cum, qk, jnp.int32(1) << range_shift
                     )
@@ -1035,6 +1045,7 @@ class DeviceIndex:
             with telemetry.stage("join:probe", nrows) as out:
                 out["tier"] = "broadcast-i32"
                 out["row_gathers"] = 2 * _searchsorted_rounds(keys.shape[0])
+                out["vmem_gathers"] = 0
                 ans = _probe_kernel_i32(keys, qk, jnp.int32(1) << range_shift)
                 telemetry.barrier(ans)
             return ans
@@ -1404,6 +1415,7 @@ def join_tables(
         g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
         n_out = stream.nrows if probe_ids is None else len(probe_ids)
         _mrg["row_gathers"] = len(g_build) + len(g_stream or ())
+        _mrg["vmem_gathers"] = 0  # ``_gather_cols`` reads build columns at their length
 
         cur = _merge_fold(
             _stream_side(stream.columns, stream_names, g_stream),
@@ -1491,15 +1503,6 @@ def _multiway_stats(counts):  # analysis: allow[JIT001] retrace is per join ARIT
     total = jnp.sum(prod)
     maxp = jnp.max(prod) if prod.shape[0] else jnp.int32(0)
     return jnp.stack([total, maxp, inter])
-
-
-def _whole_device(*arrays) -> bool:
-    """True when every array sits whole on a single device."""
-    for a in arrays:
-        sh = getattr(a, "sharding", None)
-        if sh is None or len(sh.device_set) != 1:
-            return False
-    return True
 
 
 #: ``join:expand``'s ``form`` on the unique-partial paths
@@ -1612,16 +1615,25 @@ def _multiway_expand_host(lowers, counts):
     return probe_ids, tuple(build_ids), total, inter
 
 
-@register_kernel("join.gather_multiway")
-def _gather_multiway(build_codes, build_ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """All build sides' row-materializing gathers in ONE jit call (the
-    dimension tables are small: each is copied to fast memory inside
-    the program)."""
-    out = []
-    for codes, ids in zip(build_codes, build_ids):
-        idx = jnp.asarray(ids, dtype=jnp.int32)
-        out.append(tuple(jnp.take(c, idx, axis=0) for c in codes))
-    return tuple(out)
+@register_kernel("join.gather_multiway", static_argnames=("vmem",))
+def _gather_multiway(build_codes, build_ids, vmem=None):  # analysis: allow[JIT001] — arity fixed per pipeline shape
+    """All build sides' row-materializing gathers in ONE jit call.
+    *vmem* says per dimension whether its tables are read from VMEM
+    (``_gather_build``; None: no dimension's are)."""
+    vmem = vmem or (False,) * len(build_codes)
+    return tuple(
+        take_small(codes, ids, vmem=v) for codes, ids, v in zip(build_codes, build_ids, vmem)
+    )
+
+
+def _gather_build(build_codes, build_ids, _mrg: dict):
+    """The multiway joins' build-side emit where every array shares a
+    placement: ``_gather_multiway`` with each dimension's gather form
+    read off its tables (``vmem_gather_selected``), and the lanes the
+    VMEM kernel serves counted into ``join:merge``'s ``vmem_gathers``."""
+    vmem = tuple(map(vmem_gather_selected, build_codes, build_ids))
+    _mrg["vmem_gathers"] = sum(len(c) for c, v in zip(build_codes, vmem) if v)
+    return _gather_multiway(build_codes, build_ids, vmem=vmem)  # analysis: allow[RETRACE002] read off shapes and placement: three values a dimension
 
 
 def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
@@ -1733,8 +1745,9 @@ def multiway_join(
     flat_build = tuple(c for side in build_codes for c in side)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
+        _mrg["vmem_gathers"] = 0
         if same_placement(flat_build + tuple(build_ids)):
-            g_build = _gather_multiway(build_codes, build_ids)
+            g_build = _gather_build(build_codes, build_ids, _mrg)
         else:  # mixed placements (the host-expand tier lands here)
             g_build = tuple(map(_take_each, build_codes, build_ids))
         g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
@@ -1848,12 +1861,13 @@ def multiway_join_selected(
     flat_build = tuple(c for side in build_codes for c in side)
 
     with telemetry.stage("join:merge", n_sel) as _mrg:
+        _mrg["vmem_gathers"] = 0
         if probe_ids is None:
             # every selected row matched once per dimension: the stream
             # side IS the selection — the one gather the staged
             # materialize would have paid anyway (identity: none at all)
             if same_placement(flat_build + tuple(build_ids)):
-                g_build = _gather_multiway(build_codes, build_ids)
+                g_build = _gather_build(build_codes, build_ids, _mrg)
             else:
                 g_build = tuple(map(_take_each, build_codes, build_ids))
             if identity:
