@@ -1169,10 +1169,19 @@ def expand_matches_device(
     if total is None:
         total = int(jnp.sum(counts))  # the one O(1) sync
     padded = 1 << max(total - 1, 0).bit_length()
-    probe_ids, build_ids = _expand_kernel(
-        jnp.asarray(lower), jnp.asarray(counts), padded
-    )
-    return probe_ids[:total], build_ids[:total]
+    padded_ids = _expand_kernel(jnp.asarray(lower), jnp.asarray(counts), padded)
+    # the cut lowers once per total: a slice compiles in milliseconds, and
+    # the expansion, which does not, never sees the total
+    return _expand_head_kernel(padded_ids, total=total)  # analysis: allow[RETRACE002]
+
+
+@register_kernel("join.expand_head", static_argnames=("total",))
+def _expand_head_kernel(ids: Tuple[jax.Array, ...], total: int) -> Tuple[jax.Array, ...]:  # analysis: allow[JIT001] retrace is per id-lane count, not per data length
+    """The first *total* slots of every padded id lane.  A program of
+    its own (``ops/sort.py``'s ``dedup.head``) so that the expansion
+    compiles once per power of two, not per total, and so that the cut
+    runs under a ``csvplus.`` name, not as an eager slice a lane."""
+    return tuple(lane[:total] for lane in ids)
 
 
 def _checked_probe_cols(
@@ -1308,6 +1317,17 @@ def _stream_side(cols, names, gathered):
     return {n: cols[n].with_storage(g) for n, g in zip(names, gathered)}
 
 
+def _count_gathers(_mrg: dict, build_lanes: int, g_stream) -> None:
+    """``join:merge``'s gather counts: the lanes gathered from the build
+    side(s) and from the stream (none where its rows pass through), and
+    their sum under the name the stage has carried since PR 26."""
+    stream_lanes = len(g_stream or ())
+    _mrg.update(
+        row_gathers=build_lanes + stream_lanes,
+        build_gathers=build_lanes, stream_gathers=stream_lanes,
+    )
+
+
 def _merge_fold(cur, sides):
     """Fold the cascade's merge left to right over *sides* of
     ``(dev_index, kept names, gathered storages)``: level d inserts
@@ -1386,6 +1406,7 @@ def join_tables(
                     _exp.update(
                         path="fan-out", form="prefix-scatter",
                         row_gathers=2 + _slot_gathers((entry,)),
+                        probes=stream.nrows, max_run=maxc,
                     )
                 entry = None  # the ids are build rows now, not depth-2 slots
                 _exp["padded"] = padded
@@ -1405,17 +1426,20 @@ def join_tables(
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        # the build side's gathers in one jit call; where every row
-        # matched once the stream's columns pass through untouched, else
-        # its survivors move a program a lane (``_gather_lanes``)
-        if same_placement(build_codes + (build_ids,)):
+        # the build side: composed tables (depth 2) and a mesh's lanes
+        # in one jit call, a column read at its own length on one device a
+        # program a lane (``_gather_lanes``), as the stream's survivors
+        # are; where every row matched once the stream passes untouched
+        if entry is None and whole_device(build_ids, *build_codes):
+            g_build = _gather_lanes(build_codes, build_ids)
+        elif same_placement(build_codes + (build_ids,)):
             g_build = _gather_cols(build_codes, build_ids)
         else:
             g_build = _take_each(build_codes, build_ids)
         g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
         n_out = stream.nrows if probe_ids is None else len(probe_ids)
-        _mrg["row_gathers"] = len(g_build) + len(g_stream or ())
-        _mrg["vmem_gathers"] = 0  # ``_gather_cols`` reads build columns at their length
+        _count_gathers(_mrg, len(g_build), g_stream)
+        _mrg["vmem_gathers"] = 0  # neither program reaches ``take_small``: ``jnp.take``
 
         cur = _merge_fold(
             _stream_side(stream.columns, stream_names, g_stream),
@@ -1428,18 +1452,20 @@ def join_tables(
 
 @register_kernel("join.gather_lane")
 def _gather_lane(storage, ids):
-    """One stream lane's surviving rows: a program of its own, because a
-    program gets ONE cross-program prefetch — a lane gathered here is
+    """One lane's rows at *ids*: a program of its own, because a program
+    gets one or two cross-program prefetches — a lane gathered here is
     read from fast memory, where of four lanes gathered by one program
-    three are read where they lie, at a third of the rate (``PERF.md``
-    §5, PR 43)."""
+    two or three are read where they lie, at a third of the rate
+    (``PERF.md`` §5: PR 43, the stream's lanes; PR 45, the build side's)."""
     return jnp.take(storage, ids, axis=0)
 
 
 def _gather_lanes(stream_codes, probe_ids):
-    """The stream's lanes at *probe_ids* (the joins that did not match
-    every row once).  Mixed placements (the partitioned tier's numpy ids
-    over a mesh-sharded stream) take the eager per-array path."""
+    """Full-length lanes at *probe_ids*, a program a lane: the stream's
+    survivors (the joins that did not match every row once) and, on one
+    device, the build side's columns.  Mixed placements (the partitioned
+    tier's numpy ids over a mesh-sharded stream) take the eager
+    per-array path."""
     if same_placement(stream_codes + (probe_ids,)):
         return tuple(_gather_lane(c, probe_ids) for c in stream_codes)
     return _take_each(stream_codes, probe_ids)
@@ -1752,7 +1778,7 @@ def multiway_join(
             g_build = tuple(map(_take_each, build_codes, build_ids))
         g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
         n_out = stream.nrows if probe_ids is None else total
-        _mrg["row_gathers"] = len(flat_build) + len(g_stream or ())
+        _count_gathers(_mrg, len(flat_build), g_stream)
 
         cur = _merge_fold(
             _stream_side(stream.columns, stream_names, g_stream),
@@ -1895,7 +1921,7 @@ def multiway_join_selected(
             g_build = tuple(map(_take_each, build_codes, build_ids))
             g_stream = _take_each(stream_codes, e_idx)
             n_out = total
-        _mrg["row_gathers"] = len(flat_build) + len(g_stream or ())
+        _count_gathers(_mrg, len(flat_build), g_stream)
 
         cur = _merge_fold(
             _stream_side(cols, stream_names, g_stream),

@@ -816,6 +816,7 @@ def _kernel_examples():
         "join.probe_composed": ((i, jnp.int32(0), i, None), {}),
         "join.probe_composed_range": ((i, None, jnp.int32(8)), {}),
         "join.expand": ((i, i), {"padded_total": 16}),
+        "join.expand_head": (((i, i),), {"total": 5}),
         "join.gather_lane": ((i, i), {}),
         "join.gather_cols": (((i, i), i), {}),
         "join.probe_stats": ((i, i), {}),
@@ -842,7 +843,7 @@ def _kernel_examples():
 # the names of _kernel_examples(), spelled out so that collection touches no array
 KERNELS_LOWERED_HERE = sorted([
     "join.probe_i32pair", "join.probe_direct", "join.probe_i32", "serve.bounds_search",
-    "join.build_direct_cum", "join.pack_qk", "join.expand", "join.gather_lane",
+    "join.build_direct_cum", "join.pack_qk", "join.expand", "join.expand_head", "join.gather_lane",
     "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.compact_partial",
     "join.multiway_expand", "join.gather_multiway",
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
