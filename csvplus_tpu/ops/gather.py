@@ -20,7 +20,10 @@ table is small: past ``VMEM_GATHER_MAX_ENTRIES`` (and off the TPU, and
 for a stream that is not whole on one device: a ``pallas_call`` is not
 partitioned by GSPMD) the entry point IS ``jnp.take``, the same HLO as
 ever.  Which way a call goes is read off shapes and placement by
-``vmem_gather_selected``, at dispatch, and passed as a static flag.
+``vmem_gather_selected``, at dispatch, and passed as a static flag: the
+one rule for every caller — the multiway joins' emit and the composed
+probe (PR 44), the binary join's emit (either side) and the fan-out
+expansion's two segment reads (PR 46; ``ops/join.py``).
 """
 
 from __future__ import annotations
@@ -63,17 +66,22 @@ def whole_device(*arrays) -> bool:
     return True
 
 
-def vmem_gather_selected(tables: Sequence[jax.Array], idx):
+def vmem_gather_selected(tables: Sequence[jax.Array], idx=None):
     """The rule, read off the input at dispatch (outside the jit): the
     kernel serves these *tables* read by *idx* when they fit
     (``VMEM_GATHER_MAX_ENTRIES`` int32 entries), every array is whole on
-    ONE device, and the backend is a TPU.  The answer is ``take_small``'s
-    static *vmem* flag: False, or ``_kernel_mode()``'s."""
+    ONE device, and the backend is a TPU.  *idx* None: the index does
+    not exist yet — the program that reads the tables forms it, on the
+    device they are on (``csvplus.join.expand``; *tables* are then the
+    program's inputs, of the tables' length, dtype and placement).  The
+    answer is ``take_small``'s static *vmem* flag: False, or
+    ``_kernel_mode()``'s."""
     if not tables or any(t.ndim != 1 or t.dtype != jnp.int32 for t in tables):
         return False
     if not 0 < tables[0].shape[0] <= VMEM_GATHER_MAX_ENTRIES:
         return False
-    return whole_device(idx, *tables) and _kernel_mode()
+    placed = tuple(tables) if idx is None else (idx, *tables)
+    return whole_device(*placed) and _kernel_mode()
 
 
 def _kernel(idx_ref, *refs, size: int):

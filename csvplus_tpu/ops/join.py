@@ -216,11 +216,13 @@ def _build_direct_cum(keys: jax.Array, total_bits: int) -> jax.Array:
 #
 # An XLA gather on the TPU costs per INDEX walked, not per byte: 10M
 # indices take 0.05-0.075 s whether the table has 1,000 entries or
-# 100,000 (PERF.md section 5).  That no longer sets the price where the
-# composed tables are read: ``take_small`` (``gather.py``) serves tables
-# of up to ``VMEM_GATHER_MAX_ENTRIES`` entries from VMEM, at a cost that
-# grows with the TABLE (PERF.md section 6, PR 44); a walk saved is still
-# a walk saved.  The staged probe of one key column walks
+# 100,000 (PERF.md section 5).  That no longer sets the price where a
+# small table is read — the composed tables, and since PR 46 either side
+# of the binary join's emit and the fan-out expansion's segment reads:
+# ``take_small`` (``gather.py``) serves tables of up to
+# ``VMEM_GATHER_MAX_ENTRIES`` entries from VMEM, at a cost that grows
+# with the TABLE (PERF.md section 6, PRs 44 and 46); a walk saved is
+# still a walk saved.  The staged probe of one key column walks
 # the rows three times — ``trans[v - lo]``, ``cum[q]``, ``cum[q + range]``
 # — and each build column is one more walk through the row id.  Every
 # one of those pointers is a function of the key's VALUE alone, and the
@@ -1127,14 +1129,16 @@ def expand_matches(
     return probe_ids, build_ids
 
 
-@register_kernel("join.expand", static_argnames=("padded_total",))
-def _expand_kernel(lower, counts, padded_total: int):
+@register_kernel("join.expand", static_argnames=("padded_total", "vmem"))
+def _expand_kernel(lower, counts, padded_total: int, vmem=False):
     """Device fan-out expansion with a static output size: an exclusive
     prefix sum over counts locates each probe row's output segment, a
     scatter of segment markers + running max inverts it per output slot
     (O(n), unlike a searchsorted inversion whose ~log n sequential
     gather rounds dominate at the 100M-row scale).  Positions past the
-    true total produce clipped junk the caller slices off."""
+    true total produce clipped junk the caller slices off.  *vmem*:
+    ``vmem_gather_selected``'s answer for the two probe-length tables
+    every output slot reads (``ops/gather.py``)."""
     counts = counts.astype(jnp.int32)
     ends = jnp.cumsum(counts)
     starts = ends - counts
@@ -1147,29 +1151,36 @@ def _expand_kernel(lower, counts, padded_total: int):
     seg = seg.at[mark_pos].max(ids, mode="drop")
     probe_ids = jax.lax.cummax(seg)  # fill each segment with its probe id
     out_pos = jnp.arange(padded_total, dtype=jnp.int32)
-    group_base = jnp.take(starts, probe_ids, axis=0)
-    build_ids = jnp.take(lower.astype(jnp.int32), probe_ids, axis=0) + (
-        out_pos - group_base
-    )
+    # each slot's segment start and first build row: two tables, one index
+    group_base, first = take_small((starts, lower.astype(jnp.int32)), probe_ids, vmem=vmem)
+    build_ids = first + (out_pos - group_base)
     return probe_ids, build_ids
 
 
 def expand_matches_device(
-    lower, counts, total: "int | None" = None
+    lower, counts, total: "int | None" = None, _exp: "dict | None" = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Fan-out expansion on device; only the total (sizing the static
     output shape) crosses to host — SURVEY §7's count -> prefix-sum ->
     scatter.  The kernel compiles at the next power of two, so repeated
     joins with varying totals hit O(log n) distinct shapes, not one
     compilation per total.  A caller that already synced the total (e.g.
-    join_tables' probe stats) passes it to skip the round trip."""
+    join_tables' probe stats) passes it to skip the round trip.  The
+    scan's two segment reads go through the VMEM kernel where the probe
+    is short enough (``vmem_gather_selected``, read off the inputs: the
+    index is born inside the program, where they are); the reads it
+    served are counted into *_exp*'s ``vmem_gathers``."""
     if counts.shape[0] == 0:  # empty probe: nothing to expand
         empty = jnp.zeros(0, dtype=jnp.int32)
         return empty, empty
     if total is None:
         total = int(jnp.sum(counts))  # the one O(1) sync
     padded = 1 << max(total - 1, 0).bit_length()
-    padded_ids = _expand_kernel(jnp.asarray(lower), jnp.asarray(counts), padded)
+    lower, counts = jnp.asarray(lower), jnp.asarray(counts)
+    vmem = vmem_gather_selected((lower, counts))
+    if _exp is not None:
+        _exp["vmem_gathers"] = 2 if vmem else 0
+    padded_ids = _expand_kernel(lower, counts, padded, vmem=vmem)  # analysis: allow[RETRACE002] read off shapes and placement: three values
     # the cut lowers once per total: a slice compiles in milliseconds, and
     # the expansion, which does not, never sees the total
     return _expand_head_kernel(padded_ids, total=total)  # analysis: allow[RETRACE002]
@@ -1371,6 +1382,7 @@ def join_tables(
     lower, counts, entry = _probe_dim(dev_index, probe_cols, stream.nrows)
     probe_ids = build_ids = None
     with telemetry.stage("join:expand", stream.nrows) as _exp:
+        _exp["vmem_gathers"] = 0  # the fan-out's two segment reads where the kernel serves them
         if isinstance(lower, jax.Array):
             # (total matches, max run length) in ONE host transfer; a
             # unique build side (max run 1 — the reference's flagship
@@ -1402,7 +1414,7 @@ def join_tables(
                     )
                 else:
                     (lower,) = _slots_to_rows((lower,), (entry,))
-                    probe_ids, build_ids = expand_matches_device(lower, counts, total)
+                    probe_ids, build_ids = expand_matches_device(lower, counts, total, _exp)
                     _exp.update(
                         path="fan-out", form="prefix-scatter",
                         row_gathers=2 + _slot_gathers((entry,)),
@@ -1426,20 +1438,23 @@ def join_tables(
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        # the build side: composed tables (depth 2) and a mesh's lanes
-        # in one jit call, a column read at its own length on one device a
-        # program a lane (``_gather_lanes``), as the stream's survivors
-        # are; where every row matched once the stream passes untouched
-        if entry is None and whole_device(build_ids, *build_codes):
-            g_build = _gather_lanes(build_codes, build_ids)
-        elif same_placement(build_codes + (build_ids,)):
-            g_build = _gather_cols(build_codes, build_ids)
-        else:
-            g_build = _take_each(build_codes, build_ids)
-        g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
+        # either side's tables that fit VMEM: ``take_small`` in one
+        # program (``_emit_side``).  Else the build side's composed tables
+        # (depth 2) and a mesh's lanes in one jit call, a column read at
+        # its own length on one device a program a lane, as the stream's
+        # survivors are; where every row matched once the stream passes
+        # untouched
+        g_build, build_vmem = _emit_side(
+            build_codes, build_ids, entry is None and whole_device(build_ids, *build_codes)
+        )
+        g_stream, stream_vmem = (
+            (None, False) if probe_ids is None else _emit_side(stream_codes, probe_ids, True)
+        )
         n_out = stream.nrows if probe_ids is None else len(probe_ids)
         _count_gathers(_mrg, len(g_build), g_stream)
-        _mrg["vmem_gathers"] = 0  # neither program reaches ``take_small``: ``jnp.take``
+        _mrg["vmem_gathers"] = (len(g_build) if build_vmem else 0) + (
+            len(g_stream) if stream_vmem else 0
+        )
 
         cur = _merge_fold(
             _stream_side(stream.columns, stream_names, g_stream),
@@ -1471,10 +1486,30 @@ def _gather_lanes(stream_codes, probe_ids):
     return _take_each(stream_codes, probe_ids)
 
 
-@register_kernel("join.gather_cols")
-def _gather_cols(codes, ids):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    idx = jnp.asarray(ids, dtype=jnp.int32)
-    return tuple(jnp.take(c, idx, axis=0) for c in codes)
+@register_kernel("join.gather_cols", static_argnames=("vmem",))
+def _gather_cols(codes, ids, vmem=False):  # analysis: allow[JIT001] — arity fixed per pipeline shape, two gather forms
+    """Lanes sharing one index in ONE program.  *vmem*:
+    ``vmem_gather_selected``'s answer for *codes* (``ops/gather.py``);
+    False is ``jnp.take`` a lane, the program as it ever was."""
+    return take_small(codes, ids, vmem=vmem)
+
+
+def _emit_side(codes, ids, lane_each: bool):
+    """One side of the binary join's emit — *codes* read at *ids* — and
+    whether the VMEM kernel served its lanes.  Tables the rule admits
+    (``vmem_gather_selected``: small, whole on the ids' device, a TPU)
+    go through ``take_small`` in ONE ``csvplus.join.gather_cols``; of
+    the others *lane_each* lanes move a program a lane
+    (``_gather_lanes``), the rest in one jit call where every array
+    shares a placement, eagerly where not."""
+    if not same_placement(codes + (ids,)):
+        return _take_each(codes, ids), False
+    vmem = vmem_gather_selected(codes, ids)
+    if vmem:
+        return _gather_cols(codes, ids, vmem), True  # analysis: allow[RETRACE002] read off shapes and placement: three values
+    if lane_each:
+        return _gather_lanes(codes, ids), False
+    return _gather_cols(codes, ids), False
 
 
 @register_kernel("join.probe_stats")
@@ -1672,6 +1707,7 @@ def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
     (then, and only then, *entries* survive: a depth-2 dimension's
     ``build_ids`` are slots of its composed columns)."""
     dims = len(entries)
+    _exp["vmem_gathers"] = 0  # ``…multiway_expand``'s reads are ``jnp.take``
     if all(isinstance(lo, jax.Array) for lo in lowers):
         # (total, max fanout, intermediate rows avoided) in ONE
         # host transfer; unique dimensions skip the expansion scan

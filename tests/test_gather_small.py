@@ -2,11 +2,12 @@
 
 ``jnp.take`` is the arbiter, bit for bit: the kernel alone over every
 shape class of table, lane count and stream length (interpret mode, so
-small N), then the rule that selects it, then the two programs that
-call it — ``csvplus.join.gather_multiway`` and
-``csvplus.join.probe_composed`` — with the kernel forced by a fixture
+small N), then the rule that selects it, then the programs that call
+it — ``csvplus.join.gather_multiway`` and ``csvplus.join.probe_composed``
+(ISSUE 44), the binary join's ``csvplus.join.gather_cols`` and
+``csvplus.join.expand`` (ISSUE 46) — with the kernel forced by a fixture
 against the same joins without it, and the ``vmem_gathers`` counter of
-``join:probe`` / ``join:merge``.
+``join:probe`` / ``join:expand`` / ``join:merge``.
 """
 
 import numpy as np
@@ -15,12 +16,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from csvplus_tpu.columnar.table import DeviceTable, StringColumn
+from csvplus_tpu.columnar.typed import IntColumn
 from csvplus_tpu.ops import gather as G
 from csvplus_tpu.ops import join as J
+from csvplus_tpu.ops.sort import sort_table
 from csvplus_tpu.utils.observe import telemetry
 
 from test_composed_probe import (
-    JOIN_CASES, PROBE_CASES, _assert_same_table, _join_case, _staged_only,
+    JOIN_CASES, PROBE_CASES, _assert_same_table, _index, _join_case, _staged_only, _stock,
 )
 from test_join_compact import _deployment, needs8
 
@@ -121,6 +125,10 @@ def test_the_rule_reads_size_dtype_and_placement(kernel_forced):
     assert G.vmem_gather_selected((), idx) is False
     assert G.vmem_gather_selected((jnp.zeros(10, jnp.int8),), idx) is False
     assert G.vmem_gather_selected((fits,), np.zeros(4096, np.int32)) is False  # a host array: no placement
+    # no index yet (the program that reads the tables forms it where they are)
+    assert G.vmem_gather_selected((fits, fits)) == "interpret"
+    assert G.vmem_gather_selected((jnp.zeros(G.VMEM_GATHER_MAX_ENTRIES + 1, jnp.int32),) * 2) is False
+    assert G.vmem_gather_selected((fits, np.zeros(10, np.int32))) is False
 
 
 @needs8
@@ -191,28 +199,195 @@ def test_composed_probe_through_the_kernel_equals_jnp_take(case, kernel_forced):
 
 
 @needs8
-def test_a_row_sharded_join_records_no_vmem_gather(kernel_forced):
+@pytest.mark.parametrize("dims", [2, 1], ids=["multiway", "binary"])
+def test_a_row_sharded_join_records_no_vmem_gather(dims, kernel_forced):
     stream, specs, _, _, _ = _deployment("pow2+1", "row-sharded")
-    got, recs = _joined(stream, specs)
+    got, recs = _joined(stream, specs[:dims])
     assert 0 < got.nrows < stream.nrows
-    for r in _stages(recs, "join:probe") + _stages(recs, "join:merge"):
+    for r in _stages(recs, "join:probe") + _stages(recs, "join:expand") + _stages(recs, "join:merge"):
         assert r.extra["vmem_gathers"] == 0, r.stage
 
 
 @pytest.mark.parametrize("staged", [False, True], ids=["composed", "staged"])
 @pytest.mark.parametrize("dims", [1, 2], ids=["binary", "multiway"])
-def test_probe_and_merge_always_carry_the_key(dims, staged, monkeypatch):
-    stream, specs, _, _ = _join_case("misses")
+@pytest.mark.parametrize("case", ["misses", "fan-out"])
+def test_probe_and_merge_always_carry_the_key(case, dims, staged, monkeypatch):
+    """...and ``join:expand``; off the TPU every one of them reads 0."""
+    stream, specs, _, _ = _join_case(case)
     if staged:
         with _staged_only(monkeypatch):
             _, recs = _joined(stream, specs[:dims])
     else:
         _, recs = _joined(stream, specs[:dims])
-    probes, merges = _stages(recs, "join:probe"), _stages(recs, "join:merge")
-    assert len(probes) == dims and len(merges) == 1
+    probes, merges, expands = (_stages(recs, "join:" + s) for s in ("probe", "merge", "expand"))
+    assert len(probes) == dims and len(merges) == len(expands) == 1
     assert {r.extra["tier"] for r in probes} == {"direct" if staged else "direct-composed"}
-    for r in probes + merges:
+    for r in probes + merges + expands:
         assert r.extra["vmem_gathers"] == 0 and "row_gathers" in r.extra
+
+
+# ---- the binary join through it (ISSUE 46) ----------------------------------
+
+
+def _binary(stream, di, cols):
+    with telemetry.collect() as recs:
+        got = J.join_tables(stream, di, cols)
+        return got, list(recs)
+
+
+@pytest.mark.parametrize("dim", [0, 1], ids=["people", "stock"])
+@pytest.mark.parametrize("case", JOIN_CASES)
+def test_join_tables_through_the_kernel_equals_jnp_take(case, dim, monkeypatch):
+    """Every path of the binary join — ``unique-identity`` over composed
+    tables (depth 2) and over build rows, ``unique-partial``, ``fan-out``
+    with runs of 0, 1, 2 and 3 — with both sides' lanes and the
+    expansion's two reads served by the kernel: these tables all fit."""
+    stream, specs, path, _ = _join_case(case)
+    di, cols = specs[dim]
+    want, ref_recs = _binary(stream, di, cols)
+    with monkeypatch.context() as m:
+        m.setattr(G, "_kernel_mode", lambda: "interpret")
+        got, recs = _binary(stream, di, cols)
+    _assert_same_table(got, want)
+    (expand,), (ref_expand,) = _stages(recs, "join:expand"), _stages(ref_recs, "join:expand")
+    (merge,), (ref_merge,) = _stages(recs, "join:merge"), _stages(ref_recs, "join:merge")
+    assert ref_expand.extra["vmem_gathers"] == ref_merge.extra["vmem_gathers"] == 0
+    fan = expand.extra["path"] == "fan-out"
+    assert fan == (dim == 0 and path == "fan-out")
+    if fan:  # the padded tail is cut: the total is no power of two
+        assert expand.extra["padded"] > got.nrows > expand.extra["padded"] // 2
+    assert expand.extra["vmem_gathers"] == (2 if fan else 0)
+    for key in ("path", "tier", "form", "padded", "row_gathers", "host_sync_elements"):
+        assert expand.extra[key] == ref_expand.extra[key], key
+    for key in ("row_gathers", "build_gathers", "stream_gathers"):
+        assert merge.extra[key] == ref_merge.extra[key], key
+    assert merge.extra["vmem_gathers"] == merge.extra["row_gathers"] > 0
+
+
+def _statements(n_orders: int, n_people: int = 40, n_stock: int = 20):
+    """The statements cell's tables at a small size: people — the
+    stream, in no key order —, the orders under a NON-unique index on
+    ``cust_id`` (customer 0 places none, customer 1 one, the others
+    many) and stock under its unique index."""
+    rng = np.random.default_rng(46)
+    cust = rng.integers(2, n_people, n_orders)
+    cust[0] = 1
+    prod = rng.integers(0, n_stock, n_orders)
+    orders = DeviceTable(
+        {
+            "cust_id": IntColumn(b"c", jnp.asarray(cust.astype(np.int32))),
+            "prod_id": IntColumn(b"p", jnp.asarray(prod.astype(np.int32))),
+            "qty": StringColumn.from_values([str(1 + i % 9) for i in range(n_orders)], None),
+            "ts": StringColumn.from_values([f"t{i % 977:03d}" for i in range(n_orders)], None),
+        },
+        n_orders, None,
+    )
+    ids = rng.permutation(n_people)
+    people = DeviceTable(
+        {
+            "id": IntColumn(b"c", jnp.asarray(ids.astype(np.int32))),
+            "name": StringColumn.from_values([f"n{i % 7}" for i in ids], None),
+            "surname": StringColumn.from_values([f"s{i % 11}" for i in ids], None),
+        },
+        n_people, None,
+    )
+    by_cust = J.DeviceIndex.build(sort_table(orders, ["cust_id"]), ["cust_id"])
+    return people, by_cust, _index(_stock(range(n_stock)), ["prod_id"])
+
+
+def _cascade(people, by_cust, stock):
+    with telemetry.collect() as recs:
+        first = J.join_tables(people, by_cust, ("id",))
+        got = J.join_tables(first, stock, ("prod_id",))
+        return got, list(recs)
+
+
+def test_the_statements_cascade_counts_what_the_cell_counts(monkeypatch):
+    """``people.Join(by_cust, "id").Join(stock)`` with an orders table of
+    ``VMEM_GATHER_MAX_ENTRIES + 1`` rows, one over what the kernel takes:
+    the first join's build side (four orders lanes) stays ``jnp.take``,
+    a program a lane, its stream side (people's three lanes) and the
+    expansion's two reads go through the kernel, and so do the stock
+    join's two composed tables — the counters of the cell (ISSUE 46)."""
+    people, by_cust, stock = _statements(G.VMEM_GATHER_MAX_ENTRIES + 1)
+    want, ref_recs = _cascade(people, by_cust, stock)
+    calls = []
+    with monkeypatch.context() as m:
+        m.setattr(G, "_kernel_mode", lambda: "interpret")
+        m.setattr(J, "_gather_lane", lambda c, i, _real=J._gather_lane: calls.append(1) or _real(c, i))
+        got, recs = _cascade(people, by_cust, stock)
+    assert got.nrows == want.nrows == G.VMEM_GATHER_MAX_ENTRIES + 1
+    assert list(got.columns) == list(want.columns) and len(got.columns) == 9
+    for name, col in got.columns.items():
+        assert np.array_equal(np.asarray(col.storage), np.asarray(want.columns[name].storage)), name
+    assert got.to_rows()[:500] == want.to_rows()[:500]
+    expands, merges = _stages(recs, "join:expand"), _stages(recs, "join:merge")
+    assert [e.extra["path"] for e in expands] == ["fan-out", "unique-identity"]
+    assert [e.extra["vmem_gathers"] for e in expands] == [2, 0]
+    assert expands[0].extra["padded"] == 262_144 and expands[0].extra["row_gathers"] == 2
+    assert sum(e.extra["host_sync_elements"] for e in expands) == 4
+    first, second = (m.extra for m in merges)
+    assert (first["build_gathers"], first["stream_gathers"], first["vmem_gathers"]) == (4, 3, 3)
+    assert (second["build_gathers"], second["stream_gathers"], second["vmem_gathers"]) == (2, 0, 2)
+    assert len(calls) == 4  # the orders' lanes alone still move a program a lane
+    assert all(r.extra["vmem_gathers"] == 0 for r in _stages(ref_recs, "join:expand") + _stages(ref_recs, "join:merge"))
+
+
+def test_a_probe_one_row_over_the_limit_expands_by_jnp_take(kernel_forced):
+    n = G.VMEM_GATHER_MAX_ENTRIES + 1
+    counts = jnp.asarray((np.arange(n) % 3).astype(np.int32))
+    lower = jnp.cumsum(counts) - counts
+    total, rec = int(counts.sum()), {}
+    probe_ids, build_ids = J.expand_matches_device(lower, counts, total, rec)
+    assert rec == {"vmem_gathers": 0}
+    want = np.repeat(np.arange(n), np.asarray(counts))
+    assert np.array_equal(np.asarray(probe_ids), want)
+    assert np.array_equal(np.asarray(build_ids), np.arange(total))  # runs laid end to end
+    rec = {}
+    J.expand_matches_device(lower[:-1], counts[:-1], None, rec)
+    assert rec == {"vmem_gathers": 2}
+
+
+def _as_it_was(name, fn, **jit_kwargs):
+    """The parent's program under the program's name."""
+    fn.__name__ = fn.__qualname__ = "csvplus." + name
+    return jax.jit(fn, **jit_kwargs)
+
+
+def test_without_the_kernel_both_programs_lower_to_the_parents_hlo():
+    """``vmem=False`` — off the TPU, over the limit, under a mesh — is
+    the program as it was, to the letter: the compile cache's key of
+    every cell that runs ``gather_cols`` with large tables stays."""
+    def lane(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32)
+
+    def gather_cols(codes, ids):
+        idx = jnp.asarray(ids, dtype=jnp.int32)
+        return tuple(jnp.take(c, idx, axis=0) for c in codes)
+
+    args = ((lane(5000),) * 3, lane(20000))
+    was = _as_it_was("join.gather_cols", gather_cols).lower(*args).as_text()
+    assert J._gather_cols.lower(*args).as_text() == J._gather_cols.lower(*args, vmem=False).as_text() == was
+
+    def expand(lower, counts, padded_total: int):
+        counts = counts.astype(jnp.int32)
+        ends = jnp.cumsum(counts)
+        starts = ends - counts
+        ids = jnp.arange(counts.shape[0], dtype=jnp.int32)
+        mark_pos = jnp.where(counts > 0, starts, padded_total)
+        seg = jnp.zeros(padded_total, dtype=jnp.int32)
+        seg = seg.at[mark_pos].max(ids, mode="drop")
+        probe_ids = jax.lax.cummax(seg)
+        out_pos = jnp.arange(padded_total, dtype=jnp.int32)
+        group_base = jnp.take(starts, probe_ids, axis=0)
+        build_ids = jnp.take(lower.astype(jnp.int32), probe_ids, axis=0) + (out_pos - group_base)
+        return probe_ids, build_ids
+
+    was = _as_it_was("join.expand", expand, static_argnames=("padded_total",))
+    was = was.lower(lane(1000), lane(1000), padded_total=4096).as_text()
+    assert J._expand_kernel.lower(lane(1000), lane(1000), padded_total=4096).as_text() == was
+    forced = J._expand_kernel.lower(lane(1000), lane(1000), padded_total=4096, vmem="interpret").as_text()
+    assert forced != was
 
 
 # ---- the chip's compiler, without the chip ----------------------------------
@@ -247,6 +422,26 @@ def test_the_emit_compiles_for_the_chip_at_the_cells_shapes(one_chip, rows, peop
     text = J._gather_multiway.lower(codes, ids, vmem=(True, True)).compile().as_text()
     assert text.count("tpu_custom_call") == 2  # a kernel a dimension
     plain = J._gather_multiway.lower(codes, ids, vmem=(False, False)).compile().as_text()
+    assert "tpu_custom_call" not in plain
+
+
+@pytest.mark.parametrize("side,tables,entries,rows", [
+    ("people", 3, 100_000, 10_000_000), ("stock", 2, 1000, 10_000_000), ("limit", 3, 131_073, 1_000_003),
+])
+def test_the_binary_emit_compiles_for_the_chip_at_the_cells_shapes(one_chip, side, tables, entries, rows):
+    """``statements-fanout-resident``: people's three lanes and stock's
+    two composed tables at 10,000,000 ids, one kernel call a side."""
+    codes, ids = (_lane(entries, one_chip),) * tables, _lane(rows, one_chip)
+    text = J._gather_cols.lower(codes, ids, vmem=True).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tpu_custom_call" not in J._gather_cols.lower(codes, ids).compile().as_text()
+
+
+def test_the_expansion_compiles_for_the_chip_at_the_cells_shapes(one_chip, probes=100_000, padded=16_777_216):
+    args = (_lane(probes, one_chip), _lane(probes, one_chip))
+    text = J._expand_kernel.lower(*args, padded_total=padded, vmem=True).compile().as_text()
+    assert text.count("tpu_custom_call") == 1  # one call reads both tables
+    plain = J._expand_kernel.lower(*args, padded_total=padded).compile().as_text()
     assert "tpu_custom_call" not in plain
 
 
