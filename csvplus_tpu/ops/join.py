@@ -1380,7 +1380,7 @@ def join_tables(
 
     probe_cols = _checked_probe_cols(stream, columns)
     lower, counts, entry = _probe_dim(dev_index, probe_cols, stream.nrows)
-    probe_ids = build_ids = None
+    probe_ids = build_ids = runs = None
     with telemetry.stage("join:expand", stream.nrows) as _exp:
         _exp["vmem_gathers"] = 0  # the fan-out's two segment reads where the kernel serves them
         if isinstance(lower, jax.Array):
@@ -1415,10 +1415,10 @@ def join_tables(
                 else:
                     (lower,) = _slots_to_rows((lower,), (entry,))
                     probe_ids, build_ids = expand_matches_device(lower, counts, total, _exp)
+                    runs = (lower, counts, total)  # probe p: build rows lower[p] .. + counts[p]
                     _exp.update(
-                        path="fan-out", form="prefix-scatter",
+                        path="fan-out", form="prefix-scatter", probes=stream.nrows, max_run=maxc,
                         row_gathers=2 + _slot_gathers((entry,)),
-                        probes=stream.nrows, max_run=maxc,
                     )
                 entry = None  # the ids are build rows now, not depth-2 slots
                 _exp["padded"] = padded
@@ -1438,14 +1438,14 @@ def join_tables(
     stream_codes = tuple(stream.columns[n].storage for n in stream_names)
 
     with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        # either side's tables that fit VMEM: ``take_small`` in one
-        # program (``_emit_side``).  Else the build side's composed tables
-        # (depth 2) and a mesh's lanes in one jit call, a column read at
-        # its own length on one device a program a lane, as the stream's
-        # survivors are; where every row matched once the stream passes
-        # untouched
-        g_build, build_vmem = _emit_side(
-            build_codes, build_ids, entry is None and whole_device(build_ids, *build_codes)
+        # the fan-out's build side a run at a time where the rule admits it
+        # (``_emit_build_side``); else either side's tables that fit VMEM
+        # through ``take_small`` in one program (``_emit_side``), the build
+        # side's composed tables (depth 2) and a mesh's lanes in one jit
+        # call, a column read at its own length on one device a program a
+        # lane, as the stream's survivors are; every row matched once: no move
+        g_build, build_vmem, _mrg["run_copies"] = _emit_build_side(
+            build_codes, build_ids, entry is None and whole_device(build_ids, *build_codes), runs
         )
         g_stream, stream_vmem = (
             (None, False) if probe_ids is None else _emit_side(stream_codes, probe_ids, True)
@@ -1984,3 +1984,48 @@ def except_mask(
     probe_cols = _checked_probe_cols(stream, columns)
     _, counts = dev_index.probe(probe_cols, stream.nrows)
     return counts == 0
+
+
+# -- the fan-out's build side, a run at a time (ISSUE 47) ---------------------
+#
+# Down here, import and all: a Pallas program's compile-cache key holds
+# the file and line of every calling frame (PERF.md section 6, PR 46), so
+# a line added above a ``take_small`` call site or above a caller of one
+# recompiles the multiway joins' kernels in cells this code never runs in.
+
+from .run_copy import copy_runs, run_copy_selected  # noqa: E402
+
+
+@register_kernel("join.gather_runs", static_argnames=("padded", "kernel"))
+def _gather_runs_kernel(tables, first, counts, padded: int, kernel=True):  # analysis: allow[JIT001] retrace is per build-lane count and per power of two, not per total
+    """The build side's lanes at the fan-out's runs, *padded* slots a
+    lane (``ops/run_copy.py``): the expansion's padded length, so it
+    compiles per power of two, as ``csvplus.join.expand`` does."""
+    return copy_runs(tables, first, counts, padded, kernel=kernel)
+
+
+def gather_runs(tables, first, counts, total: int, *, kernel=True) -> Tuple[jax.Array, ...]:
+    """``tuple(jnp.take(t, build_ids) for t in tables)`` for the
+    ``build_ids`` of ``expand_matches_device(first, counts, total)``,
+    bit for bit, without them: output rows ``starts[p] .. starts[p] +
+    counts[p] - 1`` (``starts`` the exclusive prefix sum) hold
+    ``t[first[p] .. first[p] + counts[p] - 1]``, and a probe that matched
+    nothing writes nothing.  *kernel*: ``run_copy_selected``'s answer."""
+    tables = tuple(tables)
+    if not tables or not total:
+        return tuple(t[:0] for t in tables)
+    padded = 1 << (total - 1).bit_length()
+    lanes = _gather_runs_kernel(tables, jnp.asarray(first), jnp.asarray(counts), padded=padded, kernel=kernel)  # analysis: allow[RETRACE002] a power of two, and the rule's two values
+    return _expand_head_kernel(lanes, total=total)  # analysis: allow[RETRACE002] the cut lowers once per total, in milliseconds
+
+
+def _emit_build_side(codes, ids, lane_each: bool, runs):
+    """The build side of the binary join's emit — ``_emit_side``'s pair
+    and the lanes the run copy moved.  *runs*: the fan-out's ``(lower,
+    counts, total)``, None on every other path; where the rule admits
+    them (``run_copy_selected``, read off *codes* and *runs*) the lanes
+    are copied a run at a time and *ids* are not read."""
+    kernel = runs is not None and run_copy_selected(codes, *runs)
+    if kernel:
+        return gather_runs(codes, *runs, kernel=kernel), False, len(codes)
+    return (*_emit_side(codes, ids, lane_each), 0)

@@ -13,8 +13,10 @@ within a customer the orders keep the file's order, and that is what the
 reference below — written from that description over row dicts, sharing
 nothing with the engine — holds the ``PlanCache`` path to: rows, row
 order, column order, and the ``join:expand`` record of the ``fan-out``
-path.  The full-size deployment is
-``benchmark/configs/orders-by-customer-10m.json``.
+path.  The last cases run the chip's kernels in interpret mode — the
+build side's lanes a run at a time (``csvplus.join.gather_runs``, ISSUE
+47), people's through the VMEM gather — against the same scan.  The
+full-size deployment is ``benchmark/configs/orders-by-customer-10m.json``.
 """
 
 import bisect
@@ -24,6 +26,7 @@ import pytest
 
 from csvplus_tpu import FromFile, Like
 from csvplus_tpu.analysis import optimize_plan
+from csvplus_tpu.ops import gather as G
 from csvplus_tpu.ops import join as J
 from csvplus_tpu.serve.plancache import PlanCache
 from csvplus_tpu.utils.observe import telemetry
@@ -89,31 +92,47 @@ def _join(stream: list, index_rows: list, key: str, column: str) -> list:
     return out
 
 
-CASES = [  # (groups, the stream is empty, the second join too, seed)
-    pytest.param("uniform", False, False, 45, id="uniform-groups"),
-    pytest.param("one-holds-half", False, False, 45, id="one-customer-holds-half"),
-    pytest.param("some-without-orders", False, False, 45, id="customers-without-orders"),
-    pytest.param("uniform", True, False, 45, id="empty-stream"),
-    pytest.param("uniform", False, True, 45, id="cascade"),
-    pytest.param("uniform", False, True, 4_500_000_045, id="second-seed"),
+CASES = [  # (groups, the stream is empty, the second join too, seed, the chip's kernels interpreted)
+    pytest.param("uniform", False, False, 45, False, id="uniform-groups"),
+    pytest.param("one-holds-half", False, False, 45, False, id="one-customer-holds-half"),
+    pytest.param("some-without-orders", False, False, 45, False, id="customers-without-orders"),
+    pytest.param("uniform", True, False, 45, False, id="empty-stream"),
+    pytest.param("uniform", False, True, 45, False, id="cascade"),
+    pytest.param("uniform", False, True, 4_500_000_045, False, id="second-seed"),
+    # half the probes match nothing (runs of 0 between runs of ~60), then the whole cell
+    pytest.param("some-without-orders", False, False, 47, True, id="run-copy-customers-without-orders"),
+    pytest.param("one-holds-half", False, True, 4_700_000_047, True, id="run-copy-cascade-one-holds-half"),
+    pytest.param("uniform", True, False, 47, True, id="run-copy-empty-stream"),
 ]
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _journal_of_its_own():
+    """As ``tests/test_gather_small.py``: the interpreted kernels' traces
+    (hundreds of ``compile`` spans a case) stay out of the process
+    journal, whose ``dropped`` ``tests/test_journal.py`` pins at 0."""
+    from csvplus_tpu.obs.span import Journal, tracer
+
+    kept, tracer.journal = tracer.journal, Journal(trace_id=-47)
+    yield
+    tracer.journal = kept
 
 
 @pytest.fixture
 def programs(monkeypatch):
     """Calls of the two emit programs, by name."""
-    calls = {"_gather_lane": 0, "_gather_cols": 0}
+    calls = {"_gather_lane": 0, "_gather_cols": 0, "_gather_runs_kernel": 0}
     for name in calls:
-        def counted(*args, _real=getattr(J, name), _name=name):
+        def counted(*args, _real=getattr(J, name), _name=name, **kwargs):
             calls[_name] += 1
-            return _real(*args)
+            return _real(*args, **kwargs)
 
         monkeypatch.setattr(J, name, counted)
     return calls
 
 
-@pytest.mark.parametrize("case, empty, cascade, seed", CASES)
-def test_plancache_equals_upstreams_scan(tmp_path, programs, case, empty, cascade, seed):
+@pytest.mark.parametrize("case, empty, cascade, seed, kernels", CASES)
+def test_plancache_equals_upstreams_scan(tmp_path, programs, monkeypatch, case, empty, cascade, seed, kernels):
     paths, orders_rows, people_rows, stock_rows = _files(tmp_path, case, seed)
     if empty:
         people_rows = []
@@ -136,7 +155,9 @@ def test_plancache_equals_upstreams_scan(tmp_path, programs, case, empty, cascad
     if cascade:
         src = src.Join(stock.UniqueIndexOn("prod_id").sync())
     cache = PlanCache()
-    programs.update(_gather_lane=0, _gather_cols=0)  # the index builds are over
+    programs.update(dict.fromkeys(programs, 0))  # the index builds are over
+    if kernels:  # as the chip would choose them: only the rules' backend test is answered
+        monkeypatch.setattr(G, "_kernel_mode", lambda: "interpret")
     with telemetry.collect() as recs:
         table = cache.execute(src.plan).sync()
     emit_programs = dict(programs)
@@ -156,10 +177,18 @@ def test_plancache_equals_upstreams_scan(tmp_path, programs, case, empty, cascad
     assert fan["emitted"] == ORDERS and fan["padded"] == 8192 and fan["host_sync_elements"] == 2
     merges = [r.extra for r in recs if r.stage == "join:merge"]
     assert (merges[0]["build_gathers"], merges[0]["stream_gathers"], merges[0]["row_gathers"]) == (4, 3, 7)
-    # every lane read at its own length moves in a program of its own (four
-    # of the orders, three of the people); stock's two composed tables ride
-    # one program (run twice in a first execution: to compose them, to emit)
-    assert emit_programs == {"_gather_lane": 7, "_gather_cols": 2 if cascade else 0}
+    if kernels:
+        # the orders' four lanes move by ONE run copy (its mean run is 30 or
+        # 60 rows), people's three by one VMEM gather; no lane a program
+        assert (merges[0]["run_copies"], merges[0]["vmem_gathers"]) == (4, 3)
+        assert emit_programs == {"_gather_lane": 0, "_gather_cols": 3 if cascade else 1, "_gather_runs_kernel": 1}
+        assert [m["run_copies"] for m in merges[1:]] == [0] * cascade  # unique-identity: no runs
+    else:
+        # every lane read at its own length moves in a program of its own (four
+        # of the orders, three of the people); stock's two composed tables ride
+        # one program (run twice in a first execution: to compose them, to emit)
+        assert emit_programs == {"_gather_lane": 7, "_gather_cols": 2 if cascade else 0, "_gather_runs_kernel": 0}
+        assert merges[0]["run_copies"] == 0
     if cascade:
         # prod_id is born from the first join's build side, so the two
         # joins may not fuse into one pass: the rule says so and two run

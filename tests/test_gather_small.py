@@ -7,7 +7,10 @@ it — ``csvplus.join.gather_multiway`` and ``csvplus.join.probe_composed``
 (ISSUE 44), the binary join's ``csvplus.join.gather_cols`` and
 ``csvplus.join.expand`` (ISSUE 46) — with the kernel forced by a fixture
 against the same joins without it, and the ``vmem_gathers`` counter of
-``join:probe`` / ``join:expand`` / ``join:merge``.
+``join:probe`` / ``join:expand`` / ``join:merge``.  Beside each, the run
+copy's (``ops/run_copy.py``, ISSUE 47; the kernel alone is in
+``tests/test_gather_runs.py``): its rule, the fan-out's build side
+through it with ``join:merge``'s ``run_copies``, and every bypass.
 """
 
 import numpy as np
@@ -20,6 +23,7 @@ from csvplus_tpu.columnar.table import DeviceTable, StringColumn
 from csvplus_tpu.columnar.typed import IntColumn
 from csvplus_tpu.ops import gather as G
 from csvplus_tpu.ops import join as J
+from csvplus_tpu.ops import run_copy as RC
 from csvplus_tpu.ops.sort import sort_table
 from csvplus_tpu.utils.observe import telemetry
 
@@ -140,6 +144,53 @@ def test_a_mesh_sharded_stream_keeps_jnp_take(kernel_forced):
     mesh = make_mesh(8)
     idx = jax.device_put(np.zeros(4096, np.int32), NamedSharding(mesh, row_spec(mesh)))
     assert G.vmem_gather_selected((jnp.zeros(1000, jnp.int32),), idx) is False
+    # ...and the run copy's rule its gathers: the probe's answer is the mesh's
+    lane, whole = jnp.zeros(50_000, jnp.int32), jnp.zeros(4096, jnp.int32)
+    total = 4096 * RC.RUN_COPY_MIN_MEAN_RUN
+    assert RC.run_copy_selected((lane,), whole, whole, total) == "interpret"
+    assert RC.run_copy_selected((lane,), idx, idx, total) is False
+    assert RC.run_copy_selected((lane,), whole, idx, total) is False
+
+
+def _described(n: int, dtype=jnp.int32):
+    """A lane by its shape alone, placed on the first device: all the
+    rule reads (a 50M-row lane is 200 MB this test never fills)."""
+    from jax.sharding import SingleDeviceSharding
+
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=SingleDeviceSharding(jax.devices()[0]))
+
+
+def test_off_the_tpu_the_run_copys_rule_chooses_the_gathers():
+    lane, probe = _described(10_000_000), _described(100_000)
+    assert jax.default_backend() != "tpu"
+    assert RC.run_copy_selected((lane,) * 4, probe, probe, 10_000_000) is False
+
+
+def test_the_run_copys_rule_reads_runs_size_dtype_and_placement(kernel_forced, monkeypatch):
+    lane, probe = _described(10_000_000), _described(100_000)
+    # the cell's first join: four 10M-row lanes, 100,000 runs of 100
+    assert RC.run_copy_selected((lane,) * 4, probe, probe, 10_000_000) == "interpret"
+    # the mean run, total / probes, against the constant
+    at = 100_000 * RC.RUN_COPY_MIN_MEAN_RUN
+    assert RC.run_copy_selected((lane,), probe, probe, at) == "interpret"
+    assert RC.run_copy_selected((lane,), probe, probe, at - 1) is False
+    assert RC.run_copy_selected((lane,), _described(0), _described(0), 0) is False
+    # one int32 dimension a table, one length, one device
+    assert RC.run_copy_selected((), probe, probe, at) is False
+    assert RC.run_copy_selected((_described(1000, jnp.int8),), probe, probe, at) is False
+    assert RC.run_copy_selected((lane, _described(9_999_999)), probe, probe, at) is False
+    assert RC.run_copy_selected((jax.ShapeDtypeStruct((10, 10), jnp.int32, sharding=lane.sharding),), probe, probe, at) is False
+    assert RC.run_copy_selected((lane,), np.zeros(100_000, np.int32), probe, at) is False  # a host array: no placement
+    assert RC.run_copy_selected((np.zeros(1000, np.int32),), probe, probe, at) is False
+    # a lane whole in the kernel's share of VMEM: dedup's 50M rows (200 MB) never
+    assert RC._tables_per_call(10_000_000) == 2 and RC._tables_per_call(50_000_000) == 0
+    assert RC.run_copy_selected((_described(50_000_000),), probe, probe, at) is False
+    fits = int(RC._V5E_VMEM_BYTES * RC._VMEM_SHARE) // 4 - (2 * RC._BLOCK_ROWS + 8) * 128
+    assert RC.run_copy_selected((_described(fits - 128),), probe, probe, at) == "interpret"
+    assert RC.run_copy_selected((_described(fits + 128),), probe, probe, at) is False
+    monkeypatch.setattr(RC, "_vmem_capacity_bytes", lambda: 16 * 1024 * 1024)  # another chip's core
+    assert RC.run_copy_selected((lane,), probe, probe, at) is False
+    assert RC.run_copy_selected((_described(2_000_000),), probe, probe, at) == "interpret"
 
 
 # ---- the join through it ----------------------------------------------------
@@ -206,6 +257,7 @@ def test_a_row_sharded_join_records_no_vmem_gather(dims, kernel_forced):
     assert 0 < got.nrows < stream.nrows
     for r in _stages(recs, "join:probe") + _stages(recs, "join:expand") + _stages(recs, "join:merge"):
         assert r.extra["vmem_gathers"] == 0, r.stage
+    assert [r.extra.get("run_copies", 0) for r in _stages(recs, "join:merge")] == [0]
 
 
 @pytest.mark.parametrize("staged", [False, True], ids=["composed", "staged"])
@@ -262,6 +314,8 @@ def test_join_tables_through_the_kernel_equals_jnp_take(case, dim, monkeypatch):
     for key in ("row_gathers", "build_gathers", "stream_gathers"):
         assert merge.extra[key] == ref_merge.extra[key], key
     assert merge.extra["vmem_gathers"] == merge.extra["row_gathers"] > 0
+    # runs of 0 to 3 are under the run copy's constant; the other paths have no runs
+    assert merge.extra["run_copies"] == ref_merge.extra["run_copies"] == 0
 
 
 def _statements(n_orders: int, n_people: int = 40, n_stock: int = 20):
@@ -304,11 +358,12 @@ def _cascade(people, by_cust, stock):
 
 def test_the_statements_cascade_counts_what_the_cell_counts(monkeypatch):
     """``people.Join(by_cust, "id").Join(stock)`` with an orders table of
-    ``VMEM_GATHER_MAX_ENTRIES + 1`` rows, one over what the kernel takes:
-    the first join's build side (four orders lanes) stays ``jnp.take``,
-    a program a lane, its stream side (people's three lanes) and the
-    expansion's two reads go through the kernel, and so do the stock
-    join's two composed tables — the counters of the cell (ISSUE 46)."""
+    ``VMEM_GATHER_MAX_ENTRIES + 1`` rows, one over what the VMEM gather
+    takes: the first join's build side (four orders lanes, 40 runs of
+    ~3,400 rows) moves by the run copy and no longer a program a lane,
+    its stream side (people's three lanes) and the expansion's two
+    reads go through the VMEM gather, and so do the stock join's two
+    composed tables — the counters of the cell (ISSUE 46, ISSUE 47)."""
     people, by_cust, stock = _statements(G.VMEM_GATHER_MAX_ENTRIES + 1)
     want, ref_recs = _cascade(people, by_cust, stock)
     calls = []
@@ -329,8 +384,80 @@ def test_the_statements_cascade_counts_what_the_cell_counts(monkeypatch):
     first, second = (m.extra for m in merges)
     assert (first["build_gathers"], first["stream_gathers"], first["vmem_gathers"]) == (4, 3, 3)
     assert (second["build_gathers"], second["stream_gathers"], second["vmem_gathers"]) == (2, 0, 2)
-    assert len(calls) == 4  # the orders' lanes alone still move a program a lane
+    assert (first["run_copies"], second["run_copies"]) == (4, 0)  # the second join is unique-identity: no runs
+    assert len(calls) == 0  # no lane moves a program a lane any more...
     assert all(r.extra["vmem_gathers"] == 0 for r in _stages(ref_recs, "join:expand") + _stages(ref_recs, "join:merge"))
+    # ...where the kernels run: without them the orders' four still do
+    assert [m.extra["run_copies"] for m in _stages(ref_recs, "join:merge")] == [0, 0]
+
+
+def _no_run_copy(monkeypatch):
+    def refuse(*args, **kwargs):
+        raise AssertionError("csvplus.join.gather_runs ran")
+
+    monkeypatch.setattr(J, "_gather_runs_kernel", refuse)
+
+
+@pytest.mark.parametrize("why, lane_programs, vmem_gathers", [
+    ("off-the-tpu", 7, 0), ("short-runs", 0, 7), ("past-the-vmem-share", 0, 7), ("a-table-of-int8", 4, 3),
+])
+def test_a_fan_out_the_rule_refuses_emits_by_the_parents_programs(why, lane_programs, vmem_gathers, monkeypatch):
+    """Long runs (40 customers' ~125 orders each) — and one reason each
+    why the run copy does not serve them: ``join:merge`` counts 0
+    ``run_copies``, ``csvplus.join.gather_runs`` never runs, the lanes
+    move as the parent moved them (``csvplus.join.gather_lane``, a
+    program a lane, or the VMEM gather: these tables are small), and
+    the rows are the parent's."""
+    people, by_cust, _ = _statements(5000)
+    want, ref_recs = _binary(people, by_cust, ("id",))
+    assert _stages(ref_recs, "join:expand")[0].extra["path"] == "fan-out"
+    calls = []
+    _no_run_copy(monkeypatch)
+    monkeypatch.setattr(J, "_gather_lane", lambda c, i, _real=J._gather_lane: calls.append(1) or _real(c, i))
+    if why != "off-the-tpu":
+        monkeypatch.setattr(G, "_kernel_mode", lambda: "interpret")
+    if why == "short-runs":
+        monkeypatch.setattr(RC, "RUN_COPY_MIN_MEAN_RUN", 126)  # 5000 rows / 40 probes
+    elif why == "past-the-vmem-share":
+        monkeypatch.setattr(RC, "_vmem_capacity_bytes", lambda: 2 * 1024 * 1024)
+    elif why == "a-table-of-int8":
+        qty = by_cust.table.columns["qty"]
+        by_cust.table.columns["qty"] = qty.with_storage(qty.storage.astype(jnp.int8))
+        want, _ = _binary(people, by_cust, ("id",))
+        calls.clear()
+    got, recs = _binary(people, by_cust, ("id",))
+    _assert_same_table(got, want)
+    (merge,) = _stages(recs, "join:merge")
+    assert (merge.extra["run_copies"], merge.extra["build_gathers"]) == (0, 4)
+    assert (len(calls), merge.extra["vmem_gathers"]) == (lane_programs, vmem_gathers)
+
+
+def test_the_fan_out_through_the_run_copy_equals_the_gathers(kernel_forced):
+    """...and with nothing in its way the same join copies runs: the
+    rows are the gathers', ``build_gathers`` is still 4."""
+    people, by_cust, _ = _statements(5000)
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(G, "_kernel_mode", lambda: False)
+        want, _ = _binary(people, by_cust, ("id",))
+    got, recs = _binary(people, by_cust, ("id",))
+    _assert_same_table(got, want)
+    (merge,) = _stages(recs, "join:merge")
+    assert (merge.extra["run_copies"], merge.extra["build_gathers"], merge.extra["vmem_gathers"]) == (4, 4, 3)
+    assert merge.extra["row_gathers"] == 7
+
+
+@pytest.mark.parametrize("case", ["all-matched", "misses", "holes"])
+def test_the_unique_paths_carry_no_runs(case, kernel_forced, monkeypatch):
+    """``unique-identity`` and ``unique-partial`` never reach the rule:
+    there are no runs to read it off."""
+    _no_run_copy(monkeypatch)
+    monkeypatch.setattr(RC, "run_copy_selected", lambda *a: pytest.fail("the rule was asked"))
+    monkeypatch.setattr(J, "run_copy_selected", RC.run_copy_selected)
+    stream, specs, path, _ = _join_case(case)
+    for di, cols in specs:
+        _, recs = _binary(stream, di, cols)
+        assert _stages(recs, "join:expand")[0].extra["path"] in ("unique-identity", "unique-partial")
+        assert _stages(recs, "join:merge")[0].extra["run_copies"] == 0
 
 
 def test_a_probe_one_row_over_the_limit_expands_by_jnp_take(kernel_forced):
@@ -368,6 +495,15 @@ def test_without_the_kernel_both_programs_lower_to_the_parents_hlo():
     args = ((lane(5000),) * 3, lane(20000))
     was = _as_it_was("join.gather_cols", gather_cols).lower(*args).as_text()
     assert J._gather_cols.lower(*args).as_text() == J._gather_cols.lower(*args, vmem=False).as_text() == was
+
+    # the program a lane that the build side's lanes move by wherever the run
+    # copy's rule says no (ISSUE 47): off the TPU, short runs, a lane past the
+    # VMEM share, a mesh — and the unique paths, which have no runs
+    def gather_lane(storage, ids):
+        return jnp.take(storage, ids, axis=0)
+
+    was = _as_it_was("join.gather_lane", gather_lane).lower(lane(5000), lane(20000)).as_text()
+    assert J._gather_lane.lower(lane(5000), lane(20000)).as_text() == was
 
     def expand(lower, counts, padded_total: int):
         counts = counts.astype(jnp.int32)
@@ -443,6 +579,16 @@ def test_the_expansion_compiles_for_the_chip_at_the_cells_shapes(one_chip, probe
     assert text.count("tpu_custom_call") == 1  # one call reads both tables
     plain = J._expand_kernel.lower(*args, padded_total=padded).compile().as_text()
     assert "tpu_custom_call" not in plain
+
+
+def test_the_run_copy_compiles_for_the_chip_at_the_cells_shapes(one_chip, rows=10_000_000, probes=100_000, padded=16_777_216):
+    """``statements-fanout-resident``'s first join: four 10M-row lanes of
+    the sorted orders, two a call — 2 x 40 MB whole in VMEM, which
+    interpret mode cannot refuse and the chip's compiler can."""
+    args = ((_lane(rows, one_chip),) * 4, _lane(probes, one_chip), _lane(probes, one_chip))
+    text = J._gather_runs_kernel.lower(*args, padded=padded, kernel=True).compile().as_text()
+    assert text.count("tpu_custom_call") == 4 // RC._MAX_TABLES
+    assert RC._call_bytes(rows, RC._MAX_TABLES) < RC._V5E_VMEM_BYTES * RC._VMEM_SHARE
 
 
 @pytest.mark.parametrize("tables", [1, 2], ids=["unique", "counted"])

@@ -819,6 +819,7 @@ def _kernel_examples():
         "join.expand_head": (((i, i),), {"total": 5}),
         "join.gather_lane": ((i, i), {}),
         "join.gather_cols": (((i, i), i), {}),
+        "join.gather_runs": (((i, i), i, i), {"padded": 32, "kernel": "interpret"}),
         "join.probe_stats": ((i, i), {}),
         "join.multiway_stats": (((i, i),), {}),
         "join.compact_partial": (((i, i), (i, i)), {"padded": 8}),
@@ -844,7 +845,7 @@ def _kernel_examples():
 KERNELS_LOWERED_HERE = sorted([
     "join.probe_i32pair", "join.probe_direct", "join.probe_i32", "serve.bounds_search",
     "join.build_direct_cum", "join.pack_qk", "join.expand", "join.expand_head", "join.gather_lane",
-    "join.gather_cols", "join.probe_stats", "join.multiway_stats", "join.compact_partial",
+    "join.gather_cols", "join.gather_runs", "join.probe_stats", "join.multiway_stats", "join.compact_partial",
     "join.multiway_expand", "join.gather_multiway",
     "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
     "typed.translate_empty", "table.gather_take", "table.gather_take_rows",
