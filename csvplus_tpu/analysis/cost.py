@@ -427,6 +427,11 @@ def rank_join_orders(
             return False
         for idx, p in enumerate(perm):
             if facts[p].multiplicity != PV.NARROW:
+                # Only a narrowing stage may move earlier
+                # (``prove_swap_before`` proves nothing for any other
+                # mover, and ``plancert`` refuses the permute).
+                if any(q < p for q in perm[idx + 1:]):
+                    return False
                 continue
             # Stages it now precedes but originally followed.
             for q in perm[idx + 1:]:

@@ -371,7 +371,7 @@ def optimize_plan(root: P.PlanNode, report=None, *,
             changed = False
             for j in range(2, n):
                 p, q = order[j], order[j - 1]
-                if p not in run_set or q not in run_set:
+                if p not in run_set or q not in run_set or not _is_mover(facts[p]):
                     continue
                 if rank_of[p] < rank_of[q] and try_swap(
                         "join-order", order, j):

@@ -20,20 +20,28 @@ table is small: past ``VMEM_GATHER_MAX_ENTRIES`` (and off the TPU, and
 for a stream that is not whole on one device: a ``pallas_call`` is not
 partitioned by GSPMD) the entry point IS ``jnp.take``, the same HLO as
 ever.  Which way a call goes is read off shapes and placement by
-``vmem_gather_selected``, at dispatch, and passed as a static flag: the
-one rule for every caller — the multiway joins' emit and the composed
-probe (PR 44), the binary join's emit (either side) and the fan-out
-expansion's two segment reads (PR 46; ``ops/join.py``).
+``vmem_gather_selected``, at dispatch, and passed as a static flag.
+
+Every row a join moves goes through :func:`emit`, below the kernel: the
+one place that chooses between this kernel, the run copy
+(``ops/run_copy.py``), a program a lane, one program a group and eager
+takes, and that reports what it chose.  The two table reads inside a
+program that are no emit — the composed probe's and the fan-out
+expansion's (``ops/join.py``) — ask ``vmem_gather_selected`` themselves.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..columnar.table import same_placement
+from ..obs.recompile import register_kernel
+from . import run_copy  # it reads ``whole_device`` and ``_kernel_mode`` here, at call time
 
 # the largest table the kernel takes: fixed from the chip (PERF.md §6, PR 44)
 VMEM_GATHER_MAX_ENTRIES = 131_073
@@ -161,3 +169,144 @@ def take_small(tables: Sequence[jax.Array], idx: jax.Array, *, vmem=False) -> Tu
         for at in range(0, len(tables), _MAX_TABLES)
         for out in _vmem_take(tables[at : at + _MAX_TABLES], idx, interpret=vmem == "interpret")
     )
+
+
+# -- the emit: every row a join moves -------------------------------------------
+
+
+@register_kernel("join.gather_lane")
+def _gather_lane(storage, ids):
+    """One lane's rows at *ids*: a program of its own, because a program
+    gets one or two cross-program prefetches — a lane gathered here is
+    read from fast memory, where of four lanes gathered by one program
+    two or three are read where they lie, at a third of the rate
+    (``PERF.md`` §6: PR 43, the stream's lanes; PR 45, the build side's)."""
+    return jnp.take(storage, ids, axis=0)
+
+
+@register_kernel("join.gather_cols", static_argnames=("vmem",))
+def _gather_cols(tables, ids, vmem):  # analysis: allow[JIT001] — arity fixed per pipeline shape, three gather forms a group
+    """Groups of lanes, each group read by its one index, in ONE
+    program.  *vmem* says per group whether its tables are read from
+    VMEM (``vmem_gather_selected``'s answers); False is ``jnp.take`` a
+    lane."""
+    return tuple(take_small(t, i, vmem=v) for t, i, v in zip(tables, ids, vmem))
+
+
+@register_kernel("join.expand_head", static_argnames=("total",))
+def _expand_head_kernel(ids: Tuple[jax.Array, ...], total: int) -> Tuple[jax.Array, ...]:  # analysis: allow[JIT001] retrace is per id-lane count, not per data length
+    """The first *total* slots of every padded lane.  A program of its
+    own (``ops/sort.py``'s ``dedup.head``) so that what pads — the
+    fan-out expansion, the run copy — compiles once per power of two,
+    not per total, and so that the cut runs under a ``csvplus.`` name,
+    not as an eager slice a lane."""
+    return tuple(lane[:total] for lane in ids)
+
+
+@register_kernel("join.gather_runs", static_argnames=("padded", "kernel"))
+def _gather_runs_kernel(tables, first, counts, padded: int, kernel=True):  # analysis: allow[JIT001] retrace is per build-lane count and per power of two, not per total
+    """The build side's lanes at the fan-out's runs, *padded* slots a
+    lane (``ops/run_copy.py``): the expansion's padded length, so it
+    compiles per power of two, as ``csvplus.join.expand`` does."""
+    return run_copy.copy_runs(tables, first, counts, padded, kernel=kernel)
+
+
+def gather_runs(tables, first, counts, total: int, *, kernel=True) -> Tuple[jax.Array, ...]:
+    """``tuple(jnp.take(t, build_ids) for t in tables)`` for the
+    ``build_ids`` of the fan-out expansion of ``(first, counts)``, bit
+    for bit, without them: output rows ``starts[p] .. starts[p] +
+    counts[p] - 1`` (``starts`` the exclusive prefix sum) hold
+    ``t[first[p] .. first[p] + counts[p] - 1]``, and a probe that matched
+    nothing writes nothing.  *kernel*: ``run_copy_selected``'s answer."""
+    tables = tuple(tables)
+    if not tables or not total:
+        return tuple(t[:0] for t in tables)
+    padded = 1 << (total - 1).bit_length()
+    lanes = _gather_runs_kernel(tables, jnp.asarray(first), jnp.asarray(counts), padded=padded, kernel=kernel)
+    return _expand_head_kernel(lanes, total=total)
+
+
+class Lanes(NamedTuple):
+    """One group of an emit: *tables* all read by the one *idx*.  *runs*,
+    on the fan-out's build side only, is the probe's answer ``(first,
+    counts, total)`` that *idx* expands: probe *p* matched the build
+    rows ``first[p] .. first[p] + counts[p] - 1``."""
+
+    tables: Tuple[jax.Array, ...]
+    idx: Any
+    runs: Optional[tuple] = None
+
+
+class Emitted(NamedTuple):
+    """What :func:`emit` did: per group its *lanes* and the *form* that
+    moved them (``eager`` | ``runs`` | ``vmem`` | ``lane`` | ``cols``;
+    ``none`` for a group of no table), and the registered *programs* it
+    dispatched, in order."""
+
+    lanes: Tuple[Tuple[jax.Array, ...], ...]
+    forms: Tuple[str, ...]
+    programs: Tuple[str, ...]
+
+    def moved(self, form: str) -> int:
+        """Lanes *form* moved (``join:merge``'s ``vmem_gathers`` and
+        ``run_copies`` are ``moved("vmem")`` and ``moved("runs")``)."""
+        return sum(len(g) for g, f in zip(self.lanes, self.forms) if f == form)
+
+
+def _form(tables, idx, runs):
+    """``(form, the kernel's static flag)`` for one group: the decision
+    table of :func:`emit`, in its order."""
+    if not tables:
+        return "none", False
+    if not same_placement(tables + (idx,)):
+        return "eager", False
+    if runs is not None and (kernel := run_copy.run_copy_selected(tables, *runs)):
+        return "runs", kernel
+    if vmem := vmem_gather_selected(tables, idx):
+        return "vmem", vmem
+    if whole_device(idx, *tables):
+        return "lane", False
+    return "cols", False
+
+
+def emit(groups: Sequence[Lanes]) -> Emitted:
+    """``tuple(jnp.take(t, idx, axis=0) for t in tables)`` for every
+    group, bit for bit, in the form the chip taught for what the group
+    IS — read off placement, table length, dtype, lane count and the
+    runs' shape here, at the dispatch, outside any jit.  In this order:
+
+    * arrays of mixed placement (the partitioned tier's host ids over a
+      sharded stream) — eager takes, each free to resolve its own;
+    * *runs* that ``run_copy_selected`` admits — the lanes are copied a
+      run at a time (``csvplus.join.gather_runs``) and *idx* is not read;
+    * tables ``vmem_gather_selected`` admits — ``take_small``, every such
+      group of the call in ONE ``csvplus.join.gather_cols``, dispatched
+      where the first of them stands;
+    * lanes whole on one device — a program a lane
+      (``csvplus.join.gather_lane``);
+    * else (lanes sharded over a mesh: a ``pallas_call`` is not
+      partitioned) — one ``csvplus.join.gather_cols`` for the group."""
+    plan = [_form(*g) for g in groups]
+    shared = [at for at, (form, _) in enumerate(plan) if form == "vmem"]
+    out, programs = [() for _ in groups], []
+    for at, ((tables, idx, runs), (form, flag)) in enumerate(zip(groups, plan)):
+        if form == "eager":
+            i32 = jnp.asarray(idx, dtype=jnp.int32)
+            out[at] = tuple(jnp.take(t, i32, axis=0) for t in tables)
+        elif form == "runs":
+            out[at] = gather_runs(tables, *runs, kernel=flag)
+            programs += ["join.gather_runs", "join.expand_head"]
+        elif form == "lane":
+            out[at] = tuple(_gather_lane(t, idx) for t in tables)
+            programs += ["join.gather_lane"] * len(tables)
+        elif form == "cols" or (form == "vmem" and at == shared[0]):
+            ats = shared if form == "vmem" else [at]
+            got = _gather_cols(
+                tuple(groups[a].tables for a in ats),
+                tuple(groups[a].idx for a in ats),
+                vmem=tuple(plan[a][1] for a in ats),  # analysis: allow[RETRACE002] the rule's answers, read off shapes and placement: three values a group
+            )
+            for a, lanes in zip(ats, got):
+                out[a] = lanes
+            programs.append("join.gather_cols")
+    return Emitted(tuple(out), tuple(form for form, _ in plan), tuple(programs))

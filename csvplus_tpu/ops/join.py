@@ -57,7 +57,7 @@ from ..obs.recompile import register_kernel
 from ..obs.span import tracer
 from ..utils.env import env_int
 from ..utils.observe import telemetry
-from .gather import take_small, vmem_gather_selected, whole_device
+from .gather import Lanes, _expand_head_kernel, _gather_cols, emit, take_small, vmem_gather_selected, whole_device
 
 
 def _bits_for(n: int) -> int:
@@ -217,8 +217,8 @@ def _build_direct_cum(keys: jax.Array, total_bits: int) -> jax.Array:
 # An XLA gather on the TPU costs per INDEX walked, not per byte: 10M
 # indices take 0.05-0.075 s whether the table has 1,000 entries or
 # 100,000 (PERF.md section 5).  That no longer sets the price where a
-# small table is read — the composed tables, and since PR 46 either side
-# of the binary join's emit and the fan-out expansion's segment reads:
+# small table is read — by any join's emit (``gather.emit``), by the
+# composed probe or by the fan-out expansion's segment reads:
 # ``take_small`` (``gather.py``) serves tables of up to
 # ``VMEM_GATHER_MAX_ENTRIES`` entries from VMEM, at a cost that grows
 # with the TABLE (PERF.md section 6, PRs 44 and 46); a walk saved is
@@ -911,7 +911,7 @@ class DeviceIndex:
                 srcs = tuple(self.table.columns[n].storage for n in stale)
                 with telemetry.stage("join:compose", entry.size) as out:
                     out["columns"] = len(stale)
-                    got = _gather_cols(srcs, entry.lower_tab)
+                    (got,) = _gather_cols((srcs,), (entry.lower_tab,), vmem=(False,))
                 entry.col_tabs.update(zip(stale, zip(srcs, got)))
                 self._compositions += 1
             return tuple(entry.col_tabs[n][1] for n in names)
@@ -1183,16 +1183,7 @@ def expand_matches_device(
     padded_ids = _expand_kernel(lower, counts, padded, vmem=vmem)  # analysis: allow[RETRACE002] read off shapes and placement: three values
     # the cut lowers once per total: a slice compiles in milliseconds, and
     # the expansion, which does not, never sees the total
-    return _expand_head_kernel(padded_ids, total=total)  # analysis: allow[RETRACE002]
-
-
-@register_kernel("join.expand_head", static_argnames=("total",))
-def _expand_head_kernel(ids: Tuple[jax.Array, ...], total: int) -> Tuple[jax.Array, ...]:  # analysis: allow[JIT001] retrace is per id-lane count, not per data length
-    """The first *total* slots of every padded id lane.  A program of
-    its own (``ops/sort.py``'s ``dedup.head``) so that the expansion
-    compiles once per power of two, not per total, and so that the cut
-    runs under a ``csvplus.`` name, not as an eager slice a lane."""
-    return tuple(lane[:total] for lane in ids)
+    return _expand_head_kernel(padded_ids, total=total)
 
 
 def _checked_probe_cols(
@@ -1312,31 +1303,12 @@ def _build_sources(dev_index: "DeviceIndex", names, ids, entry):
     )
 
 
-def _take_each(tables, ids):
-    """Eager per-array takes, each free to resolve its own placement
-    (mixed placements: the partitioned tier's numpy ids over a
-    mesh-sharded stream with a single-device build table)."""
-    idx = jnp.asarray(ids, dtype=jnp.int32)
-    return tuple(jnp.take(t, idx, axis=0) for t in tables)
-
-
 def _stream_side(cols, names, gathered):
     """The stream's columns as the merge fold's first running result:
     as they stand when no row moved, else over the gathered storage."""
     if gathered is None:
         return dict(cols)
     return {n: cols[n].with_storage(g) for n, g in zip(names, gathered)}
-
-
-def _count_gathers(_mrg: dict, build_lanes: int, g_stream) -> None:
-    """``join:merge``'s gather counts: the lanes gathered from the build
-    side(s) and from the stream (none where its rows pass through), and
-    their sum under the name the stage has carried since PR 26."""
-    stream_lanes = len(g_stream or ())
-    _mrg.update(
-        row_gathers=build_lanes + stream_lanes,
-        build_gathers=build_lanes, stream_gathers=stream_lanes,
-    )
 
 
 def _merge_fold(cur, sides):
@@ -1358,6 +1330,50 @@ def _merge_fold(cur, sides):
             new[name] = col
         cur = new
     return cur
+
+
+def _emit_and_merge(cols, specs, probe_ids, build_ids, entries, runs, sel, n_in: int, device) -> DeviceTable:
+    """The tail the three join operators share: name the build columns
+    the merge can read, find their sources, move every row through the
+    one emit (``ops/gather.py``), fill ``join:merge`` from its record
+    and fold the merge.  *cols*: the stream's columns at full length;
+    *probe_ids* None: every stream row matched once in every dimension,
+    so the stream's rows pass through; *entries*: per dimension its
+    depth-2 entry where its *build_ids* are slots of the composed
+    columns; *runs*: the binary fan-out's ``(lower, counts, total)``;
+    *sel*: the fused join's selection (None: the stream IS *cols*),
+    composed into the index once — ``take(take(S, sel), ids) == take(S,
+    take(sel, ids))`` — so the stream's lanes move from full-length
+    storage in one gather, never materialize-then-gather."""
+    kept = [_kept_build_names(di, cols) for di, _ in specs]
+    groups = [
+        Lanes(_build_sources(di, names, ids, e), ids)
+        for (di, _), names, ids, e in zip(specs, kept, build_ids, entries)
+    ]
+    if runs is not None:  # the fan-out is the binary join's: one build side
+        groups[0] = groups[0]._replace(runs=runs)
+    stream_names = list(cols)
+    n_out = n_in if probe_ids is None else int(probe_ids.shape[0])
+    with telemetry.stage("join:merge", n_in) as _mrg:
+        idx = probe_ids
+        if sel is not None:
+            idx = sel if probe_ids is None else emit([Lanes((sel,), probe_ids)]).lanes[0][0]
+        if idx is not None:  # else no row of the stream moves
+            groups.append(Lanes(tuple(cols[n].storage for n in stream_names), idx))
+        done = emit(groups)
+        g_build = done.lanes[: len(specs)]
+        g_stream = None if idx is None else done.lanes[-1]
+        build_lanes, stream_lanes = sum(map(len, g_build)), len(g_stream or ())
+        _mrg.update(
+            row_gathers=build_lanes + stream_lanes, build_gathers=build_lanes, stream_gathers=stream_lanes,
+            vmem_gathers=done.moved("vmem"), run_copies=done.moved("runs"), rows_out=n_out,
+        )
+        cur = _merge_fold(
+            _stream_side(cols, stream_names, g_stream),
+            [(di, names, g) for (di, _), names, g in zip(specs, kept, g_build)],
+        )
+        telemetry.barrier(tuple(c.storage for c in cur.values()))
+    return DeviceTable(cur, n_out, device)
 
 
 def join_tables(
@@ -1432,84 +1448,10 @@ def join_tables(
         _exp.update(rows_out=total, emitted=total)
         telemetry.barrier((probe_ids, build_ids))
 
-    build_names = _kept_build_names(dev_index, stream.columns)
-    build_codes = _build_sources(dev_index, build_names, build_ids, entry)
-    stream_names = list(stream.columns)
-    stream_codes = tuple(stream.columns[n].storage for n in stream_names)
-
-    with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        # the fan-out's build side a run at a time where the rule admits it
-        # (``_emit_build_side``); else either side's tables that fit VMEM
-        # through ``take_small`` in one program (``_emit_side``), the build
-        # side's composed tables (depth 2) and a mesh's lanes in one jit
-        # call, a column read at its own length on one device a program a
-        # lane, as the stream's survivors are; every row matched once: no move
-        g_build, build_vmem, _mrg["run_copies"] = _emit_build_side(
-            build_codes, build_ids, entry is None and whole_device(build_ids, *build_codes), runs
-        )
-        g_stream, stream_vmem = (
-            (None, False) if probe_ids is None else _emit_side(stream_codes, probe_ids, True)
-        )
-        n_out = stream.nrows if probe_ids is None else len(probe_ids)
-        _count_gathers(_mrg, len(g_build), g_stream)
-        _mrg["vmem_gathers"] = (len(g_build) if build_vmem else 0) + (
-            len(g_stream) if stream_vmem else 0
-        )
-
-        cur = _merge_fold(
-            _stream_side(stream.columns, stream_names, g_stream),
-            [(dev_index, build_names, g_build)],
-        )
-        _mrg["rows_out"] = n_out
-        telemetry.barrier(tuple(c.storage for c in cur.values()))
-    return DeviceTable(cur, n_out, stream.device)
-
-
-@register_kernel("join.gather_lane")
-def _gather_lane(storage, ids):
-    """One lane's rows at *ids*: a program of its own, because a program
-    gets one or two cross-program prefetches — a lane gathered here is
-    read from fast memory, where of four lanes gathered by one program
-    two or three are read where they lie, at a third of the rate
-    (``PERF.md`` §5: PR 43, the stream's lanes; PR 45, the build side's)."""
-    return jnp.take(storage, ids, axis=0)
-
-
-def _gather_lanes(stream_codes, probe_ids):
-    """Full-length lanes at *probe_ids*, a program a lane: the stream's
-    survivors (the joins that did not match every row once) and, on one
-    device, the build side's columns.  Mixed placements (the partitioned
-    tier's numpy ids over a mesh-sharded stream) take the eager
-    per-array path."""
-    if same_placement(stream_codes + (probe_ids,)):
-        return tuple(_gather_lane(c, probe_ids) for c in stream_codes)
-    return _take_each(stream_codes, probe_ids)
-
-
-@register_kernel("join.gather_cols", static_argnames=("vmem",))
-def _gather_cols(codes, ids, vmem=False):  # analysis: allow[JIT001] — arity fixed per pipeline shape, two gather forms
-    """Lanes sharing one index in ONE program.  *vmem*:
-    ``vmem_gather_selected``'s answer for *codes* (``ops/gather.py``);
-    False is ``jnp.take`` a lane, the program as it ever was."""
-    return take_small(codes, ids, vmem=vmem)
-
-
-def _emit_side(codes, ids, lane_each: bool):
-    """One side of the binary join's emit — *codes* read at *ids* — and
-    whether the VMEM kernel served its lanes.  Tables the rule admits
-    (``vmem_gather_selected``: small, whole on the ids' device, a TPU)
-    go through ``take_small`` in ONE ``csvplus.join.gather_cols``; of
-    the others *lane_each* lanes move a program a lane
-    (``_gather_lanes``), the rest in one jit call where every array
-    shares a placement, eagerly where not."""
-    if not same_placement(codes + (ids,)):
-        return _take_each(codes, ids), False
-    vmem = vmem_gather_selected(codes, ids)
-    if vmem:
-        return _gather_cols(codes, ids, vmem), True  # analysis: allow[RETRACE002] read off shapes and placement: three values
-    if lane_each:
-        return _gather_lanes(codes, ids), False
-    return _gather_cols(codes, ids), False
+    return _emit_and_merge(
+        stream.columns, [(dev_index, columns)], probe_ids, (build_ids,), (entry,), runs, None,
+        stream.nrows, stream.device,
+    )
 
 
 @register_kernel("join.probe_stats")
@@ -1676,33 +1618,12 @@ def _multiway_expand_host(lowers, counts):
     return probe_ids, tuple(build_ids), total, inter
 
 
-@register_kernel("join.gather_multiway", static_argnames=("vmem",))
-def _gather_multiway(build_codes, build_ids, vmem=None):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """All build sides' row-materializing gathers in ONE jit call.
-    *vmem* says per dimension whether its tables are read from VMEM
-    (``_gather_build``; None: no dimension's are)."""
-    vmem = vmem or (False,) * len(build_codes)
-    return tuple(
-        take_small(codes, ids, vmem=v) for codes, ids, v in zip(build_codes, build_ids, vmem)
-    )
-
-
-def _gather_build(build_codes, build_ids, _mrg: dict):
-    """The multiway joins' build-side emit where every array shares a
-    placement: ``_gather_multiway`` with each dimension's gather form
-    read off its tables (``vmem_gather_selected``), and the lanes the
-    VMEM kernel serves counted into ``join:merge``'s ``vmem_gathers``."""
-    vmem = tuple(map(vmem_gather_selected, build_codes, build_ids))
-    _mrg["vmem_gathers"] = sum(len(c) for c, v in zip(build_codes, vmem) if v)
-    return _gather_multiway(build_codes, build_ids, vmem=vmem)  # analysis: allow[RETRACE002] read off shapes and placement: three values a dimension
-
-
 def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
     """The expansion decision shared by the multiway joins: one stats
     sync (total, max fanout, intermediate rows avoided), then the
     unique-identity / unique-partial / fan-out / host-expand ids, and
     the ``join:expand`` extras (``docs/OBSERVABILITY.md``).
-    Returns ``(probe_ids, build_ids, entries, total, inter)``;
+    Returns ``(probe_ids, build_ids, entries, inter)``;
     ``probe_ids`` None = every row matched once in EVERY dimension
     (then, and only then, *entries* survive: a depth-2 dimension's
     ``build_ids`` are slots of its composed columns)."""
@@ -1718,7 +1639,7 @@ def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
         _exp.update(tier="device", host_sync_elements=3, rows_out=total, emitted=total)
         if maxp <= 1 and total == nrows:
             _exp.update(path=prefix + "-unique-identity", form="identity", padded=0, row_gathers=0)
-            return None, lowers, entries, total, inter
+            return None, lowers, entries, inter
         padded = 1 << max(total - 1, 0).bit_length() if total else 1
         if maxp <= 1:
             probe_ids, build_ids = _compact_unique_partial(
@@ -1744,7 +1665,7 @@ def _multiway_ids(lowers, counts, entries, nrows: int, prefix: str, _exp: dict):
             path=prefix + "-host-expand", tier="host", form="numpy", padded=0,
             row_gathers=0, host_sync_elements=0, rows_out=total, emitted=total,
         )
-    return probe_ids, build_ids, (None,) * dims, total, inter
+    return probe_ids, build_ids, (None,) * dims, inter
 
 
 def multiway_join(
@@ -1756,8 +1677,6 @@ def multiway_join(
     ``join_tables`` applied left to right, without materializing any
     intermediate table.  *specs* lists the cascade's (DeviceIndex, key
     columns) pairs in cascade order."""
-    from ..obs.joinskew import joinskew
-
     if len(specs) == 1:  # degenerate run: exactly the binary join
         return join_tables(stream, specs[0][0], specs[0][1])
 
@@ -1781,53 +1700,41 @@ def multiway_join(
     # ORIGINAL stream rows.  The fusion license (rewrite.py) guarantees
     # later dimensions' keys are PRESENT before the run, so validating
     # them here raises exactly what the cascade's per-level checks would.
+    return _multiway(
+        stream.columns, specs, lambda kcols: _checked_probe_cols(stream, kcols),
+        stream.nrows, "multiway", None, stream.device,
+    )
+
+
+def _multiway(cols, specs, probe_cols_of, n_in: int, prefix: str, sel, device) -> DeviceTable:
+    """The single pass of ``multiway_join`` and ``multiway_join_selected``
+    over *n_in* stream rows: every dimension's probe (*probe_cols_of* a
+    spec's key columns: the stream's, validated, or the selection's),
+    the shared expansion decision, the shared tail, and the skew
+    plane's multiway counter (the staged binary join never ticks it)."""
+    from ..obs.joinskew import joinskew
+
     part_info: dict = {}
     answers = [
-        _probe_dim(
-            dev_index, _checked_probe_cols(stream, cols), stream.nrows, part_info
-        )
-        for dev_index, cols in specs
+        _probe_dim(dev_index, probe_cols_of(kcols), n_in, part_info)
+        for dev_index, kcols in specs
     ]
     lowers, counts, entries = (tuple(a[i] for a in answers) for i in range(3))
 
-    with telemetry.stage("join:expand", stream.nrows) as _exp:
+    with telemetry.stage("join:expand", n_in) as _exp:
         _exp["dims"] = len(specs)
-        probe_ids, build_ids, entries, total, inter = _multiway_ids(
-            lowers, counts, entries, stream.nrows, "multiway", _exp
+        probe_ids, build_ids, entries, inter = _multiway_ids(
+            lowers, counts, entries, n_in, prefix, _exp
         )
         telemetry.barrier((probe_ids,) + tuple(build_ids))
 
-    build_names = [_kept_build_names(di, stream.columns) for di, _ in specs]
-    build_codes = tuple(
-        _build_sources(di, names, bid, e)
-        for (di, _), names, bid, e in zip(specs, build_names, build_ids, entries)
-    )
-    stream_names = list(stream.columns)
-    stream_codes = tuple(stream.columns[n].storage for n in stream_names)
-    flat_build = tuple(c for side in build_codes for c in side)
-
-    with telemetry.stage("join:merge", stream.nrows) as _mrg:
-        _mrg["vmem_gathers"] = 0
-        if same_placement(flat_build + tuple(build_ids)):
-            g_build = _gather_build(build_codes, build_ids, _mrg)
-        else:  # mixed placements (the host-expand tier lands here)
-            g_build = tuple(map(_take_each, build_codes, build_ids))
-        g_stream = None if probe_ids is None else _gather_lanes(stream_codes, probe_ids)
-        n_out = stream.nrows if probe_ids is None else total
-        _count_gathers(_mrg, len(flat_build), g_stream)
-
-        cur = _merge_fold(
-            _stream_side(stream.columns, stream_names, g_stream),
-            [(di, names, g) for (di, _), names, g in zip(specs, build_names, g_build)],
+    out = _emit_and_merge(cols, specs, probe_ids, build_ids, entries, None, sel, n_in, device)
+    if len(specs) >= 2:
+        joinskew.on_multiway(
+            "+".join(",".join(di.key_columns) for di, _ in specs),
+            len(specs), n_in, out.nrows, inter,
         )
-        _mrg["rows_out"] = n_out
-        telemetry.barrier(tuple(c.storage for c in cur.values()))
-
-    joinskew.on_multiway(
-        "+".join(",".join(di.key_columns) for di, _ in specs),
-        len(specs), stream.nrows, n_out, inter,
-    )
-    return DeviceTable(cur, n_out, stream.device)
+    return out
 
 
 # -- fused probe pass over a selection (ISSUE 19) ---------------------------
@@ -1855,24 +1762,6 @@ def multiway_join(
 # raises the host-parity errors with scan-base-correct row numbers).
 
 
-@register_kernel("join.gather_fused_both")
-def _gather_fused_both(build_codes, stream_codes, build_ids, probe_ids, sel):  # analysis: allow[JIT001] — arity fixed per pipeline shape
-    """The fused emit: every build side's gathers, and the stream columns
-    gathered from FULL-length storage by the composed ``sel[probe_ids]``
-    index — gather associativity is the whole fusion win (one gather
-    instead of materialize-then-gather)."""
-    out_b = []
-    for codes, ids in zip(build_codes, build_ids):
-        idx = jnp.asarray(ids, dtype=jnp.int32)
-        out_b.append(tuple(jnp.take(c, idx, axis=0) for c in codes))
-    p_idx = jnp.asarray(probe_ids, dtype=jnp.int32)
-    e_idx = jnp.take(jnp.asarray(sel, dtype=jnp.int32), p_idx, axis=0)
-    return (
-        tuple(out_b),
-        tuple(jnp.take(c, e_idx, axis=0) for c in stream_codes),
-    )
-
-
 def multiway_join_selected(
     cols,
     sel,
@@ -1887,91 +1776,13 @@ def multiway_join_selected(
     is the selected row-id array, *identity* asserts sel is the whole
     range in order (then per-column gathers pass through, exactly like
     ``materialize()``'s identity fast path)."""
-    from ..obs.joinskew import joinskew
-
-    n_sel = int(sel.shape[0])
-
     # every dimension probes the SELECTED key values: the same arrays a
     # staged materialize would have produced, so probe answers (and the
     # shared partitioned-tier state threading) match the staged run
-    part_info: dict = {}
-    answers = [
-        _probe_dim(
-            dev_index,
-            [cols[c] if identity else cols[c].gather(sel) for c in kcols],
-            n_sel,
-            part_info,
-        )
-        for dev_index, kcols in specs
-    ]
-    lowers, counts, entries = (tuple(a[i] for a in answers) for i in range(3))
-
-    with telemetry.stage("join:expand", n_sel) as _exp:
-        _exp["dims"] = len(specs)
-        probe_ids, build_ids, entries, total, inter = _multiway_ids(
-            lowers, counts, entries, n_sel, "fused", _exp
-        )
-        telemetry.barrier((probe_ids,) + tuple(build_ids))
-
-    build_names = [_kept_build_names(di, cols) for di, _ in specs]
-    build_codes = tuple(
-        _build_sources(di, names, bid, e)
-        for (di, _), names, bid, e in zip(specs, build_names, build_ids, entries)
+    return _multiway(
+        cols, specs, lambda kcols: [cols[c] if identity else cols[c].gather(sel) for c in kcols],
+        int(sel.shape[0]), "fused", None if identity else sel, device,
     )
-    stream_names = list(cols)
-    stream_codes = tuple(cols[n].storage for n in stream_names)
-    flat_build = tuple(c for side in build_codes for c in side)
-
-    with telemetry.stage("join:merge", n_sel) as _mrg:
-        _mrg["vmem_gathers"] = 0
-        if probe_ids is None:
-            # every selected row matched once per dimension: the stream
-            # side IS the selection — the one gather the staged
-            # materialize would have paid anyway (identity: none at all)
-            if same_placement(flat_build + tuple(build_ids)):
-                g_build = _gather_build(build_codes, build_ids, _mrg)
-            else:
-                g_build = tuple(map(_take_each, build_codes, build_ids))
-            if identity:
-                g_stream = None
-            elif same_placement(stream_codes + (sel,)):
-                g_stream = _gather_cols(stream_codes, sel)
-            else:
-                g_stream = _take_each(stream_codes, sel)
-            n_out = n_sel
-        elif same_placement(flat_build + stream_codes):
-            # the fused win: ONE composed gather from full-length
-            # storage replaces materialize-then-gather
-            g_build, g_stream = _gather_fused_both(
-                build_codes, stream_codes, build_ids, probe_ids, sel
-            )
-            n_out = total
-        else:
-            # mixed placements: compose the index eagerly, then eager
-            # per-column takes (the host-expand tier lands here)
-            e_idx = jnp.take(
-                jnp.asarray(sel, dtype=jnp.int32),
-                jnp.asarray(probe_ids, dtype=jnp.int32),
-                axis=0,
-            )
-            g_build = tuple(map(_take_each, build_codes, build_ids))
-            g_stream = _take_each(stream_codes, e_idx)
-            n_out = total
-        _count_gathers(_mrg, len(flat_build), g_stream)
-
-        cur = _merge_fold(
-            _stream_side(cols, stream_names, g_stream),
-            [(di, names, g) for (di, _), names, g in zip(specs, build_names, g_build)],
-        )
-        _mrg["rows_out"] = n_out
-        telemetry.barrier(tuple(c.storage for c in cur.values()))
-
-    if len(specs) >= 2:  # counter parity: the staged binary join never ticks
-        joinskew.on_multiway(
-            "+".join(",".join(di.key_columns) for di, _ in specs),
-            len(specs), n_sel, n_out, inter,
-        )
-    return DeviceTable(cur, n_out, device)
 
 
 def except_mask(
@@ -1984,48 +1795,3 @@ def except_mask(
     probe_cols = _checked_probe_cols(stream, columns)
     _, counts = dev_index.probe(probe_cols, stream.nrows)
     return counts == 0
-
-
-# -- the fan-out's build side, a run at a time (ISSUE 47) ---------------------
-#
-# Down here, import and all: a Pallas program's compile-cache key holds
-# the file and line of every calling frame (PERF.md section 6, PR 46), so
-# a line added above a ``take_small`` call site or above a caller of one
-# recompiles the multiway joins' kernels in cells this code never runs in.
-
-from .run_copy import copy_runs, run_copy_selected  # noqa: E402
-
-
-@register_kernel("join.gather_runs", static_argnames=("padded", "kernel"))
-def _gather_runs_kernel(tables, first, counts, padded: int, kernel=True):  # analysis: allow[JIT001] retrace is per build-lane count and per power of two, not per total
-    """The build side's lanes at the fan-out's runs, *padded* slots a
-    lane (``ops/run_copy.py``): the expansion's padded length, so it
-    compiles per power of two, as ``csvplus.join.expand`` does."""
-    return copy_runs(tables, first, counts, padded, kernel=kernel)
-
-
-def gather_runs(tables, first, counts, total: int, *, kernel=True) -> Tuple[jax.Array, ...]:
-    """``tuple(jnp.take(t, build_ids) for t in tables)`` for the
-    ``build_ids`` of ``expand_matches_device(first, counts, total)``,
-    bit for bit, without them: output rows ``starts[p] .. starts[p] +
-    counts[p] - 1`` (``starts`` the exclusive prefix sum) hold
-    ``t[first[p] .. first[p] + counts[p] - 1]``, and a probe that matched
-    nothing writes nothing.  *kernel*: ``run_copy_selected``'s answer."""
-    tables = tuple(tables)
-    if not tables or not total:
-        return tuple(t[:0] for t in tables)
-    padded = 1 << (total - 1).bit_length()
-    lanes = _gather_runs_kernel(tables, jnp.asarray(first), jnp.asarray(counts), padded=padded, kernel=kernel)  # analysis: allow[RETRACE002] a power of two, and the rule's two values
-    return _expand_head_kernel(lanes, total=total)  # analysis: allow[RETRACE002] the cut lowers once per total, in milliseconds
-
-
-def _emit_build_side(codes, ids, lane_each: bool, runs):
-    """The build side of the binary join's emit — ``_emit_side``'s pair
-    and the lanes the run copy moved.  *runs*: the fan-out's ``(lower,
-    counts, total)``, None on every other path; where the rule admits
-    them (``run_copy_selected``, read off *codes* and *runs*) the lanes
-    are copied a run at a time and *ids* are not read."""
-    kernel = runs is not None and run_copy_selected(codes, *runs)
-    if kernel:
-        return gather_runs(codes, *runs, kernel=kernel), False, len(codes)
-    return (*_emit_side(codes, ids, lane_each), 0)

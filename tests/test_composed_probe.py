@@ -2,7 +2,7 @@
 kernels it stands in for.
 
 The staged chain — ``_translate_*`` / ``_apply_code_translation`` ->
-``_pack_qk_kernel`` -> ``_probe_kernel_direct`` -> ``_gather_multiway``
+``_pack_qk_kernel`` -> ``_probe_kernel_direct`` -> the emit's gathers
 — stays the arbiter: every case here runs both and compares bitwise
 (``lower`` where a row matched, ``counts`` everywhere, the joined table's
 values, row order and column order).  The shapes that must not engage

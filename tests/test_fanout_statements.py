@@ -120,14 +120,17 @@ def _journal_of_its_own():
 
 @pytest.fixture
 def programs(monkeypatch):
-    """Calls of the two emit programs, by name."""
-    calls = {"_gather_lane": 0, "_gather_cols": 0, "_gather_runs_kernel": 0}
-    for name in calls:
-        def counted(*args, _real=getattr(J, name), _name=name, **kwargs):
-            calls[_name] += 1
-            return _real(*args, **kwargs)
+    """The programs the emit dispatched, by name, off its own records."""
+    calls = {"join.gather_lane": 0, "join.gather_cols": 0, "join.gather_runs": 0}
 
-        monkeypatch.setattr(J, name, counted)
+    def counted(groups, _real=G.emit):
+        done = _real(groups)
+        for name in done.programs:
+            if name in calls:
+                calls[name] += 1
+        return done
+
+    monkeypatch.setattr(J, "emit", counted)
     return calls
 
 
@@ -181,13 +184,13 @@ def test_plancache_equals_upstreams_scan(tmp_path, programs, monkeypatch, case, 
         # the orders' four lanes move by ONE run copy (its mean run is 30 or
         # 60 rows), people's three by one VMEM gather; no lane a program
         assert (merges[0]["run_copies"], merges[0]["vmem_gathers"]) == (4, 3)
-        assert emit_programs == {"_gather_lane": 0, "_gather_cols": 3 if cascade else 1, "_gather_runs_kernel": 1}
+        # (stock's two composed tables ride a VMEM gather of their own in the cascade)
+        assert emit_programs == {"join.gather_lane": 0, "join.gather_cols": 2 if cascade else 1, "join.gather_runs": 1}
         assert [m["run_copies"] for m in merges[1:]] == [0] * cascade  # unique-identity: no runs
     else:
-        # every lane read at its own length moves in a program of its own (four
-        # of the orders, three of the people); stock's two composed tables ride
-        # one program (run twice in a first execution: to compose them, to emit)
-        assert emit_programs == {"_gather_lane": 7, "_gather_cols": 2 if cascade else 0, "_gather_runs_kernel": 0}
+        # every lane whole on one device moves in a program of its own: four of
+        # the orders, three of the people, and stock's two composed tables
+        assert emit_programs == {"join.gather_lane": 9 if cascade else 7, "join.gather_cols": 0, "join.gather_runs": 0}
         assert merges[0]["run_copies"] == 0
     if cascade:
         # prod_id is born from the first join's build side, so the two

@@ -15,6 +15,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from csvplus_tpu.ops import gather as G
 from csvplus_tpu.ops import join as J
 from csvplus_tpu.ops import run_copy as RC
 
@@ -38,9 +39,9 @@ def small_blocks(monkeypatch):
     that a few thousand rows cross many of both."""
     monkeypatch.setattr(RC, "_BLOCK_ROWS", 8)
     monkeypatch.setattr(RC, "_CHUNK_RUNS", 128)
-    J._gather_runs_kernel.clear_cache()  # the constants are no part of jit's key
+    G._gather_runs_kernel.clear_cache()  # the constants are no part of jit's key
     yield
-    J._gather_runs_kernel.clear_cache()
+    G._gather_runs_kernel.clear_cache()
 
 
 def _tables(rng, n: int, tables: int):
@@ -107,7 +108,7 @@ def test_gather_runs_is_jnp_take_of_the_expansions_ids(case, tables, small_block
     tabs = _tables(rng, n, tables)
     build_ids, want = _want(tabs, first, counts, total)
     assert np.array_equal(np.asarray(build_ids), np.concatenate([np.arange(f, f + c) for f, c in zip(first, counts)]))
-    got = J.gather_runs(tabs, jnp.asarray(first), jnp.asarray(counts), total, kernel="interpret")
+    got = G.gather_runs(tabs, jnp.asarray(first), jnp.asarray(counts), total, kernel="interpret")
     assert len(got) == tables
     for g, w in zip(got, want):
         assert g.dtype == jnp.int32 and g.shape == (total,)
@@ -124,7 +125,7 @@ def test_at_the_blocks_the_chip_uses(n, probes, mean):
     total = int(counts.sum())
     tabs = _tables(rng, n, 2)
     _, want = _want(tabs, first, counts, total)
-    got = J.gather_runs(tabs, jnp.asarray(first), jnp.asarray(counts), total, kernel="interpret")
+    got = G.gather_runs(tabs, jnp.asarray(first), jnp.asarray(counts), total, kernel="interpret")
     for g, w in zip(got, want):
         assert np.array_equal(np.asarray(g), w)
 
@@ -136,7 +137,7 @@ def test_the_last_rows_of_the_lane_are_reached(small_blocks):
     tab = jnp.arange(n, dtype=jnp.int32) * 3 + 1
     first = np.array([n - 5, 0, n - 300, 1023], np.int32)
     counts = np.array([5, 7, 300, 1], np.int32)
-    (got,) = J.gather_runs((tab,), jnp.asarray(first), jnp.asarray(counts), 313, kernel="interpret")
+    (got,) = G.gather_runs((tab,), jnp.asarray(first), jnp.asarray(counts), 313, kernel="interpret")
     want = np.concatenate([np.arange(f, f + c) for f, c in zip(first, counts)]) * 3 + 1
     assert np.array_equal(np.asarray(got), want)
 
@@ -145,9 +146,9 @@ def test_the_last_rows_of_the_lane_are_reached(small_blocks):
 def test_no_run_writes_nothing(probes):
     tabs = _tables(np.random.default_rng(1), 500, 3)
     zeros = jnp.zeros(probes, jnp.int32)
-    got = J.gather_runs(tabs, zeros, zeros, 0, kernel="interpret")
+    got = G.gather_runs(tabs, zeros, zeros, 0, kernel="interpret")
     assert len(got) == 3 and all(g.shape == (0,) and g.dtype == jnp.int32 for g in got)
-    assert J.gather_runs((), zeros, zeros, 0, kernel="interpret") == ()
+    assert G.gather_runs((), zeros, zeros, 0, kernel="interpret") == ()
 
 
 def test_the_work_items_cover_every_run_once(small_blocks):

@@ -3,9 +3,9 @@
 ``jnp.take`` is the arbiter, bit for bit: the kernel alone over every
 shape class of table, lane count and stream length (interpret mode, so
 small N), then the rule that selects it, then the programs that call
-it — ``csvplus.join.gather_multiway`` and ``csvplus.join.probe_composed``
-(ISSUE 44), the binary join's ``csvplus.join.gather_cols`` and
-``csvplus.join.expand`` (ISSUE 46) — with the kernel forced by a fixture
+it — the emit's ``csvplus.join.gather_cols`` (``ops/gather.py``'s one
+entry point and its decision table, ISSUE 48), ``csvplus.join.probe_composed``
+(ISSUE 44) and ``csvplus.join.expand`` (ISSUE 46) — with the kernel forced by a fixture
 against the same joins without it, and the ``vmem_gathers`` counter of
 ``join:probe`` / ``join:expand`` / ``join:merge``.  Beside each, the run
 copy's (``ops/run_copy.py``, ISSUE 47; the kernel alone is in
@@ -193,6 +193,87 @@ def test_the_run_copys_rule_reads_runs_size_dtype_and_placement(kernel_forced, m
     assert RC.run_copy_selected((_described(2_000_000),), probe, probe, at) == "interpret"
 
 
+# ---- the emit: one entry point, one decision table (ISSUE 48) ---------------
+
+
+def _runs(rng, entries: int, probes: int, mean: int):
+    """A fan-out's answer ``(first, counts, total)`` — runs of 0 to
+    ``2 * mean`` rows anywhere they fit — and the ``build_ids`` it expands to."""
+    counts = rng.integers(0, 2 * mean + 1, probes).astype(np.int32)
+    first = (rng.integers(0, entries, probes) % np.maximum(entries - counts + 1, 1)).astype(np.int32)
+    total = int(counts.sum())
+    _, build_ids = J.expand_matches_device(jnp.asarray(first), jnp.asarray(counts), total)
+    return (jnp.asarray(first), jnp.asarray(counts), total), build_ids
+
+
+def _emit_case(case: str, rng):
+    """One row of the table: ``(groups, forms, programs)``."""
+    big = G.VMEM_GATHER_MAX_ENTRIES + 1
+    small = G.Lanes(_tables(rng, 1000, 3), _indices(rng, 1000, 3000))
+    if case == "mixed-placement":  # the partitioned tier's host ids
+        return [G.Lanes(small.tables, np.asarray(small.idx))], ("eager",), ()
+    if case in ("small-tables", "off-the-tpu"):  # two dimensions share the one program
+        groups = [small, G.Lanes(_tables(rng, 300, 2), _indices(rng, 300, 3000))]
+        if case == "off-the-tpu":
+            return groups, ("lane", "lane"), ("join.gather_lane",) * 5
+        return groups, ("vmem", "vmem"), ("join.gather_cols",)
+    if case == "large-lanes-one-device":
+        return [G.Lanes(_tables(rng, big, 2), _indices(rng, big, 3000))], ("lane",), ("join.gather_lane",) * 2
+    if case == "mesh-sharded-lanes":
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from csvplus_tpu.parallel.mesh import make_mesh, row_spec
+
+        mesh = make_mesh(8)
+        idx = jax.device_put(np.asarray(_indices(rng, 1000, 4096)), NamedSharding(mesh, row_spec(mesh)))
+        tabs = tuple(jax.device_put(t, NamedSharding(mesh, PartitionSpec())) for t in small.tables)
+        return [G.Lanes(tabs, idx)], ("cols",), ("join.gather_cols",)
+    if case in ("runs-admitted", "runs-of-mean-under-8", "statements-first-join"):
+        tabs = _tables(rng, 5000, 3)
+        runs, build_ids = _runs(rng, 5000, 40, 3 if case == "runs-of-mean-under-8" else 60)
+        build = G.Lanes(tabs, build_ids, runs)
+        if case == "runs-of-mean-under-8":  # the gathers' price a run: these tables fit VMEM
+            assert runs[2] < 40 * RC.RUN_COPY_MIN_MEAN_RUN
+            return [build], ("vmem",), ("join.gather_cols",)
+        copied = ("join.gather_runs", "join.expand_head")
+        if case == "runs-admitted":
+            return [build], ("runs",), copied
+        return [build, small], ("runs", "vmem"), copied + ("join.gather_cols",)
+    if case == "selective-star":  # the dimensions' one program stands where the first of them does
+        stream = G.Lanes(_tables(rng, big, 2), _indices(rng, big, 3000))
+        dim = G.Lanes(_tables(rng, 300, 2), _indices(rng, 300, 3000))
+        return [small, stream, dim], ("vmem", "lane", "vmem"), ("join.gather_cols",) + ("join.gather_lane",) * 2
+    if case == "empty-index":
+        return [G.Lanes(small.tables, jnp.zeros(0, jnp.int32))], ("vmem",), ("join.gather_cols",)
+    assert case == "zero-tables"
+    return [G.Lanes((), small.idx), small], ("none", "vmem"), ("join.gather_cols",)
+
+
+@pytest.mark.parametrize("case", [
+    "mixed-placement", "small-tables", "off-the-tpu", "large-lanes-one-device",
+    pytest.param("mesh-sharded-lanes", marks=needs8), "runs-admitted", "runs-of-mean-under-8",
+    "statements-first-join", "selective-star", "empty-index", "zero-tables",
+])
+def test_the_emit_is_jnp_take_and_says_what_it_did(case, monkeypatch):
+    """Every form of ``ops/gather.py``'s decision table — the kernels as
+    the chip would choose them, run by the interpreter — gives
+    ``jnp.take``'s lanes bit for bit, and the record names the form of
+    each group, the programs dispatched, and the lanes each form moved."""
+    if case != "off-the-tpu":
+        monkeypatch.setattr(G, "_kernel_mode", lambda: "interpret")
+    groups, forms, programs = _emit_case(case, np.random.default_rng(len(case)))
+    done = G.emit(groups)
+    assert (done.forms, done.programs) == (forms, programs)
+    assert len(done.lanes) == len(groups)
+    for got, (tables, idx, _) in zip(done.lanes, groups):
+        assert len(got) == len(tables)
+        for g, t in zip(got, tables):
+            want = jnp.take(t, jnp.asarray(idx), axis=0)
+            assert g.dtype == want.dtype and np.array_equal(np.asarray(g), np.asarray(want))
+    for form in ("eager", "runs", "vmem", "lane", "cols"):
+        assert done.moved(form) == sum(len(g.tables) for g, f in zip(groups, forms) if f == form)
+
+
 # ---- the join through it ----------------------------------------------------
 
 
@@ -217,9 +298,9 @@ def test_multiway_join_through_the_kernel_equals_jnp_take(case, monkeypatch):
     (merge,), (ref_merge,) = _stages(recs, "join:merge"), _stages(ref_recs, "join:merge")
     lanes = sum(len(J._kept_build_names(di, stream.columns)) for di, _ in specs)
     streamed = 0 if got.nrows == stream.nrows else len(stream.columns)
-    # every build lane of these small dimensions is served by the kernel; the
-    # stream's own survivors (a program a lane, at the stream's length) never are
-    assert merge.extra["vmem_gathers"] == lanes
+    # every build lane of these small dimensions is served by the kernel, and so
+    # are the survivors of a stream this short (at the cells' length: a program a lane)
+    assert merge.extra["vmem_gathers"] == lanes + streamed
     assert merge.extra["row_gathers"] == ref_merge.extra["row_gathers"] == lanes + streamed
     assert ref_merge.extra["vmem_gathers"] == 0
     probes, ref_probes = _stages(recs, "join:probe"), _stages(ref_recs, "join:probe")
@@ -369,7 +450,7 @@ def test_the_statements_cascade_counts_what_the_cell_counts(monkeypatch):
     calls = []
     with monkeypatch.context() as m:
         m.setattr(G, "_kernel_mode", lambda: "interpret")
-        m.setattr(J, "_gather_lane", lambda c, i, _real=J._gather_lane: calls.append(1) or _real(c, i))
+        m.setattr(G, "_gather_lane", lambda c, i, _real=G._gather_lane: calls.append(1) or _real(c, i))
         got, recs = _cascade(people, by_cust, stock)
     assert got.nrows == want.nrows == G.VMEM_GATHER_MAX_ENTRIES + 1
     assert list(got.columns) == list(want.columns) and len(got.columns) == 9
@@ -395,7 +476,7 @@ def _no_run_copy(monkeypatch):
     def refuse(*args, **kwargs):
         raise AssertionError("csvplus.join.gather_runs ran")
 
-    monkeypatch.setattr(J, "_gather_runs_kernel", refuse)
+    monkeypatch.setattr(G, "_gather_runs_kernel", refuse)
 
 
 @pytest.mark.parametrize("why, lane_programs, vmem_gathers", [
@@ -413,7 +494,7 @@ def test_a_fan_out_the_rule_refuses_emits_by_the_parents_programs(why, lane_prog
     assert _stages(ref_recs, "join:expand")[0].extra["path"] == "fan-out"
     calls = []
     _no_run_copy(monkeypatch)
-    monkeypatch.setattr(J, "_gather_lane", lambda c, i, _real=J._gather_lane: calls.append(1) or _real(c, i))
+    monkeypatch.setattr(G, "_gather_lane", lambda c, i, _real=G._gather_lane: calls.append(1) or _real(c, i))
     if why != "off-the-tpu":
         monkeypatch.setattr(G, "_kernel_mode", lambda: "interpret")
     if why == "short-runs":
@@ -452,7 +533,6 @@ def test_the_unique_paths_carry_no_runs(case, kernel_forced, monkeypatch):
     there are no runs to read it off."""
     _no_run_copy(monkeypatch)
     monkeypatch.setattr(RC, "run_copy_selected", lambda *a: pytest.fail("the rule was asked"))
-    monkeypatch.setattr(J, "run_copy_selected", RC.run_copy_selected)
     stream, specs, path, _ = _join_case(case)
     for di, cols in specs:
         _, recs = _binary(stream, di, cols)
@@ -482,19 +562,19 @@ def _as_it_was(name, fn, **jit_kwargs):
 
 
 def test_without_the_kernel_both_programs_lower_to_the_parents_hlo():
-    """``vmem=False`` — off the TPU, over the limit, under a mesh — is
-    the program as it was, to the letter: the compile cache's key of
-    every cell that runs ``gather_cols`` with large tables stays."""
+    """``vmem`` False — off the TPU, over the limit, under a mesh — is
+    ``jnp.take`` a lane and nothing else, to the letter."""
     def lane(n):
         return jax.ShapeDtypeStruct((n,), jnp.int32)
 
-    def gather_cols(codes, ids):
-        idx = jnp.asarray(ids, dtype=jnp.int32)
-        return tuple(jnp.take(c, idx, axis=0) for c in codes)
+    def gather_cols(tables, ids):  # a group of lanes an index, since ISSUE 48
+        return tuple(
+            tuple(jnp.take(c, jnp.asarray(i, dtype=jnp.int32), axis=0) for c in t) for t, i in zip(tables, ids)
+        )
 
-    args = ((lane(5000),) * 3, lane(20000))
+    args = (((lane(5000),) * 3, (lane(700),) * 2), (lane(20000), lane(300)))
     was = _as_it_was("join.gather_cols", gather_cols).lower(*args).as_text()
-    assert J._gather_cols.lower(*args).as_text() == J._gather_cols.lower(*args, vmem=False).as_text() == was
+    assert G._gather_cols.lower(*args, vmem=(False, False)).as_text() == was
 
     # the program a lane that the build side's lanes move by wherever the run
     # copy's rule says no (ISSUE 47): off the TPU, short runs, a lane past the
@@ -503,7 +583,7 @@ def test_without_the_kernel_both_programs_lower_to_the_parents_hlo():
         return jnp.take(storage, ids, axis=0)
 
     was = _as_it_was("join.gather_lane", gather_lane).lower(lane(5000), lane(20000)).as_text()
-    assert J._gather_lane.lower(lane(5000), lane(20000)).as_text() == was
+    assert G._gather_lane.lower(lane(5000), lane(20000)).as_text() == was
 
     def expand(lower, counts, padded_total: int):
         counts = counts.astype(jnp.int32)
@@ -555,9 +635,9 @@ def _lane(n, sharding):
 def test_the_emit_compiles_for_the_chip_at_the_cells_shapes(one_chip, rows, people):
     codes = ((_lane(people, one_chip),) * 3, (_lane(1000, one_chip),) * 2)
     ids = (_lane(rows, one_chip),) * 2
-    text = J._gather_multiway.lower(codes, ids, vmem=(True, True)).compile().as_text()
+    text = G._gather_cols.lower(codes, ids, vmem=(True, True)).compile().as_text()
     assert text.count("tpu_custom_call") == 2  # a kernel a dimension
-    plain = J._gather_multiway.lower(codes, ids, vmem=(False, False)).compile().as_text()
+    plain = G._gather_cols.lower(codes, ids, vmem=(False, False)).compile().as_text()
     assert "tpu_custom_call" not in plain
 
 
@@ -567,10 +647,10 @@ def test_the_emit_compiles_for_the_chip_at_the_cells_shapes(one_chip, rows, peop
 def test_the_binary_emit_compiles_for_the_chip_at_the_cells_shapes(one_chip, side, tables, entries, rows):
     """``statements-fanout-resident``: people's three lanes and stock's
     two composed tables at 10,000,000 ids, one kernel call a side."""
-    codes, ids = (_lane(entries, one_chip),) * tables, _lane(rows, one_chip)
-    text = J._gather_cols.lower(codes, ids, vmem=True).compile().as_text()
+    codes, ids = ((_lane(entries, one_chip),) * tables,), (_lane(rows, one_chip),)
+    text = G._gather_cols.lower(codes, ids, vmem=(True,)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
-    assert "tpu_custom_call" not in J._gather_cols.lower(codes, ids).compile().as_text()
+    assert "tpu_custom_call" not in G._gather_cols.lower(codes, ids, vmem=(False,)).compile().as_text()
 
 
 def test_the_expansion_compiles_for_the_chip_at_the_cells_shapes(one_chip, probes=100_000, padded=16_777_216):
@@ -586,7 +666,7 @@ def test_the_run_copy_compiles_for_the_chip_at_the_cells_shapes(one_chip, rows=1
     the sorted orders, two a call — 2 x 40 MB whole in VMEM, which
     interpret mode cannot refuse and the chip's compiler can."""
     args = ((_lane(rows, one_chip),) * 4, _lane(probes, one_chip), _lane(probes, one_chip))
-    text = J._gather_runs_kernel.lower(*args, padded=padded, kernel=True).compile().as_text()
+    text = G._gather_runs_kernel.lower(*args, padded=padded, kernel=True).compile().as_text()
     assert text.count("tpu_custom_call") == 4 // RC._MAX_TABLES
     assert RC._call_bytes(rows, RC._MAX_TABLES) < RC._V5E_VMEM_BYTES * RC._VMEM_SHARE
 

@@ -818,14 +818,12 @@ def _kernel_examples():
         "join.expand": ((i, i), {"padded_total": 16}),
         "join.expand_head": (((i, i),), {"total": 5}),
         "join.gather_lane": ((i, i), {}),
-        "join.gather_cols": (((i, i), i), {}),
+        "join.gather_cols": ((((i,), (i, i)), (i, i)), {"vmem": (False, "interpret")}),
         "join.gather_runs": (((i, i), i, i), {"padded": 32, "kernel": "interpret"}),
         "join.probe_stats": ((i, i), {}),
         "join.multiway_stats": (((i, i),), {}),
         "join.compact_partial": (((i, i), (i, i)), {"padded": 8}),
         "join.multiway_expand": (((i, i), (i, i)), {"padded_total": 16}),
-        "join.gather_multiway": ((((i,), (i, i)), (i, i)), {}),
-        "join.gather_fused_both": ((((i,), (i,)), (i, i), (i, i), i, i), {}),
         "typed.translate_dense": ((i, jnp.int32(0), i), {}),
         "typed.translate_sorted": ((i, i, i), {}),
         "typed.translate_empty": ((i,), {}),
@@ -846,8 +844,7 @@ KERNELS_LOWERED_HERE = sorted([
     "join.probe_i32pair", "join.probe_direct", "join.probe_i32", "serve.bounds_search",
     "join.build_direct_cum", "join.pack_qk", "join.expand", "join.expand_head", "join.gather_lane",
     "join.gather_cols", "join.gather_runs", "join.probe_stats", "join.multiway_stats", "join.compact_partial",
-    "join.multiway_expand", "join.gather_multiway",
-    "join.gather_fused_both", "typed.translate_dense", "typed.translate_sorted",
+    "join.multiway_expand", "typed.translate_dense", "typed.translate_sorted",
     "typed.translate_empty", "table.gather_take", "table.gather_take_rows",
     "table.apply_code_translation",
     "table.sync_probe", "join.compose_probe", "join.probe_composed", "join.probe_composed_range",

@@ -316,6 +316,34 @@ def test_rank_join_orders_marks_submitted_and_provable():
     assert best["provable"] and not best["submitted"]
 
 
+def test_an_expanding_join_never_moves_up_over_an_anti_join():
+    """``scan > Except(dim) > Join(dim)`` under sketches that price the
+    join as the more selective stage: the join-first order is cheaper
+    but NOT provable — only a narrowing stage may move earlier — so the
+    rewriter leaves the order alone (``plancert`` refuses a permute
+    whose mover is a ``Join``)."""
+    from csvplus_tpu.analysis import verify_plan
+    from csvplus_tpu.analysis.cost import rank_join_orders
+    from csvplus_tpu.obs.sketch import SpaceSaving
+
+    plan = P.Join(
+        P.Except(P.Scan(_fact()), _dim(10), ("id",)),
+        _dim(),
+        ("id",),
+    )
+    sk = SpaceSaving(k=256)
+    sk.offer_many([str(i) for i in range(200)])  # 200 keys, one row each: fanout 0.25
+    ranked = rank_join_orders(plan, verify_plan(plan), sketches={"id": sk})
+    join_first = next(r for r in ranked if r["order"][0].startswith("Join"))
+    assert join_first is ranked[0] and not join_first["submitted"]  # the cheaper order...
+    assert join_first["provable"] is False  # ...moves an EXPAND stage earlier
+    result = optimize_plan(plan, sketches={"id": sk})
+    steps = result.recipe.steps if result.recipe is not None else ()
+    assert not any(step[0] == "permute" for step in steps)
+    assert not any(r.startswith("join-order") for r in result.applied)
+    assert _chain_ops(result.root)[:3] == ["Scan", "Except", "Join"]
+
+
 # -- the verdict assertion ---------------------------------------------
 
 
