@@ -1,9 +1,16 @@
-"""The least bytes the lane GATHERS of ``dedup`` (the
-``csvplus.table.gather_take`` programs of one execution: the index
-build's permutation of the payload lanes and the compaction of every
-lane) must move through HBM, from shapes: a lower bound for
-``kernel.dedup_gather_roofline_pct``, never a count of what the program
-moved.
+"""The least bytes the LANE MOVES of ``dedup`` must move through HBM, from
+shapes: a lower bound for ``kernel.dedup_gather_roofline_pct``, never a
+count of what the program moved.
+
+The pair ``kernel.dedup_gather_device_s`` / ``…roofline_pct`` times the
+programs of one execution that move the payload lanes from the resident
+table into the result: ``csvplus.table.gather_take`` (the index build's
+permutation of the lanes), ``csvplus.dedup.compact`` (since PR 37 one
+sort that carries every lane past the dropped rows) and
+``csvplus.dedup.head`` (the cut to the kept rows).  This file names no
+implementation: lanes that come to ride ``csvplus.index.sort`` (ROADMAP
+S1b) are then ``kernel.index_sort_*``'s seconds, and the pair still has
+the compaction to time.
 
 What every implementation of the step must move, 4 bytes a cell (int32
 value lanes and int32 dictionary codes): the result holds, for every

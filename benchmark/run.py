@@ -9,6 +9,9 @@ per-layer metric is a file found by name (see README.md):
     drivers/<driver>.py   queries/<query>.py   least_bytes/<query>.py
     layer_metrics/<metric>.json   readers/<reader>.py
 
+and a per-layer metric is read in the cells that its entry in
+``BENCHMARK.json`` lists (``layer_metrics_for``).
+
 The run places the compile cache, refuses a machine without the TPU
 the cell asks for (``--rehearse-cpu``, which only a caller passes, is
 the one way onto a CPU), builds the native scanner, generates the data
@@ -47,6 +50,11 @@ ROOT = os.path.dirname(HERE)
 def load_json(*parts: str) -> dict:
     with open(os.path.join(HERE, *parts)) as f:
         return json.load(f)
+
+
+def load_cell(name: str) -> dict:
+    """workloads/<name>.json, carrying its own name."""
+    return dict(load_json("workloads", f"{name}.json"), name=name)
 
 
 def load_module(kind: str, name: str):
@@ -193,15 +201,42 @@ def memory_peak_bytes(devices) -> int:
     return peak
 
 
-def layer_metrics_for(cell_name: str) -> list:
-    """The per-layer metric files that list this cell."""
+def layer_metrics_for(cell: dict) -> list:
+    """The per-layer metrics of one cell: the entries of ``BENCHMARK.json``
+    ``per_layer`` whose ``workloads`` list it, each with its
+    ``layer_metrics/<name>.json`` (``reader``, ``selector``, ``unit``).
+
+    One file is one quantity, read in whichever cells list it.  What
+    differs by cell inside a quantity, the least-bytes file of a roofline
+    share, is the cell's to name (``"least_bytes": {metric: file}`` in its
+    ``workloads/`` file) and the selector's own is the fallback; the
+    reader is handed the selector with the file resolved.  A roofline share
+    with no file, a file that is not there, and a named metric the cell is
+    not listed for end the run here, at start-up."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = [m["name"] for m in json.load(f)["per_layer"] if cell["name"] in m["workloads"]]
+    named = cell.get("least_bytes", {})
+    stray = sorted(set(named) - set(listed))
+    if stray:
+        raise SystemExit(
+            f"benchmark: workloads/{cell['name']}.json names least bytes for {stray}, "
+            "which BENCHMARK.json does not list the cell for"
+        )
     found = []
-    for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics", "*.json"))):
-        with open(path) as f:
-            m = json.load(f)
-        m["name"] = os.path.basename(path)[: -len(".json")]
-        if cell_name in m["workloads"]:
-            found.append(m)
+    for name in listed:
+        m = load_json("layer_metrics", f"{name}.json")
+        m["name"] = name
+        selector = m["selector"] = dict(m.get("selector", {}))
+        if name in named:
+            selector["least_bytes"] = named[name]
+        if selector.get("what", "").endswith("roofline_pct"):
+            least = selector.get("least_bytes")
+            if least is None or not os.path.exists(os.path.join(HERE, "least_bytes", f"{least}.py")):
+                raise SystemExit(
+                    f"benchmark: {name} in {cell['name']}: no least-bytes file {least!r} "
+                    "(the cell's workloads/ file names it, else the metric's selector)"
+                )
+        found.append(m)
     return found
 
 
@@ -233,9 +268,9 @@ def main(argv=None, out=sys.stdout, tamper=None) -> int:
     # the benchmark's own modules (reference, gen, readers), then the
     # program under test (csvplus_tpu)
     sys.path[:0] = [p for p in (HERE, ROOT) if p not in sys.path]
-    cell = load_json("workloads", f"{args.workload}.json")
-    cell["name"] = args.workload
+    cell = load_cell(args.workload)
     cfg = load_json("configs", f"{cell['config']}.json")
+    layer_metrics = layer_metrics_for(cell)
     peaks = load_json("peaks.json")
     driver = load_module("drivers", cell["driver"])
 
@@ -284,12 +319,12 @@ def main(argv=None, out=sys.stdout, tamper=None) -> int:
     h = Harness(cell, cfg, args.seed, platform, root, traced, compiles, out)
     try:
         with compiles.listening():
-            return _run(h, driver, args, devices, device, peaks, tamper)
+            return _run(h, driver, args, devices, device, peaks, layer_metrics, tamper)
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
 
-def _run(h: Harness, driver, args, devices, device, peaks, tamper) -> int:
+def _run(h: Harness, driver, args, devices, device, peaks, layer_metrics, tamper) -> int:
     import jax
 
     from csvplus_tpu.obs.span import tracer
@@ -373,9 +408,9 @@ def _run(h: Harness, driver, args, devices, device, peaks, tamper) -> int:
                 device["window_s"] = red["window_s"]
                 result["breakdown"] = red["breakdown"]
             metrics = {}
-            for m in layer_metrics_for(h.cell["name"]):
+            for m in layer_metrics:
                 reader = load_module("readers", m["reader"])
-                value = reader.read(h, state, samples, m.get("selector", {}))
+                value = reader.read(h, state, samples, m["selector"])
                 if value is not None:
                     metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         else:

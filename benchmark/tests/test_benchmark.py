@@ -12,6 +12,7 @@ from __future__ import annotations
 import io
 import json
 import os
+import shutil
 import sys
 
 import pytest
@@ -23,6 +24,7 @@ sys.path[:0] = [BENCH, HERE]
 
 import run  # noqa: E402
 import tampers  # noqa: E402
+from control_dedup import keep_the_second_copy  # noqa: E402
 from readers import device_trace  # noqa: E402
 
 ROWS = "200000"
@@ -31,6 +33,7 @@ CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+OWN_TAMPER = {"dedup-resident": keep_the_second_copy}  # else tampers.swap_one_value
 
 
 def reported_by(cell: str) -> set:
@@ -103,9 +106,11 @@ def test_second_seed_compiles_nothing_new(cell):
 
 
 @pytest.mark.parametrize("cell", cells_of("batch_query"))
-@pytest.mark.parametrize("nth", [None, 5])  # 5: the second execution of the window
+@pytest.mark.parametrize("nth", [None, 5])  # 5: an execution of the window
 def test_a_swapped_value_makes_the_run_incorrect(cell, nth):
-    rc, _, result = rehearse(cell, 2_500_000_000, tamper=tampers.swap_one_value(nth))
+    """The cell's own tamper where its result has no ``ts`` to swap."""
+    tamper = OWN_TAMPER.get(cell, tampers.swap_one_value)
+    rc, _, result = rehearse(cell, 2_500_000_000, tamper=tamper(nth))
     assert rc == 0 and result["correct"] is False and result["failed"] >= 1
 
 
@@ -212,6 +217,19 @@ PER_LAYER = {m["name"]: m for m in BENCHMARK["per_layer"]}
 LAYER_FILES = {
     name[: -len(".json")] for name in os.listdir(os.path.join(BENCH, "layer_metrics"))
 }
+PER_LAYER_MAX = 128  # the contract's
+
+
+def test_the_per_layer_table_has_room():
+    free = PER_LAYER_MAX - len(BENCHMARK["per_layer"])
+    assert free >= 0, f"per_layer holds {len(BENCHMARK['per_layer'])} entries of {PER_LAYER_MAX}: {free} names free"
+    # one entry a quantity: a name differs only where what is read or what it moves does
+    quantities = {}
+    for name in LAYER_FILES:
+        m = run.load_json("layer_metrics", f"{name}.json")
+        key = (m["reader"], json.dumps(m.get("selector", {}), sort_keys=True), m["moves"])
+        assert key not in quantities, f"{name} and {quantities[key]} are one quantity: list the cell in the entry that is there"
+        quantities[key] = name
 
 
 @pytest.mark.parametrize(
@@ -219,14 +237,18 @@ LAYER_FILES = {
     [("per_layer", n) for n in sorted(set(PER_LAYER) | LAYER_FILES)] + [("cell", c) for c in CELLS],
 )
 def test_benchmark_json_is_consistent(kind, name):
-    """A per-layer metric has its entry and its file, and ``moves`` an
-    end-to-end metric that each of its cells reports; a cell reports
-    ``setup_s`` and exactly one rate, under the name its file gives."""
+    """A per-layer metric has its entry and its file, the entry lists its
+    cells and the file none, and ``moves`` is an end-to-end metric that
+    each of its cells reports; a cell reports ``setup_s`` and exactly one
+    rate, under the name its file gives, and the least-bytes files it
+    names exist, for metrics that list it."""
     if kind == "per_layer":
         assert name in PER_LAYER, f"layer_metrics/{name}.json has no entry in per_layer"
         assert name in LAYER_FILES, f"per_layer entry {name} has no layer_metrics file"
         m = PER_LAYER[name]
+        assert "workloads" not in run.load_json("layer_metrics", f"{name}.json")
         assert m["workloads"] and set(m["workloads"]) <= set(CELLS)
+        assert len(m["workloads"]) == len(set(m["workloads"]))
         for cell in m["workloads"]:
             assert m["moves"] in reported_by(cell), (name, m["moves"], cell)
         return
@@ -236,6 +258,11 @@ def test_benchmark_json_is_consistent(kind, name):
     if cell["driver"] == "batch_query":
         assert {cell.get("metric", "rows_per_s")} == rates
     assert any(name in m["workloads"] and m["moves"] in rates for m in PER_LAYER.values())
+    # start-up's own check: a least-bytes file that is there for every roofline share
+    # of the cell, and no file named for a metric that does not list it
+    assert {m["name"] for m in run.layer_metrics_for(run.load_cell(name))} == {
+        n for n, m in PER_LAYER.items() if name in m["workloads"]
+    }
 
 
 def test_benchmark_json_agrees_with_the_files_found_by_name():
@@ -258,10 +285,84 @@ def test_benchmark_json_agrees_with_the_files_found_by_name():
     listed = {m["name"]: m for m in BENCHMARK["per_layer"]}
     assert set(on_disk) == set(listed)
     for name, m in listed.items():
-        for key in ("layer", "unit", "better", "moves", "source", "workloads"):
+        for key in ("layer", "unit", "better", "moves", "source"):
             assert on_disk[name][key] == m[key], (name, key)
         assert os.path.exists(os.path.join(BENCH, "readers", f"{on_disk[name]['reader']}.py"))
         least = on_disk[name].get("selector", {}).get("least_bytes")
         assert least is None or os.path.exists(os.path.join(BENCH, "least_bytes", f"{least}.py"))
     peaks = run.load_json("peaks.json")
     assert all("source" in p and p["hbm_bytes_per_s"] > 0 for p in peaks.values())
+
+
+def _a_copy_of_the_yardstick(tmp_path, monkeypatch):
+    """``BENCHMARK.json`` and ``benchmark/``'s data files copied under
+    *tmp_path*, and ``run`` pointed at the copy."""
+    copy = tmp_path / "benchmark"
+    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns("tests", "fixtures", "__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    monkeypatch.setattr(run, "HERE", str(copy))
+    monkeypatch.setattr(run, "ROOT", str(tmp_path))
+    return copy
+
+
+def _files_under(root) -> dict:
+    seen = {}
+    for folder, _, names in os.walk(root):
+        if "__pycache__" in folder:
+            continue
+        for name in names:
+            path = os.path.join(folder, name)
+            with open(path, "rb") as f:
+                seen[os.path.relpath(path, root)] = f.read()
+    return seen
+
+
+def test_a_new_cell_joins_a_metric_by_entries_and_files_alone(tmp_path, monkeypatch):
+    """What a later ``model_config`` PR does: a new ``workloads/`` file
+    that names its own least bytes, a new ``least_bytes/`` file, and the
+    cell's name APPENDED to the lists of ``BENCHMARK.json`` — no file that
+    exists under ``benchmark/`` is written."""
+    before = _files_under(BENCH)
+    copy = _a_copy_of_the_yardstick(tmp_path, monkeypatch)
+    cell, metric = "made-up-resident", "kernel.join_emit_roofline_pct"
+    contract = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    for m in contract["per_layer"]:
+        if m["name"] in (metric, "kernel.join_emit_device_s"):
+            m["workloads"].append(cell)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(contract))
+    (copy / "workloads" / f"{cell}.json").write_text(json.dumps(
+        {"config": "orders-star-10m", "driver": "batch_query", "query": "star3",
+         "least_bytes": {metric: "made_up_emit"}}
+    ))
+    (copy / "least_bytes" / "made_up_emit.py").write_text("def least_bytes(cfg, fact_rows):\n    return 7 * fact_rows\n")
+
+    found = {m["name"]: m for m in run.layer_metrics_for(run.load_cell(cell))}
+    assert set(found) == {metric, "kernel.join_emit_device_s"}
+    assert found[metric]["selector"]["least_bytes"] == "made_up_emit"
+    assert found[metric]["reader"] == "kernel_trace" and found[metric]["unit"] == "%"
+    assert run.load_module("least_bytes", "made_up_emit").least_bytes({}, 3) == 21
+    # the cells that were there read what they read
+    selective = {m["name"]: m for m in run.layer_metrics_for(run.load_cell("star3-selective-resident"))}
+    assert selective[metric]["selector"]["least_bytes"] == "star3_selective_emit"
+    assert _files_under(BENCH) == before  # nothing under benchmark/ written, added or removed
+    kept = _files_under(copy)
+    added = set(kept) - {k for k in before if not k.startswith(("tests", "fixtures"))}
+    assert added == {f"workloads/{cell}.json", "least_bytes/made_up_emit.py"}
+    assert all(kept[k] == before[k] for k in kept if k not in added)
+
+
+@pytest.mark.parametrize("fault", ["no file named", "a file that is not there", "a metric that does not list the cell"])
+def test_a_roofline_share_without_least_bytes_ends_the_run_at_start_up(fault, tmp_path, monkeypatch):
+    copy = _a_copy_of_the_yardstick(tmp_path, monkeypatch)
+    cell = run.load_json("workloads", "star3-resident.json")
+    if fault == "no file named":
+        del cell["least_bytes"]["kernel.join_emit_roofline_pct"]  # and the selector has none
+    elif fault == "a file that is not there":
+        cell["least_bytes"]["kernel.join_emit_roofline_pct"] = "nowhere"
+    else:
+        cell["least_bytes"]["kernel.pjoin_probe_roofline_pct"] = "lookupjoin_probe"
+    (copy / "workloads" / "star3-resident.json").write_text(json.dumps(cell))
+    out = io.StringIO()
+    with pytest.raises(SystemExit, match="least.bytes"):
+        run.main(["--workload", "star3-resident", "--seed", "1", "--seconds", "1", "--trace", "0", "--rehearse-cpu"], out=out)
+    assert out.getvalue() == ""

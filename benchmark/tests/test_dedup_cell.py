@@ -4,11 +4,9 @@ refusal, its control and the selectors of its per-layer metrics
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
 
 (``test_benchmark.py`` rehearses the cell end to end with the others:
-every case there that is parametrised by cell runs it too.  One of them
-cannot pass: ``test_a_swapped_value_makes_the_run_incorrect[None-dedup-resident]``,
-whose tamper swaps two cells of a ``ts`` column that this query's result
-does not have, so the warm-up raises; the cell's own tamper is
-``control_dedup.keep_the_second_copy``, below.)
+every case there that is parametrised by cell runs it too, the tampered
+run with this cell's own tamper, ``control_dedup.keep_the_second_copy``:
+this query's result has no ``ts`` column to swap.)
 """
 
 from __future__ import annotations
@@ -24,7 +22,6 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
-REPO = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, HERE]
 
 import reference as ref  # noqa: E402
@@ -34,13 +31,11 @@ from readers import counters, device_trace, kernel_trace, stage_extra, stage_sel
 
 CELL = "dedup-resident"
 CFG = run.load_json("configs", "people-dedup-50m.json")
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    NEW_METRICS = sorted(m["name"] for m in json.load(_f)["per_layer"] if m["workloads"] == [CELL])
 READERS = {m.__name__.split(".")[-1]: m for m in (counters, device_trace, kernel_trace, stage_extra, stage_self, stage_table)}
-
-
-def metric(name: str) -> dict:
-    return run.load_json("layer_metrics", f"{name}.json")
+# every metric whose entry lists the cell, its least-bytes file resolved as a run resolves it
+LISTED = {m["name"]: m for m in run.layer_metrics_for(run.load_cell(CELL))}
+# those a hand-made execution can feed: the stage table, the kernels, the facts (not the process journal)
+WINDOW_METRICS = sorted(n for n, m in LISTED.items() if m["reader"] in READERS)
 
 
 def generated(tmp_path, seed, rows=30_000):
@@ -118,10 +113,10 @@ def test_the_cell_runs_the_device_path_and_says_so():
 def test_a_traced_rehearsal_reports_every_metric_the_cpu_can_read():
     rc, _, result = rehearse(3_400_000_034, trace=1)
     assert rc == 0 and result["correct"] is True
-    readable = {n for n in NEW_METRICS if metric(n)["source"] != "device_trace"}
-    assert len(NEW_METRICS) == 14 and set(result["metrics"]) == readable  # no device plane on the CPU
-    assert result["metrics"]["dedup.host_sync_elems"]["value"] == 1
-    assert result["metrics"]["index.row_gathers"]["value"] == 5
+    readable = {n for n, m in LISTED.items() if m["source"] != "device_trace"}
+    assert set(result["metrics"]) == readable  # no device plane on the CPU
+    assert result["metrics"]["process.host_sync_elems"]["value"] == 1
+    assert result["metrics"]["index.row_gathers"]["value"] == 2  # the permutation's; the compaction's lanes ride a sort
 
 
 @pytest.mark.parametrize("nth", [None, 4])  # 4: the second execution of the window
@@ -198,34 +193,35 @@ def harness(per_exec, kernels, syncs):
 
 
 def read(name, h):
-    m = metric(name)
-    assert m["workloads"] == [CELL]
+    m = LISTED[name]
     return READERS[m["reader"]].read(h, None, None, m["selector"])
 
 
 def test_every_new_metrics_selector_finds_its_number():
     h = harness([EXECUTION, EXECUTION], KERNELS, [1, 1])
-    got = {name: read(name, h) for name in NEW_METRICS}
+    got = {name: read(name, h) for name in WINDOW_METRICS}
     assert all(v is not None for v in got.values()), got
     assert got["index.build_host_s"] == pytest.approx(0.01 + 1.5 + 0.7 + 0.004)
     assert got["dedup.resolve_host_s"] == pytest.approx(1.35)
     assert got["dedup.host_self_s"] == pytest.approx(0.24 + 0.2)
-    assert got["dedup.host_sync_elems"] == 1 and got["index.row_gathers"] == 5
+    assert got["process.host_sync_elems"] == 1 and got["index.row_gathers"] == 5
     assert got["kernel.index_sort_device_s"] == pytest.approx(1.5)
-    assert got["kernel.dedup_gather_device_s"] == pytest.approx(1.6)
+    moved = (3.2 + 0.8) / 2  # the programs that move the lanes: gather_take and the compaction (no head in this table)
+    assert got["kernel.dedup_gather_device_s"] == pytest.approx(moved)
     busy = sum(KERNELS.values())
-    assert got["device.unnamed_busy_pct.dedup"] == pytest.approx(100 * 0.01 / busy)
-    assert got["device.idle_pct.dedup"] == pytest.approx(2.0)
-    assert got["device.peak_hbm_bytes.dedup"] == 2_600_000_000 and got["admit.first_exec_s.dedup"] == 20.0
+    assert got["device.unnamed_busy_pct.batch"] == pytest.approx(100 * 0.01 / busy)
+    assert got["device.idle_pct.batch"] == pytest.approx(2.0)
+    assert got["device.peak_hbm_bytes.batch"] == 2_600_000_000 and got["admit.first_exec_s"] == 20.0
     # the shares of a roofline: each least_bytes file's count over the peak, over the kernel's seconds
     sort_least = 4 * 3 * ROWS  # key read, sorted key and permutation written
     assert got["kernel.index_sort_roofline_pct"] == pytest.approx(100 * (sort_least / 819e9) / 1.5)
     gather_least = 4 * 2 * 3 * 45_000_000  # each emitted lane's survivors read once and written once
-    assert got["kernel.dedup_gather_roofline_pct"] == pytest.approx(100 * (gather_least / 819e9) / 1.6)
+    assert got["kernel.dedup_gather_roofline_pct"] == pytest.approx(100 * (gather_least / 819e9) / moved)
     whole = 4 * (3 * ROWS + 4 * 45_000_000)  # three lanes in; three lanes and the packed key out
     assert run.load_module("least_bytes", "dedup").least_bytes(CFG, ROWS) == whole
-    assert got["device.bytes_roofline_pct.dedup"] == pytest.approx(100 * (whole / 819e9) / (busy / 2))
-    assert all(got[n] < 100 for n in NEW_METRICS if n.endswith("roofline_pct") or "roofline_pct." in n)
+    assert LISTED["device.bytes_roofline_pct"]["selector"]["least_bytes"] == "dedup"  # the cell names its own
+    assert got["device.bytes_roofline_pct"] == pytest.approx(100 * (whole / 819e9) / (busy / 2))
+    assert all(got[n] < 100 for n in WINDOW_METRICS if n.endswith("roofline_pct") or "roofline_pct." in n)
 
 
 def test_on_the_parent_a_new_metric_is_left_out_and_nothing_raises():
@@ -233,9 +229,9 @@ def test_on_the_parent_a_new_metric_is_left_out_and_nothing_raises():
     side of a comparison): None, which leaves the metric out of the line."""
     kernels = {"sort_kernel": 3.0, "csvplus.table.gather_take": 3.2}  # the parent's sort carries jax's name
     h = harness([PARENT, PARENT], kernels, [0, 0])
-    got = {name: read(name, h) for name in NEW_METRICS}
+    got = {name: read(name, h) for name in WINDOW_METRICS}
     for name in ("index.build_host_s", "dedup.resolve_host_s", "dedup.host_self_s", "index.row_gathers",
                  "kernel.index_sort_device_s", "kernel.index_sort_roofline_pct"):
         assert got[name] is None
-    assert got["dedup.host_sync_elems"] == 0  # the parent counts none of its reads
+    assert got["process.host_sync_elems"] == 0  # the parent counts none of its reads
     assert got["kernel.dedup_gather_device_s"] == pytest.approx(1.6)

@@ -1,8 +1,8 @@
 """The one-to-many cell ``statements-fanout-resident`` rehearsed on the
 CPU: the first join takes the ``fan-out`` path with its expansion on the
 device and the second the identity path, the result is exact on two
-seeds with the configuration's shapes, the per-layer metric that lists
-the cell is reported, the set-up refusal refuses a
+seeds with the configuration's shapes, every per-layer metric that lists
+the cell and that a CPU can read is reported, the set-up refusal refuses a
 host-tier program and a program that expands nothing, and the cell's own
 control (``control_fanout.py``) is caught.  By hand, with the other tests
 of this directory."""
@@ -31,8 +31,19 @@ def test_a_traced_rehearsal_takes_the_fan_out_path_on_the_device():
         rc, lines, result = rehearse(CELL, seed, trace=1)
         assert rc == 0 and result["correct"] is True and result["failed"] == 0
         listed = {m["name"]: m for m in BENCHMARK["per_layer"] if CELL in m["workloads"]}
-        assert set(result["metrics"]) == set(listed) == {"join.expand_host_s.fan"}
-        assert result["metrics"]["join.expand_host_s.fan"]["value"] > 0
+        missing = set(listed) - set(result["metrics"])
+        # no device plane on the CPU: only the device_trace metrics may be missing
+        assert all(listed[m]["source"] == "device_trace" for m in missing), missing
+        assert set(result["metrics"]) <= set(listed) and len(listed) >= 24
+        m = {k: v["value"] for k, v in result["metrics"].items()}
+        assert m["join.expand_host_s"] >= m["join.expand_host_self_s"] >= 0
+        assert m["join.expand_rows_out"] == 2 * int(ROWS)  # the fan-out's rows, then the identity path's
+        assert m["join.expand_padded"] == padded  # the fan-out's slots; the identity path pads nothing
+        assert m["join.expand_row_gathers"] == 2
+        assert m["join.expand_host_sync_elems"] == m["process.host_sync_elems"] == 4  # two stats reads of two scalars
+        assert m["join.row_gathers"] >= 7 + 2  # seven lanes emitted by the first join, two by the second
+        assert m["join.run_copy_lanes"] == m["join.vmem_gather_lanes"] == 0  # both kernels engage on a TPU only
+        assert m["exec.plan_nodes_host_s"] >= m["join.stages_host_s"] > 0
         stages = next(ln for ln in lines if "first execution's stages" in ln)
         expands = re.findall(r"join:expand(\{.*?\})", stages)
         assert len(expands) == 2
@@ -47,7 +58,58 @@ def test_a_traced_rehearsal_takes_the_fan_out_path_on_the_device():
         assert "'build_gathers': 2, 'stream_gathers': 0" in merges[1]
         assert any(ln.startswith("check: host executor equals the generator") for ln in lines)
         seen.append(int(re.search(r"'max_run': (\d+)", fan).group(1)))
+        assert m["join.expand_max_run"] == seen[-1]
     assert all(m > 1 for m in seen)  # the longest run is the seed's; every other shape above is not
+
+
+def test_the_least_bytes_are_the_configurations():
+    """The whole query: every table lane read once, nine result lanes
+    written once.  The emit: the probes' answers, every source lane but
+    stock's key, the nine result lanes — and no term for the ids the
+    expansion materialises (2 x 16,777,216 today)."""
+    cfg = run.load_json("configs", "orders-by-customer-10m.json")
+    rows = int(cfg["tables"]["orders"]["rows"])
+    least = {q: run.load_module("least_bytes", q).least_bytes(cfg, rows) for q in ("statements", "statements_emit")}
+    assert least["statements"] == 4 * (4 * rows + 3 * 100_000 + 3 * 1_000 + 9 * rows) == 521_212_000
+    assert least["statements_emit"] == 4 * (2 * 100_000 + 4 * rows + 3 * 100_000 + 2 * 1_000 + 9 * rows) == 522_008_000
+    named = run.load_json("workloads", f"{CELL}.json")["least_bytes"]
+    assert named == {"device.bytes_roofline_pct": "statements", "kernel.join_emit_roofline_pct": "statements_emit"}
+
+
+def test_the_device_metrics_select_the_cells_programs_and_least_bytes():
+    """The kernel readers over a hand-made reduction of three executions
+    (PR 47's split): the expansion's seconds are ``csvplus.join.expand``
+    with its cut, the emit's are every ``gather*`` and ``expand*`` program,
+    and both shares stand on this cell's own least bytes, under 100%."""
+    from types import SimpleNamespace
+
+    from readers import device_trace, kernel_trace
+
+    kernels = {"csvplus.join.expand": 0.3711, "csvplus.join.expand_head": 0.0024, "csvplus.join.gather_cols": 0.3105,
+               "csvplus.join.gather_runs": 0.0258, "csvplus.join.probe_stats": 0.006, "jit__take": 0.1461}
+    busy = sum(kernels.values())
+    red = {"kernels": kernels, "calls": {}, "busy_s": busy, "cycles": 0, "unnamed_s": kernels["jit__take"]}
+    cfg = run.load_json("configs", "orders-by-customer-10m.json")
+    h = SimpleNamespace(
+        evidence={"kernel_trace": red, "facts": {"executions": 3}, "peaks": {"hbm_bytes_per_s": 819e9},
+                  "trace": {"busy_s": busy, "window_s": busy / 0.94}},
+        cfg=cfg, data=SimpleNamespace(n=10_000_000), load_module=run.load_module,
+    )
+    listed = {m["name"]: m for m in run.layer_metrics_for(run.load_cell(CELL))}
+
+    def read(name):
+        reader = {"kernel_trace": kernel_trace, "device_trace": device_trace}[listed[name]["reader"]]
+        return reader.read(h, None, None, listed[name]["selector"])
+
+    assert read("kernel.join_expand_device_s") == pytest.approx((0.3711 + 0.0024) / 3)
+    emit = (0.3711 + 0.0024 + 0.3105 + 0.0258) / 3
+    assert read("kernel.join_emit_device_s") == pytest.approx(emit)
+    assert read("kernel.join_probe_device_s") == pytest.approx(0.002)
+    assert read("kernel.join_emit_roofline_pct") == pytest.approx(100 * (522_008_000 / 819e9) / emit)
+    assert read("device.bytes_roofline_pct") == pytest.approx(100 * (521_212_000 / 819e9) / (busy / 3))
+    assert read("device.unnamed_busy_pct.batch") == pytest.approx(100 * 0.1461 / busy)
+    assert read("device.idle_pct.batch") == pytest.approx(6.0)
+    assert 0 < read("device.bytes_roofline_pct") < read("kernel.join_emit_roofline_pct") < 100
 
 
 def test_every_customer_places_an_order_at_any_size():

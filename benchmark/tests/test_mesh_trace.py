@@ -70,7 +70,7 @@ def test_the_translate_metric_reads_the_program_the_mesh_cell_runs(fx):
     nested in it counted once, per plane and averaged, per execution."""
     with open(os.path.join(BENCH, "layer_metrics", "kernel.join_translate_device_s.mesh.json")) as f:
         metric = json.load(f)
-    assert metric["reader"] == "kernel_trace" and metric["workloads"] == ["lookupjoin-mesh4"]
+    assert metric["reader"] == "kernel_trace" and metric["moves"] == "rows_per_s.mesh"
     red = kernel_trace.reduce_kernels(fx["ops"], fx["modules"], fx["host"])
     want = mean_s(fx["expect_ns"]["translate_per_plane"])
     assert red["kernels"]["csvplus.typed.translate_sorted"] == pytest.approx(want)

@@ -31,7 +31,7 @@ def harness(per_exec):
 
 
 def test_the_metric_reads_the_rounds_the_exchange_stage_records():
-    assert METRIC["reader"] == "stage_extra" and METRIC["workloads"] == ["lookupjoin-mesh4"]
+    assert METRIC["reader"] == "stage_extra" and METRIC["moves"] == "rows_per_s.mesh"
     assert SEL == {"stages": ["join:all_to_all"], "key": "search_rounds"}
     search = [
         stage("join:translate", row_gathers=27), stage("join:skew-detect", hot_keys=0),

@@ -32,14 +32,14 @@ def test_a_traced_rehearsal_reports_every_metric_that_lists_the_cell():
         assert all(listed[m]["source"] == "device_trace" for m in missing), missing
         assert set(result["metrics"]) <= set(listed)
         m = {k: v["value"] for k, v in result["metrics"].items()}
-        assert m["join.expand_rows_out.sel"] == survivors
-        assert m["join.expand_host_sync_elems.sel"] == m["join.host_sync_elems.sel"] == 3
-        assert m["join.expand_host_s.sel"] >= m["join.expand_host_self_s.sel"] >= 0
-        assert m["join.row_gathers.sel"] >= 9 + m["join.expand_row_gathers.sel"]  # nine emitted lanes
+        assert m["join.expand_rows_out"] == survivors
+        assert m["join.expand_host_sync_elems"] == m["process.host_sync_elems"] == 3
+        assert m["join.expand_host_s"] >= m["join.expand_host_self_s"] >= 0
+        assert m["join.row_gathers.sel"] >= 9 + m["join.expand_row_gathers"]  # nine emitted lanes
         stages = next(ln for ln in lines if "first execution's stages" in ln)
         assert "'path': 'multiway-unique-partial'" in stages and "'tier': 'device'" in stages
         assert any(ln.startswith("check: host executor keeps ") for ln in lines)
-        seen.append((m["join.expand_rows_out.sel"], m["join.expand_row_gathers.sel"], m["join.row_gathers.sel"]))
+        seen.append((m["join.expand_rows_out"], m["join.expand_row_gathers"], m["join.row_gathers.sel"]))
     assert seen[0] == seen[1]  # the configuration's, not the seed's
 
 
